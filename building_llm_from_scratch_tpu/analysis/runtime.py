@@ -7,8 +7,8 @@ armed together:
   - ``jax.transfer_guard_device_to_host("disallow")`` — the real C++
     guard. On TPU/GPU it rejects every implicit d->h transfer while
     letting explicit ``jax.device_get`` through. On the CPU backend the
-    device buffer *is* host memory, so this guard never fires there
-    (measured on jax 0.4.37) — which is why the second layer exists;
+    device buffer *is* host memory, so this guard never fires there —
+    which is why the second layer exists;
   - a Python-level sentry that patches the jax array type's implicit
     conversion dunders (``__float__``/``__int__``/``__bool__``/
     ``__index__``/``item``) and wraps ``numpy.asarray``/``numpy.array``
@@ -56,10 +56,10 @@ def no_implicit_device_to_host(allow: Tuple[str, ...] = ()):
     through ``jax.device_get`` — the engine tick and the trainer's
     cadence flush already do (graft-lint GL01x keeps it that way)."""
     import jax
-    import jaxlib.xla_extension as xe
     import numpy as _np
 
-    array_cls = xe.ArrayImpl
+    # the concrete class behind jax.Array (its dunders are what convert)
+    array_cls = type(jax.numpy.zeros(()))
     saved: Dict[str, object] = {}
 
     def _make_trap(name: str, orig):

@@ -752,11 +752,12 @@ def get_args(argv=None):
                              "loop. 0 (default) logs at --eval_freq "
                              "cadence, the historical behavior.")
     parser.add_argument("--compile_cache_dir", type=str, default=None,
-                        help="Enable JAX's persistent compilation cache at "
-                             "this directory: relaunches (the preemption-"
-                             "resume loop) skip XLA compiles. The compile "
-                             "telemetry event records cache hit/miss and "
-                             "entry counts.")
+                        help="Directory of JAX's persistent compilation "
+                             "cache when JAX_COMPILATION_CACHE_DIR is "
+                             "unset (default: .jax_cache/ in the checkout): "
+                             "relaunches (the preemption-resume loop) skip "
+                             "XLA compiles. The compile telemetry event "
+                             "records cache hit/miss and entry counts.")
     parser.add_argument("--stall_timeout", type=float, default=0.0,
                         help="Opt-in per-host stall detector: if no train "
                              "step completes within this many seconds (or "

@@ -26,8 +26,9 @@ TPU-first differences from the reference:
     renders it), ``--log_every`` throughput/MFU/memory cadence decoupled
     from eval, ``--stall_timeout`` per-host hung-step flight recorder,
     per-layer-group training health + AOT compile/recompile telemetry
-    (obs/health.py, obs/compile.py), ``--compile_cache_dir`` persistent
-    XLA compilation cache.
+    (obs/health.py, obs/compile.py), persistent XLA compilation cache
+    (``JAX_COMPILATION_CACHE_DIR``, else ``--compile_cache_dir``, else
+    ``.jax_cache`` in the checkout).
 
 Usage:  python -m building_llm_from_scratch_tpu --data_dir ... [flags]
 """
@@ -43,6 +44,7 @@ from building_llm_from_scratch_tpu.build_components import build_components
 from building_llm_from_scratch_tpu.data.instruct import InstructLoader
 from building_llm_from_scratch_tpu.obs import (
     StallDetector,
+    configure_compile_cache,
     configure_metrics,
     emit_event,
     run_metadata,
@@ -77,6 +79,11 @@ def main(args):
     DecodeEngine with its serve stats) for callers/tests."""
     import jax
 
+    # BEFORE any compile (the component build device_puts and the first
+    # train step both lower programs): a relaunched preempted job skips
+    # its multi-minute XLA compiles entirely
+    cache_dir = configure_compile_cache(args.compile_cache_dir)
+
     # 1. distributed runtime + reproducibility (reference main.py:49-58)
     initialize_distributed()
     configure_default_prng()
@@ -88,13 +95,6 @@ def main(args):
     #    until the run-metadata header lands below. Then components
     #    (reference main.py:63).
     metric_logger = configure_metrics(args.metrics_jsonl)
-    if args.compile_cache_dir:
-        # BEFORE any compile (the component build device_puts and the
-        # first train step both lower programs): a relaunched preempted
-        # job skips its multi-minute XLA compiles entirely
-        from building_llm_from_scratch_tpu.obs import enable_persistent_cache
-
-        enable_persistent_cache(args.compile_cache_dir)
     comps = build_components(args)
     cfg = comps.cfg
     metric_logger.write_header(
@@ -207,7 +207,7 @@ def main(args):
         stopper=stopper,
         log_every=args.log_every,
         stall=stall,
-        compile_cache_dir=args.compile_cache_dir,
+        compile_cache_dir=cache_dir,
         prefetch=args.prefetch,
         async_ckpt=(args.async_ckpt == "on"),
     )

@@ -252,6 +252,38 @@ def _use_fused_dropout(shape) -> bool:
     return supports_shape(shape)
 
 
+def _use_fused_decode(cfg: ModelConfig, cache: Params, Tq: int) -> bool:
+    """THE rule for the pallas fused append+attend decode kernel
+    (ops/decode_step.fused_decode_step), shared by one-shot
+    ``forward_with_cache`` and the engine's ``decode_slots``: opt-in with
+    ``BLLM_FUSED_DECODE=1``, on TPU, for unquantized caches (int8 caches
+    keep the XLA path: ``decode_attention`` folds the scale sidecars into
+    its einsums, the kernel has no dequant pass) of a shape
+    ``supports_shape`` admits.
+
+    Off by default, on the evidence there is. The kernel compiles for
+    v5e and matches the XLA path alone (chip_smoke.py's kernels phase),
+    but inside a whole GPT2-124M decode program — six layers or more —
+    the compiler assigns whole (S, Hkv, Tmax, hd) cache arrays to VMEM
+    beside the kernel's own scope and refuses the program ("scoped
+    allocation 24.00M, limit 16.00M"; with a raised ``vmem_limit_bytes``
+    it only assigns more). The engine took this kernel unconditionally
+    before it had ever been compiled for a chip. For one shared scalar
+    length the one A/B on record measured it 3% slower on GPT2-124M bs8.
+    ROADMAP S1/S5 own the re-measurement."""
+    import os
+
+    from building_llm_from_scratch_tpu.ops.decode_step import supports_shape
+
+    pane = cache["k"][0]                       # (B, Hkv, Tmax, hd)
+    return (os.environ.get("BLLM_FUSED_DECODE", "0") == "1"
+            and jax.default_backend() == "tpu"
+            and not _cache_quantized(cache)
+            and supports_shape(Tq, pane.shape[2], cfg.head_dim,
+                               Hkv=cfg.n_kv_groups, Hq=cfg.n_heads,
+                               itemsize=pane.dtype.itemsize))
+
+
 def _dropout(x: jnp.ndarray, rate: float, rng: Optional[jax.Array],
              deterministic: bool) -> jnp.ndarray:
     if rate <= 0.0 or deterministic:
@@ -761,25 +793,7 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if lora is not None and lora_blocks_list is None:
         lora_blocks_list = unstack_lora_blocks(lora, cfg)
 
-    import os as _os
-
-    # BLLM_FUSED_DECODE=1 opts into the pallas fused append+attend kernel
-    # (ops/decode_step.py). It provably removes the per-token whole-cache
-    # copies XLA inserts on the while-loop carry, but measured 3% SLOWER
-    # end-to-end on GPT2-124M bs8 (690 vs 715 tok/s/seq, r5 A/B x3): its
-    # per-batch-row grid serializes attention panes the XLA path overlaps
-    # with the surrounding weight streams. On GQA (LLaMA3.2-1B bs8) the
-    # A/B is dead-even (224.1 vs 224.4 tok/s/seq — weight streaming
-    # dominates at 1B). Kept for future tuning; default off.
-    use_fused_step = False
-    if (jax.default_backend() == "tpu"
-            and _os.environ.get("BLLM_FUSED_DECODE", "0") == "1"):
-        from building_llm_from_scratch_tpu.ops.decode_step import (
-            supports_shape as _fds_supports,
-        )
-
-        Tmax = cache["k"][0].shape[2]
-        use_fused_step = _fds_supports(Tq, Tmax, cfg.head_dim)
+    use_fused_step = _use_fused_decode(cfg, cache, Tq)
 
     new_k, new_v = [], []
     for l, (p, K, V) in enumerate(zip(blocks_list, cache["k"], cache["v"])):
@@ -1115,8 +1129,9 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  ) -> Tuple[jnp.ndarray, Params]:
     """One decode tick for the whole slot batch: ``tokens`` (S, 1) are each
     slot's last accepted token, ``lengths`` (S,) its valid cache prefix.
-    Appends each row's k/v at ITS offset (ops/decode_step.slot_cache_append
-    — pallas in-place on TPU) and attends with per-row masks; returns
+    Appends each row's k/v at ITS offset (ops/decode_step.slot_cache_append;
+    the pallas fused step where ``_use_fused_decode`` says so) and attends
+    with per-row masks; returns
     (fp32 logits (S, V), updated cache). Free/finished slots compute
     garbage rows the engine ignores — the shapes never change, so XLA
     compiles exactly one decode program.
@@ -1135,17 +1150,7 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if blocks_list is None:
         blocks_list = unstack_blocks(params, cfg)
 
-    from building_llm_from_scratch_tpu.ops.decode_step import (
-        supports_shape as _fds_supports,
-    )
-
-    Tmax = cache["k"][0].shape[2]
-    # int8 caches keep the XLA path: decode_attention folds the scale
-    # sidecars into its einsums; the pallas kernel has no dequant pass
-    # yet (see ops/decode_step.supports_shape)
-    use_fused_step = (jax.default_backend() == "tpu"
-                      and not _cache_quantized(cache)
-                      and _fds_supports(1, Tmax, cfg.head_dim))
+    use_fused_step = _use_fused_decode(cfg, cache, 1)
 
     if _use_bgmv(adapter, cfg):
         ids = adapter["ids"].astype(jnp.int32)

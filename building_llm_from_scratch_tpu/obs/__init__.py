@@ -14,7 +14,7 @@ Entry points:
   - ``group_health`` / ``group_names`` / ``describe_health`` — in-graph
     per-layer-group gradient/param/update norms + non-finite localization
     (obs/health.py);
-  - ``CompileWatcher`` / ``aot_compile`` / ``enable_persistent_cache`` —
+  - ``CompileWatcher`` / ``aot_compile`` / ``configure_compile_cache`` —
     AOT compile capture, HLO cost/memory analysis, recompile detection,
     persistent-cache wiring (obs/compile.py);
   - ``StallDetector`` — opt-in hung-step flight recorder (obs/stall.py);
@@ -33,7 +33,7 @@ Entry points:
 from building_llm_from_scratch_tpu.obs.compile import (
     CompileWatcher,
     aot_compile,
-    enable_persistent_cache,
+    configure_compile_cache,
 )
 from building_llm_from_scratch_tpu.obs.health import (
     describe_health,
@@ -104,7 +104,7 @@ __all__ = [
     "mfu_from_flops",
     "CompileWatcher",
     "aot_compile",
-    "enable_persistent_cache",
+    "configure_compile_cache",
     "describe_health",
     "first_nonfinite_group",
     "group_health",

@@ -18,7 +18,7 @@ watcher emits a ``recompile`` event naming the exact leaf-path shape/dtype
 diff, then captures the new executable the same way. Steady-state calls are
 a dict lookup + the dispatch itself.
 
-``--compile_cache_dir`` enables JAX's persistent compilation cache with
+``configure_compile_cache`` places JAX's persistent compilation cache with
 entry-count/bytes telemetry: the compile event records whether this
 process's compile was served from cache (no new entries written) or paid
 for (new entries landed), so relaunch latency is measurable.
@@ -333,6 +333,12 @@ class CompileWatcher:
         except OSError:
             return None
 
+    @property
+    def executables(self) -> List[Any]:
+        """The compiled programs captured so far; ``.as_text()`` is the
+        optimized HLO (chip_smoke.py looks for the kernels in it)."""
+        return list(self._compiled.values())
+
     def freeze(self) -> None:
         """Close the legitimate-signature set (multi_program mode): the
         serving engine calls this after warming its prefill buckets and
@@ -445,25 +451,38 @@ class CompileWatcher:
         return fn(*args)
 
 
-def enable_persistent_cache(cache_dir: str) -> None:
-    """Wire JAX's persistent compilation cache at ``cache_dir``
-    (--compile_cache_dir): relaunches — the preemption-resume loop — skip
-    the multi-minute XLA compile entirely. Thresholds are zeroed so every
-    executable is eligible (default jax skips sub-second compiles, which
-    would make smoke-test telemetry read as permanent misses)."""
+#: where compiled programs persist when nothing says otherwise: a fixed
+#: path inside the checkout (git-ignored). The path is part of the cache
+#: key's neighbourhood — a directory that moves never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def configure_compile_cache(cache_dir: Optional[str] = None) -> str:
+    """Place JAX's persistent compilation cache — THE one rule, called
+    first thing by every entry point (main, bench, fleet worker,
+    chip_smoke): ``JAX_COMPILATION_CACHE_DIR``, when set, wins and no
+    directory is set in code (jax reads the variable itself); otherwise
+    ``cache_dir`` (--compile_cache_dir) or ``DEFAULT_CACHE_DIR``. A
+    relaunch — the preemption-resume loop, the next run of the same
+    command on the chip — then skips its XLA compiles. Thresholds are
+    zeroed so every executable is eligible (default jax skips sub-second
+    compiles, which would make smoke-test telemetry read as permanent
+    misses). Returns the directory in use."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
-    os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:
-        # any compile BEFORE the dir is set (set_seed's PRNG key, a device
-        # put) initializes the cache machinery in its disabled state, and
-        # set_cache_dir alone cannot revive it — reset first (measured on
-        # jax 0.4.37: without this the dir stays empty forever)
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    cache_dir = cache_dir or DEFAULT_CACHE_DIR
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # a compile BEFORE the dir is set (a PRNG key, a device put)
+        # memoizes "no cache" — drop that so the new dir takes effect
         compilation_cache.reset_cache()
-    except Exception:
-        pass
-    compilation_cache.set_cache_dir(cache_dir)
-    logger.info("Persistent compilation cache at %s", cache_dir)
+        logger.info("Persistent compilation cache at %s", cache_dir)
+    return cache_dir

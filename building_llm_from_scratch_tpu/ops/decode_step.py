@@ -29,6 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from building_llm_from_scratch_tpu.parallel.collectives import mesh_kernel
+from building_llm_from_scratch_tpu.parallel.mesh import MODEL_AXIS
+
 _NEG_BIG = -1e30
 # mosaic wants >= 8 sublanes; decode's G*Tq is often 1 — pad the query rows
 _MIN_ROWS = 8
@@ -45,7 +48,7 @@ def _kernel(len_ref, q_ref, kn_ref, vn_ref, K_ref, V_ref,
     ``pl.multiple_of((t // 8) * 8, 8)`` supplies the proof), merging the
     new row into it; the attention then reads the full pane from VMEM.
     """
-    t = len_ref[0, 0]
+    t = len_ref[pl.program_id(0)]
     t8 = pl.multiple_of((t // 8) * 8, 8)
     Hkv, Tmax, hd = K_ref.shape[1:]
 
@@ -111,8 +114,8 @@ def slot_cache_append(cache: jnp.ndarray, new: jnp.ndarray,
 
     Scalar ``lengths`` degrades to the shared-offset single
     ``dynamic_update_slice`` the one-shot decode path uses. The vmap'd
-    per-row form lowers to a batched DUS; on TPU the serving engine routes
-    single-token appends through the pallas kernel below instead (which
+    per-row form lowers to a batched DUS; ``fused_decode_step`` below is
+    the opt-in pallas alternative for single-token appends (it
     additionally aliases the cache in place).
     """
     lengths = jnp.asarray(lengths)
@@ -126,7 +129,8 @@ def slot_cache_append(cache: jnp.ndarray, new: jnp.ndarray,
     return jax.vmap(one)(cache, new, lengths.astype(jnp.int32))
 
 
-def fused_decode_step(q, k_new, v_new, k_cache, v_cache, length):
+def fused_decode_step(q, k_new, v_new, k_cache, v_cache, length, *,
+                      interpret=False):
     """Append k_new/v_new at ``length`` (IN PLACE via aliasing) and attend.
 
     q:                (B, Tq, Hq, hd)   — model layout, Tq small
@@ -137,8 +141,23 @@ def fused_decode_step(q, k_new, v_new, k_cache, v_cache, length):
                       grid already runs one cell per batch row, so each
                       cell simply reads ITS row's length from SMEM.
 
-    Returns (out (B, Tq, Hq, hd), k_cache', v_cache').
+    Returns (out (B, Tq, Hq, hd), k_cache', v_cache'). Under a mesh
+    (``--serve_tp``) heads shard over the model axis, like the slot
+    cache (``MeshPlan.cache_spec``); ``interpret=True`` runs the kernel
+    on CPU for parity tests.
     """
+    length = jnp.asarray(length, jnp.int32)
+    heads = (None, None, MODEL_AXIS, None)
+    panes = (None, MODEL_AXIS, None, None)
+    return mesh_kernel(
+        lambda _, *a: _decode_step_local(*a, interpret=interpret),
+        (q, k_new, v_new, k_cache, v_cache, length),
+        (heads, heads, heads, panes, panes, (None,) * length.ndim),
+        (heads, panes, panes))
+
+
+def _decode_step_local(q, k_new, v_new, k_cache, v_cache, length, *,
+                       interpret):
     B, Tq, Hq, hd = q.shape
     _, Hkv, Tmax, _ = k_cache.shape
     if Tq != 1:
@@ -153,28 +172,30 @@ def fused_decode_step(q, k_new, v_new, k_cache, v_cache, length):
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
     knt = k_new.transpose(0, 2, 1, 3)                 # (B, Hkv, Tq, hd)
     vnt = v_new.transpose(0, 2, 1, 3)
-    # (B, 1) per-row lengths in SMEM; a scalar broadcasts to every row
-    len2 = jnp.broadcast_to(
-        jnp.reshape(jnp.asarray(length, jnp.int32), (-1, 1)), (B, 1))
+    # (B,) per-row lengths, scalar-prefetched whole into SMEM (a (1, 1)
+    # SMEM block of a (B, 1) array is not a legal mosaic tile); a scalar
+    # broadcasts to every row
+    lens = jnp.broadcast_to(jnp.reshape(length, (-1,)), (B,))
 
     blk = lambda rows: pl.BlockSpec((1, Hkv, rows, hd),
-                                    lambda b: (b, 0, 0, 0))
+                                    lambda b, len_ref: (b, 0, 0, 0))
     ko, vo, out = pl.pallas_call(
         functools.partial(_kernel, scale=1.0 / float(hd) ** 0.5),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b: (b, 0),
-                         memory_space=pltpu.SMEM),
-            blk(Rp), blk(Tq), blk(Tq), blk(Tmax), blk(Tmax),
-        ],
-        out_specs=[blk(Tmax), blk(Tmax), blk(Rp)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[blk(Rp), blk(Tq), blk(Tq), blk(Tmax), blk(Tmax)],
+            out_specs=[blk(Tmax), blk(Tmax), blk(Rp)],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
             jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
             jax.ShapeDtypeStruct((B, Hkv, Rp, hd), q.dtype),
         ],
-        input_output_aliases={4: 0, 5: 1},   # K->Ko, V->Vo in place
-    )(len2, qr, knt, vnt, k_cache, v_cache)
+        # K->Ko, V->Vo in place (operand indices count the prefetch arg)
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+    )(lens, qr, knt, vnt, k_cache, v_cache)
     out = out[:, :, :R]                               # drop row padding
     # (B, Hkv, G, Tq, hd) -> (B, Tq, Hq, hd)
     out = out.reshape(B, Hkv, G, Tq, hd).transpose(0, 3, 1, 2, 4)
@@ -214,7 +235,16 @@ def lora_bgmv(x, a_pool, b_pool, ids, scales, *, interpret=False):
     cannot skip fetching for id −1 rows. Adapter identity is DATA: any
     id mix compiles to this one program. TPU-gated via
     ``supports_lora_shape``; ``interpret=True`` runs the kernel on CPU
-    for parity tests."""
+    for parity tests. Under a mesh every operand is whole on every
+    device (the engine replicates the pool), so each runs the same call."""
+    return mesh_kernel(
+        lambda _, *a: _bgmv_local(*a, interpret=interpret),
+        (x, a_pool, b_pool, ids, scales),
+        ((None,) * 2, (None,) * 3, (None,) * 3, (None,), (None,)),
+        (None,) * 2)
+
+
+def _bgmv_local(x, a_pool, b_pool, ids, scales, *, interpret):
     S, D = x.shape
     N, _, r = a_pool.shape
     O = b_pool.shape[-1]
@@ -320,7 +350,18 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
 
     Returns (S, 1, Hq, hd) attention output. Page identity is DATA
     (scalar-prefetched), so any table contents run through one compiled
-    program. ``interpret=True`` runs on CPU for parity tests."""
+    program. ``interpret=True`` runs on CPU for parity tests. Under a
+    mesh heads shard over the model axis, like the page pool."""
+    heads = (None, None, MODEL_AXIS, None)
+    pool = (None, MODEL_AXIS, None, None)
+    return mesh_kernel(
+        lambda _, *a: _paged_attention_local(*a, interpret=interpret),
+        (q, k_pool, v_pool, page_table, jnp.asarray(lengths, jnp.int32)),
+        (heads, pool, pool, (None, None), (None,)), heads)
+
+
+def _paged_attention_local(q, k_pool, v_pool, page_table, lengths, *,
+                           interpret):
     S, Tq, Hq, hd = q.shape
     N, Hkv, P, _ = k_pool.shape
     M = page_table.shape[1]
@@ -335,7 +376,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     if Rp != R:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
     tab = page_table.astype(jnp.int32).reshape(-1)
-    lens = jnp.asarray(lengths, jnp.int32)
+    lens = lengths
 
     def kv_idx(s, m, tab_ref, len_ref):
         return (jnp.clip(tab_ref[s * M + m], 0, N - 1), 0, 0, 0)
@@ -379,16 +420,36 @@ def supports_paged_shape(Tq: int, page_tokens: int, hd: int) -> bool:
             and page_tokens % 8 == 0)
 
 
-def supports_shape(Tq: int, Tmax: int, hd: int) -> bool:
+#: the fused step runs under the compiler's default 16 MiB scoped-VMEM
+#: limit. Asking for more (``vmem_limit_bytes``) does not help where it
+#: matters: inside a whole 12-layer decode program the v5e compiler then
+#: assigns whole cache arrays to VMEM beside the kernel's own scope and
+#: refuses the program (see ``transformer._use_fused_decode``).
+_VMEM_BUDGET = 14 * 2 ** 20
+
+
+def _step_vmem_bytes(Hkv: int, rows: int, Tmax: int, hd: int,
+                     itemsize: int) -> int:
+    """One grid cell of ``fused_decode_step``: the K and V panes, in and
+    (aliased) out, each double-buffered by the pipeline, plus the fp32
+    score / probability rows."""
+    return 8 * Hkv * Tmax * hd * itemsize + 3 * Hkv * rows * Tmax * 4
+
+
+def supports_shape(Tq: int, Tmax: int, hd: int, *, Hkv: int, Hq: int,
+                   itemsize: int = 2) -> bool:
     """Kernel eligibility: single-token decode, lane-aligned head dim,
-    cache panes that fit VMEM comfortably, and 8-row-aligned Tmax (the
-    merge_store window [t8, t8+8) must stay inside the pane for every
-    t < Tmax). Prefill (Tq > 1) keeps the dynamic-update-slice +
+    all Hkv cache panes of one batch row within the VMEM budget, and
+    8-row-aligned Tmax (the merge_store window [t8, t8+8) must stay
+    inside the pane for every t < Tmax). Prefill (Tq > 1) keeps the
+    dynamic-update-slice +
     ``decode_attention`` path — it runs once per generation, so its
     copies don't matter. int8-quantized caches (serving/kvcache.py) are
     additionally gated OFF by the caller: the kernel would need an
     in-VMEM dequant pass (quantize on merge_store, fold scales into the
     score/value dots) that has no hardware to be A/B'd against in this
     container — the XLA path carries the scales instead."""
-    return (Tq == 1 and hd % 64 == 0 and hd <= 256 and Tmax <= 8192
-            and Tmax % 8 == 0)
+    rows = max(_MIN_ROWS, Hq // Hkv)
+    return (Tq == 1 and hd % 64 == 0 and hd <= 256 and Tmax % 8 == 0
+            and _step_vmem_bytes(Hkv, rows, Tmax, hd, itemsize)
+            <= _VMEM_BUDGET)

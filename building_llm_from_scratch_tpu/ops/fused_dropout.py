@@ -21,6 +21,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from building_llm_from_scratch_tpu.parallel.collectives import mesh_kernel
+from building_llm_from_scratch_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
+
 _WEYL = -1640531527  # 0x9E3779B9 as int32
 
 
@@ -155,19 +158,35 @@ def _seed_from_rng(rng):
     return jax.random.bits(rng, (1, 2), jnp.uint32).astype(jnp.int32)
 
 
+def _activation_axes(ndim: int):
+    """Mesh axes that shard a (B, T, ..., D) activation: batch on data,
+    tokens on seq, features whole (replicated over model after the
+    row-parallel psum, where every model shard must draw the SAME mask)."""
+    lead = (DATA_AXIS, SEQ_AXIS)[:ndim - 1]
+    return lead + (None,) * (ndim - len(lead))
+
+
 def fused_dropout(h: jnp.ndarray, rate: float, rng: jax.Array) -> jnp.ndarray:
     """dropout(h) with the mask drawn in-kernel (never stored)."""
-    shape = h.shape
-    out = _dropout2d(h.reshape(-1, shape[-1]), _seed_from_rng(rng),
-                     float(rate))
-    return out.reshape(shape)
+    def per_shard(shard, h, seed):
+        seed = seed + shard * jnp.int32(_WEYL)
+        return _dropout2d(h.reshape(-1, h.shape[-1]), seed,
+                          float(rate)).reshape(h.shape)
+
+    axes = _activation_axes(h.ndim)
+    return mesh_kernel(per_shard, (h, _seed_from_rng(rng)),
+                       (axes, (None, None)), axes)
 
 
 def fused_dropout_add(x: jnp.ndarray, h: jnp.ndarray, rate: float,
                       rng: jax.Array) -> jnp.ndarray:
     """x + dropout(h) — the pre-norm residual update — in one pass."""
-    shape = h.shape
-    out = _dropout_add2d(x.reshape(-1, shape[-1]),
-                         h.reshape(-1, shape[-1]),
-                         _seed_from_rng(rng), float(rate))
-    return out.reshape(shape)
+    def per_shard(shard, x, h, seed):
+        seed = seed + shard * jnp.int32(_WEYL)
+        return _dropout_add2d(x.reshape(-1, h.shape[-1]),
+                              h.reshape(-1, h.shape[-1]), seed,
+                              float(rate)).reshape(h.shape)
+
+    axes = _activation_axes(h.ndim)
+    return mesh_kernel(per_shard, (x, h, _seed_from_rng(rng)),
+                       (axes, axes, (None, None)), axes)

@@ -29,9 +29,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from building_llm_from_scratch_tpu.parallel.collectives import shard_map
 from building_llm_from_scratch_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
 
 _NEG_INF = -1e30

@@ -42,6 +42,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from building_llm_from_scratch_tpu.parallel.collectives import mesh_kernel
+from building_llm_from_scratch_tpu.parallel.mesh import DATA_AXIS
+
 _NEG_BIG = -1e30
 
 
@@ -83,7 +86,7 @@ def softmax_xent(x2: jnp.ndarray,        # (N, D) final hidden states
     return nll
 
 
-def _use_pallas_fwd(N, D, V) -> bool:
+def _pallas_fwd_requested() -> bool:
     """Opt-in (BLLM_XENT_PALLAS=1): the pallas forward streams the vocab
     through VMEM so the (N, Vp) fp32 logits temp (1.6GB at GPT2-124M bs8)
     never exists — but measured DEAD-EVEN on the headline (97.42k vs
@@ -92,32 +95,36 @@ def _use_pallas_fwd(N, D, V) -> bool:
     default: it buys HBM headroom, not steady-state speed."""
     import os
 
-    if os.environ.get("BLLM_XENT_PALLAS", "0") != "1":
-        return False
-    if jax.default_backend() != "tpu" or len(jax.devices()) != 1:
-        # pallas_call is not auto-partitioned by GSPMD: on a sharded mesh
-        # it would force gathering the (N, D)/(D, V) operands, and the
-        # VMEM gate below would be evaluated on GLOBAL shapes anyway —
-        # single-device only (a shard_map wrapper could lift this)
-        return False
-    from building_llm_from_scratch_tpu.ops.xent_fwd_pallas import (
-        supports_shape,
-    )
-
-    return supports_shape(N, D, V)
+    return (os.environ.get("BLLM_XENT_PALLAS", "0") == "1"
+            and jax.default_backend() == "tpu")
 
 
 def _xent_fwd_impl(x2, w_head, targets, chunk):
+    """Forward (nll, lse)."""
+    if not _pallas_fwd_requested():
+        return _xent_fwd_xla(x2, w_head, targets, chunk)
+    from building_llm_from_scratch_tpu.ops.xent_fwd_pallas import (
+        supports_shape,
+        xent_fwd,
+    )
+
+    def per_shard(_, x2, w_head, targets):
+        # each shard decides for ITS rows: the gate budgets VMEM for the
+        # shape the kernel really gets
+        if supports_shape(x2.shape[0], *w_head.shape):
+            return xent_fwd(x2, w_head, targets)
+        return _xent_fwd_xla(x2, w_head, targets, chunk)
+
+    # GSPMD cannot partition the kernel: token rows shard over the data
+    # axis, the head is gathered whole
+    rows = (DATA_AXIS,)
+    return mesh_kernel(per_shard, (x2, w_head, targets),
+                       (rows + (None,), (None, None), rows), (rows, rows))
+
+
+def _xent_fwd_xla(x2, w_head, targets, chunk):
     N, D = x2.shape
     V = w_head.shape[1]
-    if _use_pallas_fwd(N, D, V):
-        # pallas forward (ops/xent_fwd_pallas.py): vocabulary streamed
-        # through VMEM, fp32 logits never reach HBM
-        from building_llm_from_scratch_tpu.ops.xent_fwd_pallas import (
-            xent_fwd,
-        )
-
-        return xent_fwd(x2, w_head, targets)
     wp, n_chunks = _pad_vocab(w_head, chunk)
 
     def body(carry, c):

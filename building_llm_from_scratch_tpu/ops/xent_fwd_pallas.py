@@ -27,6 +27,9 @@ _NEG_BIG = -1e30
 # accumulators are (N, LANES) lane-replicated (mosaic wants 2D tiles);
 # 128 lanes keeps the reductions layout-native
 _LANES = 128
+# the kernel keeps whole-N panes resident, far past the compiler's default
+# 16 MiB scoped-VMEM limit; v5e has 128 MiB of VMEM per core
+_VMEM_LIMIT = 100 * 2 ** 20
 
 
 def _kernel(x_ref, w_ref, tgt_ref, lse_ref, tl_ref, m_ref, s_ref, *,
@@ -105,18 +108,23 @@ def xent_fwd(x2: jnp.ndarray,       # (N, D) hidden states
             pltpu.VMEM((N, _LANES), jnp.float32),            # running sum
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
     )(x2, w_head, tgt2)
     lse1 = lse[:, 0]
     return lse1 - tl[:, 0], lse1
 
 
 def supports_shape(N: int, D: int, V: int, bv: int = 512) -> bool:
-    """VMEM budget: resident x (N*D bf16) + logits chunk (N*bv f32) +
-    4 accumulator panes (N*128 f32) + weight chunk; gate well under the
-    16MB-per-buffer / ~128MB total VMEM of v5e."""
-    x_mb = N * D * 2 / 1e6
+    """VMEM budget under ``_VMEM_LIMIT``: resident x (N*D bf16) and the
+    streamed weight chunk, both double-buffered by the pipeline, + logits
+    chunk (N*bv f32) + 4 accumulator panes (N*128 f32). The model is
+    conservative (the v5e compiler takes 41 MB where it says 60 MB at
+    N8192 D768); the bound is where it stopped refusing shapes
+    (tests/test_tpu_compile.py compiles the largest one admitted)."""
+    x_mb = 2 * N * D * 2 / 1e6
+    w_mb = 2 * D * bv * 2 / 1e6
     s_mb = N * bv * 4 / 1e6
     acc_mb = 4 * N * _LANES * 4 / 1e6
     return (N % 8 == 0 and D % 128 == 0 and N >= 128
-            and x_mb + s_mb + acc_mb + D * bv * 2 / 1e6 < 90)
+            and x_mb + w_mb + s_mb + acc_mb < 98)

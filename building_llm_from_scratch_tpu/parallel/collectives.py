@@ -10,14 +10,19 @@ equivalents (SURVEY.md §2.2 "Communication backend"):
   FSDP FULL_STATE_DICT gather        -> gather_full(tree)
 
 Explicit collectives (psum/all_gather/ppermute) are provided for
-``shard_map`` kernels (ring attention) that hand-schedule communication.
+``shard_map`` kernels (ring attention) that hand-schedule communication;
+``trace_under_mesh`` + ``mesh_kernel`` put pallas kernels, which GSPMD
+cannot partition, under a shard_map of the step's mesh.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 
 def is_coordinator() -> bool:
@@ -61,28 +66,67 @@ def gather_full(tree: Any) -> Any:
 
 # shard_map building blocks -------------------------------------------------
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: the top-level ``jax.shard_map`` alias
-    (and its ``check_vma`` kwarg) only exist on newer jax; older releases
-    ship ``jax.experimental.shard_map.shard_map`` with the same semantics
-    under the ``check_rep`` spelling. Every shard_map in this codebase goes
-    through here so a jax upgrade/downgrade never strands the explicit-
-    collective paths (ring attention, pipeline, bf16_hybrid step).
+def trace_under_mesh(fn, mesh):
+    """Make ``mesh`` the ambient (abstract) mesh while ``fn`` is traced.
 
-    Known old-API limitation: differentiating THROUGH a shard_map whose
-    out_specs include a replicated SCALAR (the pipeline loss) fails in the
-    transpose on jax<0.5 with either check_rep setting (_SpecError under
-    False, cond replication-mismatch under True; both fixed upstream
-    alongside the alias). The pp grad-through tests carry a conditional
-    xfail for it; forward/eval paths and grad-INSIDE-shard_map (ring
-    attention, the explicit bf16_hybrid step) work on both APIs."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), and a jitted step only learns its mesh from its
+    operands' shardings, which tracing does not show. Step builders that
+    know their plan's mesh wrap the function they jit with this, so
+    ``mesh_kernel`` below can read the mesh where the kernel is called.
+    No mesh, or one device: ``fn`` unchanged."""
+    if mesh is None or mesh.size == 1:
+        return fn
 
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return traced
+
+
+def mesh_kernel(fn, args, in_axes, out_axes):
+    """Call ``fn(shard, *args)`` — a pallas kernel — once per shard of
+    the ambient mesh (``trace_under_mesh``).
+
+    ``in_axes`` / ``out_axes`` name, per operand / result and per array
+    dim, the mesh axis that may shard that dim (None: never). The call
+    is manual over every auto-partitioned axis (Mosaic refuses one left
+    auto, even of size one); an axis larger than one shards the dims
+    tagged with it where it divides them all, and operands stay whole
+    over the others (GSPMD gathers them there — correct, and visible in
+    the HLO). ``shard`` is the linear index of this shard over the mapped
+    axes (int32 0 without a mesh) for kernels that seed a PRNG per shard.
+    Inside an enclosing shard_map every axis is already manual, so the
+    kernel is called bare."""
+    mesh = jax.sharding.get_abstract_mesh()
+    big = [] if mesh.empty else [a for a in mesh.auto_axes
+                                 if mesh.shape[a] > 1]
+    if not big:
+        return fn(jnp.int32(0), *args)
+    live = []
+    for name in big:
+        dims = [x.shape[d] for x, axes in zip(args, in_axes)
+                for d, a in enumerate(axes) if a == name]
+        if dims and all(d % mesh.shape[name] == 0 for d in dims):
+            live.append(name)
+
+    def spec(axes):
+        return PartitionSpec(*(a if a in live else None for a in axes))
+
+    def body(*shard_args):
+        shard = jnp.int32(0)
+        for name in live:
+            shard = shard * mesh.shape[name] + jax.lax.axis_index(name)
+        return fn(shard, *shard_args)
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(spec(a) for a in in_axes),
+        out_specs=jax.tree_util.tree_map(
+            spec, out_axes, is_leaf=lambda a: isinstance(a, tuple) and all(
+                e is None or isinstance(e, str) for e in a)),
+        axis_names=frozenset(mesh.auto_axes), check_vma=False)(*args)
 
 
 def psum(x, axis_name: str):

@@ -46,10 +46,10 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from building_llm_from_scratch_tpu.configs import ModelConfig
-from building_llm_from_scratch_tpu.parallel.collectives import shard_map
 from building_llm_from_scratch_tpu.models.transformer import (
     _block,
     _embed,

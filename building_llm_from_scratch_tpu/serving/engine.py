@@ -83,6 +83,9 @@ from building_llm_from_scratch_tpu.obs.metrics import (
     render_prometheus,
 )
 from building_llm_from_scratch_tpu.obs.schema import TICK_PHASES
+from building_llm_from_scratch_tpu.parallel.collectives import (
+    trace_under_mesh,
+)
 from building_llm_from_scratch_tpu.serving.adapters import BASE_ADAPTER
 from building_llm_from_scratch_tpu.serving.kvcache import (
     KVCachePolicy,
@@ -330,6 +333,11 @@ class DecodeEngine:
             lambda x: x.sharding, self.cache)
             if mesh_plan is not None else None)
         self._blocks = unstack_blocks(self.params, cfg)
+        #: the weights ride every compiled program as an ARGUMENT: closed
+        #: over, jit bakes them into each program as constants (GPT2-124M
+        #: bf16: 0.3 GB per program, 40 s per compile on the chip, and
+        #: enough host memory over the program family to be killed at 40 GiB)
+        self._weights = (self.params, self._blocks)
         if self.adapters is not None and mesh_plan is not None:
             # the stacked pool rides every compiled call as data — it has
             # to live on THIS engine's mesh (replicated: every model
@@ -389,23 +397,28 @@ class DecodeEngine:
             self.adapters.set_in_use_probe(self._adapter_rows_in_use)
 
         # donate the cache pytree: the caller always rebinds self.cache
-        # to the outputs, so XLA may alias input->output and the pallas
-        # in-place append really is in place (no per-tick full-cache
-        # copy). The prefix-EXTRACT program deliberately does NOT donate
+        # to the outputs, so XLA may alias input->output (and the opt-in
+        # pallas append is in place: no per-tick full-cache copy). The
+        # prefix-EXTRACT program deliberately does NOT donate
         # — it only reads the cache (the next donating call reuses the
         # same arrays).
         import functools
 
-        prefill_jit = jax.jit(self._prefill_impl, donate_argnums=(0,))
+        def jit(fn, **kw):
+            # the tp/sp engine's kernels shard_map over the plan's mesh
+            return jax.jit(trace_under_mesh(
+                fn, mesh_plan.mesh if mesh_plan is not None else None), **kw)
+
+        prefill_jit = jit(self._prefill_impl, donate_argnums=(0,))
         # paged: the chunk/step programs take the page table as one more
         # traced argument and write/read through it; the monolithic
         # prefill and the prefix copy/extract pair are never CALLED
         # (paged implies chunked prefill, and a paged hit is a host
         # table write) — they stay built so the watcher set is stable
-        chunk_jit = jax.jit(self._paged_chunk_impl if self._paged
-                            else self._chunk_impl, donate_argnums=(0,))
-        copy_jit = jax.jit(self._copy_impl, donate_argnums=(0,))
-        extract_jit = jax.jit(functools.partial(
+        chunk_jit = jit(self._paged_chunk_impl if self._paged
+                        else self._chunk_impl, donate_argnums=(0,))
+        copy_jit = jit(self._copy_impl, donate_argnums=(0,))
+        extract_jit = jit(functools.partial(
             extract_prefix_panes, pane_len=self._prefix_pane_len))
         # spec on: the Tq=k+1 verify program IS the tick program — the
         # plain decode step is never built (every slot, spec-opted-out
@@ -417,7 +430,7 @@ class DecodeEngine:
         else:
             step_impl = (self._verify_impl if self.spec_k
                          else self._decode_impl)
-        step_jit = jax.jit(step_impl, donate_argnums=(0,))
+        step_jit = jit(step_impl, donate_argnums=(0,))
         step_label = "serve_verify" if self.spec_k else "serve_decode"
         if watch_compiles:
             self._prefill = CompileWatcher(prefill_jit,
@@ -694,7 +707,7 @@ class DecodeEngine:
     # -- jitted programs (close over params/cfg/blocks so per-tick call
     # signatures carry only the small mutable state + caches) -------------
 
-    def _prefill_impl(self, cache, tokens, prompt_len, slot,
+    def _prefill_impl(self, cache, weights, tokens, prompt_len, slot,
                       base_key, temp, topk, pool=None, pool_scale=None,
                       adapter_id=None):
         import jax.numpy as jnp
@@ -704,8 +717,8 @@ class DecodeEngine:
             adapter = {"pool": pool, "scaling": pool_scale,
                        "ids": jnp.reshape(adapter_id, (1,))}
         logits, cache = prefill_into_slot(
-            self.params, self.cfg, tokens, prompt_len, slot,
-            cache, self._blocks, adapter=adapter)
+            weights[0], self.cfg, tokens, prompt_len, slot,
+            cache, weights[1], adapter=adapter)
         key0 = token_rng(base_key, 0)
         tok = sample_tokens_dynamic(
             logits[None], key0[None], jnp.reshape(temp, (1,)),
@@ -716,8 +729,8 @@ class DecodeEngine:
         ok = jnp.all(jnp.isfinite(logits))
         return tok, ok, self._pin_cache(cache)
 
-    def _chunk_impl(self, cache, tokens, chunk_start, prompt_len, slot,
-                    base_key, temp, topk, pool=None, pool_scale=None,
+    def _chunk_impl(self, cache, weights, tokens, chunk_start, prompt_len,
+                    slot, base_key, temp, topk, pool=None, pool_scale=None,
                     adapter_id=None):
         """One C-token prefill chunk (the chunked tier's ONE compiled
         prefill program). Samples the would-be first token every call —
@@ -742,8 +755,8 @@ class DecodeEngine:
             adapter = {"pool": pool, "scaling": pool_scale,
                        "ids": jnp.reshape(adapter_id, (1,))}
         logits, cache = prefill_chunk_into_slot(
-            self.params, self.cfg, tokens, chunk_start, prompt_len, slot,
-            cache, self._blocks, adapter=adapter)
+            weights[0], self.cfg, tokens, chunk_start, prompt_len, slot,
+            cache, weights[1], adapter=adapter)
         key0 = token_rng(base_key, 0)
         tok = sample_tokens_dynamic(
             logits[None], key0[None], jnp.reshape(temp, (1,)),
@@ -756,7 +769,7 @@ class DecodeEngine:
         into row ``slot`` — the whole cached-span compute (no forward)."""
         return self._pin_cache(copy_prefix_into_slot(cache, panes, slot))
 
-    def _decode_impl(self, cache, tokens, lengths, base_keys,
+    def _decode_impl(self, cache, weights, tokens, lengths, base_keys,
                      n_gen, temps, topks, pool=None, pool_scale=None,
                      adapter_ids=None):
         import jax
@@ -767,8 +780,8 @@ class DecodeEngine:
             adapter = {"pool": pool, "scaling": pool_scale,
                        "ids": adapter_ids}
         logits, cache = decode_slots(
-            self.params, self.cfg, tokens[:, None], lengths,
-            cache, self._blocks, adapter=adapter)
+            weights[0], self.cfg, tokens[:, None], lengths,
+            cache, weights[1], adapter=adapter)
         keys = jax.vmap(token_rng)(base_keys, n_gen)
         nxt = sample_tokens_dynamic(logits, keys, temps, topks,
                                     self.max_top_k)
@@ -778,7 +791,7 @@ class DecodeEngine:
         ok = jnp.all(jnp.isfinite(logits), axis=-1)
         return nxt, ok, self._pin_cache(cache)
 
-    def _verify_impl(self, cache, tokens, lengths, base_keys,
+    def _verify_impl(self, cache, weights, tokens, lengths, base_keys,
                      n_gen, temps, topks, pool=None, pool_scale=None,
                      adapter_ids=None):
         """Speculative tick: ONE Tq=k+1 forward scores every slot's
@@ -800,7 +813,7 @@ class DecodeEngine:
             adapter = {"pool": pool, "scaling": pool_scale,
                        "ids": adapter_ids}
         logits, cache = verify_slots(
-            self.params, self.cfg, tokens, lengths, cache, self._blocks,
+            weights[0], self.cfg, tokens, lengths, cache, weights[1],
             adapter=adapter)
         Tq = tokens.shape[1]
         offsets = n_gen[:, None] + jnp.arange(Tq)[None, :]     # (S, Tq)
@@ -815,8 +828,8 @@ class DecodeEngine:
     # each call as TRACED DATA (one (S, max_pages) signature — page churn
     # never recompiles, mirroring the adapter-pool trick) ----------------
 
-    def _paged_chunk_impl(self, cache, tokens, chunk_start, prompt_len,
-                          slot, page_table, base_key, temp, topk,
+    def _paged_chunk_impl(self, cache, weights, tokens, chunk_start,
+                          prompt_len, slot, page_table, base_key, temp, topk,
                           pool=None, pool_scale=None, adapter_id=None):
         import jax
         import jax.numpy as jnp
@@ -832,8 +845,8 @@ class DecodeEngine:
             adapter = {"pool": pool, "scaling": pool_scale,
                        "ids": jnp.reshape(adapter_id, (1,))}
         logits, cache = paged_prefill_chunk_into_slot(
-            self.params, self.cfg, tokens, chunk_start, prompt_len, slot,
-            page_table, cache, self._blocks, adapter=adapter,
+            weights[0], self.cfg, tokens, chunk_start, prompt_len, slot,
+            page_table, cache, weights[1], adapter=adapter,
             cache_len=self._cache_len)
         key0 = token_rng(base_key, 0)
         tok = sample_tokens_dynamic(
@@ -842,7 +855,7 @@ class DecodeEngine:
         ok = jnp.all(jnp.isfinite(logits))
         return tok, ok, self._pin_cache(cache)
 
-    def _paged_decode_impl(self, cache, tokens, lengths, page_table,
+    def _paged_decode_impl(self, cache, weights, tokens, lengths, page_table,
                            base_keys, n_gen, temps, topks, pool=None,
                            pool_scale=None, adapter_ids=None):
         import jax
@@ -853,8 +866,8 @@ class DecodeEngine:
             adapter = {"pool": pool, "scaling": pool_scale,
                        "ids": adapter_ids}
         logits, cache = paged_decode_slots(
-            self.params, self.cfg, tokens[:, None], lengths, page_table,
-            cache, self._blocks, adapter=adapter,
+            weights[0], self.cfg, tokens[:, None], lengths, page_table,
+            cache, weights[1], adapter=adapter,
             cache_len=self._cache_len)
         keys = jax.vmap(token_rng)(base_keys, n_gen)
         nxt = sample_tokens_dynamic(logits, keys, temps, topks,
@@ -862,7 +875,7 @@ class DecodeEngine:
         ok = jnp.all(jnp.isfinite(logits), axis=-1)
         return nxt, ok, self._pin_cache(cache)
 
-    def _paged_verify_impl(self, cache, tokens, lengths, page_table,
+    def _paged_verify_impl(self, cache, weights, tokens, lengths, page_table,
                            base_keys, n_gen, temps, topks, pool=None,
                            pool_scale=None, adapter_ids=None):
         import jax
@@ -877,8 +890,8 @@ class DecodeEngine:
             adapter = {"pool": pool, "scaling": pool_scale,
                        "ids": adapter_ids}
         logits, cache = paged_verify_slots(
-            self.params, self.cfg, tokens, lengths, page_table, cache,
-            self._blocks, adapter=adapter, cache_len=self._cache_len)
+            weights[0], self.cfg, tokens, lengths, page_table, cache,
+            weights[1], adapter=adapter, cache_len=self._cache_len)
         Tq = tokens.shape[1]
         offsets = n_gen[:, None] + jnp.arange(Tq)[None, :]     # (S, Tq)
         keys = jax.vmap(jax.vmap(token_rng, in_axes=(None, 0)))(
@@ -1322,9 +1335,9 @@ class DecodeEngine:
         # dispatch), so timing the call alone would book the execution
         # wait into whatever host line happens to touch a result first
         t_pf = time.perf_counter()
-        tok, ok, cache = self._prefill(self.cache, padded, np.int32(Tp),
-                                       np.int32(slot), base_key, temp,
-                                       topk,
+        tok, ok, cache = self._prefill(self.cache, self._weights, padded,
+                                       np.int32(Tp), np.int32(slot),
+                                       base_key, temp, topk,
                                        *self._pool_args_for(adapter_row))
         if self._generation != gen:
             return          # abandoned mid-prefill: commit nothing
@@ -1546,7 +1559,7 @@ class DecodeEngine:
                 # tail's columns stay unmapped and scatter into trash
                 self._ensure_pages(slot, hi)
             tok, ok, cache = self._prefill_chunk(
-                self.cache, chunk, np.int32(lo), np.int32(Tp),
+                self.cache, self._weights, chunk, np.int32(lo), np.int32(Tp),
                 np.int32(slot),
                 *((self._page_table,) if self._paged else ()),
                 st["base_key"], st["temp"], st["topk"],
@@ -1966,7 +1979,7 @@ class DecodeEngine:
                         slot, int(self._lengths[slot]) + 1)  # graft-ok: GL011 host numpy
             t_dec = time.perf_counter()
             nxt, ok, cache = self._decode(
-                self.cache, self._last_tokens, self._lengths,
+                self.cache, self._weights, self._last_tokens, self._lengths,
                 *((self._page_table,) if self._paged else ()),
                 self._base_keys, self._n_gen, self._temps,
                 self._topks, *(self._pool_args() + (self._adapter_ids,)
@@ -2056,7 +2069,7 @@ class DecodeEngine:
                     slot, int(self._lengths[slot]) + 1 + k)  # graft-ok: GL011 host numpy
         t_dec = time.perf_counter()
         toks, n_acc, ok, cache = self._verify(
-            self.cache, tokens_in, self._lengths,
+            self.cache, self._weights, tokens_in, self._lengths,
             *((self._page_table,) if self._paged else ()),
             self._base_keys, self._n_gen, self._temps, self._topks,
             *(self._pool_args() + (self._adapter_ids,)
@@ -2383,8 +2396,8 @@ class DecodeEngine:
                 # gather rides the pinned trash page, so warming compiles
                 # the real programs without allocating a single page
                 tok, _ok, cache = self._prefill_chunk(
-                    self.cache, dummy, np.int32(0), np.int32(1),
-                    np.int32(0),
+                    self.cache, self._weights, dummy, np.int32(0),
+                    np.int32(1), np.int32(0),
                     *((self._page_table,) if self._paged else ()),
                     zero_key, np.float32(0.0), np.int32(0),
                     *self._pool_args_for(np.int32(-1)))
@@ -2401,7 +2414,7 @@ class DecodeEngine:
                 for Tpb in buckets:
                     dummy = np.zeros((1, Tpb), np.int32)
                     tok, _ok, cache = self._prefill(
-                        self.cache, dummy, np.int32(1),
+                        self.cache, self._weights, dummy, np.int32(1),
                         np.int32(0), zero_key, np.float32(0.0),
                         np.int32(0), *self._pool_args_for(np.int32(-1)))
                     self.cache = cache
@@ -2412,7 +2425,7 @@ class DecodeEngine:
                 warm_tokens = np.zeros((self.n_slots, self.spec_k + 1),
                                        np.int32)
                 nxt, _n_acc, _ok, cache = self._verify(
-                    self.cache, warm_tokens, self._lengths,
+                    self.cache, self._weights, warm_tokens, self._lengths,
                     *((self._page_table,) if self._paged else ()),
                     self._base_keys, self._n_gen, self._temps,
                     self._topks, *(self._pool_args()
@@ -2420,7 +2433,8 @@ class DecodeEngine:
                                    if self.adapters is not None else ()))
             else:
                 nxt, _ok, cache = self._decode(
-                    self.cache, self._last_tokens, self._lengths,
+                    self.cache, self._weights, self._last_tokens,
+                    self._lengths,
                     *((self._page_table,) if self._paged else ()),
                     self._base_keys, self._n_gen,
                     self._temps, self._topks,

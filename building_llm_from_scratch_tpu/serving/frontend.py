@@ -372,6 +372,18 @@ def run_serve(args, comps, metric_logger) -> DecodeEngine:
     max_prompt = getattr(args, "serve_max_prompt", 0) or None
     n_workers = getattr(args, "serve_workers", 0)
     if n_workers > 0:
+        import jax
+
+        if jax.default_backend() != "cpu":
+            # this process built the components, so it holds the chip, and
+            # a chip belongs to one process: the workers could only die on
+            # it or — worse — serve from the CPU under the same metrics
+            raise RuntimeError(
+                f"--serve_workers starts worker processes that each open "
+                f"the accelerator, but this process already holds the "
+                f"{jax.default_backend()} device (one process at a time). "
+                f"On an accelerator host use --serve_replicas: in-process "
+                f"replicas, one per chip.")
         # cross-process fleet (serving/fleet.py): N supervised worker
         # PROCESSES behind one engine-shaped facade. Workers rebuild
         # cfg + params from the spec (init_params is seed-deterministic;
@@ -597,8 +609,15 @@ def _serve_frontends(args, engine, stalls, metric_logger):
         watcher.join(timeout=5)
         if stopper.requested and not engine.draining:
             engine.drain(timeout=args.drain_timeout)
+        died = engine.healthz_payload()["status"] == "dead"
         engine.shutdown()
         for det in stalls:
             det.stop()
         metric_logger.close()
+    if died:
+        # every in-flight request already carries its error line; the
+        # PROCESS must not report success for an engine whose loop died
+        # (a step that failed to compile or run)
+        raise RuntimeError("the serving engine died while serving; see the "
+                           "'decode-engine loop died' traceback above")
     return engine

@@ -121,18 +121,17 @@ class EngineSpec:
         return cls(**json.loads(s))
 
 
-def apply_host_env(devices: int, platform: str = "cpu") -> None:
-    """Force-host device count + platform env, BEFORE jax imports.
-
-    Each worker process pins its own device count (the bench's
-    subprocess trick, now the fleet's default): the parent's jax — if
-    any — is untouched.
-    """
+def apply_host_env(devices: int) -> None:
+    """Forced-host device count, BEFORE jax imports: each worker process
+    pins its own (the flag only shapes the CPU platform; the parent's jax
+    — if any — is untouched). The PLATFORM is never chosen here: a
+    worker runs where its environment says (``JAX_PLATFORMS`` from
+    whoever spawned it), else on jax's default — never on the CPU by
+    default on a host that has a chip."""
     if devices > 1:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={devices}").strip()
-    os.environ.setdefault("JAX_PLATFORMS", platform)
 
 
 def build_engine(spec: EngineSpec, replica: Optional[int] = None):
@@ -860,6 +859,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     seed_request_ids((args.replica * 1000 + args.incarnation + 1)
                      * 1_000_000)
 
+    from building_llm_from_scratch_tpu.obs import configure_compile_cache
+
+    configure_compile_cache()
     engine = build_engine(spec, replica=args.replica)
     engine.warmup()
     engine.start()

@@ -47,6 +47,9 @@ from building_llm_from_scratch_tpu.ops.softmax_xent import (
     fused_cross_entropy_loss,
     fused_cross_entropy_sums,
 )
+from building_llm_from_scratch_tpu.parallel.collectives import (
+    trace_under_mesh,
+)
 from building_llm_from_scratch_tpu.training.precision import (
     PrecisionPolicy,
     cast_floating,
@@ -152,6 +155,7 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
                     lora_rank: Optional[int] = None,
                     policy: Optional[PrecisionPolicy] = None,
                     sp_mesh=None,
+                    mesh=None,
                     use_fused_xent: Optional[bool] = None,
                     grad_accum: int = 1,
                     jit: bool = True) -> Callable:
@@ -160,6 +164,10 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
     batch: {"inputs": (B,T) i32, "targets": (B,T) i32, "weights": (B,T) f32}.
     ``sp_mesh``: mesh with seq axis > 1 routes attention through the ring
     schedule (sequence parallelism; see ops/ring_attention.py).
+    ``mesh``: the mesh the state and batches are placed on (GSPMD shard
+    modes) — made visible to the trace so the pallas kernels, which GSPMD
+    cannot partition, shard_map themselves over it
+    (parallel/collectives.mesh_kernel).
     ``grad_accum`` > 1 splits the batch into that many microbatches and
     runs them through a ``lax.scan`` INSIDE the jitted step, accumulating
     fp32 gradients and the weighted-CE numerator/denominator — activation
@@ -243,7 +251,8 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
         return _finish_step(state, loss, grads, batch["inputs"].size,
                             optimizer, lr_schedule, policy)
 
-    fn = train_step if grad_accum == 1 else train_step_accum
+    fn = trace_under_mesh(
+        train_step if grad_accum == 1 else train_step_accum, mesh)
     if jit:
         return jax.jit(fn, donate_argnums=(0,))
     return fn
@@ -366,7 +375,7 @@ def make_sharded_train_step(cfg: ModelConfig,
     """
     from jax.sharding import PartitionSpec as P
 
-    from building_llm_from_scratch_tpu.parallel.collectives import shard_map
+    from jax import shard_map
     from building_llm_from_scratch_tpu.parallel.mesh import (
         DATA_AXIS,
         SEQ_AXIS,
@@ -522,8 +531,10 @@ def make_eval_step(cfg: ModelConfig, *,
                    lora_rank: Optional[int] = None,
                    policy: Optional[PrecisionPolicy] = None,
                    sp_mesh=None,
+                   mesh=None,
                    jit: bool = True) -> Callable:
-    """Build eval_step(state, batch) -> loss (deterministic, no grads)."""
+    """Build eval_step(state, batch) -> loss (deterministic, no grads).
+    ``mesh`` as in ``make_train_step``."""
     full_params = make_full_params_fn(cfg, lora_alpha=lora_alpha,
                                       lora_rank=lora_rank, policy=policy)
     loss_impl, _ = make_loss_fns(cfg)
@@ -535,6 +546,7 @@ def make_eval_step(cfg: ModelConfig, *,
         return loss_impl(params, hidden, batch["targets"],
                          batch.get("weights"))
 
+    eval_step = trace_under_mesh(eval_step, mesh)
     if jit:
         return jax.jit(eval_step)
     return eval_step

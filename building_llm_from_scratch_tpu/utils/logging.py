@@ -28,17 +28,12 @@ _FORMAT = "%(asctime)s - %(name)s - %(levelname)s - %(message)s"
 def _coordinator_if_known() -> bool:
     """True unless this process is provably a non-coordinator. Never
     initializes jax (see module docstring)."""
-    if sys.modules.get("jax") is None:
-        return True
-    try:
-        from jax._src import distributed
-
-        pid = getattr(distributed.global_state, "process_id", None)
-        if pid is not None:
-            return pid == 0
-    except Exception:
-        pass
-    return True
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.distributed.is_initialized():
+        return True         # one process, or not a multi-host job (yet)
+    # after jax.distributed.initialize() the backend is about to come up
+    # anyway; before it, the branch above never touches it
+    return jax.process_index() == 0
 
 
 class _CoordinatorFilter(logging.Filter):

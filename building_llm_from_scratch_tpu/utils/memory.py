@@ -80,21 +80,13 @@ def log_device_memory(logger, prefix: str = "") -> None:
     for d in jax.local_devices():
         stats = device_memory_stats(d)
         if not stats:
-            # remote/tunnel backends expose no live stats; fall back to the
-            # size of this process's live arrays on the device — an in-use
-            # floor, not a peak
-            # sum the actual shard bytes resident on THIS device: dividing
-            # global nbytes by the device count undercounts replicated
-            # arrays (each replica holds the FULL buffer)
-            live = sum(
-                s.data.nbytes
-                for x in jax.live_arrays()
-                if getattr(x, "sharding", None) is not None
-                and d in x.sharding.device_set
-                for s in x.addressable_shards
-                if s.device == d) / 1024**3
-            logger.info("%s%s: live stats unavailable; live jax.Arrays "
-                        "hold >= %.2fGB", prefix, d, live)
+            if d.platform == "tpu":
+                raise RuntimeError(
+                    f"{d} reports no memory_stats(): a TPU always does, so "
+                    f"something is wrong with the runtime — refusing to "
+                    f"print a guess as a peak")
+            logger.info("%s%s: no memory_stats() on platform '%s' "
+                        "(not measured)", prefix, d, d.platform)
             continue
         in_use = stats.get("bytes_in_use", 0) / 1024**3
         peak = stats.get("peak_bytes_in_use", 0) / 1024**3

@@ -1,8 +1,8 @@
 """Synthesize an offline Alpaca-FORMAT instruction dataset.
 
 Zero network egress means the real tatsu-lab alpaca_data.json
-(datasets/alpaca.py) cannot download, so the SFT convergence run in
-RESULTS.md uses deterministic string-manipulation tasks in the exact
+(datasets/alpaca.py) cannot download, so an offline SFT convergence run
+uses deterministic string-manipulation tasks in the exact
 Alpaca schema ({"instruction", "input", "output"}). The tasks are chosen
 so a byte-level model can visibly LEARN them (reverse/uppercase/repeat):
 before-SFT samples are garbage, after-SFT samples follow the instruction —

@@ -4,7 +4,7 @@ This environment has zero network egress, so the Gutenberg download
 (datasets/gutenberg.py `download_archive`) cannot run; the packing side of
 that pipeline is reused verbatim here over the ~500MB of English prose and
 source text shipped with the Python installation — a genuine (if unusual)
-corpus for the convergence runs recorded in RESULTS.md.
+corpus for offline convergence runs.
 
   python scripts/build_local_corpus.py [out_dir] [max_mb]
 """

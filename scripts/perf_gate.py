@@ -31,7 +31,6 @@ Usage:
   python scripts/perf_gate.py --benches micro_train,micro_serve
   python scripts/perf_gate.py --update-baseline --reason "why it changed"
   python scripts/perf_gate.py --report            # perf trajectory table
-  python scripts/perf_gate.py --backfill          # BENCH_r0N.json -> results/perf/
 """
 
 import argparse
@@ -63,8 +62,8 @@ sys.path.insert(1, os.path.dirname(os.path.abspath(__file__)))
 def _load_perf(pure: bool = False):
     """Handle on obs/perf.py. ``pure=True`` loads it by FILE PATH —
     stdlib-only, skipping obs/__init__ and therefore jax (the
-    analysis.base.load_schema_module pattern) — for the report/backfill
-    paths, which only read/write JSONL. The gate paths import the
+    analysis.base.load_schema_module pattern) — for the report
+    path, which only reads JSONL. The gate paths import the
     package module instead: they run benches, whose BenchResult objects
     must share class identity with the module comparing them."""
     if pure:
@@ -327,16 +326,11 @@ def cmd_update_baseline(args):
 
 
 def cmd_report(args):
-    # pure file-path load: --report/--backfill only read/write JSONL and
-    # must work (fast) without jax or the accelerator stack
+    # pure file-path load: --report only reads JSONL and must work
+    # (fast) without jax or the accelerator stack
     perf = _load_perf(pure=True)
-    store = perf.TrajectoryStore(os.path.join(REPO_ROOT, "results", "perf"))
-    if args.backfill:
-        added = perf.backfill_bench_history(REPO_ROOT, store)
-        print(f"backfilled {added} row(s) from BENCH_r*.json into "
-              f"{store.root}")
-    if args.report:
-        perf.render_trajectory(store)
+    perf.render_trajectory(
+        perf.TrajectoryStore(os.path.join(REPO_ROOT, "results", "perf")))
     return 0
 
 
@@ -373,11 +367,8 @@ def main(argv=None):
     p.add_argument("--report", action="store_true",
                    help="print the perf trajectory table "
                         "(results/perf/*.jsonl) and exit")
-    p.add_argument("--backfill", action="store_true",
-                   help="backfill BENCH_r0N.json snapshots into the "
-                        "trajectory store and exit")
     args = p.parse_args(argv)
-    if args.report or args.backfill:
+    if args.report:
         return cmd_report(args)
     if args.update_baseline:
         return cmd_update_baseline(args)

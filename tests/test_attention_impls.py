@@ -99,8 +99,7 @@ def test_auto_uses_xla_for_decode_shapes():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=0)
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="pallas flash kernel needs a real TPU")
+@pytest.mark.needs_tpu
 def test_pallas_matches_xla_on_tpu():
     q, k, v = _qkv(T=512, D=64, dtype=jnp.bfloat16)
     want = np.asarray(causal_attention(q, k, v, impl="xla"), np.float32)
@@ -108,8 +107,7 @@ def test_pallas_matches_xla_on_tpu():
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="pallas flash kernel needs a real TPU")
+@pytest.mark.needs_tpu
 def test_pallas_gradients_match_xla_on_tpu():
     """The tuned-block pallas path must be exact in the backward too (it
     feeds real training steps when auto picks it at seq >= 2048)."""

@@ -1,20 +1,32 @@
 """Fused decode-step kernel (ops/decode_step.py) parity vs the jnp
 decode path, plus the custom-VJP norm gradient checks (round 5).
 
-The pallas kernel tests need the real chip (RUN_TPU_TESTS=1); the norm
-gradient tests run everywhere.
+The decode-step kernel runs compiled on the chip and interpreted on the
+CPU; the xent kernel case needs the real chip (chip_smoke.py runs it
+there); the norm gradient tests run everywhere.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-needs_tpu = pytest.mark.skipif(jax.default_backend() != "tpu",
-                               reason="pallas TPU kernel (RUN_TPU_TESTS=1)")
+needs_tpu = pytest.mark.needs_tpu      # skipped off-chip by conftest.py
 
 
-@needs_tpu
+def _decode_step():
+    """The fused kernel, jitted: compiled on the chip, interpreted on the
+    CPU (same kernel body, so the parity cases run everywhere)."""
+    from building_llm_from_scratch_tpu.ops.decode_step import (
+        fused_decode_step,
+    )
+
+    return jax.jit(functools.partial(
+        fused_decode_step, interpret=jax.default_backend() != "tpu"))
+
+
 @pytest.mark.parametrize("B,Hq,Hkv,hd,Tmax,t", [
     (2, 12, 12, 64, 320, 5),      # GPT2-ish MHA
     (2, 32, 8, 64, 320, 17),      # GQA
@@ -23,9 +35,6 @@ needs_tpu = pytest.mark.skipif(jax.default_backend() != "tpu",
 ])
 def test_fused_decode_step_matches_jnp_path(B, Hq, Hkv, hd, Tmax, t):
     from building_llm_from_scratch_tpu.ops.attention import decode_attention
-    from building_llm_from_scratch_tpu.ops.decode_step import (
-        fused_decode_step,
-    )
 
     Tq = 1
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -44,7 +53,7 @@ def test_fused_decode_step_matches_jnp_path(B, Hq, Hkv, hd, Tmax, t):
     ref = decode_attention(q, K2, V2, q_positions=positions,
                            kv_length=length + Tq)
 
-    out, Ko, Vo = jax.jit(fused_decode_step)(q, kn, vn, K, V, length)
+    out, Ko, Vo = _decode_step()(q, kn, vn, K, V, length)
     np.testing.assert_allclose(np.asarray(Ko, np.float32),
                                np.asarray(K2, np.float32))
     np.testing.assert_allclose(np.asarray(Vo, np.float32),
@@ -54,15 +63,10 @@ def test_fused_decode_step_matches_jnp_path(B, Hq, Hkv, hd, Tmax, t):
                                atol=2e-2, rtol=2e-2)
 
 
-@needs_tpu
 def test_fused_decode_step_per_row_lengths():
     """Per-row lengths (the serving engine's slot batch, ops/decode_step
     slot semantics): each row appends at ITS offset and attends its own
     valid prefix — must match running each row alone at a scalar length."""
-    from building_llm_from_scratch_tpu.ops.decode_step import (
-        fused_decode_step,
-    )
-
     B, Hq, Hkv, hd, Tmax = 3, 12, 12, 64, 320
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     q = jax.random.normal(ks[0], (B, 1, Hq, hd), jnp.bfloat16)
@@ -72,9 +76,9 @@ def test_fused_decode_step_per_row_lengths():
     V = jax.random.normal(ks[4], (B, Hkv, Tmax, hd), jnp.bfloat16)
     lengths = jnp.asarray([0, 7, 133], jnp.int32)
 
-    out, Ko, Vo = jax.jit(fused_decode_step)(q, kn, vn, K, V, lengths)
+    out, Ko, Vo = _decode_step()(q, kn, vn, K, V, lengths)
     for b in range(B):
-        ob, Kb, Vb = jax.jit(fused_decode_step)(
+        ob, Kb, Vb = _decode_step()(
             q[b:b + 1], kn[b:b + 1], vn[b:b + 1], K[b:b + 1], V[b:b + 1],
             lengths[b])
         np.testing.assert_allclose(np.asarray(Ko[b:b + 1], np.float32),
@@ -89,10 +93,15 @@ def test_fused_decode_step_per_row_lengths():
 def test_decode_step_supports_shape_gates():
     from building_llm_from_scratch_tpu.ops.decode_step import supports_shape
 
-    assert supports_shape(1, 320, 64)
-    assert not supports_shape(2, 320, 64)      # single-token only
-    assert not supports_shape(1, 60, 64)       # Tmax must be 8-aligned
-    assert not supports_shape(1, 320, 96)      # head dim lane alignment
+    mha = dict(Hkv=12, Hq=12)
+    assert supports_shape(1, 320, 64, **mha)
+    assert not supports_shape(2, 320, 64, **mha)   # single-token only
+    assert not supports_shape(1, 60, 64, **mha)    # Tmax must be 8-aligned
+    assert not supports_shape(1, 320, 96, **mha)   # head dim lane alignment
+    # every Hkv pane of a row sits in VMEM at once: the engine's GPT2-124M
+    # shape fits, a 32-head 8k-token fp32 cache does not
+    assert supports_shape(1, 1024, 64, **mha)
+    assert not supports_shape(1, 8192, 128, Hkv=32, Hq=32, itemsize=4)
 
 
 # ---------------------------------------------------------------------------
@@ -167,24 +176,20 @@ def test_rmsnorm_custom_vjp_gradients(_norm_inputs):
 
 @needs_tpu
 @pytest.mark.parametrize("N,D,V", [(1024, 768, 50257), (256, 128, 999)])
-def test_pallas_xent_fwd_matches_xla(N, D, V, monkeypatch):
+def test_pallas_xent_fwd_matches_xla(N, D, V):
     """ops/xent_fwd_pallas.py (opt-in BLLM_XENT_PALLAS=1): nll and lse
-    match the XLA online-logsumexp forward exactly. The reference call
-    must NOT itself route through the kernel (it would if the opt-in env
-    var were exported in this process — the comparison would be
-    vacuous), so the gate is forced off for it."""
+    match the XLA online-logsumexp forward exactly."""
     from building_llm_from_scratch_tpu.ops.softmax_xent import (
-        _xent_fwd_impl,
+        _xent_fwd_xla,
     )
     from building_llm_from_scratch_tpu.ops.xent_fwd_pallas import xent_fwd
 
-    monkeypatch.setenv("BLLM_XENT_PALLAS", "0")
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     x = jax.random.normal(ks[0], (N, D), jnp.bfloat16)
     w = jax.random.normal(ks[1], (D, V), jnp.bfloat16) * 0.02
     t = jax.random.randint(ks[2], (N,), 0, V)
     nll, lse = jax.jit(xent_fwd)(x, w, t)
-    nll_ref, lse_ref = _xent_fwd_impl(x, w, t, 51200)
+    nll_ref, lse_ref = _xent_fwd_xla(x, w, t, 51200)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(nll), np.asarray(nll_ref),

@@ -14,8 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-needs_tpu = pytest.mark.skipif(jax.default_backend() != "tpu",
-                               reason="pallas fused kernel needs a real TPU")
+needs_tpu = pytest.mark.needs_tpu      # skipped off-chip by conftest.py
 
 
 def _qkv(B=2, T=512, Hq=4, Hkv=4, D=64, dtype=jnp.bfloat16, seed=0):

@@ -296,9 +296,14 @@ def test_extract_prefix_panes_zero_clamps_shareable_state(model):
     panes = []
     for suffix in ([33, 34, 35], [44]):
         prompt = np.concatenate([prefix, np.asarray(suffix, np.int32)])
+        # both donors ride the SAME prefill bucket, as in the engine: one
+        # program, so the shared span's sums associate identically (two
+        # prompt lengths are two XLA programs, equal only to an ulp)
+        padded = np.zeros((1, 16), np.int32)
+        padded[0, :len(prompt)] = prompt
         cache = init_slot_cache(cfg, 1, 32)
         _l, cache = prefill_into_slot(
-            params, cfg, jnp.asarray(prompt[None]),
+            params, cfg, jnp.asarray(padded),
             jnp.asarray(len(prompt), jnp.int32),
             jnp.asarray(0, jnp.int32), cache)
         panes.append(extract_prefix_panes(

@@ -406,7 +406,9 @@ def test_forward_adapter_mixed_ids_matches_per_row_lora(cfg, base_params):
     scaling = np.full((2,), ALPHA / RANK, np.float32)
     got = forward(base_params, cfg, tokens,
                   adapter={"pool": pool, "scaling": scaling, "ids": ids})
-    ref1 = forward(base_params, cfg, tokens[1:2])
+    # same batch shape as ``got``: a (1, T) forward is another XLA
+    # program whose sums associate differently (equal only to an ulp)
+    ref1 = forward(base_params, cfg, tokens)[1:2]
     ref0 = forward(base_params, cfg, tokens[2:3], lora=lora0,
                    lora_scaling=ALPHA / RANK)
     ref_1 = forward(base_params, cfg, tokens[0:1], lora=lora1,

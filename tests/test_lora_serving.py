@@ -485,8 +485,7 @@ def test_lora_bgmv_interpret_parity():
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="real pallas kernel needs a TPU")
+@pytest.mark.needs_tpu
 def test_lora_bgmv_tpu_parity():
     from building_llm_from_scratch_tpu.ops.decode_step import lora_bgmv
 

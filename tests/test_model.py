@@ -167,12 +167,6 @@ def test_gpt2_124M_param_count_full_size():
     assert 160e6 < n < 170e6
 
 
-@pytest.mark.xfail(
-    not hasattr(jax, "shard_map"),
-    reason="KV-cache vs dense-forward greedy argmax parity diverges on "
-           "this older jax CPU backend (reduction-order sensitive on an "
-           "untrained model); passes on current jax",
-    strict=False)
 def test_bucketed_generate_greedy_matches_dense_loop(rng_key):
     """generate() pads the prompt to a shape bucket and resets the cache
     length to the REAL prompt length — greedy output must equal the naive

@@ -583,11 +583,10 @@ def test_logging_non_coordinator_gates_info(monkeypatch):
     """The docstring always promised process-0 INFO gating; now it exists:
     below-WARNING records drop on non-coordinator processes unless
     BLLM_LOG_ALL_HOSTS is set."""
-    from jax._src import distributed
-
     lg, stream = _capture_logger("test_obs.gating")
     monkeypatch.delenv("BLLM_LOG_ALL_HOSTS", raising=False)
-    monkeypatch.setattr(distributed.global_state, "process_id", 3)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True)
+    monkeypatch.setattr(jax, "process_index", lambda: 3)
     lg.info("invisible info")
     lg.warning("visible warning")
     monkeypatch.setenv("BLLM_LOG_ALL_HOSTS", "1")
@@ -596,7 +595,7 @@ def test_logging_non_coordinator_gates_info(monkeypatch):
     assert "invisible info" not in out
     assert "visible warning" in out
     assert "debug override info" in out
-    monkeypatch.setattr(distributed.global_state, "process_id", 0)
+    monkeypatch.setattr(jax, "process_index", lambda: 0)
     lg.info("coordinator info")
     assert "coordinator info" in stream.getvalue()
 

@@ -2,9 +2,8 @@
 BenchResult schema round-trip, structural-fingerprint determinism, the
 two gate modes (structural fires on injected recompiles / FLOP growth
 with the offending program named; timing is silent across identical
-reruns but fires on an injected 1.5x slowdown), the trajectory store +
-BENCH_r01-r05 backfill, the bench runner end-to-end, and the
-summarize_metrics --compare view the gate's diagnosis reuses."""
+reruns but fires on an injected 1.5x slowdown), the trajectory store,
+the bench runner end-to-end, and the summarize_metrics --compare view the gate's diagnosis reuses."""
 
 import io
 import json
@@ -38,7 +37,7 @@ def _capture_fingerprint(fn, *args, label="prog"):
 def test_bench_result_roundtrip():
     res = perf.BenchResult(name="toy", metric="toy tokens/sec", value=123.4,
                            unit="tokens/sec", detail={"arm": {"x": 1}},
-                           vs_baseline=1.5, time=1700000000.0)
+                           time=1700000000.0)
     res.add_metric("mfu", 0.41, "fraction")
     res.repeats = perf.repeat_stats([120.0, 123.4, 125.0])
     res.env = perf.bench_env()
@@ -225,7 +224,7 @@ def test_timing_noise_floor_scales_with_stddev():
 
 
 # ---------------------------------------------------------------------------
-# Trajectory store + BENCH_r01-r05 backfill
+# Trajectory store
 # ---------------------------------------------------------------------------
 
 def test_trajectory_store_roundtrip(tmp_path):
@@ -240,30 +239,6 @@ def test_trajectory_store_roundtrip(tmp_path):
     assert store.names() == ["toy"]
     with pytest.raises(ValueError):
         store.append({"type": "bench", "name": "toy"})  # invalid row
-
-
-def test_backfill_covers_bench_r01_to_r05(tmp_path):
-    store = perf.TrajectoryStore(str(tmp_path / "perf"))
-    added = perf.backfill_bench_history(REPO_ROOT, store)
-    assert added == 5
-    rows = store.load("headline")
-    sources = sorted(r["source"] for r in rows)
-    assert sources == [f"BENCH_r0{i}.json" for i in range(1, 6)]
-    values = {r["source"]: r["value"] for r in rows}
-    assert values["BENCH_r02.json"] == 37039.6
-    assert values["BENCH_r05.json"] == 99274.1
-    # r04/r05 carry MFU; every row validates against the schema
-    assert all(perf.validate_row(r) == [] for r in rows)
-    r05 = next(r for r in rows if r["source"] == "BENCH_r05.json")
-    assert r05["metrics"]["mfu"]["value"] == 0.402
-    # idempotent: a second backfill adds nothing
-    assert perf.backfill_bench_history(REPO_ROOT, store) == 0
-    out = io.StringIO()
-    n = perf.render_trajectory(store, out=out)
-    text = out.getvalue()
-    assert n == 5
-    for needle in ("BENCH_r01.json", "BENCH_r05.json", "99274.1", "0.402"):
-        assert needle in text, text
 
 
 def test_trajectory_tolerates_header_rows(tmp_path):
@@ -295,16 +270,6 @@ def test_compare_structural_finding_iff_digest_differs():
     findings = perf.compare_structural(base, fresh)
     assert findings and any("decode" in f["detail"] for f in findings)
     assert perf.compare_structural(base, dict(base)) == []
-
-
-def test_checked_in_trajectory_covers_history():
-    """The committed results/perf/headline.jsonl must already contain the
-    backfilled r01-r05 rows — the bench history is machine-readable in
-    the repo itself, not only after running a script."""
-    store = perf.TrajectoryStore()
-    rows = store.load("headline")
-    sources = {r.get("source") for r in rows}
-    assert {f"BENCH_r0{i}.json" for i in range(1, 6)} <= sources
 
 
 # ---------------------------------------------------------------------------

@@ -25,16 +25,6 @@ from building_llm_from_scratch_tpu.training.train_step import (
 
 
 
-# jax<0.5 (no jax.shard_map alias) cannot transpose a shard_map whose out
-# is a replicated scalar (the pipeline loss): jax.experimental.shard_map
-# raises _SpecError in the grad path (fixed upstream alongside the alias).
-# Forward/eval pp paths work; only grad-through tests are affected.
-needs_shard_map_transpose = pytest.mark.xfail(
-    not hasattr(jax, "shard_map"),
-    reason="shard_map transpose of a replicated scalar out is broken on "
-           "this jax version (fixed upstream with jax.shard_map)",
-    strict=False)
-
 def _cfg(n_layers=4):
     return get_config("llama3_2", "1B", debug=True).replace(
         emb_dim=64, hidden_dim=128, vocab_size=512, context_length=64,
@@ -70,7 +60,6 @@ def test_pp_loss_matches_single_device(stages, n_micro):
     assert abs(got - want) < 1e-5, (got, want)
 
 
-@needs_shard_map_transpose
 def test_pp_gradients_match_single_device():
     cfg = _cfg(n_layers=4)
     mesh = make_pp_mesh(4)
@@ -87,7 +76,6 @@ def test_pp_gradients_match_single_device():
             err_msg=str(path))
 
 
-@needs_shard_map_transpose
 def test_pp_training_matches_single_device():
     """3 pipelined train steps == 3 single-device steps."""
     cfg = _cfg(n_layers=8)
@@ -119,7 +107,6 @@ def test_pp_training_matches_single_device():
     np.testing.assert_allclose(got_w, ref_w, rtol=2e-3, atol=2e-5)
 
 
-@needs_shard_map_transpose
 def test_pp_tp_loss_and_gradients_match_single_device():
     """pp x tp (round-5 VERDICT #6): (data=2, stage=2, model=2) mesh —
     loss AND every RAW gradient leaf match single-device. No manual
@@ -189,7 +176,6 @@ def test_pp_tp_state_shardings_split_model_axis():
     assert sh["tok_emb"]["weight"].spec == P()
 
 
-@needs_shard_map_transpose
 def test_pp_tp_dropout_trains_gpt2():
     """GPT-2 (dropout 0.1, qkv biases) under pp x tp: runs and the loss is
     finite — attention masks fold the model-shard index, residual masks
@@ -210,7 +196,6 @@ def test_pp_tp_dropout_trains_gpt2():
     assert np.isfinite(losses).all(), losses
 
 
-@needs_shard_map_transpose
 def test_pp_lora_matches_single_device():
     """pp + LoRA: adapters merge before the stage split; losses match the
     plain LoRA step and ONLY the adapters update."""
@@ -290,7 +275,6 @@ def test_pp_rejects_bad_shapes():
 # round-4 (pipeline v2): remat opt-in, dropout, drain-tick gating
 # ---------------------------------------------------------------------------
 
-@needs_shard_map_transpose
 def test_pp_gradients_match_with_and_without_remat():
     """--use_actv_ckpt only changes memory/recompute, never values: pp
     grads with remat on == off (and == single-device)."""
@@ -311,7 +295,6 @@ def test_pp_gradients_match_with_and_without_remat():
         g_plain, g_remat)
 
 
-@needs_shard_map_transpose
 def test_pp_dropout_trains_gpt2():
     """GPT-2 (dropout 0.1) pipelines since v2: per-(micro,data,stage,layer)
     folded masks; losses finite and decreasing on a repeated batch."""

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 from building_llm_from_scratch_tpu.configs import get_config
 from building_llm_from_scratch_tpu.models import forward, init_params
@@ -14,7 +15,6 @@ from building_llm_from_scratch_tpu.ops.ring_attention import (
     ring_causal_attention,
 )
 from building_llm_from_scratch_tpu.parallel import build_mesh_plan
-from building_llm_from_scratch_tpu.parallel.collectives import shard_map
 from building_llm_from_scratch_tpu.training import (
     build_optimizer,
     init_train_state,

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from building_llm_from_scratch_tpu.configs import get_config
 from building_llm_from_scratch_tpu.models import forward, init_params
@@ -221,3 +221,46 @@ def test_zero1_trainer_keeps_opt_state_sharded():
     # at least the big mu leaves remain sharded over the data axis
     assert any(leaf.sharding.spec != P() for _, leaf in mu), (
         "zero1 optimizer state was silently replicated")
+
+
+def test_mesh_kernel_matches_unsharded_forward_and_grads():
+    """``mesh_kernel`` (what every pallas kernel calls itself through) is
+    placement, not semantics: under a (data, seq, model) mesh — with
+    activations replicated over model and heads sharded over it — a
+    stand-in kernel gives the values AND gradients of the bare call. The
+    replicated axis is the sharp edge: its cotangents must not be counted
+    once per model shard."""
+    from building_llm_from_scratch_tpu.parallel.collectives import (
+        mesh_kernel,
+        trace_under_mesh,
+    )
+
+    mesh = make_mesh(data=2, seq=2, model=2)
+    act = ("data", "seq", None)
+    heads = ("data", None, "model", None)
+    shards = []
+
+    def op(x, h, w):
+        def residual(shard, x, h):
+            shards.append(shard)
+            return x + jnp.tanh(h) * 2.0
+
+        y = mesh_kernel(residual, (x, h), (act, act), act)
+        q = (y @ w).reshape(*y.shape[:2], 4, 8)
+        z = mesh_kernel(lambda _, q: jnp.sin(q), (q,), (heads,), heads)
+        return (z ** 2).sum()
+
+    rng = np.random.default_rng(0)
+    x, h = (jnp.asarray(rng.normal(size=(4, 8, 16)), jnp.float32)
+            for _ in range(2))
+    w = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
+    want = jax.value_and_grad(op, (0, 1, 2))(x, h, w)     # no mesh: bare
+    assert int(shards[0]) == 0
+    rows = NamedSharding(mesh, P("data", "seq"))
+    got = jax.jit(trace_under_mesh(jax.value_and_grad(op, (0, 1, 2)), mesh))(
+        jax.device_put(x, rows), jax.device_put(h, rows),
+        jax.device_put(w, NamedSharding(mesh, P(None, "model"))))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-4)

@@ -1,0 +1,195 @@
+"""Compile the pallas kernels for a DESCRIBED TPU v5e, without a chip.
+
+The chip's compiler is installed here and compiles for a topology that is
+described, not attached (``on-chip-measurement`` guide §2, third rehearsal).
+Interpret-mode CPU tests cannot see what it refuses: an SMEM block that is
+not a legal tile, more scoped VMEM than a kernel may use, a Mosaic call
+handed to GSPMD without a shard_map. Every kernel here had passed its
+interpret-mode tests while the compiler refused it. Nothing runs, so these
+cases say nothing about results or times (chip_smoke.py does, on the chip).
+
+ONE file, by design: only one process may hold the TPU library, so the
+topology is described inside a module-scoped fixture — after a test of this
+file has started, in the one xdist worker that was given the file — and
+never at import or collection.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from building_llm_from_scratch_tpu.ops import decode_step as ds
+from building_llm_from_scratch_tpu.ops import fused_attention as fa
+from building_llm_from_scratch_tpu.ops import fused_dropout as fd
+from building_llm_from_scratch_tpu.ops import xent_fwd_pallas as xp
+from building_llm_from_scratch_tpu.parallel.collectives import (
+    trace_under_mesh,
+)
+
+BF16, I32 = jnp.bfloat16, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to a persistent cache but
+    # cannot be read back without a chip: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *specs) -> str:
+    """Lower + compile for the described chip; the optimized HLO text."""
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _spec(sharding):
+    return lambda shape, dtype=BF16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
+
+
+def _attention_grad(q, k, v, rng):
+    """Forward + all three gradients, attention dropout on."""
+    return jax.grad(lambda q, k, v: fa.fused_causal_attention(
+        q, k, v, dropout_rate=0.1, dropout_rng=rng).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("B,T,Hq,Hkv,D", [
+    (8, 1024, 12, 12, 64),       # GPT2-124M train step (bs8)
+    (8, 1024, 32, 8, 64),        # LLaMA-3.2-1B GQA 32/8
+])
+def test_fused_attention_fwd_grad_dropout(one_chip, B, T, Hq, Hkv, D):
+    assert fa.supports_shape(T, T, D)
+    s = _spec(one_chip)
+    hlo = _compile(_attention_grad, s((B, T, Hq, D)), s((B, T, Hkv, D)),
+                   s((B, T, Hkv, D)), s((2,), jnp.uint32))
+    assert hlo.count("tpu_custom_call") == 3     # fwd, dq, dkv
+
+
+def test_fused_dropout_add_fwd_grad(one_chip):
+    shape = (8, 1024, 768)
+    assert fd.supports_shape(shape)
+    s = _spec(one_chip)
+
+    def fn(x, h, rng):
+        return jax.value_and_grad(lambda h: fd.fused_dropout_add(
+            x, h, 0.1, rng).astype(jnp.float32).sum())(h)
+
+    hlo = _compile(fn, s(shape), s(shape), s((2,), jnp.uint32))
+    assert hlo.count("tpu_custom_call") == 2     # forward, mask redraw
+
+
+def _decode_specs(s, S=8, H=12, Tmax=1024, hd=64):
+    new, pane = s((S, 1, H, hd)), s((S, H, Tmax, hd))
+    return new, new, new, pane, pane, s((S,), I32)
+
+
+def test_fused_decode_step_engine_shape(one_chip):
+    """The serving engine's decode tick: 8 slots, Tmax 1024, PER-ROW
+    lengths (the (1, 1) SMEM block this call used to pass was refused)."""
+    assert ds.supports_shape(1, 1024, 64, Hkv=12, Hq=12)
+    hlo = _compile(ds.fused_decode_step, *_decode_specs(_spec(one_chip)))
+    assert hlo.count("tpu_custom_call") == 1
+
+
+def test_fused_decode_step_refused_inside_a_deep_program(one_chip):
+    """Why ``transformer._use_fused_decode`` is opt-in: from six layers up
+    the compiler assigns whole cache arrays to VMEM beside the kernel's own
+    scope and refuses the program. When this case starts FAILING (the
+    program compiles), the default can be revisited (ROADMAP S1/S5)."""
+    s = _spec(one_chip)
+    q, kn, vn, pane, _, lens = _decode_specs(s)
+
+    def six_layers(Ks, Vs, q, kn, vn, lens):
+        caches = []
+        for K, V in zip(Ks, Vs):
+            o, K, V = ds.fused_decode_step(q, kn, vn, K, V, lens)
+            q = q + o
+            caches.append((K, V))
+        return q, caches
+
+    with pytest.raises(Exception, match="vmem"):
+        _compile(six_layers, [pane] * 6, [pane] * 6, q, kn, vn, lens)
+
+
+def test_paged_decode_attention(one_chip):
+    S, H, hd, page, n_pages, max_pages = 8, 12, 64, 16, 512, 64
+    assert ds.supports_paged_shape(1, page, hd)
+    s = _spec(one_chip)
+    pool = s((n_pages, H, page, hd))
+    hlo = _compile(ds.paged_decode_attention, s((S, 1, H, hd)), pool, pool,
+                   s((S, max_pages), I32), s((S,), I32))
+    assert hlo.count("tpu_custom_call") == 1
+
+
+def test_lora_bgmv(one_chip):
+    S, D, r, O, N = 8, 768, 16, 3072, 16
+    assert ds.supports_lora_shape(D, r, O)
+    s = _spec(one_chip)
+    hlo = _compile(ds.lora_bgmv, s((S, D)), s((N, D, r)), s((N, r, O)),
+                   s((S,), I32), s((N,), jnp.float32))
+    assert hlo.count("tpu_custom_call") == 1
+
+
+def test_xent_fwd_largest_admitted_shape(one_chip):
+    """The gate's VMEM budget, at its edge: the largest hidden width it
+    admits for 2048 rows compiles, and the next lane multiple is refused
+    by the gate (not by the compiler, mid-run). The vocabulary only sets
+    the grid length, so a short one keeps the case quick."""
+    N, D, V = 2048, 8704, 1024
+    assert xp.supports_shape(N, D, V)
+    assert not xp.supports_shape(N, D + 1024, V)
+    assert not xp.supports_shape(16384, 768, 50257)   # refused at 100 MiB
+    s = _spec(one_chip)
+    hlo = _compile(xp.xent_fwd, s((N, D)), s((D, V)), s((N,), I32))
+    assert hlo.count("tpu_custom_call") == 1
+
+
+def test_sharded_attention_on_four_devices(topo):
+    """GSPMD refuses a bare Mosaic call ("wrap the call in a shard_map"):
+    under a 4-device data mesh the kernels shard_map themselves over the
+    mesh the step builder made visible (parallel/collectives)."""
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    s = _spec(NamedSharding(mesh, P("data")))
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    qkv = s((16, 1024, 12, 64))
+    hlo = _compile(trace_under_mesh(_attention_grad, mesh),
+                   qkv, qkv, qkv, rng)
+    assert hlo.count("tpu_custom_call") == 3
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(_attention_grad, qkv, qkv, qkv, rng)   # no mesh in scope
+
+
+def test_sharded_decode_step_on_four_devices(topo):
+    """``--serve_tp 4``: heads (and the slot cache's panes) shard over the
+    model axis; each device appends and attends its own three heads."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
+                ("data", "seq", "model"))
+    heads = _spec(NamedSharding(mesh, P(None, None, "model")))
+    panes = _spec(NamedSharding(mesh, P(None, "model")))
+    new, pane = heads((8, 1, 12, 64)), panes((8, 12, 1024, 64))
+    lens = jax.ShapeDtypeStruct((8,), I32, sharding=NamedSharding(mesh, P()))
+    hlo = _compile(trace_under_mesh(ds.fused_decode_step, mesh),
+                   new, new, new, pane, pane, lens)
+    assert hlo.count("tpu_custom_call") == 1
