@@ -60,6 +60,7 @@ def token_rng(rng: jax.Array, i) -> jax.Array:
     return jax.random.fold_in(rng, i)
 
 
+@jax.named_scope("sampling")
 def sample_tokens_dynamic(logits: jnp.ndarray, keys: jnp.ndarray,
                           temperature: jnp.ndarray, top_k: jnp.ndarray,
                           max_top_k: int) -> jnp.ndarray:

@@ -126,6 +126,7 @@ def unstack_lora_blocks(lora: Params, cfg: ModelConfig) -> list:
     ]
 
 
+@jax.named_scope("head")
 def _head_logits(x: jnp.ndarray, w: jnp.ndarray,
                  node: Optional[Params] = None,
                  scaling=None) -> jnp.ndarray:
@@ -212,6 +213,7 @@ def _norm(cfg: ModelConfig, p: Params, x: jnp.ndarray) -> jnp.ndarray:
     return layernorm(x, p["scale"], p.get("bias"), eps=cfg.layernorm_eps)
 
 
+@jax.named_scope("mlp")
 def _mlp(cfg: ModelConfig, p: Params, x: jnp.ndarray,
          tp_axis: Optional[str] = None,
          adp: Optional[Params] = None) -> jnp.ndarray:
@@ -315,6 +317,7 @@ def _residual_dropout(x: jnp.ndarray, h: jnp.ndarray, rate: float,
     return x + _dropout(h, rate, rng, deterministic)
 
 
+@jax.named_scope("attention")
 def _qkv_proj(cfg: ModelConfig, p: Params, x: jnp.ndarray,
               rope, positions, adp: Optional[Params] = None):
     """Shared q/k/v projection (+biases, head reshape, RoPE) — the single
@@ -351,6 +354,7 @@ def _qkv_proj(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     return q, k, v
 
 
+@jax.named_scope("attention")
 def _attn_out_proj(p: Params, out: jnp.ndarray, B: int, Tq: int,
                    tp_axis: Optional[str] = None,
                    adp: Optional[Params] = None) -> jnp.ndarray:
@@ -427,15 +431,16 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
             dropout_rate=cfg.drop_rate if dropout_on else 0.0,
             dropout_rng=rng if dropout_on else None)
     else:
-        out = causal_attention(
-            q, k, v,
-            q_positions=q_positions,
-            kv_length=kv_length,
-            dropout_rate=cfg.drop_rate,
-            dropout_rng=rng,
-            deterministic=deterministic,
-            impl=cfg.attn_impl,
-        )
+        with jax.named_scope("attention"):
+            out = causal_attention(
+                q, k, v,
+                q_positions=q_positions,
+                kv_length=kv_length,
+                dropout_rate=cfg.drop_rate,
+                dropout_rng=rng,
+                deterministic=deterministic,
+                impl=cfg.attn_impl,
+            )
     out = checkpoint_name(out, "attn_out")
     out = _attn_out_proj(p, out, B, Tq, tp_axis=tp_axis, adp=adp)
     return out, new_cache
@@ -889,6 +894,7 @@ def _cache_quantized(cache: Params) -> bool:
     return "k_scale" in cache
 
 
+@jax.named_scope("cache_update")
 def _slot_write(cache: Params, name: str, pane: jnp.ndarray, offsets: tuple,
                 new: Params) -> None:
     """Append one layer's cache write into the ``new`` accumulator:
@@ -912,6 +918,7 @@ def _new_cache_acc(cache: Params) -> Params:
     return {name: [] for name in cache}
 
 
+@jax.named_scope("cache_update")
 def _slot_append_kv(cache: Params, new: Params, l: int,
                     K: jnp.ndarray, V: jnp.ndarray,
                     k: jnp.ndarray, v: jnp.ndarray,
@@ -994,8 +1001,9 @@ def prefill_into_slot(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         h = _norm(cfg, p["norm1"], x)
         q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
                             adp=adp["attn"] if adp is not None else None)
-        out = causal_attention(q, k, v, q_positions=positions,
-                               kv_length=prompt_len)
+        with jax.named_scope("attention"):
+            out = causal_attention(q, k, v, q_positions=positions,
+                                   kv_length=prompt_len)
         # (1, Tpb, Hkv, hd) -> cache-native (1, Hkv, Tpb, hd) pane at
         # (slot, 0, 0, 0); Tpb <= Tmax by the engine's admission check
         k = jnp.where(valid, k, jnp.zeros((), k.dtype))
@@ -1186,9 +1194,10 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             new["v"].append(V)
         else:
             K, V = _slot_append_kv(cache, new, l, K, V, k, v, lengths)
-            out = decode_attention(q, K, V, q_positions=positions,
-                                   kv_length=lengths + 1,
-                                   **_layer_scales(new, l))
+            with jax.named_scope("attention"):
+                out = decode_attention(q, K, V, q_positions=positions,
+                                       kv_length=lengths + 1,
+                                       **_layer_scales(new, l))
         x = x + _attn_out_proj(p["attn"], out, S, 1,
                                adp=adp["attn"] if adp is not None else None)
         x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),

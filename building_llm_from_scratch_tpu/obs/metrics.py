@@ -50,13 +50,14 @@ watcher thread.
 from __future__ import annotations
 
 import bisect
+import collections
 import json
 import os
 import re
 import sys
 import threading
 import time
-from typing import Any, Dict, IO, Optional
+from typing import Any, Dict, IO, List, Optional
 
 from building_llm_from_scratch_tpu.obs.schema import SCHEMA_VERSION
 from building_llm_from_scratch_tpu.utils.logging import setup_logger
@@ -328,6 +329,18 @@ def render_prometheus(counters: Dict[str, float],
     return "\n".join(lines) + "\n"
 
 
+#: Newest rows kept in memory per kind, file or no file (``recent``), for
+#: the kinds that have a reader in the process: ``RECENT_RECORDS`` of a
+#: memory-only kind (``keep_record``: the engine's tick records, a dozen
+#: numbers each; eight minutes of 60 ms ticks) and ``RECENT_FILE_ROWS`` of
+#: the JSONL row types ``RECENT_FILE_KINDS`` (larger: a cadence row carries
+#: every counter and gauge). ``event`` and ``health`` rows go to the file
+#: only.
+RECENT_RECORDS = 8192
+RECENT_FILE_ROWS = 2048
+RECENT_FILE_KINDS = ("span", "metrics")
+
+
 class MetricLogger:
     """Counters/gauges/timings plus a typed JSONL sink.
 
@@ -335,6 +348,12 @@ class MetricLogger:
     for tests/inspection) but writes nothing. All writes go through one
     lock; rows are flushed immediately — a preempted run keeps every row
     up to its last completed cadence.
+
+    Whether or not a file is set, the newest rows of each kind stay in a
+    bounded buffer that ``recent(kind)`` returns: what a benchmark reads
+    back when its window closes, and what ``/healthz`` and the stall
+    detector show of the ticks before a hang (``obs/stall.last_ticks``). The buffer holds
+    JSON-plain values only, never a reference into the program's state.
     """
 
     def __init__(self, jsonl_path: Optional[str] = None,
@@ -364,6 +383,7 @@ class MetricLogger:
         # flushed right after it, keeping the header the first line
         self._pre_header: list = []               # guarded-by: _lock
         self._last_step = -1                      # guarded-by: _lock
+        self._recent: Dict[str, collections.deque] = {}  # guarded-by: _lock
 
     # -- aggregation -----------------------------------------------------
 
@@ -393,11 +413,35 @@ class MetricLogger:
             return False
         return not self.coordinator_only or _is_coordinator()
 
+    def keep_record(self, kind: str, row: Dict[str, Any]) -> None:
+        """Keep one memory-only record of ``kind`` (never written to the
+        file). The caller hands over a dict of plain numbers and strings
+        that it does not touch again."""
+        with self._lock:
+            self._keep(kind, row, RECENT_RECORDS)
+
+    # holds: _lock
+    def _keep(self, kind: str, row: Dict[str, Any], maxlen: int) -> None:
+        buf = self._recent.get(kind)
+        if buf is None:
+            buf = self._recent[kind] = collections.deque(maxlen=maxlen)
+        buf.append(row)
+
+    def recent(self, kind: str) -> List[Dict[str, Any]]:
+        """The newest rows of ``kind``, oldest first: a JSONL row type of
+        ``RECENT_FILE_KINDS`` or a ``keep_record`` kind (``tick``)."""
+        with self._lock:
+            return list(self._recent.get(kind, ()))
+
     def _write_row(self, row: Dict[str, Any]) -> None:
-        """Append one row. Never raises: telemetry failure must not take
-        down the training loop it observes."""
+        """Keep one row in memory and, where a file is set, append it.
+        Never raises: telemetry failure must not take down the training
+        loop it observes."""
+        row = _jsonable(row)
         try:
             with self._lock:
+                if row["type"] in RECENT_FILE_KINDS:
+                    self._keep(row["type"], row, RECENT_FILE_ROWS)
                 # writability is decided under the lock: a close() racing
                 # this write either lands before (row dropped) or after
                 # (row flushed) — never between check and write
@@ -427,7 +471,7 @@ class MetricLogger:
                             n += 1
                         os.rename(self.jsonl_path, f"{self.jsonl_path}.{n}")
                     self._file = open(self.jsonl_path, "a")
-                self._file.write(json.dumps(_jsonable(row)) + "\n")
+                self._file.write(json.dumps(row) + "\n")
                 self._file.flush()
         except OSError as e:
             logger.warning("Metrics sink write failed (%s); row dropped.", e)

@@ -86,6 +86,39 @@ TICK_PHASES = ("admit", "prefix_copy", "prefill", "prefill_shard", "draft",
                "decode_dispatch", "host_fetch", "sample_commit",
                "callback_detok")
 
+#: Each tick phase's name in a profiler trace (``jax.profiler``
+#: ``TraceAnnotation``, opened by the engine's ``StepTimeline``): prefixed,
+#: so none can be mistaken for a span of the runtime, and short, because a
+#: device-idle gap is labelled ``<host span>___after_<program>_before_
+#: <program>`` and cut at 64 characters (benchmark/trace.py). Every tick is
+#: one ``StepTraceAnnotation`` named ``TICK_STEP``; the engine thread's time
+#: outside ``step()`` lies in ``TICK_BETWEEN`` (heartbeats, the cadence
+#: row) or ``TICK_IDLE_WAIT`` (nothing to do).
+TICK_SPANS = {"admit": "tick.admit", "prefix_copy": "tick.pfx_copy",
+              "prefill": "tick.prefill", "prefill_shard": "tick.pf_shard",
+              "draft": "tick.draft", "decode_dispatch": "tick.dispatch",
+              "host_fetch": "tick.fetch", "sample_commit": "tick.commit",
+              "callback_detok": "tick.callback"}
+TICK_STEP = "tick"
+TICK_BETWEEN = "tick.between"
+TICK_IDLE_WAIT = "tick.idle_wait"
+
+#: One memory-only record per tick (``get_metrics().recent("tick")``;
+#: serving/engine.py ``_book_tick``), numbers only. ``t0``/``t1`` bound the
+#: timed part of ``step()``; ``t_dispatch``/``t_fetch`` are the ends of
+#: ``decode_dispatch`` and ``host_fetch`` (absent where the tick ran no
+#: decode program): from one tick's ``t_fetch`` to the next's
+#: ``t_dispatch`` the device has no decode queued. All four are
+#: ``time.perf_counter`` readings, which on Linux is the clock of
+#: ``time.monotonic`` (CLOCK_MONOTONIC) that stamps requests, so a span
+#: row's ``t_submit`` lies on the same axis; ``wall_submit`` on the span
+#: row is the one anchor to unix time. ``phases`` holds the self seconds
+#: of each phase that ran; ``tick`` is ``n_ticks`` after the tick; ``rows``
+#: of the program's ``n_slots`` rows decoded a token.
+TICK_RECORD_FIELDS = ("tick", "t0", "t1", "t_dispatch", "t_fetch", "phases",
+                      "rows", "n_slots", "admitted", "queue_depth",
+                      "replica")
+
 #: Trainer StepTimeline segments (``<segment>_s`` fields of training
 #: cadence metrics rows; obs/timeline.py owns the measurement).
 TRAIN_SEGMENTS = ("data_wait", "dispatch", "host_fetch", "eval", "sample",

@@ -19,8 +19,10 @@ would itself hang behind the wedged one — so this watcher:
     eval, checkpoint saves) — without the floor a fast-stepping run
     false-fires on its first eval;
   - on firing, logs every Python thread's stack (``sys._current_frames``)
-    and live ``device.memory_stats()`` for the local devices, and emits a
-    structured ``stall`` event — all local, no collectives;
+    and live ``device.memory_stats()`` for the local devices and, in a
+    serving process, the engine's last tick records (``last_ticks``: which
+    phase of which tick the time went into before the hang), and emits
+    a structured ``stall`` event — all local, no collectives;
   - never kills anything: it is a flight recorder, not a watchdog. It
     re-arms after the next heartbeat, so an intermittent stall produces
     one dump per episode instead of a dump per poll tick.
@@ -52,6 +54,33 @@ def format_all_stacks() -> str:
         parts.append(f"--- Thread {name} (ident {ident}) ---")
         parts.append("".join(traceback.format_stack(frame)).rstrip())
     return "\n".join(parts)
+
+
+#: Tick records a dump and ``/healthz`` show: the ticks before the hang.
+LAST_TICKS = 8
+
+
+def last_ticks(replica: Optional[int] = None) -> List[dict]:
+    """The serving engine's newest ``LAST_TICKS`` tick records (of
+    ``replica``, where a fleet shares the process), from the metrics hub,
+    each cut to what an operator reads: which tick, how long ago it ended,
+    its wall and phases in ms, the rows that decoded of the program's, the
+    requests it admitted and the queue it started behind. A wedged tick
+    books no record: these are the ones before it. Empty in a process
+    that serves nothing."""
+    from building_llm_from_scratch_tpu.obs.metrics import get_metrics
+
+    now = time.perf_counter()
+    ticks = [t for t in get_metrics().recent("tick")
+             if replica is None or t.get("replica") == replica]
+    return [{"tick": t["tick"], "ended_s_ago": round(now - t["t1"], 3),
+             "wall_ms": round(1e3 * (t["t1"] - t["t0"]), 3),
+             "phases_ms": {ph: round(1e3 * dt, 3)
+                           for ph, dt in t["phases"].items()},
+             "rows": t["rows"], "n_slots": t["n_slots"],
+             "admitted": t["admitted"], "queue_depth": t["queue_depth"],
+             **({"replica": t["replica"]} if "replica" in t else {})}
+            for t in ticks[-LAST_TICKS:]]
 
 
 def _device_memory_report() -> dict:
@@ -155,8 +184,8 @@ class StallDetector:
         logger.error(
             "STALL: no train step completed in %.1fs (threshold %.1fs). "
             "Dumping all Python thread stacks (host-local; no collectives):"
-            "\n%s\nDevice memory stats: %s",
-            elapsed, thr, format_all_stacks(), mem)
+            "\n%s\nDevice memory stats: %s\nLast serving ticks: %s",
+            elapsed, thr, format_all_stacks(), mem, last_ticks())
         from building_llm_from_scratch_tpu.obs.metrics import emit_event
 
         emit_event("stall", elapsed_s=round(elapsed, 3),

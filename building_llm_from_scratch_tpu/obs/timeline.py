@@ -1,4 +1,5 @@
-"""Per-step wall-clock breakdown + profiler trace annotation.
+"""Per-step wall-clock breakdown + profiler trace annotation, for the
+trainer's step loop and the serving engine's tick alike.
 
 Two jobs, one API:
 
@@ -11,9 +12,10 @@ Two jobs, one API:
      sampling (ISSUE-2 satellite: the old ``t_tokens/t_start`` window
      deflated tok/s whenever a sample or save fired inside it).
   2. **Navigability** — the same spans become ``jax.profiler``
-     ``TraceAnnotation`` blocks, and each train step gets a
-     ``StepTraceAnnotation``, so a ``--profile`` xplane capture shows named
-     regions instead of an undifferentiated op soup.
+     ``TraceAnnotation`` blocks, and each train step or engine tick gets
+     a ``StepTraceAnnotation``, so an xplane capture shows named regions
+     instead of an undifferentiated op soup, and a gap in the device's
+     work can be named by what the host was doing in it.
 
 Annotations are no-ops when no trace is active (jax makes them ~free), so
 the spans stay on permanently — they are NOT gated on ``--profile``.
@@ -21,84 +23,91 @@ the spans stay on permanently — they are NOT gated on ``--profile``.
 
 from __future__ import annotations
 
-import contextlib
 import time
-from typing import Dict, Iterator, Optional
+from typing import Dict, Mapping, Optional
+
+import jax
 
 #: Segments excluded from the throughput window: host-side cadence work
 #: that is not training (the step loop is paused, not slow).
 NON_STEP_SEGMENTS = ("eval", "sample", "checkpoint")
 
+#: Named ``jax.profiler.TraceAnnotation`` span, for a stretch of a loop's
+#: life that belongs to no segment (the engine loop between two ticks), and
+#: the ``StepTraceAnnotation`` that holds one step of a loop.
+annotate = jax.profiler.TraceAnnotation
+annotate_step = jax.profiler.StepTraceAnnotation
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named ``jax.profiler.TraceAnnotation`` span (degrades to a no-op if
-    the profiler API is unavailable)."""
-    try:
-        import jax
 
-        ctx = jax.profiler.TraceAnnotation(name)
-    except Exception:
-        ctx = contextlib.nullcontext()
-    with ctx:
-        yield
+class _Span:
+    """One open span of a ``StepTimeline`` (see ``StepTimeline.span``)."""
+
+    __slots__ = ("tl", "segment", "ann", "t0", "closed0")
+
+    def __init__(self, tl: "StepTimeline", segment: str, ann):
+        self.tl, self.segment, self.ann = tl, segment, ann
+
+    def __enter__(self) -> None:
+        self.ann.__enter__()
+        self.closed0 = self.tl._closed
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        tl = self.tl
+        now = time.perf_counter()
+        # self time: the spans that closed inside this one have booked
+        # exactly the part of this interval their own names cover
+        own = max(now - self.t0 - (tl._closed - self.closed0), 0.0)
+        tl._closed += own
+        tl.seconds[self.segment] = tl.seconds.get(self.segment, 0.0) + own
+        tl.ends[self.segment] = now
+        self.ann.__exit__(*exc)
 
 
 class StepTimeline:
-    """Accumulates named wall-clock segments between ``drain()`` calls.
-
-    The trainer drains once per logging cadence; the returned dict is the
-    window's breakdown in seconds. Spans double as profiler trace
+    """Accumulates named wall-clock segments between ``drain()`` calls:
+    the one span primitive of both loops. The trainer drains once per
+    logging cadence, the serving engine once per tick; the returned dict
+    is the breakdown in seconds. Spans double as profiler trace
     annotations (see module docstring).
+
+    ``span_names`` maps a segment to its name in the trace where that is
+    not the segment's own (``obs/schema.py`` ``TICK_SPANS``).
+    Spans nest: a segment's seconds are its SELF time, its duration less
+    what the spans inside it booked, so segments never count an interval
+    twice and their sum cannot pass the wall. One thread at a time.
     """
 
-    def __init__(self):
+    def __init__(self, span_names: Optional[Mapping[str, str]] = None):
+        self.span_names = span_names or {}
         self.seconds: Dict[str, float] = {}
+        #: ``time.perf_counter`` at which each segment last closed
+        self.ends: Dict[str, float] = {}
         self.steps_in_window = 0
+        self._closed = 0.0      # self seconds of every span closed so far
 
-    def add(self, segment: str, dt: float) -> None:
-        self.seconds[segment] = self.seconds.get(segment, 0.0) + dt
-
-    @contextlib.contextmanager
-    def span(self, segment: str) -> Iterator[None]:
+    def span(self, segment: str) -> _Span:
         """Time a block into ``segment`` and annotate it in the trace."""
-        t0 = time.perf_counter()
-        try:
-            with annotate(segment):
-                yield
-        finally:
-            self.add(segment, time.perf_counter() - t0)
+        return _Span(self, segment,
+                     annotate(self.span_names.get(segment, segment)))
 
-    @contextlib.contextmanager
-    def step_span(self, step_num: int) -> Iterator[None]:
+    def step_span(self, step_num: int) -> _Span:
         """One train step: ``StepTraceAnnotation`` (so xplane groups ops
         per step) + ``dispatch`` accounting. The measured time is DISPATCH
         latency — jitted steps return before the device finishes; the
-        execution catch-up is visible as ``host_fetch`` at cadence."""
-        t0 = time.perf_counter()
-        try:
-            import jax
-
-            ctx = jax.profiler.StepTraceAnnotation("train",
-                                                   step_num=step_num)
-        except Exception:
-            ctx = contextlib.nullcontext()
-        try:
-            with ctx:
-                yield
-        finally:
-            self.add("dispatch", time.perf_counter() - t0)
-            self.steps_in_window += 1
-
-    def non_step_seconds(self) -> float:
-        return sum(self.seconds.get(k, 0.0) for k in NON_STEP_SEGMENTS)
+        execution catch-up is visible as ``host_fetch`` at cadence. (The
+        engine's tick opens its own ``StepTraceAnnotation`` and books its
+        wall itself, from the spans inside it.)"""
+        self.steps_in_window += 1
+        return _Span(self, "dispatch",
+                     annotate_step("train", step_num=step_num))
 
     def drain(self) -> Dict[str, float]:
         """Return and reset the current window's breakdown. The dict also
-        carries ``steps`` (train steps dispatched in the window)."""
-        out = dict(self.seconds)
+        carries ``steps`` (steps accounted in the window)."""
+        out, self.seconds = self.seconds, {}
         out["steps"] = self.steps_in_window
-        self.seconds = {}
+        self.ends = {}
         self.steps_in_window = 0
         return out
 

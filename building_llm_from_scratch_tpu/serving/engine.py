@@ -82,7 +82,19 @@ from building_llm_from_scratch_tpu.obs.metrics import (
     get_metrics,
     render_prometheus,
 )
-from building_llm_from_scratch_tpu.obs.schema import TICK_PHASES
+from building_llm_from_scratch_tpu.obs.schema import (
+    TICK_BETWEEN,
+    TICK_IDLE_WAIT,
+    TICK_PHASES,
+    TICK_SPANS,
+    TICK_STEP,
+)
+from building_llm_from_scratch_tpu.obs.stall import last_ticks
+from building_llm_from_scratch_tpu.obs.timeline import (
+    StepTimeline,
+    annotate,
+    annotate_step,
+)
 from building_llm_from_scratch_tpu.parallel.collectives import (
     trace_under_mesh,
 )
@@ -517,36 +529,32 @@ class DecodeEngine:
             7.5, 11.0, 17.0, 26.0, 40.0, 60.0))
         self.slo_window = RollingRatio(window_s=300.0)
         self._t_start_mono = time.monotonic()
-        self._window_tokens = 0             # guarded-by: _lock
-        self._window_t0 = time.monotonic()  # guarded-by: _lock
-        # per-tick phase breakdown (obs/trace.TICK_PHASES): wall-clock
-        # accumulated with perf_counter ONLY — the instrumentation adds
-        # zero device fetches (guard-tested). `_tick_acc` is the current
-        # metrics window (reset at cadence, logged into the metrics row);
-        # `tick_phase_totals` is cumulative for the /metrics counters.
-        self._tick_acc = {ph: 0.0
-                          for ph in TICK_PHASES}         # guarded-by: _lock
-        self._tick_acc_total = 0.0                       # guarded-by: _lock
+        # per-tick phase breakdown (obs/schema.TICK_PHASES): every phase
+        # is a span of the tick's timeline (obs/timeline.StepTimeline,
+        # the trainer's primitive): perf_counter ONLY — the
+        # instrumentation adds zero device fetches (guard-tested) — and a
+        # TraceAnnotation, so a device trace names the host's part of a
+        # gap. `_book_tick` drains it once a tick into one record
+        # (get_metrics().recent("tick")) and into the cumulative totals
+        # the /metrics counters export.
+        # (one tick at a time uses it; `_restart` replaces it)
+        self._tl = StepTimeline(
+            TICK_SPANS)                 # guarded-by: _restart_lock [writes]
+        self._tick_rec: dict = {}                        # guarded-by: _lock
         self.tick_phase_totals = {ph: 0.0
                                   for ph in TICK_PHASES}  # guarded-by: _lock
         self.tick_seconds_total = 0.0                    # guarded-by: _lock
-        self._window_ticks = 0                           # guarded-by: _lock
-        self._win_t0_wall = time.time()                  # guarded-by: _lock
-        # KV-engine window counters (chunked prefill + prefix cache):
-        # drained into the cadence metrics row like the tick phases
-        self._window_prefill_chunks = 0                  # guarded-by: _lock
-        self._window_prefix_hits = 0                     # guarded-by: _lock
-        self._window_prefix_misses = 0                   # guarded-by: _lock
-        self._tick_pf0 = 0.0                             # guarded-by: _lock
-        # speculative-decoding accounting: drafted = k per spec-enabled
-        # decoding row per tick; accepted = the in-graph n_acc (draft
-        # tokens the verify committed). Cumulative totals feed /metrics
-        # and the acceptance-ratio gauge; window counters drain into the
-        # cadence metrics row
+        # chunked-prefill chunks (prefix hits and misses: the store keeps
+        # count) and the speculative-decoding accounting: drafted = k per
+        # spec-enabled decoding row per tick; accepted = the in-graph
+        # n_acc (draft tokens the verify committed). All cumulative:
+        # /metrics exports the spec pair, and the cadence metrics row
+        # reports each as the difference since the row before
+        # (`_win_base`)
+        self.prefill_chunks = 0                          # guarded-by: _lock
         self.spec_tokens_drafted = 0                     # guarded-by: _lock
         self.spec_tokens_accepted = 0                    # guarded-by: _lock
-        self._window_spec_drafted = 0                    # guarded-by: _lock
-        self._window_spec_accepted = 0                   # guarded-by: _lock
+        self._win_base = self._cumulative()              # guarded-by: _lock
 
     # -- mesh placement (tp-sharded engine) --------------------------------
 
@@ -1334,31 +1342,30 @@ class DecodeEngine:
         # the jitted call returns before the device finishes (async
         # dispatch), so timing the call alone would book the execution
         # wait into whatever host line happens to touch a result first
-        t_pf = time.perf_counter()
-        tok, ok, cache = self._prefill(self.cache, self._weights, padded,
-                                       np.int32(Tp), np.int32(slot),
-                                       base_key, temp, topk,
-                                       *self._pool_args_for(adapter_row))
-        if self._generation != gen:
-            return          # abandoned mid-prefill: commit nothing
-        self.cache = cache
-        req.state = RUNNING
-        req.slot = slot
-        req.t_admit = time.monotonic()
-        self._lengths[slot] = Tp
-        self._n_gen[slot] = 0
-        self._base_keys[slot] = base_key
-        self._temps[slot] = temp
-        self._topks[slot] = topk
-        self._adapter_ids[slot] = adapter_row
-        if self._hist is not None:
-            self._hist[slot, :Tp] = req.prompt_ids
-            self._hist_len[slot] = Tp
-        if self.hooks.poison_nan(req):
-            self._poison_slot_cache(slot)      # fault injection (tests)
-        # explicit fetch; blocks until prefill ran
-        ok_host = bool(jax.device_get(ok))
-        self._tick_add("prefill", time.perf_counter() - t_pf)
+        with self._tl.span("prefill"):
+            tok, ok, cache = self._prefill(
+                self.cache, self._weights, padded, np.int32(Tp),
+                np.int32(slot), base_key, temp, topk,
+                *self._pool_args_for(adapter_row))
+            if self._generation != gen:
+                return          # abandoned mid-prefill: commit nothing
+            self.cache = cache
+            req.state = RUNNING
+            req.slot = slot
+            req.t_admit = time.monotonic()
+            self._lengths[slot] = Tp
+            self._n_gen[slot] = 0
+            self._base_keys[slot] = base_key
+            self._temps[slot] = temp
+            self._topks[slot] = topk
+            self._adapter_ids[slot] = adapter_row
+            if self._hist is not None:
+                self._hist[slot, :Tp] = req.prompt_ids
+                self._hist_len[slot] = Tp
+            if self.hooks.poison_nan(req):
+                self._poison_slot_cache(slot)      # fault injection (tests)
+            # explicit fetch; blocks until prefill ran
+            ok_host = bool(jax.device_get(ok))
         if not ok_host:
             self._fail_request(slot, req,
                                "non-finite logits in prefill",
@@ -1402,7 +1409,6 @@ class DecodeEngine:
                     return      # abandoned mid-copy: commit nothing
                 pos = span
             else:
-                self._window_prefix_misses += 1
                 self._ev("prefix_miss", request_id=req.id,
                                     prompt_tokens=Tp,
                                     adapter=req.params.adapter)
@@ -1441,18 +1447,16 @@ class DecodeEngine:
         if self._paged:
             return self._apply_paged_hit(slot, req, gen, span, entry,
                                          late, prev_pos)
-        t_cp = time.perf_counter()
-        try:
-            cache = self._prefix_copy(self.cache, entry.panes,
-                                      np.int32(slot))
-        finally:
-            self.prefix_store.release(entry)
+        with self._tl.span("prefix_copy"):
+            try:
+                cache = self._prefix_copy(self.cache, entry.panes,
+                                          np.int32(slot))
+            finally:
+                self.prefix_store.release(entry)
         if self._generation != gen:
             return False
         self.cache = cache
         self.pane_copies += 1   # spy: paged mode asserts this stays 0
-        self._window_prefix_hits += 1
-        self._tick_add("prefix_copy", time.perf_counter() - t_cp)
         # the exact quantity ROADMAP item 1 (paged KV) optimizes: KV
         # bytes this hit spared the request from recomputing
         req.prefix_bytes_saved += ((span - prev_pos)
@@ -1500,7 +1504,6 @@ class DecodeEngine:
         if refund > 0:
             self.page_pool.unreserve(refund)
             self._pages_reserved[slot] -= refund
-        self._window_prefix_hits += 1
         req.prefix_bytes_saved += ((span - prev_pos)
                                    * self._kv_bytes_per_token)
         Tp = int(req.prompt_ids.size)   # graft-ok: GL011 host numpy size
@@ -1549,28 +1552,26 @@ class DecodeEngine:
                         return False
                     st["pos"] = span
                     self._lengths[slot] = span
-            t_pf = time.perf_counter()
-            lo = st["pos"]
-            hi = min(lo + C, Tp)
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, : hi - lo] = req.prompt_ids[lo:hi]
-            if self._paged:
-                # back the chunk's real columns with pages; the pad
-                # tail's columns stay unmapped and scatter into trash
-                self._ensure_pages(slot, hi)
-            tok, ok, cache = self._prefill_chunk(
-                self.cache, self._weights, chunk, np.int32(lo), np.int32(Tp),
-                np.int32(slot),
-                *((self._page_table,) if self._paged else ()),
-                st["base_key"], st["temp"], st["topk"],
-                *self._pool_args_for(st["adapter_row"]))
-            if self._generation != gen:
-                return False        # abandoned mid-chunk: commit nothing
-            self.cache = cache
-            st["pos"] = lo + C
-            self._window_prefill_chunks += 1
-            self._tick_add(self._prefill_phase,
-                           time.perf_counter() - t_pf)
+            with self._tl.span(self._prefill_phase):
+                lo = st["pos"]
+                hi = min(lo + C, Tp)
+                chunk = np.zeros((1, C), np.int32)
+                chunk[0, : hi - lo] = req.prompt_ids[lo:hi]
+                if self._paged:
+                    # back the chunk's real columns with pages; the pad
+                    # tail's columns stay unmapped and scatter into trash
+                    self._ensure_pages(slot, hi)
+                tok, ok, cache = self._prefill_chunk(
+                    self.cache, self._weights, chunk, np.int32(lo),
+                    np.int32(Tp), np.int32(slot),
+                    *((self._page_table,) if self._paged else ()),
+                    st["base_key"], st["temp"], st["topk"],
+                    *self._pool_args_for(st["adapter_row"]))
+                if self._generation != gen:
+                    return False    # abandoned mid-chunk: commit nothing
+                self.cache = cache
+                st["pos"] = lo + C
+                self.prefill_chunks += 1
             # EARLY insertion: the moment the chunk covering the storable
             # span lands, the pane [0, span) is final — store it NOW so
             # co-admitted sharers (still mid-prefill behind us) catch up
@@ -1586,10 +1587,8 @@ class DecodeEngine:
                 continue
             # final chunk: the request's first token. Explicit fetch —
             # the ONLY chunk that syncs (mirrors the legacy prefill)
-            t_pf = time.perf_counter()
-            ok_host = bool(jax.device_get(ok))
-            self._tick_add(self._prefill_phase,
-                           time.perf_counter() - t_pf)
+            with self._tl.span(self._prefill_phase):
+                ok_host = bool(jax.device_get(ok))
             del self._prefill_state[slot]
             self._lengths[slot] = Tp
             if self.hooks.poison_nan(req):
@@ -1643,10 +1642,9 @@ class DecodeEngine:
                     entries=self.prefix_store.n_entries,
                     adapter=req.params.adapter)
             return
-        t_ex = time.perf_counter()
-        panes = self._prefix_extract(self.cache, np.int32(slot),
-                                     np.int32(span))
-        self._tick_add("prefix_copy", time.perf_counter() - t_ex)
+        with self._tl.span("prefix_copy"):
+            panes = self._prefix_extract(self.cache, np.int32(slot),
+                                         np.int32(span))
         if self._generation != gen:
             return
         nbytes = self.prefix_store.insert(prefix_ids, tag, panes)
@@ -1825,31 +1823,35 @@ class DecodeEngine:
         get_metrics().log_span(**req.trace_row())
 
     # holds: _lock
-    def _tick_add(self, phase: str, dt: float) -> None:
-        """Accumulate wall-clock into one tick phase: the current metrics
-        window (drained into the cadence row) and the cumulative totals
-        (the ``/metrics`` counters). perf_counter only — NEVER a device
-        fetch (the no-per-tick-host-sync guard test enforces this)."""
-        self._tick_acc[phase] += dt
-        self.tick_phase_totals[phase] += dt
-
-    # holds: _lock
-    def _book_tick_wall(self, t0: float) -> None:
-        """Add a tick's elapsed wall time to the window/cumulative
-        totals. Called on EVERY exit from the timed part of ``step()`` —
-        including generation-abort returns, which have already booked
-        phase seconds: skipping the total there would let a restart
-        window's phases sum past its ``tick_total_s``. Also folds the
-        tick's prefill+prefix-copy wall into ``tick_prefill_hist`` (the
-        per-tick distribution the chunking A/B reads)."""
-        dt = time.perf_counter() - t0
-        self._tick_acc_total += dt
-        self.tick_seconds_total += dt
-        pf = (self.tick_phase_totals["prefill"]
-              + self.tick_phase_totals["prefill_shard"]
-              + self.tick_phase_totals["prefix_copy"]) - self._tick_pf0
+    def _book_tick(self, tl: StepTimeline, rec: dict, t0: float,
+                   t1: float) -> None:
+        """Close one tick's account: drain its spans into the cumulative
+        totals, fold its prefill+prefix-copy seconds into
+        ``tick_prefill_hist`` (the per-tick distribution the chunking A/B
+        reads) and keep one record of it (obs/schema.TICK_RECORD_FIELDS).
+        Called on EVERY exit from the timed part of ``step()`` —
+        generation-abort returns and raising ticks included, which have
+        already booked phase seconds: skipping the wall there would let
+        the phases sum past ``tick_seconds_total``."""
+        ends = tl.ends
+        phases = tl.drain()  # graft-ok: GL032 StepTimeline's drain, not the engine's
+        del phases["steps"]
+        for ph, dt in phases.items():
+            self.tick_phase_totals[ph] += dt
+        self.tick_seconds_total += t1 - t0
+        pf = sum(phases.get(ph, 0.0)
+                 for ph in ("prefill", "prefill_shard", "prefix_copy"))
         if pf > 0:
             self.tick_prefill_hist.observe(pf)
+        rec.update(tick=self.n_ticks, t0=t0, t1=t1, phases=phases,
+                   n_slots=self.n_slots)
+        if "decode_dispatch" in ends:
+            rec["t_dispatch"] = ends["decode_dispatch"]
+        if "host_fetch" in ends:
+            rec["t_fetch"] = ends["host_fetch"]
+        if self.replica is not None:
+            rec["replica"] = self.replica
+        get_metrics().keep_record("tick", rec)
 
     # -- the tick ---------------------------------------------------------
 
@@ -1862,31 +1864,41 @@ class DecodeEngine:
         replaces the lock, so a tick that un-wedges AFTER the supervisor
         abandoned it discovers the bump at the next checkpoint and returns
         without committing any state into the restarted engine."""
-        import jax
-
         gen = self._generation
         lock = self._lock
-        with lock:
-            if self._generation != gen or self._dead is not None:
-                return False
-            t_tick0 = time.perf_counter()
-            self._tick_pf0 = (self.tick_phase_totals["prefill"]
-                              + self.tick_phase_totals["prefill_shard"]
-                              + self.tick_phase_totals["prefix_copy"])
-            self.hooks.before_tick(self)       # injected hang/fault point
-            if self._generation != gen:
-                self._book_tick_wall(t_tick0)
-                return False
-            # tick-phase accounting: `admit` is the admission/cancel/
-            # bookkeeping remainder — the nested prefill/prefix-copy
-            # device calls and client callbacks accumulate into their own
-            # phases, so they are subtracted out via before/after
-            # snapshots
-            nested0 = (self._tick_acc["prefill"]
-                       + self._tick_acc["prefill_shard"]
-                       + self._tick_acc["prefix_copy"]
-                       + self._tick_acc["callback_detok"])
-            t_adm0 = time.perf_counter()
+        tl = self._tl
+        # the `tick` step is open over the wait for the lock and the
+        # booking too, so that a trace finds all of step() in a span
+        n_step = self.n_ticks + 1  # graft-ok: GL031 a label for the trace; only ticks write it
+        with annotate_step(TICK_STEP, step_num=n_step):
+            with lock:
+                if self._generation != gen or self._dead is not None:
+                    return False
+                n0 = self.n_ticks
+                rec = self._tick_rec = {"rows": 0, "admitted": 0,
+                                        "queue_depth": len(self.queue)}
+                t0 = time.perf_counter()
+                try:
+                    return self._tick(gen)
+                finally:
+                    t1 = time.perf_counter()
+                    with annotate(TICK_BETWEEN):
+                        self._book_tick(tl, rec, t0, t1)
+                        if self.n_ticks != n0:      # a raising tick counts none
+                            self._maybe_log_metrics()
+
+    # holds: _lock
+    def _tick(self, gen: int) -> bool:
+        """The timed part of ``step()``. Every phase is a span of
+        ``self._tl``; nested spans (a prefill or a client callback inside
+        ``admit``, callbacks inside ``sample_commit``) book their own
+        seconds and the outer phase keeps the remainder."""
+        import jax
+
+        self.hooks.before_tick(self)       # injected hang/fault point
+        if self._generation != gen:
+            return False
+        with self._tl.span("admit"):
             # re-run admission until no progress: a request can finish
             # DURING admission (eos on its first sampled token, or
             # max_new_tokens=1), freeing its slot after admit_from already
@@ -1906,9 +1918,9 @@ class DecodeEngine:
                     if not ok:
                         bounced = i
                         break
+                    self._tick_rec["admitted"] += 1
                     self._admit(slot, req, gen)
                     if self._generation != gen:
-                        self._book_tick_wall(t_tick0)
                         return False
                 if bounced is not None:
                     # hand the refused head — and everything admit_from
@@ -1929,85 +1941,73 @@ class DecodeEngine:
                     self._fail_request(slot, req, "cancelled by client",
                                        reason="cancelled",
                                        finish=FINISH_CANCELLED)
-            nested = (self._tick_acc["prefill"]
-                      + self._tick_acc["prefill_shard"]
-                      + self._tick_acc["prefix_copy"]
-                      + self._tick_acc["callback_detok"]) - nested0
-            self._tick_add("admit", max(
-                time.perf_counter() - t_adm0 - nested, 0.0))
-            # chunked-prefill pump: one C-token chunk per mid-prefill
-            # slot, BEFORE the decode step — a slot whose final chunk
-            # lands here joins this very tick's decode batch (the same
-            # admit-then-decode cadence the monolithic path has)
-            if self._prefill_state:
-                if not self._chunk_tick(gen):
-                    self._book_tick_wall(t_tick0)
-                    return False
-            active = self.scheduler.active()
-            if not active:
-                # all slots free. Legacy: admission drained the queue
-                # too. Chunked: a first-token eos inside _chunk_tick can
-                # free the last slot with requests still queued — report
-                # progress so the next tick admits them (an admission-
-                # only tick still books its wall time so phases keep
-                # summing to it)
-                self._book_tick_wall(t_tick0)
-                return len(self.queue) > 0
-            # mid-prefill slots ride through the fixed-shape decode step
-            # as ignored rows (their garbage append lands at the next
-            # chunk's write position — see _admit_chunked); with NO row
-            # actually decoding, skip the step entirely
-            decoding = [(s, r) for s, r in active
-                        if s not in self._prefill_state]
-            if not decoding:
-                self.n_ticks += 1
-                self._window_ticks += 1
-                self._book_tick_wall(t_tick0)
-                self._maybe_log_metrics()
-                return True
-            if self.spec_k:
-                # speculative tick: draft k per slot, ONE verify forward,
-                # multi-token commit (serving/spec.py + _verify_tick)
-                return self._verify_tick(decoding, gen, t_tick0)
-            if self._paged:
-                # grow each decoding slot's table BEFORE dispatch: the
-                # append lands at column lengths//P, which must point at
-                # a real page (mid-prefill rows ride as ignored garbage
-                # into the pinned trash page — no allocation for them)
-                for slot, _req in decoding:
-                    self._ensure_pages(
-                        slot, int(self._lengths[slot]) + 1)  # graft-ok: GL011 host numpy
-            t_dec = time.perf_counter()
+        # chunked-prefill pump: one C-token chunk per mid-prefill
+        # slot, BEFORE the decode step — a slot whose final chunk
+        # lands here joins this very tick's decode batch (the same
+        # admit-then-decode cadence the monolithic path has)
+        if self._prefill_state and not self._chunk_tick(gen):
+            return False
+        active = self.scheduler.active()
+        if not active:
+            # all slots free. Legacy: admission drained the queue
+            # too. Chunked: a first-token eos inside _chunk_tick can
+            # free the last slot with requests still queued — report
+            # progress so the next tick admits them (an admission-
+            # only tick still books its wall time so phases keep
+            # summing to it)
+            return len(self.queue) > 0
+        # mid-prefill slots ride through the fixed-shape decode step
+        # as ignored rows (their garbage append lands at the next
+        # chunk's write position — see _admit_chunked); with NO row
+        # actually decoding, skip the step entirely
+        decoding = [(s, r) for s, r in active
+                    if s not in self._prefill_state]
+        if not decoding:
+            self.n_ticks += 1
+            return True
+        self._tick_rec["rows"] = len(decoding)
+        if self.spec_k:
+            # speculative tick: draft k per slot, ONE verify forward,
+            # multi-token commit (serving/spec.py + _verify_tick)
+            return self._verify_tick(decoding, gen)
+        if self._paged:
+            # grow each decoding slot's table BEFORE dispatch: the
+            # append lands at column lengths//P, which must point at
+            # a real page (mid-prefill rows ride as ignored garbage
+            # into the pinned trash page — no allocation for them)
+            for slot, _req in decoding:
+                self._ensure_pages(
+                    slot, int(self._lengths[slot]) + 1)  # graft-ok: GL011 host numpy
+        with self._tl.span("decode_dispatch"):
             nxt, ok, cache = self._decode(
                 self.cache, self._weights, self._last_tokens, self._lengths,
                 *((self._page_table,) if self._paged else ()),
                 self._base_keys, self._n_gen, self._temps,
                 self._topks, *(self._pool_args() + (self._adapter_ids,)
                                if self.adapters is not None else ()))
-            self._tick_add("decode_dispatch", time.perf_counter() - t_dec)
-            if self._generation != gen:
-                self._book_tick_wall(t_tick0)
-                return False
-            # `host_fetch` covers the donated-cache rebind AND the two
-            # device->host fetches: dropping the old (donated-away)
-            # cache arrays and the device_get both block on the in-flight
-            # step, so this phase is "waiting for the device to catch up".
-            # EXPLICIT device_get, never np.asarray/float(): these are
-            # the tick's only two sanctioned d->h transfers, and the
-            # transfer-guard sentry test proves nothing implicit remains
-            t_fetch = time.perf_counter()
+        if self._generation != gen:
+            return False
+        # `host_fetch` covers the donated-cache rebind AND the two
+        # device->host fetches: dropping the old (donated-away)
+        # cache arrays and the device_get both block on the in-flight
+        # step, so this phase is "waiting for the device to catch up".
+        # EXPLICIT device_get, never np.asarray/float(): these are
+        # the tick's only two sanctioned d->h transfers, and the
+        # transfer-guard sentry test proves nothing implicit remains
+        with self._tl.span("host_fetch"):
             self.cache = cache
             nxt = jax.device_get(nxt)
             ok_rows = jax.device_get(ok)
-            self._tick_add("host_fetch", time.perf_counter() - t_fetch)
-            cb0 = self._tick_acc["callback_detok"]
-            t_commit = time.perf_counter()
+        # the fetch is a wedge point: a tick the supervisor abandoned in it
+        # must not open a span on the timeline `_restart` has put in place
+        if self._generation != gen:
+            return False
+        with self._tl.span("sample_commit"):
             for slot, req in decoding:
                 # a slow-client hook inside _accept_token is a wedge point
                 # the supervisor may abandon mid-loop — stop committing
                 # rows the moment the generation moves on
                 if self._generation != gen:
-                    self._book_tick_wall(t_tick0)
                     return False
                 # this tick wrote the slot's previous token at _lengths
                 self._lengths[slot] += 1
@@ -2018,21 +2018,15 @@ class DecodeEngine:
                         reason="non_finite_logits")
                     continue
                 self._accept_token(slot, req, int(nxt[slot]), gen)
-            self._tick_add("sample_commit", max(
-                time.perf_counter() - t_commit
-                - (self._tick_acc["callback_detok"] - cb0), 0.0))
-            self.n_ticks += 1
-            self._window_ticks += 1
-            self._book_tick_wall(t_tick0)
-            self._maybe_log_metrics()
-            return True
+        self.n_ticks += 1
+        return True
 
     def run_until_idle(self) -> None:
         while self.step():
             pass
 
     # holds: _lock
-    def _verify_tick(self, decoding, gen: int, t_tick0: float) -> bool:
+    def _verify_tick(self, decoding, gen: int) -> bool:
         """One speculative tick: propose k drafts per decoding slot
         (host-side, ``drafter.propose`` against the slot's own history),
         run THE one compiled verify program over all slots, and commit
@@ -2045,21 +2039,20 @@ class DecodeEngine:
         per-request semantics cost no extra programs. Mid-prefill slots
         were already filtered out of ``decoding`` by the caller and ride
         as ignored rows inside the program, exactly as in the plain
-        decode tick. Returns False on a generation abort (tick wall
-        already booked), mirroring ``step()``'s decode block."""
+        decode tick. Returns False on a generation abort, mirroring
+        ``_tick``'s decode block."""
         import jax
 
         k = self.spec_k
-        t_draft = time.perf_counter()
-        drafts = np.zeros((self.n_slots, k), np.int32)
-        for slot, req in decoding:
-            if req.params.spec:
-                n_hist = self._hist_len[slot]
-                drafts[slot] = self.drafter.propose(
-                    self._hist[slot, :n_hist], k)
-        tokens_in = np.concatenate(
-            [self._last_tokens[:, None], drafts], axis=1)
-        self._tick_add("draft", time.perf_counter() - t_draft)
+        with self._tl.span("draft"):
+            drafts = np.zeros((self.n_slots, k), np.int32)
+            for slot, req in decoding:
+                if req.params.spec:
+                    n_hist = self._hist_len[slot]
+                    drafts[slot] = self.drafter.propose(
+                        self._hist[slot, :n_hist], k)
+            tokens_in = np.concatenate(
+                [self._last_tokens[:, None], drafts], axis=1)
         if self._paged:
             # verify appends k+1 candidates at lengths..lengths+k; the
             # spec headroom (_cache_len = max_len + spec_k) guarantees
@@ -2067,68 +2060,56 @@ class DecodeEngine:
             for slot, _req in decoding:
                 self._ensure_pages(
                     slot, int(self._lengths[slot]) + 1 + k)  # graft-ok: GL011 host numpy
-        t_dec = time.perf_counter()
-        toks, n_acc, ok, cache = self._verify(
-            self.cache, self._weights, tokens_in, self._lengths,
-            *((self._page_table,) if self._paged else ()),
-            self._base_keys, self._n_gen, self._temps, self._topks,
-            *(self._pool_args() + (self._adapter_ids,)
-              if self.adapters is not None else ()))
-        self._tick_add("decode_dispatch", time.perf_counter() - t_dec)
+        with self._tl.span("decode_dispatch"):
+            toks, n_acc, ok, cache = self._verify(
+                self.cache, self._weights, tokens_in, self._lengths,
+                *((self._page_table,) if self._paged else ()),
+                self._base_keys, self._n_gen, self._temps, self._topks,
+                *(self._pool_args() + (self._adapter_ids,)
+                  if self.adapters is not None else ()))
         if self._generation != gen:
-            self._book_tick_wall(t_tick0)
             return False
         # ONE explicit fetch for the tick's three results (+ the donated
         # cache rebind) — the same sanctioned d->h discipline as the
         # plain decode tick
-        t_fetch = time.perf_counter()
-        self.cache = cache
-        toks, n_acc, ok_rows = jax.device_get((toks, n_acc, ok))
-        self._tick_add("host_fetch", time.perf_counter() - t_fetch)
-        cb0 = self._tick_acc["callback_detok"]
-        t_commit = time.perf_counter()
-        for slot, req in decoding:
-            if self._generation != gen:
-                self._book_tick_wall(t_tick0)
-                return False
-            if not bool(ok_rows[slot]):
-                self._fail_request(
-                    slot, req,
-                    f"non-finite logits at token {len(req.output_ids)}",
-                    reason="non_finite_logits")
-                continue
-            is_spec = req.params.spec
-            n_commit = 1 + (int(n_acc[slot]) if is_spec else 0)
-            if is_spec:
-                # acceptance telemetry counts the IN-GRAPH decision
-                # (drafter quality), independent of host truncation at
-                # eos/budget below
-                accepted = int(n_acc[slot])
-                req.spec_drafted += k
-                req.spec_accepted += accepted
-                self.spec_tokens_drafted += k
-                self.spec_tokens_accepted += accepted
-                self._window_spec_drafted += k
-                self._window_spec_accepted += accepted
-            for j in range(n_commit):
-                # each commit advances the row's valid-KV prefix by one:
-                # position j's entry was appended by THIS tick's verify
-                # (the trailing rejected entries stay past the prefix,
-                # masked everywhere and overwritten next tick)
-                self._lengths[slot] += 1
-                self._accept_token(slot, req, int(toks[slot, j]), gen)
+        with self._tl.span("host_fetch"):
+            self.cache = cache
+            toks, n_acc, ok_rows = jax.device_get((toks, n_acc, ok))
+        if self._generation != gen:
+            return False
+        with self._tl.span("sample_commit"):
+            for slot, req in decoding:
                 if self._generation != gen:
-                    self._book_tick_wall(t_tick0)
                     return False
-                if req.done:
-                    break               # eos/budget/fault: slot already freed
-        self._tick_add("sample_commit", max(
-            time.perf_counter() - t_commit
-            - (self._tick_acc["callback_detok"] - cb0), 0.0))
+                if not bool(ok_rows[slot]):
+                    self._fail_request(
+                        slot, req,
+                        f"non-finite logits at token {len(req.output_ids)}",
+                        reason="non_finite_logits")
+                    continue
+                is_spec = req.params.spec
+                n_commit = 1 + (int(n_acc[slot]) if is_spec else 0)
+                if is_spec:
+                    # acceptance telemetry counts the IN-GRAPH decision
+                    # (drafter quality), independent of host truncation
+                    # at eos/budget below
+                    accepted = int(n_acc[slot])
+                    req.spec_drafted += k
+                    req.spec_accepted += accepted
+                    self.spec_tokens_drafted += k
+                    self.spec_tokens_accepted += accepted
+                for j in range(n_commit):
+                    # each commit advances the row's valid-KV prefix by
+                    # one: position j's entry was appended by THIS tick's
+                    # verify (the trailing rejected entries stay past the
+                    # prefix, masked everywhere and overwritten next tick)
+                    self._lengths[slot] += 1
+                    self._accept_token(slot, req, int(toks[slot, j]), gen)
+                    if self._generation != gen:
+                        return False
+                    if req.done:
+                        break           # eos/budget/fault: slot already freed
         self.n_ticks += 1
-        self._window_ticks += 1
-        self._book_tick_wall(t_tick0)
-        self._maybe_log_metrics()
         return True
 
     # holds: _lock
@@ -2152,25 +2133,22 @@ class DecodeEngine:
             self._hist[slot, self._hist_len[slot]] = tok
             self._hist_len[slot] += 1
         self.tokens_generated += 1
-        self._window_tokens += 1
-        t_cb = time.perf_counter()
         try:
             # the request's OWN host path: detok + client callback. A
             # fault here (raising on_token, tokenizer bug on this output)
             # is this request's problem alone — fail it, free the slot,
             # co-residents decode on undisturbed
-            piece = self._detok_piece(req)
-            if req.on_token is not None:
-                req.on_token(req, tok, piece)
-            self.hooks.after_token(req, tok)   # injected slow-client point
+            with self._tl.span("callback_detok"):
+                piece = self._detok_piece(req)
+                if req.on_token is not None:
+                    req.on_token(req, tok, piece)
+                self.hooks.after_token(req, tok)   # injected slow-client point
         except Exception as e:  # noqa: BLE001 — poison request, isolate
-            self._tick_add("callback_detok", time.perf_counter() - t_cb)
             if self._generation != gen:
                 return      # restart already failed this request
             self._fail_request(slot, req, f"token callback failed: {e!r}",
                                reason="callback_error")
             return
-        self._tick_add("callback_detok", time.perf_counter() - t_cb)
         if self._generation != gen:
             # the callback/hook above is a wedge point — un-wedging after
             # a supervisor restart must not finish/free slots that now
@@ -2307,12 +2285,23 @@ class DecodeEngine:
             self._work.notify_all()
 
     # holds: _lock
+    def _cumulative(self) -> dict:
+        """The counters a cadence row reports as differences, now."""
+        return {"t": time.monotonic(), "wall": time.time(),
+                "tokens": self.tokens_generated, "ticks": self.n_ticks,
+                "tick_s": self.tick_seconds_total,
+                "prefill_chunks": self.prefill_chunks,
+                "spec_drafted": self.spec_tokens_drafted,
+                "spec_accepted": self.spec_tokens_accepted,
+                **self.tick_phase_totals}
+
+    # holds: _lock
     def _maybe_log_metrics(self) -> None:
         if self.metrics_every <= 0 or self.n_ticks % self.metrics_every:
             return
-        now = time.monotonic()
-        now_wall = time.time()
-        dt = max(now - self._window_t0, 1e-9)
+        base, now = self._win_base, self._cumulative()
+        self._win_base = now
+        win = {k: now[k] - base[k] for k in now}
         sink = get_metrics()
         sink.gauge("slot_occupancy", self.scheduler.occupancy())
         sink.gauge("queue_depth", len(self.queue))
@@ -2324,39 +2313,25 @@ class DecodeEngine:
         # (perf_counter), fetched device values are NOT involved — the
         # per-tick host syncs stay exactly the two the decode loop always
         # had (next-token + ok mask; guard-tested)
-        phases = {f"tick_{ph}_s": round(self._tick_acc[ph], 6)
-                  for ph in TICK_PHASES}
+        phases = {f"tick_{ph}_s": round(win[ph], 6) for ph in TICK_PHASES}
         kv = {}
         if self.kv_policy.prefill_chunk > 0:
-            kv["prefill_chunks"] = self._window_prefill_chunks
-        if self.prefix_store is not None:
-            kv["prefix_hits"] = self._window_prefix_hits
-            kv["prefix_misses"] = self._window_prefix_misses
+            kv["prefill_chunks"] = win["prefill_chunks"]
         if self.spec_k:
-            kv["spec_drafted"] = self._window_spec_drafted
-            kv["spec_accepted"] = self._window_spec_accepted
+            kv["spec_drafted"] = win["spec_drafted"]
+            kv["spec_accepted"] = win["spec_accepted"]
         fleet = ({"replica": self.replica, "monotonic": False}
                  if self.replica is not None else {})
         sink.log_metrics(self.n_ticks, **fleet,
-                         serve_tok_s=round(self._window_tokens / dt, 2),
+                         serve_tok_s=round(
+                             win["tokens"] / max(win["t"], 1e-9), 2),
                          requests_finished=self.requests_finished,
                          tokens_generated=self.tokens_generated,
-                         ticks_in_window=self._window_ticks,
-                         win_t0=round(self._win_t0_wall, 6),
-                         win_dur_s=round(now_wall - self._win_t0_wall, 6),
-                         tick_total_s=round(self._tick_acc_total, 6),
+                         ticks_in_window=win["ticks"],
+                         win_t0=round(base["wall"], 6),
+                         win_dur_s=round(win["wall"], 6),
+                         tick_total_s=round(win["tick_s"], 6),
                          **phases, **kv)
-        self._window_tokens = 0
-        self._window_t0 = now
-        self._window_ticks = 0
-        self._win_t0_wall = now_wall
-        self._window_prefill_chunks = 0
-        self._window_prefix_hits = 0
-        self._window_prefix_misses = 0
-        self._window_spec_drafted = 0
-        self._window_spec_accepted = 0
-        self._tick_acc = {ph: 0.0 for ph in TICK_PHASES}
-        self._tick_acc_total = 0.0
         # memory-ledger cadence: snapshot + drift/pressure detectors +
         # the memory_snapshot event the trace renders as counter tracks.
         # Pure nbytes/host math — the tick's device syncs stay the two
@@ -2451,9 +2426,7 @@ class DecodeEngine:
             self._adapter_ids[:] = -1
             # re-anchor the metrics window: the first cadence row should
             # describe serving, not a window stretched over compile time
-            self._window_t0 = time.monotonic()
-            self._win_t0_wall = time.time()
-            self._window_tokens = 0
+            self._win_base = self._cumulative()
             self.warmed_up = True
         bps = self.kv_policy.bytes_per_slot(self.cfg, self._cache_len)
         spec_fields = ({"spec_k": self.spec_k,
@@ -2517,10 +2490,14 @@ class DecodeEngine:
 
         def loop():
             while not self._stop.is_set() and self._generation == gen:
-                if self.supervisor is not None:
-                    self.supervisor.notify_tick()
-                if self._heartbeat is not None:
-                    self._heartbeat()
+                # a span over each stretch of this thread's life outside
+                # step(), so that a device trace finds the host in some
+                # span of the engine whenever the chip waits for it
+                with annotate(TICK_BETWEEN):
+                    if self.supervisor is not None:
+                        self.supervisor.notify_tick()
+                    if self._heartbeat is not None:
+                        self._heartbeat()
                 try:
                     progressed = self.step()
                 except Exception as e:          # noqa: BLE001 — must not
@@ -2538,7 +2515,7 @@ class DecodeEngine:
                         self._fail_all(f"engine loop error: {e!r}")
                     return
                 if not progressed:
-                    with self._work:
+                    with annotate(TICK_IDLE_WAIT), self._work:
                         self._work.wait(timeout=0.05)
 
         self._thread = threading.Thread(target=loop, name="decode-engine",
@@ -2575,6 +2552,9 @@ class DecodeEngine:
             # lock forever; new threads must not queue behind it
             self._lock = threading.RLock()
             self._work = threading.Condition()
+            # and a fresh timeline: the abandoned tick closes its open
+            # spans and books its wall on the one it started with
+            self._tl = StepTimeline(TICK_SPANS)
             failed = 0
             failed_ids = []
             with self._lock:
@@ -3043,6 +3023,8 @@ class DecodeEngine:
             "occupancy": self.scheduler.occupancy(),
             "slo_miss_ratio": gauges.get("slo_miss_ratio"),
             "counters": counters,
+            # which phase of which tick the time last went into
+            "last_ticks": last_ticks(self.replica),
         }
 
 

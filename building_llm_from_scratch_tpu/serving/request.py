@@ -289,6 +289,10 @@ class Request:
             "dur_s": max(t_end - self.t_submit, 0.0),
             "children": children,
             "request_id": self.id,
+            # the submit stamp on the monotonic clock itself (the tick
+            # records' clock: obs/schema.TICK_RECORD_FIELDS), so a reader
+            # can lay a request beside the ticks that served it
+            "t_submit": self.t_submit,
             "outcome": self.outcome(),
             "n_prompt_tokens": int(len(self.prompt_ids)),
             "n_tokens": len(self.output_ids),

@@ -58,6 +58,7 @@ from building_llm_from_scratch_tpu.training.precision import (
 Params = Dict[str, Any]
 
 
+@jax.named_scope("cross_entropy")
 def cross_entropy_loss(logits: jnp.ndarray, targets: jnp.ndarray,
                        weights: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Weighted token-mean cross entropy in fp32."""
@@ -85,16 +86,19 @@ def make_loss_fns(cfg: ModelConfig, use_fused_xent: Optional[bool] = None):
     Both take (params, hidden, targets, weights) where ``hidden`` is the
     pre-head activation from ``forward_hidden``."""
     if _auto_fused_xent(cfg, use_fused_xent):
+        @jax.named_scope("head_xent")
         def loss(params, hidden, targets, weights):
             return fused_cross_entropy_loss(hidden,
                                             params["head"]["weight"],
                                             targets, weights)
 
+        @jax.named_scope("head_xent")
         def sums(params, hidden, targets, weights):
             return fused_cross_entropy_sums(hidden,
                                             params["head"]["weight"],
                                             targets, weights)
     else:
+        @jax.named_scope("head")
         def _logits(params, hidden):
             return jnp.einsum("btd,dv->btv", hidden,
                               params["head"]["weight"],
@@ -326,6 +330,7 @@ def _finish_step(state: Params, loss, grads, n_tokens: int,
     return new_state, metrics
 
 
+@jax.named_scope("cross_entropy")
 def cross_entropy_sums(logits: jnp.ndarray, targets: jnp.ndarray,
                        weights: Optional[jnp.ndarray]):
     """(weighted negative-log-likelihood sum, weight sum) in fp32 — the

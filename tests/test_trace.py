@@ -23,6 +23,7 @@ from building_llm_from_scratch_tpu.obs import (
     chrome_trace,
     configure_metrics,
     export_chrome_trace,
+    get_metrics,
     render_prometheus,
 )
 from building_llm_from_scratch_tpu.obs.trace import TICK_PHASES
@@ -212,11 +213,16 @@ def test_trace_lifecycle_audit_every_outcome_closes_one_tree(model, sink):
     # EXPIRED: deadline passes while queued
     eng.run_until_idle()                            # finishes `held`
     held.result(timeout=10)
+    # (no clock decides which outcome this is: with no service estimate
+    # submit cannot predict a miss and shed it, whatever the finished
+    # requests above taught the EWMAs on a loaded host; and the deadline
+    # is moved into the past by hand, not slept out)
+    eng._tpot_ewma = eng._tokens_ewma = None
     expired_h = eng.submit(np.array([8], np.int32),
                            SamplingParams(max_new_tokens=2,
                                           ignore_eos=True,
-                                          deadline_s=0.01))
-    time.sleep(0.05)                                # deadline passes
+                                          deadline_s=60.0))
+    expired_h.t_deadline = time.monotonic() - 1.0   # deadline passes
     eng.run_until_idle()
     from building_llm_from_scratch_tpu.serving.request import (
         RequestExpiredError,
@@ -225,8 +231,10 @@ def test_trace_lifecycle_audit_every_outcome_closes_one_tree(model, sink):
     with pytest.raises(RequestExpiredError):
         expired_h.result(timeout=10)
 
-    # SHED: service EWMAs exist now; an impossible deadline is rejected
-    # at submit (predicted miss), without ever entering the queue
+    # SHED: with a service estimate (set, not measured: one second a
+    # token) an impossible deadline is rejected at submit (predicted
+    # miss), without ever entering the queue
+    eng._tpot_ewma, eng._tokens_ewma = 1.0, 8.0
     with pytest.raises(SLOShedError):
         eng.submit(np.array([9], np.int32),
                    SamplingParams(max_new_tokens=60, ignore_eos=True,
@@ -316,6 +324,307 @@ def test_tick_phase_breakdown_sums_to_tick_wall_time(model, sink):
     assert sum(eng.tick_phase_totals.values()) <= eng.tick_seconds_total * 1.02
     # decode must be a real, nonzero phase on every loaded window
     assert all(r["tick_decode_dispatch_s"] > 0 for r in ticks)
+
+
+def _burst(eng, n=4, new_tokens=10):
+    handles = [eng.submit(np.array([3, 4, 5, 6], np.int32),
+                          SamplingParams(max_new_tokens=new_tokens,
+                                         ignore_eos=True, seed=i))
+               for i in range(n)]
+    for h in handles:
+        h.result(timeout=60)
+    return handles
+
+
+def test_tick_spans_reach_the_profiler_trace_and_name_idle_gaps(
+        model, tmp_path):
+    """The engine's tick uses the trainer's span: under a profiler session
+    (the benchmark's ``Capture``) every phase that ran is a host span of
+    its registered name inside a ``tick`` step, and a device-idle gap
+    between two decode programs is named by a ``tick.*`` span, not
+    ``no_host_span``."""
+    from benchmark import trace as btrace
+    from building_llm_from_scratch_tpu.obs.schema import (
+        TICK_BETWEEN,
+        TICK_SPANS,
+        TICK_STEP,
+    )
+
+    cfg, params = model
+    configure_metrics(None)
+    eng = DecodeEngine(cfg, params, n_slots=2, max_len=64, metrics_every=4)
+    eng.warmup()
+    eng.start()
+    capture = btrace.Capture(str(tmp_path / "trace"))
+    capture.start()
+    try:
+        _burst(eng)
+        with eng._lock:     # a request is done before its last tick is:
+            pass            # let that tick close its spans
+    finally:
+        capture.stop()
+        eng.shutdown(drain=False)
+    files = sorted((tmp_path / "trace").rglob("*.xplane.pb"))
+    assert files, "the profiler wrote no trace"
+    loaded = btrace.load(str(files[-1]))
+    by_name = {}
+    for a, b, name in loaded["host"]:
+        by_name.setdefault(name, []).append((a, b))
+    ran = {ph for r in get_metrics().recent("tick") for ph in r["phases"]}
+    assert {"admit", "prefill", "decode_dispatch", "host_fetch",
+            "sample_commit", "callback_detok"} <= ran
+    ticks = by_name[TICK_STEP]
+    for ph in ran:
+        spans = by_name.get(TICK_SPANS[ph])
+        assert spans, f"phase {ph} ran and left no span {TICK_SPANS[ph]}"
+        for a, b in spans:
+            assert any(ta <= a and b <= tb for ta, tb in ticks), ph
+    assert by_name.get(TICK_BETWEEN), "the loop's own span is missing"
+    # a device that ran each decode program from its dispatch to the end
+    # of its fetch, as the chip does: what it idles on is the host between
+    # one tick's fetch and the next tick's dispatch
+    dispatches = sorted(by_name[TICK_SPANS["decode_dispatch"]])
+    fetches = sorted(by_name[TICK_SPANS["host_fetch"]])
+    runs = [(d[0], f[1], "jit__decode_impl(1)")
+            for d, f in zip(dispatches, fetches)]
+    assert len(runs) >= 8
+    loaded["devices"] = {"/device:TPU:0": {"modules": runs, "ops": runs}}
+    # (on the CPU the backend's own threads write their operations into
+    # the host plane; the chip's do not, so they are left out here)
+    loaded["host"] = [h for h in loaded["host"] if h[2].startswith("tick")]
+    gaps = dict(map(tuple, btrace.reduce(loaded)["idle_gaps"]))
+    named = {k: v for k, v in gaps.items() if "___after_" in k}
+    by_tick = sum(v for k, v in named.items() if k.startswith("tick"))
+    assert any(k.startswith("tick.") and k.endswith(
+        "___after_jit__decode_impl_before_jit__decode_impl") for k in named)
+    assert by_tick >= 0.75 * sum(named.values()), named
+
+
+def test_one_tick_record_on_every_exit_of_step(model):
+    """One plain record a tick, whatever way ``step()`` left its timed
+    part (decode, admission only, idle, a generation abort, a raising
+    tick): phases sum to the wall, the stamps are ordered, and the ring
+    keeps numbers only, so the engine is collectable after shutdown."""
+    import gc
+    import weakref
+
+    from building_llm_from_scratch_tpu.obs import metrics as obs_metrics
+    from building_llm_from_scratch_tpu.obs.schema import TICK_RECORD_FIELDS
+    from building_llm_from_scratch_tpu.serving.supervisor import FaultHooks
+
+    cfg, params = model
+    sink = configure_metrics(None)
+
+    class Hooks(FaultHooks):
+        abort = raise_ = False
+
+        def before_tick(self, engine):
+            if self.abort:
+                engine._generation += 1       # what _restart does first
+            if self.raise_:
+                raise RuntimeError("tick exploded")
+
+    hooks = Hooks()
+    eng = DecodeEngine(cfg, params, n_slots=2, max_len=64, metrics_every=0,
+                       hooks=hooks)
+    eng.warmup()
+    n_steps = 0
+
+    def step():
+        nonlocal n_steps
+        n_steps += 1
+        return eng.step()
+
+    assert step() is False                       # idle: nothing queued
+    eng.submit(np.array([3, 4, 5], np.int32),
+               SamplingParams(max_new_tokens=1, ignore_eos=True))
+    assert step() is False                       # admission only: one token
+    for i in range(3):
+        eng.submit(np.array([3, 4, 5, 6], np.int32),
+                   SamplingParams(max_new_tokens=6, ignore_eos=True, seed=i))
+    while step():
+        pass
+    hooks.raise_ = True
+    with pytest.raises(RuntimeError):
+        step()
+    hooks.raise_, hooks.abort = False, True
+    assert step() is False                       # generation abort
+    recs = sink.recent("tick")
+    assert len(recs) == n_steps
+    decode = [r for r in recs if "decode_dispatch" in r["phases"]]
+    assert decode and recs[1]["admitted"] == 1
+    assert set(recs[0]["phases"]) == {"admit"} and recs[0]["rows"] == 0
+    assert max(r["queue_depth"] for r in recs) >= 2
+    for r in recs:
+        wall = r["t1"] - r["t0"]
+        assert 0 <= sum(r["phases"].values()) <= wall * 1.001 + 1e-9, r
+        assert set(r["phases"]) <= set(TICK_PHASES)
+        assert set(r) <= set(TICK_RECORD_FIELDS)
+        assert r["n_slots"] == 2 and 0 <= r["rows"] <= 2
+    for r in decode:
+        assert r["t0"] <= r["t_dispatch"] <= r["t_fetch"] <= r["t1"]
+        assert r["rows"] >= 1
+    assert [r["tick"] for r in decode] == sorted(r["tick"] for r in decode)
+    assert sum(r["rows"] for r in decode) + 4 == eng.tokens_generated
+    # totals are the records' sums: one account, read two ways
+    assert eng.tick_seconds_total == pytest.approx(
+        sum(r["t1"] - r["t0"] for r in recs))
+    for ph in TICK_PHASES:
+        assert eng.tick_phase_totals[ph] == pytest.approx(
+            sum(r["phases"].get(ph, 0.0) for r in recs))
+
+    # the ring never passes its length
+    for i in range(obs_metrics.RECENT_RECORDS + 10):
+        sink.keep_record("tick", {"tick": i})
+    assert len(sink.recent("tick")) == obs_metrics.RECENT_RECORDS
+    assert sink.recent("tick")[-1] == {"tick": obs_metrics.RECENT_RECORDS + 9}
+
+    # ... and holds plain numbers and strings, no way back to the engine:
+    # walked through every container, and the engine is collectable
+    todo, plain = list(recs), (dict, list, str, int, float, type(None))
+    while todo:
+        obj = todo.pop()
+        assert isinstance(obj, plain), type(obj)
+        if isinstance(obj, (dict, list)):
+            todo.extend(gc.get_referents(obj))
+    sink.keep_record("tick", recs[-1])
+    ref = weakref.ref(eng)
+    eng.shutdown(drain=False)
+    del eng, hooks, step
+    gc.collect()
+    assert ref() is None
+    configure_metrics(None)
+
+
+def test_a_tick_abandoned_in_its_fetch_leaves_the_new_timeline_alone(
+        model, monkeypatch):
+    """``_restart`` bumps the generation and gives the engine a fresh
+    timeline while a wedged tick still sits in ``host_fetch``. When that
+    tick un-wedges it opens no span on the new timeline, commits nothing,
+    and books what it did from the timeline it started with."""
+    from building_llm_from_scratch_tpu.obs.schema import TICK_SPANS
+    from building_llm_from_scratch_tpu.obs.timeline import StepTimeline
+
+    cfg, params = model
+    sink = configure_metrics(None)
+    eng = DecodeEngine(cfg, params, n_slots=2, max_len=64, metrics_every=0)
+    eng.warmup()
+    handle = eng.submit(np.array([3, 4, 5], np.int32),
+                        SamplingParams(max_new_tokens=4, ignore_eos=True))
+    assert eng.step()                       # admits and decodes once
+    old, n_out = eng._tl, len(handle.output_ids)
+    real_get = jax.device_get
+
+    def wedged_get(x):
+        out = real_get(x)
+        if eng._tl is old:                  # what _restart does meanwhile
+            eng._generation += 1
+            eng._tl = StepTimeline(TICK_SPANS)
+        return out
+
+    monkeypatch.setattr(jax, "device_get", wedged_get)
+    assert eng.step() is False
+    assert eng._tl is not old
+    assert eng._tl.seconds == {} and eng._tl.ends == {}
+    assert len(handle.output_ids) == n_out
+    rec = sink.recent("tick")[-1]
+    assert "host_fetch" in rec["phases"]
+    assert "sample_commit" not in rec["phases"]
+    assert sum(rec["phases"].values()) <= (rec["t1"] - rec["t0"]) * 1.001
+    monkeypatch.undo()
+    eng.shutdown(drain=False)
+    configure_metrics(None)
+
+
+def test_last_ticks_reach_healthz_and_the_stall_dump(model):
+    """The operator's view of the tick records: ``/healthz`` and the stall
+    detector's dump show the newest ticks (which tick, how long, which
+    phases, rows, admissions, the queue), each replica its own."""
+    import io
+    import logging
+
+    from building_llm_from_scratch_tpu.obs import stall
+
+    cfg, params = model
+    configure_metrics(None)
+    engines = [DecodeEngine(cfg, params, n_slots=2, max_len=64,
+                            metrics_every=0, replica=name)
+               for name in (0, 1)]
+    for eng, n in zip(engines, (stall.LAST_TICKS + 4, 2)):
+        eng.warmup()
+        eng.submit(np.array([3, 4, 5], np.int32),
+                   SamplingParams(max_new_tokens=n, ignore_eos=True))
+        while eng.step():
+            pass
+    for eng, name in zip(engines, (0, 1)):
+        ticks = eng.healthz_payload()["last_ticks"]
+        assert ticks and len(ticks) <= stall.LAST_TICKS
+        assert {t["replica"] for t in ticks} == {name}
+        assert ticks[-1]["tick"] == eng.n_ticks
+        assert [t["tick"] for t in ticks] == sorted(t["tick"] for t in ticks)
+        for t in ticks:
+            assert t["n_slots"] == 2 and t["rows"] <= 1
+            assert 0 <= sum(t["phases_ms"].values()) <= t["wall_ms"] * 1.01
+            assert t["ended_s_ago"] >= 0
+            assert t["queue_depth"] == t["admitted"]    # its one request
+        assert sum(t["admitted"] for t in ticks) <= 1
+    assert len(engines[0].healthz_payload()["last_ticks"]) == stall.LAST_TICKS
+    json.dumps(engines[0].healthz_payload())       # the body stays JSON
+
+    # obs loggers don't propagate: a handler of its own, not caplog
+    handler = logging.StreamHandler(io.StringIO())
+    stall.logger.addHandler(handler)
+    try:
+        stall.StallDetector(timeout=0.01)._dump(1.0, 0.01)
+    finally:
+        stall.logger.removeHandler(handler)
+    text = handler.stream.getvalue()
+    assert "Last serving ticks: [{'tick': " in text
+    assert "'replica': 1}" in text
+    for eng in engines:
+        eng.shutdown(drain=False)
+    configure_metrics(None)
+
+
+def test_hub_keeps_rows_with_and_without_a_file(model, tmp_path):
+    """``get_metrics().recent(kind)`` returns request span rows and cadence
+    rows with no file configured; with one, the same rows reach the JSONL;
+    kinds no reader asks for (``event``, ``health``) go to the file only;
+    ``configure_metrics`` starts both anew."""
+    cfg, params = model
+
+    def run():
+        eng = DecodeEngine(cfg, params, n_slots=2, max_len=64,
+                           metrics_every=2)
+        eng.warmup()
+        eng.start()
+        handles = _burst(eng, n=3, new_tokens=5)
+        eng.shutdown(drain=False)
+        return {h.id for h in handles}
+
+    hub = configure_metrics(None)
+    ids = run()
+    spans = hub.recent("span")
+    assert {s["request_id"] for s in spans} == ids
+    for s in spans:
+        assert [c["name"] for c in s["children"]] == [
+            "queued", "prefill", "decode"]
+        assert s["t_submit"] > 0 and s["outcome"] == "length"
+    assert any("tick_total_s" in r for r in hub.recent("metrics"))
+    assert hub.recent("tick") and hub.recent("event") == []
+
+    path = tmp_path / "metrics.jsonl"
+    hub = configure_metrics(str(path), run_metadata={"test": True})
+    assert hub.recent("span") == [] and hub.recent("tick") == []
+    ids = run()
+    hub.close()
+    rows = load_rows(str(path))
+    for kind in ("span", "metrics"):
+        assert [r for r in rows if r["type"] == kind] == hub.recent(kind)
+    assert any(r["type"] == "event" for r in rows)
+    assert {r["request_id"] for r in hub.recent("span")} == ids
+    assert not any(r["type"] == "tick" for r in rows)   # memory only
+    configure_metrics(None)
 
 
 def test_tick_steady_state_has_zero_implicit_transfers(model):
