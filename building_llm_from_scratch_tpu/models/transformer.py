@@ -918,6 +918,31 @@ def _new_cache_acc(cache: Params) -> Params:
     return {name: [] for name in cache}
 
 
+def kv_append_path(cache: Params, Tq: int,
+                   backend: Optional[str] = None) -> str:
+    """THE rule for how ``_slot_append_kv`` writes a tick's keys and
+    values, made once, at trace time, on what the code can observe:
+    ``"lane_window"`` (ops/decode_step.lane_window_append: one in-place
+    kernel call a layer) on a TPU backend for the shapes
+    ``supports_lane_append`` admits, ``"scatter"`` (the per-row
+    ``slot_cache_append``) for everything else: verify (Tq = k+1), int8
+    caches, ``head_dim`` 128, any other backend. The engine reports the
+    name (``stats()["kv_append"]``). ``backend`` is for tests, which
+    have no TPU to ask about."""
+    from building_llm_from_scratch_tpu.ops.decode_step import (
+        supports_lane_append,
+    )
+
+    pane = cache["k"][0]                       # (S, Hkv, Tmax, hd)
+    _, Hkv, Tmax, hd = pane.shape
+    if ((backend or jax.default_backend()) == "tpu"
+            and not _cache_quantized(cache)
+            and supports_lane_append(Tq, Tmax, hd, Hkv=Hkv,
+                                     dtype=pane.dtype)):
+        return "lane_window"
+    return "scatter"
+
+
 @jax.named_scope("cache_update")
 def _slot_append_kv(cache: Params, new: Params, l: int,
                     K: jnp.ndarray, V: jnp.ndarray,
@@ -928,23 +953,30 @@ def _slot_append_kv(cache: Params, new: Params, l: int,
     write under the int8 policy (codes + fp32 scale sidecars). THE one
     inner write rule shared by ``decode_slots`` (Tq=1) and
     ``verify_slots`` (Tq=k+1): the speculative path's bit-parity with
-    plain decode depends on these two appends never drifting. Returns
-    the appended (K, V) buffers (also pushed onto ``new``)."""
+    plain decode depends on these two appends never drifting, and the
+    two forms of the write (``kv_append_path``) leave the same bits.
+    Returns the appended (K, V) buffers (also pushed onto ``new``)."""
     from building_llm_from_scratch_tpu.ops.decode_step import (
+        lane_window_append,
         quantize_kv,
         slot_cache_append,
     )
 
     kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    if _cache_quantized(cache):
-        kt, ks = quantize_kv(kt)
-        vt, vs = quantize_kv(vt)
-        new["k_scale"].append(slot_cache_append(
-            cache["k_scale"][l], ks, lengths))
-        new["v_scale"].append(slot_cache_append(
-            cache["v_scale"][l], vs, lengths))
-    K = slot_cache_append(K, kt, lengths)
-    V = slot_cache_append(V, vt, lengths)
+    if kv_append_path(cache, k.shape[1]) == "lane_window":
+        K, V = lane_window_append(
+            K, V, kt, vt, lengths,
+            interpret=jax.default_backend() != "tpu")
+    else:
+        if _cache_quantized(cache):
+            kt, ks = quantize_kv(kt)
+            vt, vs = quantize_kv(vt)
+            new["k_scale"].append(slot_cache_append(
+                cache["k_scale"][l], ks, lengths))
+            new["v_scale"].append(slot_cache_append(
+                cache["v_scale"][l], vs, lengths))
+        K = slot_cache_append(K, kt, lengths)
+        V = slot_cache_append(V, vt, lengths)
     new["k"].append(K)
     new["v"].append(V)
     return K, V
@@ -1137,8 +1169,10 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  ) -> Tuple[jnp.ndarray, Params]:
     """One decode tick for the whole slot batch: ``tokens`` (S, 1) are each
     slot's last accepted token, ``lengths`` (S,) its valid cache prefix.
-    Appends each row's k/v at ITS offset (ops/decode_step.slot_cache_append;
-    the pallas fused step where ``_use_fused_decode`` says so) and attends
+    Appends each row's k/v at ITS offset (``_slot_append_kv``: one in-place
+    ``lane_window_append`` a layer where ``kv_append_path`` admits it, else
+    ``slot_cache_append``'s scatter; the pallas fused step where
+    ``_use_fused_decode`` says so) and attends
     with per-row masks; returns
     (fp32 logits (S, V), updated cache). Free/finished slots compute
     garbage rows the engine ignores — the shapes never change, so XLA
@@ -1221,7 +1255,8 @@ def verify_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     distribution exactly when the drafts before j were all accepted — the
     accept rule (generate.accept_draft_tokens) commits only such
     prefixes. Appends all Tq candidate k/v panes at per-row offsets (the
-    same ``slot_cache_append`` batched DUS decode uses, quantize-on-write
+    same ``_slot_append_kv`` rule decode uses: Tq > 1 always takes
+    ``slot_cache_append``'s per-row scatter, quantize-on-write
     under the int8 policy); the engine advances ``lengths`` by the
     ACCEPTED count only, so a rejected tail's entries sit past the valid
     prefix — masked by ``kv_length`` everywhere and overwritten by the

@@ -381,10 +381,12 @@ _EVENT_LIST: List[EventSpec] = [
                     "max_len", "kv_quant", "prefix_cache", "prefill_chunk",
                     "kv_bytes_per_slot", "prefix_pane_tokens", "spec_k",
                     "drafter", "replica", "kv_paged", "page_tokens",
-                    "pool_pages", "sp", "prompt_pane_tokens", "max_prompt"),
+                    "pool_pages", "sp", "prompt_pane_tokens", "max_prompt",
+                    "kv_append"),
           doc="prefill programs + decode (or spec verify) program "
               "compiled; watchers frozen; records the KVCachePolicy "
-              "(quant/chunk/prefix), the speculative config "
+              "(quant/chunk/prefix), which append the tick program was "
+              "built with (kv_append), the speculative config "
               "(spec_k/drafter) when on, and the seq-sharded prefill "
               "geometry (sp/prompt_pane_tokens/max_prompt) on "
               "--serve_sp engines"),

@@ -114,9 +114,13 @@ def slot_cache_append(cache: jnp.ndarray, new: jnp.ndarray,
 
     Scalar ``lengths`` degrades to the shared-offset single
     ``dynamic_update_slice`` the one-shot decode path uses. The vmap'd
-    per-row form lowers to a batched DUS; ``fused_decode_step`` below is
-    the opt-in pallas alternative for single-token appends (it
-    additionally aliases the cache in place).
+    per-row form becomes a ``scatter``, which the TPU compiler expands
+    into a ``while`` of B trips, each a serial chain of about ten tiny
+    operations (bound by their latency, not by bytes: 60% of the 1.5B
+    decode program before PR 26). It is the REFERENCE write: whatever
+    ``supports_lane_append`` refuses runs it, and ``lane_window_append``
+    below (one in-place kernel call a layer, where the gate admits it)
+    is tested against it bit for bit.
     """
     lengths = jnp.asarray(lengths)
     if lengths.ndim == 0:
@@ -127,6 +131,82 @@ def slot_cache_append(cache: jnp.ndarray, new: jnp.ndarray,
         return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), (0, t, 0))
 
     return jax.vmap(one)(cache, new, lengths.astype(jnp.int32))
+
+
+#: positions of one lane tile: the window a lane-window append rewrites
+_LANES = 128
+
+
+def _lane_append_kernel(len_ref, kn_ref, vn_ref, K_ref, V_ref,
+                        Ko_ref, Vo_ref):
+    """One slot a grid cell. The index maps already chose the 128-position
+    window that holds position ``t``; merge the new column at lane
+    ``t % 128`` into it and store the window back over itself."""
+    lane = len_ref[pl.program_id(0)] % _LANES
+    hit = jax.lax.broadcasted_iota(jnp.int32, K_ref.shape, 3) == lane
+    Ko_ref[...] = jnp.where(hit, kn_ref[...], K_ref[...])
+    Vo_ref[...] = jnp.where(hit, vn_ref[...], V_ref[...])
+
+
+def lane_window_append(k_cache, v_cache, k_new, v_new, lengths, *,
+                       interpret=False):
+    """Append ONE token a row to both buffers of a layer, in place, in one
+    call: ``k_new``/``v_new`` (S, Hkv, 1, hd) go to position
+    ``lengths[s]`` (>= 0) of ``k_cache``/``v_cache`` (S, Hkv, Tmax, hd).
+    What it leaves is bit-identical to two ``slot_cache_append``s.
+
+    Works on the cache's OWN device layout. For ``head_dim < 128`` the
+    TPU runtime keeps (S, Hkv, Tmax, hd) with positions on the 128 lanes
+    and ``head_dim`` on the sublanes (``{2,3,1,0:T(8,128)(2,1)}``: a
+    minor dimension of 64 would waste half of every tile), so
+    ``swapaxes(2, 3)`` is a bitcast there and a token is one lane column.
+    The kernel sees (S, Hkv, hd, Tmax), takes the one (1, Hkv, hd, 128)
+    window at lane block ``lengths[s] // 128`` (scalar-prefetched), and
+    aliases each cache onto its output: windows never visited stay where
+    they are, and no pane is copied or relaid. (A kernel handed the
+    logical shape, or a hinted scatter, gets whole-pane relayout copies
+    in and out: PERF.md section 7.)
+
+    Under a mesh (``--serve_tp``) heads shard over the model axis like
+    the slot cache; ``interpret=True`` runs on CPU for parity tests."""
+    lengths = jnp.asarray(lengths, jnp.int32)
+    panes = (None, MODEL_AXIS, None, None)
+    return mesh_kernel(
+        lambda _, *a: _lane_append_local(*a, interpret=interpret),
+        (k_cache, v_cache, k_new, v_new, lengths),
+        (panes, panes, panes, panes, (None,)),
+        (panes, panes))
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _lane_append_local(k_cache, v_cache, k_new, v_new, lengths, *,
+                       interpret):
+    # jitted so that a program's layers trace and lower the kernel ONCE
+    # (48 calls of one function): without it a warm set-up of the 1.5B
+    # engine spends 3 s more on its decode program
+    S, Hkv, Tmax, hd = k_cache.shape
+    # dynamic_update_slice clamps an origin past the end; so does this
+    lengths = jnp.minimum(lengths, Tmax - 1)
+    window = pl.BlockSpec((1, Hkv, hd, _LANES),
+                          lambda s, len_ref: (s, 0, 0, len_ref[s] // _LANES))
+    column = pl.BlockSpec((1, Hkv, hd, 1), lambda s, len_ref: (s, 0, 0, 0))
+    t = lambda x: jnp.swapaxes(x, 2, 3)        # a bitcast of the panes
+    ko, vo = pl.pallas_call(
+        _lane_append_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S,),
+            in_specs=[column, column, window, window],
+            out_specs=[window, window],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((S, Hkv, hd, Tmax), k_cache.dtype),
+                   jax.ShapeDtypeStruct((S, Hkv, hd, Tmax), v_cache.dtype)],
+        # K->Ko, V->Vo in place (operand indices count the prefetch arg)
+        input_output_aliases={3: 0, 4: 1},
+        interpret=interpret,
+    )(lengths, t(k_new).astype(k_cache.dtype), t(v_new).astype(v_cache.dtype),
+      t(k_cache), t(v_cache))
+    return t(ko), t(vo)
 
 
 def fused_decode_step(q, k_new, v_new, k_cache, v_cache, length, *,
@@ -418,6 +498,23 @@ def supports_paged_shape(Tq: int, page_tokens: int, hd: int) -> bool:
     reference path."""
     return (Tq == 1 and hd % 64 == 0 and hd <= 256
             and page_tokens % 8 == 0)
+
+
+def supports_lane_append(Tq: int, Tmax: int, hd: int, *, Hkv: int,
+                         dtype) -> bool:
+    """``lane_window_append`` eligibility: one token a row, a float
+    cache whose ``head_dim`` is under a lane tile (so the runtime keeps
+    positions on the lanes and the kernel's view is a bitcast; at 128
+    it keeps the logical layout, positions on the sublanes, and the
+    view would be a transpose of every pane), whole lane windows, and the
+    pipeline's blocks (two windows in, two out, two columns padded to a
+    window, each double-buffered) inside the VMEM budget. int8 caches
+    and their (…, 1) scale sidecars have other native layouts. Whatever
+    this refuses keeps ``slot_cache_append``'s scatter."""
+    dtype = jnp.dtype(dtype)
+    return (Tq == 1 and jnp.issubdtype(dtype, jnp.floating)
+            and hd < _LANES and hd % 16 == 0 and Tmax % _LANES == 0
+            and 12 * Hkv * hd * _LANES * dtype.itemsize <= _VMEM_BUDGET)
 
 
 #: the fused step runs under the compiler's default 16 MiB scoped-VMEM

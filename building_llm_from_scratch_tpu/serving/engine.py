@@ -61,8 +61,10 @@ from building_llm_from_scratch_tpu.generate import (
     token_rng,
 )
 from building_llm_from_scratch_tpu.models.transformer import (
+    _use_fused_decode,
     decode_slots,
     init_slot_cache,
+    kv_append_path,
     paged_decode_slots,
     paged_prefill_chunk_into_slot,
     paged_verify_slots,
@@ -344,6 +346,16 @@ class DecodeEngine:
         self._cache_shardings = (jax.tree_util.tree_map(
             lambda x: x.sharding, self.cache)
             if mesh_plan is not None else None)
+        #: which write the tick program's append was built with, chosen
+        #: once, at trace time, by the rule the program itself asks
+        #: (``kv_append_path``): "lane_window" | "scatter", or the other
+        #: two writers' names. In ``stats()``, ``/healthz``, the warm-up event
+        if self._paged:
+            self.kv_append = "paged"
+        elif not self.spec_k and _use_fused_decode(cfg, self.cache, 1):
+            self.kv_append = "fused_step"
+        else:
+            self.kv_append = kv_append_path(self.cache, self.spec_k + 1)
         self._blocks = unstack_blocks(self.params, cfg)
         #: the weights ride every compiled program as an ARGUMENT: closed
         #: over, jit bakes them into each program as constants (GPT2-124M
@@ -2446,6 +2458,7 @@ class DecodeEngine:
             buckets=buckets, seconds=round(time.monotonic() - t0, 3),
             n_slots=self.n_slots, max_len=self.max_len,
             kv_bytes_per_slot=bps["total_bytes"],
+            kv_append=self.kv_append,
             prefix_pane_tokens=(self._prefix_pane_len
                                 if self.prefix_store is not None
                                 else None),
@@ -2848,6 +2861,7 @@ class DecodeEngine:
             if self.adapters is not None:
                 out["adapters_loaded"] = self.adapters.n_loaded
             out["kv_policy"] = self.kv_policy.describe()
+            out["kv_append"] = self.kv_append
             out["memory"] = self.memory_ledger.describe()
             if self._paged:
                 out["page_pool"] = self.page_pool.stats()
@@ -3014,6 +3028,7 @@ class DecodeEngine:
             "queue_depth": len(self.queue),
             "queue_capacity": self.queue.max_size,
             "warmed_up": self.warmed_up,
+            "kv_append": self.kv_append,
             "draining": self.draining,
             "restarts": self.n_restarts,
             # structured snapshot (one probe answers "how is it

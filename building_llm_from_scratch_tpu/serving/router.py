@@ -758,6 +758,7 @@ class EngineRouter:
                 "occupancy": p["occupancy"],
                 "restarts": p["restarts"],
                 "slo_miss_ratio": p["slo_miss_ratio"],
+                "kv_append": p["kv_append"],
             })
         up = [r for r in replicas if r["status"] == "serving"]
         if self._dead is not None:

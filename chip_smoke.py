@@ -263,6 +263,7 @@ def serve(label: str, reqs_path: str, n: int, extra=(), model=()):
     log(f"{label}: {n} requests, "
         f"{sum(r['n_tokens'] for r in results)} tokens, "
         f"{engine.n_recompiles} bucket-miss compiles after warmup, "
+        f"kv_append {engine.kv_append}, "
         f"{time.perf_counter() - t0:.1f}s, peak HBM {peak_hbm_gib()} GiB")
     return engine, results
 
@@ -381,6 +382,7 @@ def phase_kernels() -> None:
     )
     from building_llm_from_scratch_tpu.ops.decode_step import (
         fused_decode_step,
+        lane_window_append,
         slot_cache_append,
     )
     from building_llm_from_scratch_tpu.ops.fused_attention import (
@@ -454,9 +456,28 @@ def phase_kernels() -> None:
           "kernels: fused decode step wrote the cache differently")
     np.testing.assert_allclose(f32(o), f32(ref), atol=2e-2, rtol=2e-2)
 
+    # the lane-window append (what the engine's tick program writes the
+    # cache with) vs the scatter it replaces: pure data movement, so exact,
+    # with the caches donated as the engine donates them. Window edges,
+    # both ends, and fp32 as well as bf16
+    lengths = jnp.asarray([0, 127, 128, 255, 133, 512, 1000, 1023],
+                          jnp.int32)
+    append = jax.jit(lane_window_append, donate_argnums=(0, 1))
+    for dt in (jnp.float32, jnp.bfloat16):     # bf16 last: it donates K, V
+        Kd, Vd, knd, vnd = (a.astype(dt) for a in (
+            K, V, kn.transpose(0, 2, 1, 3), vn.transpose(0, 2, 1, 3)))
+        K2 = slot_cache_append(Kd, knd, lengths)
+        V2 = slot_cache_append(Vd, vnd, lengths)
+        Ko, Vo = append(Kd, Vd, knd, vnd, lengths)
+        check(np.array_equal(f32(Ko), f32(K2))
+              and np.array_equal(f32(Vo), f32(V2)),
+              f"kernels: lane-window append wrote the {jnp.dtype(dt).name} "
+              f"cache differently from the scatter")
+
     n = run_repo_tpu_tests()
-    log(f"kernels: fused attention fwd/grad/dropout, fused dropout-add and "
-        f"fused decode step match their XLA references at the real shapes; "
+    log(f"kernels: fused attention fwd/grad/dropout, fused dropout-add, "
+        f"fused decode step and lane-window append match their XLA "
+        f"references at the real shapes; "
         f"{n} needs_tpu repo test cases pass; "
         f"{time.perf_counter() - t0:.1f}s")
 

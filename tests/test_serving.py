@@ -84,6 +84,90 @@ def test_slot_cache_append_per_row_offsets():
     np.testing.assert_array_equal(shared[:, :, 2], new[:, :, 0])
 
 
+_APPEND_T = 256
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("lengths", [
+    [0, 0, 0, 0], [127] * 4, [128] * 4, [_APPEND_T - 1] * 4,
+    [0, 127, 128, _APPEND_T - 1],
+    # past the end: dynamic_update_slice clamps, so must the kernel
+    [_APPEND_T, _APPEND_T + 44, 3, 5],
+], ids=["first", "window_end", "window_start", "last", "mixed", "clamped"])
+def test_slot_cache_append_per_row_offsets_lane_window(lengths, dtype):
+    """The lane-window kernel (interpret mode) against the scatter, which
+    stays the reference: bit for bit, every position of both buffers."""
+    from building_llm_from_scratch_tpu.ops.decode_step import (
+        lane_window_append,
+        slot_cache_append,
+        supports_lane_append,
+    )
+
+    S, H, T, D = 4, 2, _APPEND_T, 16
+    assert supports_lane_append(1, T, D, Hkv=H, dtype=dtype)
+    ks = jax.random.split(jax.random.PRNGKey(len(lengths) + sum(lengths)), 4)
+    K, V = (jax.random.normal(k, (S, H, T, D)).astype(dtype) for k in ks[:2])
+    kn, vn = (jax.random.normal(k, (S, H, 1, D)).astype(dtype)
+              for k in ks[2:])
+    lens = jnp.asarray(lengths, jnp.int32)
+    K2, V2 = jax.jit(lambda *a: lane_window_append(*a, interpret=True))(
+        K, V, kn, vn, lens)
+    for got, pane, new in ((K2, K, kn), (V2, V, vn)):
+        want = slot_cache_append(pane, new, lens)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)))
+    # and the scatter wrote where the test says it did
+    for s, t in enumerate(np.clip(lengths, 0, T - 1)):
+        np.testing.assert_array_equal(
+            np.asarray(K2[s, :, t].astype(jnp.float32)),
+            np.asarray(kn[s, :, 0].astype(jnp.float32)))
+
+
+def _append_cache(S=2, H=2, T=128, D=16, dtype=jnp.float32, quant=False):
+    cache = {"k": [jnp.zeros((S, H, T, D), dtype)],
+             "v": [jnp.zeros((S, H, T, D), dtype)]}
+    if quant:
+        cache["k_scale"] = [jnp.zeros((S, H, T, 1), jnp.float32)]
+        cache["v_scale"] = [jnp.zeros((S, H, T, 1), jnp.float32)]
+    return cache
+
+
+@pytest.mark.parametrize("why,Tq,kw", [
+    ("verify_tq", 3, {}),
+    ("int8_cache", 1, dict(dtype=jnp.int8, quant=True)),
+    ("head_dim_128", 1, dict(D=128)),
+    ("tmax_not_lane_multiple", 1, dict(T=192 + 8)),
+    ("over_vmem_budget", 1, dict(H=256, D=64)),
+    ("not_a_tpu", 1, {}),
+])
+def test_kv_append_gate_refusals_take_the_scatter(why, Tq, kw):
+    """Outside the gate the one append rule runs today's scatter: the
+    name says so, and the traced write holds no kernel call."""
+    from building_llm_from_scratch_tpu.models import transformer as tf
+
+    cache = _append_cache(**kw)
+    backend = None if why == "not_a_tpu" else "tpu"
+    assert tf.kv_append_path(_append_cache(), 1, backend="tpu") \
+        == "lane_window"
+    assert tf.kv_append_path(cache, Tq, backend=backend) == "scatter"
+    if why == "over_vmem_budget":
+        return                                  # nothing small to trace
+    S, H, _, D = cache["k"][0].shape
+    k = jnp.ones((S, Tq, H, D), jnp.float32)
+    lens = jnp.asarray([0, 5], jnp.int32)
+
+    def append(cache, k, lens):
+        new = tf._new_cache_acc(cache)
+        tf._slot_append_kv(cache, new, 0, cache["k"][0], cache["v"][0],
+                           k, k, lens)
+        return new
+
+    assert "pallas_call" not in str(jax.make_jaxpr(append)(cache, k, lens))
+
+
 def test_decode_attention_per_row_matches_scalar():
     from building_llm_from_scratch_tpu.ops.attention import decode_attention
 
@@ -166,6 +250,50 @@ def test_engine_matches_generate_greedy_and_sampled(model):
     for h, sp in zip(handles, cases):
         assert h.done and h.finish_reason in ("eos", "length")
         assert h.output_ids == solo_tokens(params, cfg, prompt, sp), sp
+
+
+@pytest.mark.parametrize("tp", [1, 2], ids=["one_device", "serve_tp2"])
+def test_engine_tokens_identical_under_lane_window_append(monkeypatch, tp):
+    """One engine run with the tick program built on the lane-window
+    kernel (the rule told it is on a TPU; the kernel interprets on the
+    CPU it really is on), one on the scatter: the same greedy and
+    sampled tokens, and each engine names its append. Under
+    ``--serve_tp`` each device's kernel appends its own head."""
+    import functools
+
+    from building_llm_from_scratch_tpu.models import transformer as tf
+    from building_llm_from_scratch_tpu.parallel.sharding import (
+        serve_mesh_plan,
+    )
+    from building_llm_from_scratch_tpu.serving import engine as engine_mod
+
+    cfg = tiny_cfg(ctx=128)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = [np.array([5, 6, 7, 8, 9], np.int32),
+               np.arange(3, 40, dtype=np.int32)]
+    cases = [SamplingParams(max_new_tokens=10, seed=3, ignore_eos=True),
+             SamplingParams(max_new_tokens=7, temperature=0.9, top_k=5,
+                            seed=3, ignore_eos=True)]
+
+    def run():
+        eng = DecodeEngine(cfg, params, n_slots=2, max_len=128,
+                           mesh_plan=serve_mesh_plan(tp=tp) if tp > 1
+                           else None)
+        handles = [eng.submit(p, sp) for p, sp in zip(prompts, cases)]
+        eng.run_until_idle()
+        assert all(h.done and h.finish_reason == "length" for h in handles)
+        assert eng.stats()["kv_append"] == eng.kv_append
+        assert eng.healthz_payload()["kv_append"] == eng.kv_append
+        return eng.kv_append, [h.output_ids for h in handles]
+
+    scatter = run()
+    on_tpu = functools.partial(tf.kv_append_path, backend="tpu")
+    monkeypatch.setattr(tf, "kv_append_path", on_tpu)
+    monkeypatch.setattr(engine_mod, "kv_append_path", on_tpu)
+    lane = run()
+    assert scatter[0] == "scatter" and lane[0] == "lane_window"
+    assert lane[1] == scatter[1]
+    assert lane[1][0] == solo_tokens(params, cfg, prompts[0], cases[0])
 
 
 def test_slot_reuse_and_seed_reproducibility(model):

@@ -132,6 +132,76 @@ def test_fused_decode_step_refused_inside_a_deep_program(one_chip):
         _compile(six_layers, [pane] * 6, [pane] * 6, q, kn, vn, lens)
 
 
+@pytest.mark.parametrize("size,S,dtype", [
+    ("1.5B", 32, "bf16"),        # the serving cells: panes (32, 25, 1024, 64)
+    ("124M", 8, "bf16"),         # (8, 12, 1024, 64)
+    ("124M", 8, "fp32"),
+])
+def test_decode_slots_appends_in_place_at_full_depth(one_chip, monkeypatch,
+                                                     size, S, dtype):
+    """``decode_slots`` itself at the model's own depth (48 and 12 layers),
+    as the engine jits it (cache donated): the append is ONE custom call a
+    layer on the cache's own layout (positions on the lanes), every pane
+    aliased through, no ``while`` (the scatter's S-trip loops), and no
+    pane copied or relaid. A kernel handed the logical shape gets a
+    relayout copy of every pane in and out; the fused step one case down
+    is refused outright. (Full depth on purpose: a six-layer cut of the
+    1.5B program leaves the compiler VMEM to spare, and it then parks
+    whole panes there, which the real program never does.)"""
+    import dataclasses
+    import re
+
+    from building_llm_from_scratch_tpu.configs import get_config_gpt2
+    from building_llm_from_scratch_tpu.models import transformer as tf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config_gpt2(size), dtype=dtype)
+    L = cfg.n_layers
+    shapes = lambda f, *a: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(f, *a))
+    params = shapes(lambda: tf.init_params(cfg, jax.random.PRNGKey(0)))
+    blocks = shapes(lambda p: tf.unstack_blocks(p, cfg), params)
+    cache = shapes(lambda: tf.init_slot_cache(cfg, S, cfg.context_length))
+    assert tf.kv_append_path(cache, 1) == "lane_window"
+    row = jax.ShapeDtypeStruct((S,), I32, sharding=one_chip)
+
+    def tick(cache, params, blocks, tokens, lengths):
+        return tf.decode_slots(params, cfg, tokens[:, None], lengths, cache,
+                               blocks)
+
+    hlo = jax.jit(tick, donate_argnums=(0,)).lower(
+        cache, params, blocks, row, row).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == L
+    assert not re.search(r" while\(", hlo)
+    H, T, hd = cfg.n_kv_groups, cfg.context_length, cfg.head_dim
+    el = "bf16" if dtype == "bf16" else "f32"
+    # a pane, as the runtime keeps it and as the kernel views it (a bitcast)
+    native = re.escape(f"{el}[{S},{H},{T},{hd}]") + r"\{2,3,1,0:"
+    viewed = re.escape(f"{el}[{S},{H},{hd},{T}]") + r"\{3,2,1,0:"
+    # the caches come in and go out in that layout ...
+    head = hlo[:hlo.index("\n")]
+    layouts = head[head.index("entry_computation_layout="):]
+    assert len(re.findall(native, layouts)) == 4 * L     # k, v: in and out
+    # ... aliased input to output, all 2L of them ...
+    assert head.count("-alias)") == 2 * L
+    # ... and are in no other layout anywhere between (no relayout) ...
+    anywhere = re.findall(
+        re.escape(f"{el}[{S},{H},") + rf"(?:{T},{hd}|{hd},{T})\]\{{[\d,]+:",
+        hlo)
+    assert anywhere and all(re.fullmatch(f"{native}|{viewed}", a)
+                            for a in anywhere), set(anywhere)
+    # ... and never copied: nothing at all at the cells' 105 MB a pane; a
+    # 124M pane (12.6 MB) the compiler may prefetch into VMEM for the
+    # attention that reads it, as it does for the scatter's program
+    copied = [ln.strip()[:160] for ln in hlo.split("\n")
+              if re.search(rf" = (?:{native}|{viewed})\S* copy", ln)]
+    if size == "124M":
+        copied = [ln for ln in copied
+                  if not re.search(r"S\(1\)\} copy-done\(", ln)]
+    assert not copied, copied[:3]
+
+
 def test_paged_decode_attention(one_chip):
     S, H, hd, page, n_pages, max_pages = 8, 12, 64, 16, 512, 64
     assert ds.supports_paged_shape(1, page, hd)
@@ -193,3 +263,21 @@ def test_sharded_decode_step_on_four_devices(topo):
     hlo = _compile(trace_under_mesh(ds.fused_decode_step, mesh),
                    new, new, new, pane, pane, lens)
     assert hlo.count("tpu_custom_call") == 1
+
+
+def test_sharded_lane_window_append_on_four_devices(topo):
+    """The tick program's append under ``--serve_tp 4``: each device takes
+    the windows of its own three heads, in place."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
+                ("data", "seq", "model"))
+    panes = _spec(NamedSharding(mesh, P(None, "model")))
+    new, pane = panes((8, 12, 1, 64)), panes((8, 12, 1024, 64))
+    lens = jax.ShapeDtypeStruct((8,), I32, sharding=NamedSharding(mesh, P()))
+    assert ds.supports_lane_append(1, 1024, 64, Hkv=12, dtype=BF16)
+    hlo = jax.jit(trace_under_mesh(ds.lane_window_append, mesh),
+                  donate_argnums=(0, 1)).lower(
+        pane, pane, new, new, lens).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "bf16[8,3,1024,64]" in hlo and " copy(" not in "".join(
+        ln for ln in hlo.split("\n") if "bf16[8,3,1024,64]" in ln
+        or "bf16[8,3,64,1024]" in ln)
