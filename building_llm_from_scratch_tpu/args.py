@@ -26,7 +26,11 @@ import argparse
 import os
 import warnings
 
-from building_llm_from_scratch_tpu.configs import MODEL_PARAMS_MAPPING
+from building_llm_from_scratch_tpu.configs import (
+    MODEL_PARAMS_MAPPING,
+    get_config,
+    refuse_unsupported,
+)
 from building_llm_from_scratch_tpu.parallel.sharding import SHARD_MODES
 
 
@@ -269,6 +273,32 @@ def perform_checks(args) -> None:
             f"Unsupported model configuration: {args.model} with "
             f"{args.num_params}. Supported sizes: "
             f"{MODEL_PARAMS_MAPPING.get(args.model, [])}")
+
+    # what the model's kinds of layer and its experts do not compose with is
+    # refused by what its config IS (the one list the serving engine asks at
+    # construction too); a tokenizer or a checkpoint, by what is registered
+    refuse_unsupported(
+        get_config(args.model, args.num_params),
+        lora=args.use_lora or args.mode == "finetune_fleet",
+        tensor_parallel=(args.shard_mode in ("tp", "tp_fsdp")
+                         or args.serve_tp > 1),
+        pipeline_parallel=args.shard_mode == "pp",
+        sequence_parallel=args.sp > 1 or args.serve_sp > 1)
+    if args.model != "GPT2":
+        from building_llm_from_scratch_tpu.data.tokenizers import (
+            HF_TOKENIZER_ASSETS,
+        )
+        from building_llm_from_scratch_tpu.weights.fetch import HF_LLAMA_FILES
+
+        if args.load_weights and args.model not in HF_LLAMA_FILES:
+            raise ValueError(
+                f"--load_weights: no checkpoint converter is registered "
+                f"for --model {args.model}: its weights are initialised.")
+        if (args.model not in HF_TOKENIZER_ASSETS
+                and not args.byte_tokenizer):
+            raise ValueError(
+                f"no tokenizer is registered for --model {args.model}: "
+                "pass --byte_tokenizer.")
 
     # analog of "FSDP requires multi-GPU" (args.py:25-26): a sharded mode on
     # a single chip is a no-op at best

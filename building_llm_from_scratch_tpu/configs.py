@@ -19,7 +19,7 @@ argument to ``jax.jit``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -89,10 +89,73 @@ class ModelConfig:
     layernorm_eps: float = 1e-5
     use_actv_ckpt: bool = False          # jax.remat on the scanned block body
     attn_impl: str = "auto"              # 'auto' | 'xla' | 'pallas'
+    # -- blocks beyond the dense pre-norm one (all off by default) ---------
+    attn_head_dim: int = 0               # 0 = emb_dim // n_heads
+    parallel_block: bool = False         # one norm; attention and FFN both
+    #                                      read it, both add to the residual
+    tie_embeddings: bool = False         # logits = h @ tok_emb.T, no head leaf
+    #: the kinds of one period of layers, repeated down the depth:
+    #: 'sliding' (attends to the last ``sliding_window`` positions, itself
+    #: included) | 'full'. Empty = every layer full.
+    layer_kinds: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    rope_interleaved: bool = False       # rotate pairs (2i, 2i+1), not halves
+    full_layers_rope: bool = True        # False: 'full' layers carry no positions
+    #: sparse experts (0 = the dense MLP): a float32 sigmoid router over
+    #: ``n_routed_experts``, the ``n_experts_per_tok`` largest renormalised,
+    #: beside ``n_shared_experts`` always-on experts whose outputs are
+    #: averaged; every expert a SwiGLU of width ``hidden_dim``.
+    n_routed_experts: int = 0
+    n_experts_per_tok: int = 0
+    n_shared_experts: int = 0
+    #: the global ids of the routed experts THIS chip holds (empty = all):
+    #: routing is over all, the routed sum over the held ones only
+    experts_held: Tuple[int, ...] = ()
+
+    def __post_init__(self):
+        # a JSON file hands lists; the config must stay hashable
+        for name in ("layer_kinds", "experts_held"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.layer_kinds:
+            if set(self.layer_kinds) - {"sliding", "full"}:
+                raise ValueError(f"layer_kinds {self.layer_kinds}: each is "
+                                 "'sliding' or 'full'")
+            if self.n_layers % len(self.layer_kinds):
+                raise ValueError(
+                    f"n_layers {self.n_layers} is not whole periods of "
+                    f"{len(self.layer_kinds)} layers")
+            if "sliding" in self.layer_kinds and self.sliding_window < 1:
+                raise ValueError("'sliding' layers need sliding_window >= 1")
+        if self.n_routed_experts:
+            held = self.held_experts
+            if not (0 < self.n_experts_per_tok <= self.n_routed_experts):
+                raise ValueError("n_experts_per_tok must be in "
+                                 "[1, n_routed_experts]")
+            if (len(set(held)) != len(held) or min(held) < 0
+                    or max(held) >= self.n_routed_experts):
+                raise ValueError(f"experts_held {held}: distinct ids under "
+                                 f"{self.n_routed_experts}")
 
     @property
     def head_dim(self) -> int:
-        return self.emb_dim // self.n_heads
+        return self.attn_head_dim or self.emb_dim // self.n_heads
+
+    @property
+    def held_experts(self) -> Tuple[int, ...]:
+        return self.experts_held or tuple(range(self.n_routed_experts))
+
+    @property
+    def is_moe(self) -> bool:
+        return self.n_routed_experts > 0
+
+    def layer_kind(self, layer: int) -> str:
+        if not self.layer_kinds:
+            return "full"
+        return self.layer_kinds[layer % len(self.layer_kinds)]
+
+    @property
+    def has_window_layers(self) -> bool:
+        return "sliding" in self.layer_kinds
 
     @property
     def jax_dtype(self):
@@ -105,9 +168,15 @@ class ModelConfig:
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
-    def num_params(self, exclude_embeddings: bool = False) -> int:
+    def num_params(self, exclude_embeddings: bool = False,
+                   active: bool = False) -> int:
         """Analytic parameter count (used for memory estimates, parity with
-        reference utils.py:112-129 which counts live tensors)."""
+        reference utils.py:112-129 which counts live tensors): the parameters
+        HELD here (a sparse model's held experts, not the published count).
+        ``active``: those one token multiplies against here instead (a
+        sparse model's router, shared experts and, of its
+        ``n_experts_per_tok`` routed experts, the share held here, as an
+        even router spreads them)."""
         d, v, t = self.emb_dim, self.vocab_size, self.context_length
         hd, nh, nkv, f = self.head_dim, self.n_heads, self.n_kv_groups, self.hidden_dim
         emb = v * d + (t * d if self.positional == "learned" else 0)
@@ -115,18 +184,76 @@ class ModelConfig:
         if self.qkv_bias:
             qkv += nh * hd + 2 * nkv * hd
         attn_out = (nh * hd) * d + (d if self.attn_out_bias else 0)
-        if self.activation == "swiglu":
+        if self.is_moe:
+            held = len(self.held_experts)
+            n_routed = (self.n_experts_per_tok * held / self.n_routed_experts
+                        if active else held)
+            mlp = (d * self.n_routed_experts
+                   + round(3 * d * f * (n_routed + self.n_shared_experts)))
+        elif self.activation == "swiglu":
             mlp = 3 * d * f
         else:
             mlp = 2 * d * f + ((f + d) if self.mlp_bias else 0)
         norm_w = d * (2 if self.norm_bias else 1)
-        per_layer = qkv + attn_out + mlp + 2 * norm_w
+        per_layer = (qkv + attn_out + mlp
+                     + (1 if self.parallel_block else 2) * norm_w)
         final_norm = d * (2 if self.norm_bias else 1)
-        head = d * v
+        head = 0 if self.tie_embeddings else d * v
         total = per_layer * self.n_layers + final_norm + head
-        if not exclude_embeddings:
+        if not exclude_embeddings or self.tie_embeddings:
             total += emb
         return total
+
+
+#: what a model with window layers ("window") or sparse experts ("moe")
+#: does not run through yet: (feature, the property that refuses it, why).
+#: ONE list, asked by the flags' checks (``args.perform_checks``) and by the
+#: serving engine at construction, by what the config IS, never by its name
+#: (PERF.md section 7 lists them; ROADMAP R1 / R2 say what each takes)
+UNSUPPORTED = (
+    ("paged", "window",
+     "the paged pool maps a slot's positions onto pages one to one and has "
+     "no ring: serve it on the slot cache"),
+    ("prefix_cache", "window",
+     "a prefix pane is a slot's first positions and a ring keeps only its "
+     "last: serve it without --serve_prefix_cache"),
+    ("int8_cache", "window",
+     "the int8 cache's scale sidecars are not ring-indexed: serve it with "
+     "the model's own cache type"),
+    ("speculation", "window",
+     "a verify tick appends k+1 positions that may wrap a ring and rejected "
+     "ones cannot be taken back from it: serve it with spec_k 0"),
+    ("tensor_parallel", "either",
+     "experts and rings have no tensor-parallel split yet: run it on one "
+     "chip, or under dp, fsdp or zero1, which shard it like any leaf "
+     "(replicas behind a router are fine)"),
+    ("pipeline_parallel", "either",
+     "a pipeline stage runs layers of one kind and no expert layer: run "
+     "it under dp, fsdp or zero1"),
+    ("sequence_parallel", "either",
+     "the ring schedule of a sequence split has no window term and the "
+     "expert dispatch no split over it"),
+    ("lora", "moe",
+     "LoRA adapters attach to the dense MLP's projections and the expert "
+     "layer has none: run it without adapters"),
+)
+
+
+def refuse_unsupported(cfg: "ModelConfig", **features) -> None:
+    """``features``: a name of ``UNSUPPORTED`` -> whether the caller is
+    about to use it. Raises one sentence for the first that ``cfg``'s window
+    layers or sparse experts do not support: nothing is silently wrong."""
+    unknown = set(features) - {name for name, _, _ in UNSUPPORTED}
+    if unknown:
+        raise TypeError(f"refuse_unsupported: no such feature {unknown}")
+    has = {"window": cfg.has_window_layers, "moe": cfg.is_moe}
+    has["either"] = has["window"] or has["moe"]
+    for name, needs, why in UNSUPPORTED:
+        if features.get(name) and has[needs]:
+            kinds = " and ".join(
+                what for k, what in (("window", "window layers"),
+                                     ("moe", "sparse experts")) if has[k])
+            raise ValueError(f"{cfg.name} ({kinds}): {why}")
 
 
 # ---------------------------------------------------------------------------
@@ -278,6 +405,45 @@ LONGCTX_CONFIG_32K = ModelConfig(
 )
 
 
+# Command A+ (CohereLabs/command-a-plus-05-2026, model_type cohere2_moe;
+# the language model): every layer a PARALLEL block (one bias-free
+# LayerNorm feeds attention and the feed-forward, both add to the
+# residual); layers in periods of four, three sliding-window (4096, RoPE
+# theta 50000 over interleaved pairs) then one full-attention layer with no
+# positions; every feed-forward 128 sigmoid-routed experts (top-8,
+# renormalised) beside 4 shared experts whose outputs are averaged; tied
+# embedding. 218B parameters, 25B active: no one chip holds it. A chip of
+# an expert-parallel deployment is this config with ``experts_held`` (and,
+# in the benchmark's file, fewer layers and a slice of the vocabulary).
+COMMAND_A_PLUS_CONFIG = ModelConfig(
+    name="command-a-plus-05-2026",
+    vocab_size=262_144,
+    context_length=200_000,
+    emb_dim=4096,
+    n_heads=128,
+    n_layers=32,
+    hidden_dim=4096,                     # one expert's width
+    n_kv_groups=8,
+    attn_head_dim=128,
+    norm="layernorm",
+    positional="rope",
+    activation="swiglu",
+    rope_base=50_000.0,
+    rope_interleaved=True,
+    full_layers_rope=False,
+    parallel_block=True,
+    tie_embeddings=True,
+    layer_kinds=("sliding", "sliding", "sliding", "full"),
+    sliding_window=4096,
+    n_routed_experts=128,
+    n_experts_per_tok=8,
+    n_shared_experts=4,
+    eos_id=255_001,
+    eos_text="<|END_OF_TURN_TOKEN|>",
+    dtype="bf16",
+)
+
+
 # Supported model types and their sizes (reference: utils.py:44-50)
 MODEL_PARAMS_MAPPING = {
     "GPT2": ["124M", "355M", "774M", "1.5B"],
@@ -286,9 +452,11 @@ MODEL_PARAMS_MAPPING = {
     "llama3_1": ["8B"],
     "llama3_2": ["1B"],
     "longctx": ["32k"],
+    "command_a_plus": ["218B"],
 }
 
 _LLAMA_REGISTRY = {
+    ("command_a_plus", "218B"): COMMAND_A_PLUS_CONFIG,
     ("llama2", "7B"): LLAMA2_CONFIG_7B,
     ("llama3", "8B"): LLAMA3_CONFIG_8B,
     ("llama3_1", "8B"): LLAMA31_CONFIG_8B,
@@ -325,8 +493,11 @@ def get_config_llama(num_params: str, model_name: str,
     cfg = _LLAMA_REGISTRY[key]
     if target_context_length and cfg.context_length != target_context_length:
         cfg = cfg.replace(
-            rope_base=rescale_theta(cfg.rope_base, cfg.context_length,
-                                    target_context_length),
+            # a model with window layers keeps its theta: its rotated
+            # layers never see past the window, whatever the context
+            rope_base=(cfg.rope_base if cfg.has_window_layers
+                       else rescale_theta(cfg.rope_base, cfg.context_length,
+                                          target_context_length)),
             context_length=target_context_length,
         )
     return cfg
@@ -358,14 +529,18 @@ def get_config(model: str, num_params: str, *,
     if debug:
         # Tiny-model override (reference build_components.py:72-80: ctx 10,
         # emb 32, 2 layers, 2 heads). We keep head_dim even for RoPE.
-        cfg = cfg.replace(
-            context_length=16,
-            emb_dim=32,
-            n_layers=2,
-            n_heads=2,
-            n_kv_groups=min(cfg.n_kv_groups, 2),
-            hidden_dim=64,
-        )
+        tiny = dict(context_length=16, emb_dim=32, n_layers=2, n_heads=2,
+                    n_kv_groups=min(cfg.n_kv_groups, 2), hidden_dim=64)
+        if cfg.layer_kinds or cfg.is_moe:
+            # the same block at a size the CPU holds: two whole periods,
+            # rings that wrap inside the 64 positions, 8 experts top-2
+            tiny.update(
+                context_length=64, n_heads=4, attn_head_dim=16,
+                n_layers=2 * max(1, len(cfg.layer_kinds)), sliding_window=8,
+                n_routed_experts=8, n_experts_per_tok=2,
+                n_shared_experts=2, experts_held=(), vocab_size=512,
+                eos_id=511)
+        cfg = cfg.replace(**tiny)
     return cfg
 
 

@@ -39,8 +39,14 @@ Parameter tree layout (linear weights stored (in, out), applied as x @ w):
                   [, "b_up": (L, F), "b_down": (L, D)]},
     },
     "final_norm": {"scale": (D,)[, "bias": (D,)]},
-    "head":      {"weight": (D, V)},
+    "head":      {"weight": (D, V)},         # absent when tie_embeddings
   }
+
+A ``parallel_block`` config has no ``norm2``; a sparse one (``cfg.is_moe``)
+has ``blocks["moe"]`` (models/moe.py) in place of ``blocks["mlp"]``. Layers
+of unlike KINDS (``cfg.layer_kinds``: 'sliding' | 'full') share one leaf
+shape, so they stack like any others; the kind decides the layer's mask,
+its positions and, in the slot cache, whether its buffer is a ring.
 """
 
 from __future__ import annotations
@@ -53,9 +59,11 @@ from jax.ad_checkpoint import checkpoint_name
 
 from building_llm_from_scratch_tpu.configs import ModelConfig
 from building_llm_from_scratch_tpu.models.lora import apply_lora, lora_delta
+from building_llm_from_scratch_tpu.models.moe import init_moe_params, moe_ffn
 from building_llm_from_scratch_tpu.ops.attention import (
     causal_attention,
     decode_attention,
+    ring_positions,
 )
 from building_llm_from_scratch_tpu.ops.activations import gelu, silu
 from building_llm_from_scratch_tpu.ops.norms import layernorm, rmsnorm
@@ -140,6 +148,17 @@ def _head_logits(x: jnp.ndarray, w: jnp.ndarray,
     return logits + lora_delta(x, node, scaling).astype(jnp.float32)
 
 
+def _logits(params: Params, x: jnp.ndarray, node: Optional[Params] = None,
+            scaling=None) -> jnp.ndarray:
+    """The output head: its own leaf, or the embedding table read the other
+    way (``tie_embeddings``: no ``head`` leaf, and no adapter on it)."""
+    if "head" in params:
+        return _head_logits(x, params["head"]["weight"], node, scaling)
+    with jax.named_scope("head"):
+        return jnp.einsum("btd,vd->btv", x, params["tok_emb"]["weight"],
+                          preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # Initialization
 # ---------------------------------------------------------------------------
@@ -147,7 +166,9 @@ def _head_logits(x: jnp.ndarray, w: jnp.ndarray,
 def _linear_init(key, in_dim: int, out_dim: int, dtype, n_layers=None):
     """Truncated-normal fan-in init (GPT-2-style 0.02-capped)."""
     std = min(0.02, in_dim ** -0.5)
-    shape = (in_dim, out_dim) if n_layers is None else (n_layers, in_dim, out_dim)
+    lead = (() if n_layers is None else
+            n_layers if isinstance(n_layers, tuple) else (n_layers,))
+    shape = lead + (in_dim, out_dim)
     return (jax.random.truncated_normal(key, -3.0, 3.0, shape, jnp.float32)
             * std).astype(dtype)
 
@@ -174,28 +195,35 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if cfg.attn_out_bias:
         attn["bo"] = zeros(L, D)
 
-    mlp: Params = {
-        "up": _linear_init(keys[4], D, F, dt, L),
-        "down": _linear_init(keys[5], F, D, dt, L),
-    }
-    if cfg.activation == "swiglu":
-        mlp["gate"] = _linear_init(keys[6], D, F, dt, L)
-    if cfg.mlp_bias:
-        mlp.update(b_up=zeros(L, F), b_down=zeros(L, D))
-
     def norm(n_layers=None):
         n: Params = {"scale": ones(n_layers, D) if n_layers else ones(D)}
         if cfg.norm_bias:
             n["bias"] = zeros(n_layers, D) if n_layers else zeros(D)
         return n
 
+    blocks: Params = {"norm1": norm(L), "attn": attn}
+    if not cfg.parallel_block:
+        blocks["norm2"] = norm(L)
+    if cfg.is_moe:
+        blocks["moe"] = init_moe_params(cfg, keys[10], _linear_init)
+    else:
+        mlp: Params = {
+            "up": _linear_init(keys[4], D, F, dt, L),
+            "down": _linear_init(keys[5], F, D, dt, L),
+        }
+        if cfg.activation == "swiglu":
+            mlp["gate"] = _linear_init(keys[6], D, F, dt, L)
+        if cfg.mlp_bias:
+            mlp.update(b_up=zeros(L, F), b_down=zeros(L, D))
+        blocks["mlp"] = mlp
     params: Params = {
         "tok_emb": {"weight": (jax.random.normal(keys[7], (V, D), jnp.float32)
                                * 0.02).astype(dt)},
-        "blocks": {"norm1": norm(L), "attn": attn, "norm2": norm(L), "mlp": mlp},
+        "blocks": blocks,
         "final_norm": norm(),
-        "head": {"weight": _linear_init(keys[8], D, V, dt)},
     }
+    if not cfg.tie_embeddings:
+        params["head"] = {"weight": _linear_init(keys[8], D, V, dt)}
     if cfg.positional == "learned":
         params["pos_emb"] = {"weight": (jax.random.normal(keys[9], (T, D),
                                                           jnp.float32)
@@ -344,8 +372,8 @@ def _qkv_proj(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     v = v.reshape(B, Tq, -1, hd)
     if rope is not None:
         cos, sin = rope
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        q = apply_rope(q, cos, sin, positions, cfg.rope_interleaved)
+        k = apply_rope(k, cos, sin, positions, cfg.rope_interleaved)
     # names for the selective-save remat policy (forward_hidden): post-RoPE
     # q/k/v are saved so the backward neither re-projects nor re-rotates
     q = checkpoint_name(q, "q")
@@ -377,8 +405,13 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                cache_kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]],
                cache_len: Optional[jnp.ndarray],
                rng: Optional[jax.Array], deterministic: bool,
-               sp_mesh=None, sp_inside=None, tp_axis=None, adp=None):
+               sp_mesh=None, sp_inside=None, tp_axis=None, adp=None,
+               window: Optional[int] = None):
     """Per-block attention; returns (out, new_cache_kv)."""
+    if window is not None and (sp_mesh is not None or sp_inside is not None):
+        raise ValueError("the ring schedule of sequence parallelism has no "
+                         "window term: a model with 'sliding' layers "
+                         "trains without --sp")
     B, Tq, D = x.shape
     hd = cfg.head_dim
 
@@ -431,7 +464,7 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
             dropout_rate=cfg.drop_rate if dropout_on else 0.0,
             dropout_rng=rng if dropout_on else None)
     else:
-        with jax.named_scope("attention"):
+        with _attention_scope(window is not None):
             out = causal_attention(
                 q, k, v,
                 q_positions=q_positions,
@@ -440,16 +473,67 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                 dropout_rng=rng,
                 deterministic=deterministic,
                 impl=cfg.attn_impl,
+                window=window,
             )
     out = checkpoint_name(out, "attn_out")
     out = _attn_out_proj(p, out, B, Tq, tp_axis=tp_axis, adp=adp)
     return out, new_cache
 
 
+def _layer_rope(cfg: ModelConfig, rope, kind: str):
+    """The tables a layer of this kind rotates by, or None."""
+    return rope if kind == "sliding" or cfg.full_layers_rope else None
+
+
+def _layer_window(cfg: ModelConfig, kind: str) -> Optional[int]:
+    return cfg.sliding_window if kind == "sliding" else None
+
+
+def _attention_scope(windowed: bool):
+    """The span a layer's attention core runs under, by its kind."""
+    return jax.named_scope("window_attention" if windowed else "attention")
+
+
+def _ffn(cfg: ModelConfig, p: Params, h: jnp.ndarray, *, tp_axis=None,
+         adp: Optional[Params] = None, live: Optional[jnp.ndarray] = None,
+         expert_rows: Optional[list] = None) -> jnp.ndarray:
+    """A block's feed-forward on its normed input: the dense MLP, or the
+    expert layer (models/moe.py). ``live`` (B, T) bool marks the real rows
+    for the experts; ``expert_rows``, where given, collects each sparse
+    layer's rows per held expert."""
+    if not cfg.is_moe:
+        return _mlp(cfg, p["mlp"], h, tp_axis=tp_axis,
+                    adp=adp["mlp"] if adp is not None else None)
+    if tp_axis is not None or adp is not None:
+        raise ValueError("the expert layer has no tensor-parallel split and "
+                         "takes no LoRA adapter")
+    out, rows = moe_ffn(cfg, p["moe"], h, live)
+    if expert_rows is not None:
+        expert_rows.append(rows)
+    return out
+
+
+def _add_branches(cfg: ModelConfig, p: Params, x: jnp.ndarray,
+                  h: jnp.ndarray, attn_out: jnp.ndarray, **ffn_kw
+                  ) -> jnp.ndarray:
+    """The residual updates of the slot loops' blocks, from a block's input
+    ``x``, its first norm ``h`` and its projected attention output. Serial:
+    the feed-forward reads a second norm of ``x + attention``. Parallel
+    (``cfg.parallel_block``): it reads ``h`` too, and both add to ``x``."""
+    if cfg.parallel_block:
+        return x + attn_out + _ffn(cfg, p, h, **ffn_kw)
+    x = x + attn_out
+    return x + _ffn(cfg, p, _norm(cfg, p["norm2"], x), **ffn_kw)
+
+
 def _block(cfg: ModelConfig, p: Params, x: jnp.ndarray,
            rope, positions, cache_kv, cache_len, rng, deterministic,
-           sp_mesh=None, sp_inside=None, tp_axis=None, adp=None):
-    """Pre-norm transformer block (reference GPT2.py:68-88, Llama3.py:159-181).
+           sp_mesh=None, sp_inside=None, tp_axis=None, adp=None,
+           kind: str = "full"):
+    """Pre-norm transformer block (reference GPT2.py:68-88, Llama3.py:159-181)
+    of one ``kind`` ('sliding': windowed attention; ``_layer_rope`` says
+    which kinds rotate). With ``cfg.parallel_block`` attention and
+    feed-forward both read the one norm and both add to the residual.
 
     ``tp_axis``: Megatron tensor parallelism INSIDE a shard_map — the
     caller feeds head-/feature-sharded wq/wk/wv/up(/gate) and input-sharded
@@ -469,15 +553,21 @@ def _block(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                                         jax.lax.axis_index(tp_axis))
     else:
         r_attn = r_res1 = r_res2 = None
-    h, new_cache = _attention(cfg, p["attn"], _norm(cfg, p["norm1"], x),
-                              rope, positions, cache_kv, cache_len,
+    n1 = _norm(cfg, p["norm1"], x)
+    h, new_cache = _attention(cfg, p["attn"], n1,
+                              _layer_rope(cfg, rope, kind), positions,
+                              cache_kv, cache_len,
                               r_attn, deterministic, sp_mesh=sp_mesh,
                               sp_inside=sp_inside, tp_axis=tp_axis,
-                              adp=adp["attn"] if adp is not None else None)
+                              adp=adp["attn"] if adp is not None else None,
+                              window=_layer_window(cfg, kind))
+    if cfg.parallel_block:
+        h = h + _ffn(cfg, p, n1, tp_axis=tp_axis, adp=adp)
+        return (_residual_dropout(x, h, cfg.drop_rate, r_res1, deterministic),
+                new_cache)
     x = _residual_dropout(x, h, cfg.drop_rate, r_res1, deterministic)
     x = checkpoint_name(x, "resid_mid")
-    h = _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x), tp_axis=tp_axis,
-             adp=adp["mlp"] if adp is not None else None)
+    h = _ffn(cfg, p, _norm(cfg, p["norm2"], x), tp_axis=tp_axis, adp=adp)
     x = _residual_dropout(x, h, cfg.drop_rate, r_res2, deterministic)
     return x, new_cache
 
@@ -615,7 +705,7 @@ def forward_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
     else:
         row_blocks = row_s = None
 
-    def body(carry, layer):
+    def one_layer(carry, layer, kind):
         if lora is not None:
             p, lrng, lb = layer
             adp = _block_adp(lb, lora_scaling)
@@ -629,8 +719,22 @@ def forward_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
         r = None if deterministic else lrng
         y, _ = _block(cfg, p, carry, rope, positions, None, None, r,
                       deterministic, sp_mesh=sp_mesh, sp_inside=sp_inside,
-                      adp=adp)
-        return y, None
+                      adp=adp, kind=kind)
+        return y
+
+    # the scan runs over PERIODS of layers: one layer where all are of one
+    # kind, else ``cfg.layer_kinds`` unlike layers in a row, their stacked
+    # leaves re-led (L, ...) -> (L / P, P, ...)
+    kinds = cfg.layer_kinds or ("full",)
+    P = len(kinds)
+
+    def body(carry, period):
+        if P == 1:
+            return one_layer(carry, period, kinds[0]), None
+        for j, kind in enumerate(kinds):
+            carry = one_layer(
+                carry, jax.tree_util.tree_map(lambda a: a[j], period), kind)
+        return carry, None
 
     if cfg.use_actv_ckpt:
         body = jax.checkpoint(body, prevent_cse=False)
@@ -663,7 +767,11 @@ def forward_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
         xs = (params["blocks"], layer_rngs, row_blocks)
     else:
         xs = (params["blocks"], layer_rngs)
-    x, _ = jax.lax.scan(body, x, xs, unroll=_train_scan_unroll(cfg))
+    if P > 1:
+        xs = jax.tree_util.tree_map(
+            lambda a: a.reshape((L // P, P) + a.shape[1:]), xs)
+    x, _ = jax.lax.scan(body, x, xs,
+                        unroll=max(1, _train_scan_unroll(cfg) // P))
     return _norm(cfg, params["final_norm"], x)
 
 
@@ -709,9 +817,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
             adapter["ids"])
         return _head_logits(x, params["head"]["weight"],
                             head_rows["head"]["weight"], head_s)
-    return _head_logits(x, params["head"]["weight"],
-                        lora["head"]["weight"] if lora is not None else None,
-                        lora_scaling)
+    return _logits(params, x,
+                   lora["head"]["weight"] if lora is not None else None,
+                   lora_scaling)
 
 
 # ---------------------------------------------------------------------------
@@ -756,10 +864,21 @@ def unstack_blocks(params: Params, cfg: ModelConfig) -> list:
     while-loop: slicing stacked weights inside the loop made XLA re-layout
     wq/wk/wv copies every decoded token (r5 profile: 123us/step of
     loop-invariant weight transposes)."""
-    return [
-        jax.tree_util.tree_map(lambda a, l=l: a[l], params["blocks"])
-        for l in range(cfg.n_layers)
-    ]
+    blocks = params["blocks"]
+    if not cfg.is_moe:
+        return [jax.tree_util.tree_map(lambda a, l=l: a[l], blocks)
+                for l in range(cfg.n_layers)]
+    # the routed experts stay stacked, the layer's index beside them: each
+    # is sliced inside the conditional that runs it (models/moe.py)
+    experts = blocks["moe"]["experts"]
+    rest = dict(blocks, moe={k: v for k, v in blocks["moe"].items()
+                             if k != "experts"})
+    out = []
+    for l in range(cfg.n_layers):
+        layer = jax.tree_util.tree_map(lambda a, l=l: a[l], rest)
+        layer["moe"]["experts"] = dict(experts, layer=l)
+        out.append(layer)
+    return out
 
 
 def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -798,14 +917,17 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if lora is not None and lora_blocks_list is None:
         lora_blocks_list = unstack_lora_blocks(lora, cfg)
 
-    use_fused_step = _use_fused_decode(cfg, cache, Tq)
+    use_fused_step = (not cfg.has_window_layers
+                      and _use_fused_decode(cfg, cache, Tq))
 
     new_k, new_v = [], []
     for l, (p, K, V) in enumerate(zip(blocks_list, cache["k"], cache["v"])):
         adp = (_block_adp(lora_blocks_list[l], lora_scaling)
                if lora_blocks_list is not None else None)
+        kind = cfg.layer_kind(l)
         h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
+        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
+                            positions,
                             adp=adp["attn"] if adp is not None else None)
         if use_fused_step:
             # fused in-place append + attention (ops/decode_step.py): the
@@ -825,18 +947,22 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             V = jax.lax.dynamic_update_slice(
                 V, v.transpose(0, 2, 1, 3).astype(V.dtype),
                 (0, 0, length, 0))
+            # (this cache's buffers are all as long as the sequence, so a
+            # 'sliding' layer needs its window in the mask and no ring)
             out = decode_attention(q, K, V, q_positions=positions,
-                                   kv_length=length + Tq)
+                                   kv_length=length + Tq,
+                                   window=_layer_window(cfg, kind))
         new_k.append(K)
         new_v.append(V)
-        x = x + _attn_out_proj(p["attn"], out, B, Tq,
-                               adp=adp["attn"] if adp is not None else None)
-        x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),
-                     adp=adp["mlp"] if adp is not None else None)
+        x = _add_branches(
+            cfg, p, x, h,
+            _attn_out_proj(p["attn"], out, B, Tq,
+                           adp=adp["attn"] if adp is not None else None),
+            adp=adp)
     x = _norm(cfg, params["final_norm"], x)
-    logits = _head_logits(x, params["head"]["weight"],
-                          lora["head"]["weight"] if lora is not None
-                          else None, lora_scaling)
+    logits = _logits(params, x,
+                     lora["head"]["weight"] if lora is not None else None,
+                     lora_scaling)
     new_cache = {"k": new_k, "v": new_v, "length": length + Tq}
     return logits, new_cache
 
@@ -996,6 +1122,38 @@ def _layer_scales(cache: Params, l: int, slot: Optional[jnp.ndarray] = None
     return {"k_scale": ks, "v_scale": vs}
 
 
+# A 'sliding' layer's slot buffer is a RING: position p lives at index
+# p mod R, R the buffer's length (``KVCachePolicy.ring_length``: window +
+# chunk under chunked prefill, so a chunk written BEFORE it attends has
+# overwritten nothing its first query still sees; else the slot's full
+# length, the ring that never wraps). Masks are by absolute position
+# (``ring_positions``), so an index a shorter request has not reached yet
+# reads as never written, whatever a longer request left there.
+
+def _ring_chunk(cfg: ModelConfig, kind: str, R: int, chunk_start, C: int):
+    """Where a C-token chunk starting at ``chunk_start`` lands in a layer's
+    buffer of R positions, and ``decode_attention``'s ring arguments."""
+    if kind != "sliding":
+        return chunk_start, {}
+    if R % C:
+        # chunks start at multiples of C, so with C | R none wraps the end
+        raise ValueError(f"a ring of {R} positions is not whole chunks of "
+                         f"{C}: sliding_window must be a multiple of the "
+                         "prefill chunk")
+    last = chunk_start + C - 1
+    return chunk_start % R, {
+        "kv_positions": ring_positions(jnp.reshape(last, (1,)), R),
+        "window": cfg.sliding_window}
+
+
+def _ring_step(cfg: ModelConfig, kind: str, R: int, lengths: jnp.ndarray):
+    """The same for a decode tick: each row appends position ``lengths``."""
+    if kind != "sliding":
+        return lengths, {}
+    return lengths % R, {"kv_positions": ring_positions(lengths, R),
+                         "window": cfg.sliding_window}
+
+
 def prefill_into_slot(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                       prompt_len: jnp.ndarray, slot: jnp.ndarray,
                       cache: Params, blocks_list: Optional[list] = None,
@@ -1030,12 +1188,20 @@ def prefill_into_slot(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     new = _new_cache_acc(cache)
     for l, p in enumerate(blocks_list):
         adp = adp_layers[l] if adp_layers is not None else None
+        kind = cfg.layer_kind(l)
+        window = _layer_window(cfg, kind)
+        if Tpb > cache["k"][l].shape[2]:
+            raise ValueError(
+                f"a {Tpb}-token prompt bucket does not fit layer {l}'s "
+                f"ring of {cache['k'][l].shape[2]} positions: rings are "
+                "filled by chunked prefill (KVCachePolicy.prefill_chunk)")
         h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
+        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
+                            positions,
                             adp=adp["attn"] if adp is not None else None)
-        with jax.named_scope("attention"):
+        with _attention_scope(window is not None):
             out = causal_attention(q, k, v, q_positions=positions,
-                                   kv_length=prompt_len)
+                                   kv_length=prompt_len, window=window)
         # (1, Tpb, Hkv, hd) -> cache-native (1, Hkv, Tpb, hd) pane at
         # (slot, 0, 0, 0); Tpb <= Tmax by the engine's admission check
         k = jnp.where(valid, k, jnp.zeros((), k.dtype))
@@ -1044,14 +1210,15 @@ def prefill_into_slot(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                     new)
         _slot_write(cache, "v", v.transpose(0, 2, 1, 3), (slot, 0, 0, 0),
                     new)
-        x = x + _attn_out_proj(p["attn"], out, 1, Tpb,
-                               adp=adp["attn"] if adp is not None else None)
-        x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),
-                     adp=adp["mlp"] if adp is not None else None)
+        x = _add_branches(
+            cfg, p, x, h,
+            _attn_out_proj(p["attn"], out, 1, Tpb,
+                           adp=adp["attn"] if adp is not None else None),
+            adp=adp, live=valid[:, :, 0, 0])
     x = _norm(cfg, params["final_norm"], x)
     last = jax.lax.dynamic_slice(x, (0, prompt_len - 1, 0),
                                  (1, 1, x.shape[-1]))
-    logits = _head_logits(last, params["head"]["weight"], head_node, head_s)
+    logits = _logits(params, last, head_node, head_s)
     return logits[0, 0], new
 
 
@@ -1095,32 +1262,38 @@ def prefill_chunk_into_slot(params: Params, cfg: ModelConfig,
     new = _new_cache_acc(cache)
     for l, p in enumerate(blocks_list):
         adp = adp_layers[l] if adp_layers is not None else None
+        kind = cfg.layer_kind(l)
         h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
+        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
+                            positions,
                             adp=adp["attn"] if adp is not None else None)
         k = jnp.where(valid, k, jnp.zeros((), k.dtype))
         v = jnp.where(valid, v, jnp.zeros((), v.dtype))
+        write_at, ring_kw = _ring_chunk(cfg, kind, cache["k"][l].shape[2],
+                                        chunk_start, C)
         _slot_write(cache, "k", k.transpose(0, 2, 1, 3),
-                    (slot, 0, chunk_start, 0), new)
+                    (slot, 0, write_at, 0), new)
         _slot_write(cache, "v", v.transpose(0, 2, 1, 3),
-                    (slot, 0, chunk_start, 0), new)
+                    (slot, 0, write_at, 0), new)
         # attend over THIS slot's full row, freshly including the chunk:
         # earlier chunks / the copied prefix pane are the context
         K_row = jax.lax.dynamic_slice(
             new["k"][l], (slot, 0, 0, 0), (1,) + new["k"][l].shape[1:])
         V_row = jax.lax.dynamic_slice(
             new["v"][l], (slot, 0, 0, 0), (1,) + new["v"][l].shape[1:])
-        out = decode_attention(q, K_row, V_row, q_positions=q_pos,
-                               kv_length=kv_len,
-                               **_layer_scales(new, l, slot))
-        x = x + _attn_out_proj(p["attn"], out, 1, C,
-                               adp=adp["attn"] if adp is not None else None)
-        x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),
-                     adp=adp["mlp"] if adp is not None else None)
+        with _attention_scope(bool(ring_kw)):
+            out = decode_attention(q, K_row, V_row, q_positions=q_pos,
+                                   kv_length=kv_len, **ring_kw,
+                                   **_layer_scales(new, l, slot))
+        x = _add_branches(
+            cfg, p, x, h,
+            _attn_out_proj(p["attn"], out, 1, C,
+                           adp=adp["attn"] if adp is not None else None),
+            adp=adp, live=valid[:, :, 0, 0])
     x = _norm(cfg, params["final_norm"], x)
     idx = jnp.clip(prompt_len - 1 - chunk_start, 0, C - 1)
     last = jax.lax.dynamic_slice(x, (0, idx, 0), (1, 1, x.shape[-1]))
-    logits = _head_logits(last, params["head"]["weight"], head_node, head_s)
+    logits = _logits(params, last, head_node, head_s)
     return logits[0, 0], new
 
 
@@ -1165,7 +1338,9 @@ def _bgmv_block_adp(pool_blocks_l, ids, scaling) -> Params:
 def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  lengths: jnp.ndarray, cache: Params,
                  blocks_list: Optional[list] = None,
-                 adapter: Optional[Params] = None
+                 adapter: Optional[Params] = None,
+                 live: Optional[jnp.ndarray] = None,
+                 expert_rows: Optional[list] = None
                  ) -> Tuple[jnp.ndarray, Params]:
     """One decode tick for the whole slot batch: ``tokens`` (S, 1) are each
     slot's last accepted token, ``lengths`` (S,) its valid cache prefix.
@@ -1183,6 +1358,11 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     projections. Adapter identity is a data dimension: any mix of ids
     (−1 = base model) runs through this same one compiled program, so
     hot-loading/evicting adapters never recompiles.
+
+    ``live`` (S,) bool, for a sparse model: the rows that decode a token.
+    The others (free slots, slots in mid-prefill) are routed to no expert,
+    so they read no expert's weights and count for none. ``expert_rows``:
+    a list that collects each sparse layer's rows per held expert (H,).
     """
     rope = _rope_tables(cfg)
     S = tokens.shape[0]
@@ -1192,7 +1372,8 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if blocks_list is None:
         blocks_list = unstack_blocks(params, cfg)
 
-    use_fused_step = _use_fused_decode(cfg, cache, 1)
+    use_fused_step = (not cfg.has_window_layers
+                      and _use_fused_decode(cfg, cache, 1))
 
     if _use_bgmv(adapter, cfg):
         ids = adapter["ids"].astype(jnp.int32)
@@ -1214,8 +1395,10 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     new = _new_cache_acc(cache)
     for l, (p, K, V) in enumerate(zip(blocks_list, cache["k"], cache["v"])):
         adp = adp_layers[l] if adp_layers is not None else None
+        kind = cfg.layer_kind(l)
         h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
+        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
+                            positions,
                             adp=adp["attn"] if adp is not None else None)
         if use_fused_step:
             from building_llm_from_scratch_tpu.ops.decode_step import (
@@ -1227,17 +1410,20 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             new["k"].append(K)
             new["v"].append(V)
         else:
-            K, V = _slot_append_kv(cache, new, l, K, V, k, v, lengths)
-            with jax.named_scope("attention"):
+            write_at, ring_kw = _ring_step(cfg, kind, K.shape[2], lengths)
+            K, V = _slot_append_kv(cache, new, l, K, V, k, v, write_at)
+            with _attention_scope(bool(ring_kw)):
                 out = decode_attention(q, K, V, q_positions=positions,
-                                       kv_length=lengths + 1,
+                                       kv_length=lengths + 1, **ring_kw,
                                        **_layer_scales(new, l))
-        x = x + _attn_out_proj(p["attn"], out, S, 1,
-                               adp=adp["attn"] if adp is not None else None)
-        x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),
-                     adp=adp["mlp"] if adp is not None else None)
+        x = _add_branches(
+            cfg, p, x, h,
+            _attn_out_proj(p["attn"], out, S, 1,
+                           adp=adp["attn"] if adp is not None else None),
+            adp=adp, live=None if live is None else live[:, None],
+            expert_rows=expert_rows)
     x = _norm(cfg, params["final_norm"], x)
-    logits = _head_logits(x, params["head"]["weight"], head_node, head_s)
+    logits = _logits(params, x, head_node, head_s)
     return logits[:, 0], new
 
 

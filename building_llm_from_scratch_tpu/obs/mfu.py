@@ -55,8 +55,9 @@ TPU_PEAK_FLOPS = tuple((k, spec[0]) for k, spec in DEVICE_SPECS)
 def flops_per_token(cfg: ModelConfig, seq_len: Optional[int] = None) -> int:
     """Analytic train-step FLOPs per token (fwd+bwd) for this config."""
     t = cfg.context_length if seq_len is None else seq_len
-    n_matmul = cfg.num_params(exclude_embeddings=True)
-    attention = 12 * cfg.n_layers * cfg.emb_dim * t
+    # a sparse model multiplies a token against its active parameters only
+    n_matmul = cfg.num_params(exclude_embeddings=True, active=cfg.is_moe)
+    attention = 12 * cfg.n_layers * cfg.n_heads * cfg.head_dim * t
     return 6 * n_matmul + attention
 
 

@@ -114,10 +114,17 @@ TICK_IDLE_WAIT = "tick.idle_wait"
 #: row's ``t_submit`` lies on the same axis; ``wall_submit`` on the span
 #: row is the one anchor to unix time. ``phases`` holds the self seconds
 #: of each phase that ran; ``tick`` is ``n_ticks`` after the tick; ``rows``
-#: of the program's ``n_slots`` rows decoded a token.
+#: of the program's ``n_slots`` rows decoded a token, after ``chunks`` prefill
+#: chunks of slots in mid-prefill (chunked prefill only, absent at 0), reading
+#: ``kv_positions`` cache positions (live positions of those rows, summed
+#: over the layers, a window layer counting at most its window). A sparse model's
+#: decode tick adds ``expert_rows`` (rows each held expert computed, summed
+#: over layers) and ``experts_touched`` (held experts, counted a layer,
+#: that got a row: each read its weights once).
 TICK_RECORD_FIELDS = ("tick", "t0", "t1", "t_dispatch", "t_fetch", "phases",
                       "rows", "n_slots", "admitted", "queue_depth",
-                      "replica")
+                      "replica", "chunks", "kv_positions", "expert_rows",
+                      "experts_touched")
 
 #: Trainer StepTimeline segments (``<segment>_s`` fields of training
 #: cadence metrics rows; obs/timeline.py owns the measurement).

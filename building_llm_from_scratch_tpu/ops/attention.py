@@ -75,16 +75,17 @@ def _on_tpu() -> bool:
 
 def _resolve_impl(impl: str, Tq: int, Tkv: int, head_dim: int,
                   q_positions, kv_length, dropout_active: bool,
-                  block_q: int) -> str:
+                  block_q: int, window: Optional[int] = None) -> str:
     """Pick the concrete implementation for ``impl='auto'`` and validate
     eligibility of explicit choices (falling back where semantics require)."""
     if impl not in AVAILABLE_IMPLS:
         raise NotImplementedError(
             f"attention impl '{impl}' is not available yet; "
             f"options: {AVAILABLE_IMPLS}")
-    if kv_length is not None:
+    if kv_length is not None or window is not None:
         # cached decode: Tq is 1 (or a short prefill) — the score tensor is
-        # already small and the fused kernels don't model cache validity
+        # already small and the fused kernels don't model cache validity,
+        # nor a window
         return "xla"
     if q_positions is not None:
         # flash/pallas assume q starts at kv position 0; silently computing
@@ -114,7 +115,7 @@ def _resolve_impl(impl: str, Tq: int, Tkv: int, head_dim: int,
 # ---------------------------------------------------------------------------
 
 def _xla_attention(q, k, v, *, q_positions, kv_length, dropout_rate,
-                   dropout_rng, deterministic):
+                   dropout_rng, deterministic, window=None):
     B, Tq, Hq, D = q.shape
     _, Tkv, Hkv, _ = k.shape
     G = Hq // Hkv
@@ -131,6 +132,11 @@ def _xla_attention(q, k, v, *, q_positions, kv_length, dropout_rate,
     else:
         mask = q_pos[:, :, None] >= kv_pos[None, None, :]   # (B, Tq, Tkv)
         mask = mask[:, None, None, :, :]                    # (B,1,1,Tq,Tkv)
+    if window is not None:
+        # position i attends to j with i - window < j <= i
+        near = (q_pos[..., :, None] - kv_pos) < window
+        mask = mask & (near[None, None, None] if q_pos.ndim == 1
+                       else near[:, None, None])
     if kv_length is not None:
         valid = kv_pos[None, :] < jnp.reshape(kv_length, (-1, 1))  # (B|1, Tkv)
         mask = mask & valid[:, None, None, None, :]
@@ -247,6 +253,11 @@ def _pallas_flash_attention(q, k, v, block: int = 512):
 # decode path: cache-layout-native attention
 # ---------------------------------------------------------------------------
 
+#: above this many bytes of float32 scores, ``decode_attention`` runs one
+#: key-value head (with its group of query heads) at a time
+_SCORE_BYTES_AT_ONCE = 2 ** 30
+
+
 def decode_attention(
     q: jnp.ndarray,               # (B, Tq, Hq, D) — model layout (tiny Tq)
     k_cache: jnp.ndarray,         # (B, Hkv, Tmax, D) — cache-native layout
@@ -256,6 +267,8 @@ def decode_attention(
     kv_length: jnp.ndarray,       # scalar or (B,): valid cache prefix
     k_scale: Optional[jnp.ndarray] = None,   # (B, Hkv, Tmax, 1) int8 cache
     v_scale: Optional[jnp.ndarray] = None,   # (B, Hkv, Tmax, 1) scales
+    kv_positions: Optional[jnp.ndarray] = None,   # (Tmax,) or (B, Tmax)
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Attention for KV-cache decode, consuming the cache in its OWN
     (B, H, T, D) layout.
@@ -272,12 +285,22 @@ def decode_attention(
     engine's slot batch, where every row is a different request at a
     different sequence length (serving/engine.py).
 
+    ``kv_positions``: the absolute position each cache index holds, for a
+    buffer that is a RING (index = position mod its length; see
+    ``ring_positions``); negative = never written. Default: index i holds
+    position i. ``window``: a query at position p attends to positions in
+    (p - window, p]. Masks are by absolute position either way.
+
     ``k_scale``/``v_scale`` dequantize an int8 cache (serving/kvcache.py
     int8 policy) WITHOUT materializing a dequantized copy: the per-
     position scales are constant over head_dim, so they factor out of
     the score dot (``q . (k8*s) = (q . k8) * s``) and fold into the
     probability row before the value dot (``sum_k p_k*(v8_k*s_k) =
     sum_k (p_k*s_k)*v8_k``) — exactly equal to dequantize-then-attend.
+
+    Past ``_SCORE_BYTES_AT_ONCE`` of scores (a 512-token chunk against a
+    20k-position row of 128 query heads is 5 GB) the key-value heads run
+    one at a time (``lax.map``): the same arithmetic on a slice.
     """
     B, Tq, Hq, D = q.shape
     _, Hkv, Tkv, _ = k_cache.shape
@@ -288,32 +311,64 @@ def decode_attention(
         v_cache = v_cache.astype(jnp.float32)
     # (B, Hkv, G, Tq, D) — tiny transpose (Tq is 1 for decode steps)
     qg = q.reshape(B, Tq, Hkv, G, D).transpose(0, 2, 3, 1, 4)
-    scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    if k_scale is not None:
-        # (B, Hkv, Tkv, 1) -> (B, Hkv, 1, 1, Tkv), broadcast over (G,
-        # Tq): one multiply per score, the whole K-side dequant cost
-        scores = scores * k_scale[:, :, :, 0][:, :, None, None, :]
-    kv_pos = jnp.arange(Tkv)
+    kv_pos = jnp.arange(Tkv) if kv_positions is None else kv_positions
     if q_positions.ndim == 2:
         # per-row positions/lengths: mask (B, Tq, Tkv) -> (B, 1, 1, Tq, Tkv)
-        mask = (q_positions[:, :, None] >= kv_pos[None, None, :]) \
-            & (kv_pos[None, None, :] < jnp.reshape(kv_length, (-1, 1, 1)))
-        mask = mask[:, None, None]
+        kv_pos = kv_pos[None, None, :] if kv_pos.ndim == 1 \
+            else kv_pos[:, None, :]
+        q_pos = q_positions[:, :, None]
+        mask = (q_pos >= kv_pos) \
+            & (kv_pos < jnp.reshape(kv_length, (-1, 1, 1)))
     else:
-        mask = (q_positions[:, None] >= kv_pos[None, :]) \
-            & (kv_pos[None, :] < kv_length)
-        mask = mask[None, None, None]
-    scores = jnp.where(mask, scores,
-                       jnp.asarray(_NEG_INF, scores.dtype))
-    weights = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
-    if v_scale is not None:
-        # fold the V-side scales into the probability row (exact):
-        # sum_k p_k * (v8_k * s_k) == sum_k (p_k * s_k) * v8_k
-        weights = weights * v_scale[:, :, :, 0][:, :, None, None, :]
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", weights, v_cache)
+        q_pos = q_positions[:, None]
+        kv_pos = kv_pos[None, :]
+        mask = (q_pos >= kv_pos) & (kv_pos < kv_length)
+    if kv_positions is not None:
+        mask = mask & (kv_pos >= 0)
+    if window is not None:
+        mask = mask & (q_pos - kv_pos < window)
+    mask = mask[:, None, None] if q_positions.ndim == 2 \
+        else mask[None, None, None]
+
+    def attend(qg, k_cache, v_cache, k_scale, v_scale):
+        scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k_cache,
+                            preferred_element_type=jnp.float32) * scale
+        if k_scale is not None:
+            # (B, Hkv, Tkv, 1) -> (B, Hkv, 1, 1, Tkv), broadcast over (G,
+            # Tq): one multiply per score, the whole K-side dequant cost
+            scores = scores * k_scale[:, :, :, 0][:, :, None, None, :]
+        scores = jnp.where(mask, scores,
+                           jnp.asarray(_NEG_INF, scores.dtype))
+        weights = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
+        if v_scale is not None:
+            # fold the V-side scales into the probability row (exact):
+            # sum_k p_k * (v8_k * s_k) == sum_k (p_k * s_k) * v8_k
+            weights = weights * v_scale[:, :, :, 0][:, :, None, None, :]
+        return jnp.einsum("bhgqk,bhkd->bhgqd", weights, v_cache)
+
+    if 4 * B * Hq * Tq * Tkv <= _SCORE_BYTES_AT_ONCE or Hkv == 1:
+        out = attend(qg, k_cache, v_cache, k_scale, v_scale)
+    else:
+        heads_first = lambda a: (None if a is None
+                                 else jnp.moveaxis(a, 1, 0)[:, :, None])
+        out = jax.lax.map(
+            lambda xs: attend(*xs)[:, 0],
+            tuple(heads_first(a) for a in
+                  (qg, k_cache, v_cache, k_scale, v_scale)))
+        out = jnp.moveaxis(out, 0, 1)
     # (B, Hkv, G, Tq, D) -> (B, Tq, Hq, D)
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Tq, Hq, D)
+
+
+def ring_positions(last: jnp.ndarray, ring: int) -> jnp.ndarray:
+    """The absolute position each index of a ring of ``ring`` positions
+    holds once position ``last`` (a scalar, or (B,) per row) is written:
+    the newest position congruent to the index, negative where the ring has
+    not been written that far. A buffer as long as the sequence is the ring
+    that never wraps: index i holds i, and indices past ``last`` read
+    negative. -> (ring,) or (B, ring)."""
+    last = jnp.asarray(last)[..., None]
+    return last - (last - jnp.arange(ring)) % ring
 
 
 # ---------------------------------------------------------------------------
@@ -332,8 +387,11 @@ def causal_attention(
     deterministic: bool = True,
     impl: str = "auto",
     block_q: int = 256,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Scaled dot-product attention with causal masking and GQA.
+    """Scaled dot-product attention with causal masking and GQA
+    (``window``: each position attends to the last ``window`` positions,
+    itself included; the exact xla path serves it).
 
     For training, call with q=k=v lengths equal and no kv_length. For
     cached decode, pass the full cache as k/v, absolute ``q_positions`` and
@@ -345,7 +403,7 @@ def causal_attention(
 
     dropout_active = dropout_rate > 0.0 and not deterministic
     chosen = _resolve_impl(impl, Tq, Tkv, D, q_positions, kv_length,
-                           dropout_active, block_q)
+                           dropout_active, block_q, window)
 
     if chosen == "fused":
         from building_llm_from_scratch_tpu.ops.fused_attention import (
@@ -373,4 +431,4 @@ def causal_attention(
     return _xla_attention(q, k, v, q_positions=q_positions,
                           kv_length=kv_length, dropout_rate=dropout_rate,
                           dropout_rng=dropout_rng,
-                          deterministic=deterministic)
+                          deterministic=deterministic, window=window)

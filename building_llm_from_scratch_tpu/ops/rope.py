@@ -59,8 +59,11 @@ def precompute_rope_params(
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
-               positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Rotate-half RoPE application.
+               positions: Optional[jnp.ndarray] = None,
+               interleaved: bool = False) -> jnp.ndarray:
+    """Rotate-half RoPE application (``interleaved``: rotate the pairs
+    (2i, 2i+1) by the i-th frequency instead of (i, i + d/2): GPT-J's
+    layout, the same tables).
 
     x: (batch, seq, n_heads, head_dim) — note head axis AFTER seq (our layout;
     the reference uses (b, h, t, d)).
@@ -83,6 +86,12 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
             cos_t = cos_t[:, :, None, :]
             sin_t = sin_t[:, :, None, :]
 
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(b, t, h, d // 2, 2)
+        even, odd = pairs[..., 0], pairs[..., 1]
+        c, s = cos_t[..., : d // 2], sin_t[..., : d // 2]
+        out = jnp.stack([even * c - odd * s, odd * c + even * s], axis=-1)
+        return out.reshape(b, t, h, d).astype(x.dtype)
     x1 = x[..., : d // 2]
     x2 = x[..., d // 2:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)

@@ -54,7 +54,10 @@ from typing import List, Optional, Sequence, Union
 
 import numpy as np
 
-from building_llm_from_scratch_tpu.configs import ModelConfig
+from building_llm_from_scratch_tpu.configs import (
+    ModelConfig,
+    refuse_unsupported,
+)
 from building_llm_from_scratch_tpu.generate import (
     _bucket,
     sample_tokens_dynamic,
@@ -171,6 +174,8 @@ class DecodeEngine:
         import jax
 
         self.cfg = cfg
+        self._n_window_layers = sum(cfg.layer_kind(l) == "sliding"
+                                    for l in range(cfg.n_layers))
         #: parallel/sharding.MeshPlan (or None = the historical
         #: single-device engine, byte-for-byte). tp>1 runs the whole
         #: prefill/decode/verify program family with NamedSharding'd
@@ -194,6 +199,13 @@ class DecodeEngine:
         #: compiles (monolithic-bucketed vs ONE chunk program) and the
         #: cache pytree's dtypes; hits/misses/spans are per-call data.
         self.kv_policy = kv_policy or KVCachePolicy()
+        refuse_unsupported(
+            cfg, paged=self.kv_policy.paged,
+            prefix_cache=self.kv_policy.prefix_cache,
+            int8_cache=self.kv_policy.quantized, speculation=spec_k > 0,
+            tensor_parallel=mesh_plan is not None and mesh_plan.n_model > 1,
+            sequence_parallel=mesh_plan is not None and mesh_plan.n_seq > 1,
+            lora=adapters is not None)
         #: serving/adapters.AdapterRegistry (or None = base model only).
         #: The stacked pool + per-slot adapter ids become per-call data
         #: arguments of the compiled programs — multi-tenant traffic
@@ -356,11 +368,23 @@ class DecodeEngine:
             self.kv_append = "fused_step"
         else:
             self.kv_append = kv_append_path(self.cache, self.spec_k + 1)
-        self._blocks = unstack_blocks(self.params, cfg)
         #: the weights ride every compiled program as an ARGUMENT: closed
         #: over, jit bakes them into each program as constants (GPT2-124M
         #: bf16: 0.3 GB per program, 40 s per compile on the chip, and
-        #: enough host memory over the program family to be killed at 40 GiB)
+        #: enough host memory over the program family to be killed at 40 GiB).
+        #: A sparse model's are held ONCE: its programs take their layers'
+        #: weights as static slices of the stacked leaves inside the program
+        #: (its experts have to be sliced inside their conditionals anyway).
+        #: A dense model keeps the per-layer copy beside the stacked leaves:
+        #: sliced inside, the 1.5B decode program is 4.7 ms a tick slower on
+        #: the chip. One convention for both would be the per-layer leaves
+        #: alone, but the caller's stacked tree lives through this
+        #: constructor whatever the engine drops (the caller's frame holds
+        #: it), and at the sparse cell's size 6.25 GB of it + 6.25 GB of
+        #: copy + 6.74 GB of cache is 19.2 GB of 16 (PERF.md sections 6
+        #: and 7, PR 28; ROADMAP S3)
+        self._blocks = (None if cfg.is_moe
+                        else unstack_blocks(self.params, cfg))
         self._weights = (self.params, self._blocks)
         if self.adapters is not None and mesh_plan is not None:
             # the stacked pool rides every compiled call as data — it has
@@ -791,7 +815,9 @@ class DecodeEngine:
 
     def _decode_impl(self, cache, weights, tokens, lengths, base_keys,
                      n_gen, temps, topks, pool=None, pool_scale=None,
-                     adapter_ids=None):
+                     adapter_ids=None, live=None):
+        """``live`` (S,) bool rides a sparse model's tick only
+        (``_step_tail``): the rows that decode."""
         import jax
         import jax.numpy as jnp
 
@@ -799,9 +825,13 @@ class DecodeEngine:
         if pool is not None:
             adapter = {"pool": pool, "scaling": pool_scale,
                        "ids": adapter_ids}
+        # a sparse model's tick hands back, with the two arrays the host
+        # fetches anyway, the rows each held expert computed, a layer a row
+        expert_rows = [] if self.cfg.is_moe else None
         logits, cache = decode_slots(
             weights[0], self.cfg, tokens[:, None], lengths,
-            cache, weights[1], adapter=adapter)
+            cache, weights[1], adapter=adapter, live=live,
+            expert_rows=expert_rows)
         keys = jax.vmap(token_rng)(base_keys, n_gen)
         nxt = sample_tokens_dynamic(logits, keys, temps, topks,
                                     self.max_top_k)
@@ -809,6 +839,8 @@ class DecodeEngine:
         # poisoned row (bad KV state) goes non-finite ALONE — the host
         # retires just that slot (reason non_finite_logits)
         ok = jnp.all(jnp.isfinite(logits), axis=-1)
+        if expert_rows is not None:
+            ok = (ok, jnp.stack(expert_rows))
         return nxt, ok, self._pin_cache(cache)
 
     def _verify_impl(self, cache, weights, tokens, lengths, base_keys,
@@ -930,6 +962,19 @@ class DecodeEngine:
             return ()
         pool, scale = self.adapters.pool_args()
         return (pool, scale)
+
+    def _step_tail(self) -> tuple:  # holds: _lock
+        """The decode tick's positional tail: the adapter pool and the
+        slots' rows of it; for a sparse model (which takes no adapter)
+        the rows that decode this tick."""
+        if self.cfg.is_moe:
+            live = np.zeros((self.n_slots,), np.bool_)
+            live[[s for s, _ in self.scheduler.active()
+                  if s not in self._prefill_state]] = True
+            return (None, None, None, live)
+        if self.adapters is None:
+            return ()
+        return self._pool_args() + (self._adapter_ids,)
 
     def _pool_args_for(self, adapter_row) -> tuple:
         """Prefill's positional tail: pool + scaling + THIS request's row."""
@@ -1532,87 +1577,94 @@ class DecodeEngine:
 
     # holds: _lock
     def _chunk_tick(self, gen: int) -> bool:
-        """One prefill chunk for every mid-prefill slot — the per-tick
-        prefill work is bounded by n_prefilling x one C-token program,
-        whatever the prompt lengths. Returns False on a generation
-        abort (the caller books tick wall and bails)."""
+        """ONE prefill chunk a tick, for the slot in mid-prefill that was
+        admitted first (the others wait their turn: first come, first to
+        its first token) — the per-tick prefill work is bounded by one
+        C-token program, whatever the prompt lengths and however many
+        slots are prefilling, so a token gap is one decode tick plus at
+        most one chunk. (A chunk for EVERY such slot made a gap one tick
+        plus k chunks, k the slots prefilling: the tail of the gaps then
+        sat on the steps of k. PERF.md section 6, PR 28.) Returns False on
+        a generation abort (the caller books tick wall and bails)."""
         import jax
 
         C = self.kv_policy.prefill_chunk
-        for slot in sorted(self._prefill_state):
-            st = self._prefill_state[slot]
-            req: Request = st["req"]
-            Tp = st["Tp"]
-            span_cap = (self.prefix_store.storable_span(Tp)
-                        if self.prefix_store is not None else 0)
-            # catch-up probe: a slot co-admitted with the FIRST sharer of
-            # a prefix missed at admission (the store was empty), but the
-            # sharer's pane may have landed since (early insertion below)
-            # — jump ahead by pane copy instead of recomputing chunks.
-            # count_miss=False: only admission misses are workload misses
-            tag = (self._adapter_tag(req)
-                   if self.prefix_store is not None and st["pos"] < span_cap
-                   else None)
-            if tag is not None:
-                span, entry = self.prefix_store.match(
-                    req.prompt_ids, tag,
-                    min_span=st["pos"], count_miss=False)
-                if entry is not None:
-                    if not self._apply_prefix_hit(slot, req, gen, span,
-                                                  entry, late=True,
-                                                  prev_pos=st["pos"]):
-                        return False
-                    st["pos"] = span
-                    self._lengths[slot] = span
-            with self._tl.span(self._prefill_phase):
-                lo = st["pos"]
-                hi = min(lo + C, Tp)
-                chunk = np.zeros((1, C), np.int32)
-                chunk[0, : hi - lo] = req.prompt_ids[lo:hi]
-                if self._paged:
-                    # back the chunk's real columns with pages; the pad
-                    # tail's columns stay unmapped and scatter into trash
-                    self._ensure_pages(slot, hi)
-                tok, ok, cache = self._prefill_chunk(
-                    self.cache, self._weights, chunk, np.int32(lo),
-                    np.int32(Tp), np.int32(slot),
-                    *((self._page_table,) if self._paged else ()),
-                    st["base_key"], st["temp"], st["topk"],
-                    *self._pool_args_for(st["adapter_row"]))
-                if self._generation != gen:
-                    return False    # abandoned mid-chunk: commit nothing
-                self.cache = cache
-                st["pos"] = lo + C
-                self.prefill_chunks += 1
-            # EARLY insertion: the moment the chunk covering the storable
-            # span lands, the pane [0, span) is final — store it NOW so
-            # co-admitted sharers (still mid-prefill behind us) catch up
-            # this very tick instead of after our whole prompt
-            if (self.prefix_store is not None and not st["stored"]
-                    and 0 < span_cap <= st["pos"]):
-                st["stored"] = True
-                self._maybe_store_prefix(slot, req, gen)
-                if self._generation != gen:
+        slot = min(self._prefill_state, key=lambda s: (
+            self._prefill_state[s]["req"].t_admit, s))
+        st = self._prefill_state[slot]
+        req: Request = st["req"]
+        Tp = st["Tp"]
+        span_cap = (self.prefix_store.storable_span(Tp)
+                    if self.prefix_store is not None else 0)
+        # catch-up probe: a slot co-admitted with the FIRST sharer of
+        # a prefix missed at admission (the store was empty), but the
+        # sharer's pane may have landed since (early insertion below)
+        # — jump ahead by pane copy instead of recomputing chunks.
+        # count_miss=False: only admission misses are workload misses
+        tag = (self._adapter_tag(req)
+               if self.prefix_store is not None and st["pos"] < span_cap
+               else None)
+        if tag is not None:
+            span, entry = self.prefix_store.match(
+                req.prompt_ids, tag,
+                min_span=st["pos"], count_miss=False)
+            if entry is not None:
+                if not self._apply_prefix_hit(slot, req, gen, span,
+                                              entry, late=True,
+                                              prev_pos=st["pos"]):
                     return False
-            if st["pos"] < Tp:
-                self._lengths[slot] = st["pos"]
-                continue
-            # final chunk: the request's first token. Explicit fetch —
-            # the ONLY chunk that syncs (mirrors the legacy prefill)
-            with self._tl.span(self._prefill_phase):
-                ok_host = bool(jax.device_get(ok))
-            del self._prefill_state[slot]
-            self._lengths[slot] = Tp
-            if self.hooks.poison_nan(req):
-                self._poison_slot_cache(slot)  # fault injection (tests)
-            if not ok_host:
-                self._fail_request(slot, req,
-                                   "non-finite logits in prefill",
-                                   reason="non_finite_logits")
-                continue
-            self._accept_token(slot, req, int(jax.device_get(tok)), gen)
+                st["pos"] = span
+                self._lengths[slot] = span
+        with self._tl.span(self._prefill_phase):
+            lo = st["pos"]
+            hi = min(lo + C, Tp)
+            chunk = np.zeros((1, C), np.int32)
+            chunk[0, : hi - lo] = req.prompt_ids[lo:hi]
+            if self._paged:
+                # back the chunk's real columns with pages; the pad
+                # tail's columns stay unmapped and scatter into trash
+                self._ensure_pages(slot, hi)
+            tok, ok, cache = self._prefill_chunk(
+                self.cache, self._weights, chunk, np.int32(lo),
+                np.int32(Tp), np.int32(slot),
+                *((self._page_table,) if self._paged else ()),
+                st["base_key"], st["temp"], st["topk"],
+                *self._pool_args_for(st["adapter_row"]))
+            if self._generation != gen:
+                return False    # abandoned mid-chunk: commit nothing
+            self.cache = cache
+            st["pos"] = lo + C
+            self.prefill_chunks += 1
+            self._tick_rec["chunks"] = self._tick_rec.get("chunks", 0) + 1
+        # EARLY insertion: the moment the chunk covering the storable
+        # span lands, the pane [0, span) is final — store it NOW so
+        # co-admitted sharers (still mid-prefill behind us) catch up
+        # this very tick instead of after our whole prompt
+        if (self.prefix_store is not None and not st["stored"]
+                and 0 < span_cap <= st["pos"]):
+            st["stored"] = True
+            self._maybe_store_prefix(slot, req, gen)
             if self._generation != gen:
                 return False
+        if st["pos"] < Tp:
+            self._lengths[slot] = st["pos"]
+            return True
+        # final chunk: the request's first token. Explicit fetch —
+        # the ONLY chunk that syncs (mirrors the legacy prefill)
+        with self._tl.span(self._prefill_phase):
+            ok_host = bool(jax.device_get(ok))
+        del self._prefill_state[slot]
+        self._lengths[slot] = Tp
+        if self.hooks.poison_nan(req):
+            self._poison_slot_cache(slot)  # fault injection (tests)
+        if not ok_host:
+            self._fail_request(slot, req,
+                               "non-finite logits in prefill",
+                               reason="non_finite_logits")
+            return True
+        self._accept_token(slot, req, int(jax.device_get(tok)), gen)
+        if self._generation != gen:
+            return False
         return True
 
     # holds: _lock
@@ -1828,6 +1880,19 @@ class DecodeEngine:
 
     # -- tracing / tick accounting ----------------------------------------
 
+    def _kv_positions_read(self, decoding) -> int:  # holds: _lock
+        """Cache positions this tick's attention has to read: each decoding
+        row's live positions (the one it appends included), summed over the
+        layers, a window layer counting no more than its window."""
+        lengths = self._lengths.tolist()    # plain ints: a few us a tick
+        live = [lengths[s] + 1 for s, _ in decoding]
+        n_window = self._n_window_layers
+        total = (self.cfg.n_layers - n_window) * sum(live)
+        if n_window:
+            window = self.cfg.sliding_window
+            total += n_window * sum(min(n, window) for n in live)
+        return total
+
     def _emit_span(self, req: Request) -> None:
         """Write the request's one terminal ``span`` row (request tree:
         queued/prefill/decode children under a root ``request`` span).
@@ -1991,12 +2056,13 @@ class DecodeEngine:
                 self._ensure_pages(
                     slot, int(self._lengths[slot]) + 1)  # graft-ok: GL011 host numpy
         with self._tl.span("decode_dispatch"):
+            # inside the span: the phases of a tick add up to its wall
+            self._tick_rec["kv_positions"] = self._kv_positions_read(decoding)
             nxt, ok, cache = self._decode(
                 self.cache, self._weights, self._last_tokens, self._lengths,
                 *((self._page_table,) if self._paged else ()),
                 self._base_keys, self._n_gen, self._temps,
-                self._topks, *(self._pool_args() + (self._adapter_ids,)
-                               if self.adapters is not None else ()))
+                self._topks, *self._step_tail())
         if self._generation != gen:
             return False
         # `host_fetch` covers the donated-cache rebind AND the two
@@ -2010,6 +2076,12 @@ class DecodeEngine:
             self.cache = cache
             nxt = jax.device_get(nxt)
             ok_rows = jax.device_get(ok)
+            if self.cfg.is_moe:
+                # rode the same transfer: (layers, held experts) rows
+                ok_rows, by_layer = ok_rows
+                self._tick_rec["expert_rows"] = by_layer.sum(0).tolist()
+                self._tick_rec["experts_touched"] = int(
+                    np.count_nonzero(by_layer))
         # the fetch is a wedge point: a tick the supervisor abandoned in it
         # must not open a span on the timeline `_restart` has put in place
         if self._generation != gen:
@@ -2424,9 +2496,7 @@ class DecodeEngine:
                     self._lengths,
                     *((self._page_table,) if self._paged else ()),
                     self._base_keys, self._n_gen,
-                    self._temps, self._topks,
-                    *(self._pool_args() + (self._adapter_ids,)
-                      if self.adapters is not None else ()))
+                    self._temps, self._topks, *self._step_tail())
             self.cache = cache
             jax.device_get(nxt)               # block until compiled + ran
             if isinstance(self._prefill, CompileWatcher):
@@ -2832,6 +2902,22 @@ class DecodeEngine:
             self.supervisor.stop()
         self._ev("serve_summary", **self.stats())
 
+    def layout(self) -> dict:
+        """What the model's kinds of layer made of this engine, for
+        ``stats()`` and ``/healthz``: the positions a slot holds in a
+        window layer's ring and in a full layer (no window layers: the
+        one length), and the routed experts held here."""
+        lengths = self.kv_policy.layer_lengths(self.cfg, self._cache_len)
+        out = {"kv_positions": {"full": self._cache_len}}
+        if self.cfg.has_window_layers:
+            out["kv_positions"]["ring"] = min(lengths)
+        if self.cfg.is_moe:
+            out["experts"] = {"held": list(self.cfg.held_experts),
+                              "routed": self.cfg.n_routed_experts,
+                              "per_token": self.cfg.n_experts_per_tok,
+                              "shared": self.cfg.n_shared_experts}
+        return out
+
     def stats(self) -> dict:
         with self._lock:                       # vs a mid-tick _finish()
             out = {
@@ -2862,6 +2948,7 @@ class DecodeEngine:
                 out["adapters_loaded"] = self.adapters.n_loaded
             out["kv_policy"] = self.kv_policy.describe()
             out["kv_append"] = self.kv_append
+            out.update(self.layout())
             out["memory"] = self.memory_ledger.describe()
             if self._paged:
                 out["page_pool"] = self.page_pool.stats()
@@ -3029,6 +3116,7 @@ class DecodeEngine:
             "queue_capacity": self.queue.max_size,
             "warmed_up": self.warmed_up,
             "kv_append": self.kv_append,
+            **self.layout(),
             "draining": self.draining,
             "restarts": self.n_restarts,
             # structured snapshot (one probe answers "how is it

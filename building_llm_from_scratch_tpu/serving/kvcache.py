@@ -31,7 +31,8 @@ all three:
     fixed-size C-token chunks interleaved with decode ticks. The chunk
     shape is STATIC, so the whole prefill tier is ONE compiled program
     (vs one per prompt-length bucket) and ``tick_prefill_s`` is bounded
-    by one chunk's wall time instead of the longest prompt's.
+    by one chunk's wall time instead of the longest prompt's: a tick runs
+    one chunk, for the slot in mid-prefill that was admitted first.
   - **int8 slot KV** (``kv_quant="int8"``): symmetric per-(slot, layer,
     head, position) scale quantization on append, dequantized inside
     ``decode_attention`` (scales fold into the score/value einsums, no
@@ -153,23 +154,46 @@ class KVCachePolicy:
 
         if self.paged:
             n_pages = self.total_pool_pages(n_rows, max_length)
-            shape = (n_pages, cfg.n_kv_groups, self.page_tokens,
-                     cfg.head_dim)
-            sshape = (n_pages, cfg.n_kv_groups, self.page_tokens, 1)
+            lead = [(n_pages, cfg.n_kv_groups, self.page_tokens)
+                    ] * cfg.n_layers
         else:
-            shape = (n_rows, cfg.n_kv_groups, max_length, cfg.head_dim)
-            sshape = (n_rows, cfg.n_kv_groups, max_length, 1)
+            lead = [(n_rows, cfg.n_kv_groups, length)
+                    for length in self.layer_lengths(cfg, max_length)]
         dt = self.cache_dtype(cfg)
         cache: Params = {
-            "k": [jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
-            "v": [jnp.zeros(shape, dt) for _ in range(cfg.n_layers)],
+            "k": [jnp.zeros(s + (cfg.head_dim,), dt) for s in lead],
+            "v": [jnp.zeros(s + (cfg.head_dim,), dt) for s in lead],
         }
         if self.quantized:
-            cache["k_scale"] = [jnp.zeros(sshape, jnp.float32)
-                                for _ in range(cfg.n_layers)]
-            cache["v_scale"] = [jnp.zeros(sshape, jnp.float32)
-                                for _ in range(cfg.n_layers)]
+            cache["k_scale"] = [jnp.zeros(s + (1,), jnp.float32)
+                                for s in lead]
+            cache["v_scale"] = [jnp.zeros(s + (1,), jnp.float32)
+                                for s in lead]
         return cache
+
+    def ring_length(self, cfg: ModelConfig, max_length: int) -> int:
+        """Positions a 'sliding' layer's buffer holds: under chunked
+        prefill a ring of window + chunk (a chunk is written before it
+        attends, and must not overwrite what its first query still sees),
+        never more than the slot's length; under monolithic prefill the
+        slot's length (the ring that never wraps: a whole prompt is
+        written at once, and only the mask knows the window)."""
+        if self.prefill_chunk <= 0:
+            return max_length
+        if cfg.sliding_window % self.prefill_chunk:
+            raise ValueError(
+                f"{cfg.name}: the window ({cfg.sliding_window}) must be "
+                f"whole prefill chunks ({self.prefill_chunk}) so that no "
+                "chunk wraps its ring")
+        return min(max_length, cfg.sliding_window + self.prefill_chunk)
+
+    def layer_lengths(self, cfg: ModelConfig, max_length: int) -> List[int]:
+        """Each layer's positions a slot: ``ring_length`` for a 'sliding'
+        layer, ``max_length`` for a 'full' one."""
+        ring = (self.ring_length(cfg, max_length)
+                if cfg.has_window_layers else max_length)
+        return [ring if cfg.layer_kind(l) == "sliding" else max_length
+                for l in range(cfg.n_layers)]
 
     # -- paged layout --------------------------------------------------------
 
@@ -213,8 +237,9 @@ class KVCachePolicy:
 
         per_pos = cfg.n_kv_groups * cfg.head_dim
         width = jnp.dtype(self.cache_dtype(cfg)).itemsize
-        kv = 2 * cfg.n_layers * max_length * per_pos * width
-        scale = (2 * cfg.n_layers * max_length * cfg.n_kv_groups * 4
+        positions = sum(self.layer_lengths(cfg, max_length))
+        kv = 2 * positions * per_pos * width
+        scale = (2 * positions * cfg.n_kv_groups * 4
                  if self.quantized else 0)
         return {"kv_bytes": kv, "scale_bytes": scale,
                 "total_bytes": kv + scale,

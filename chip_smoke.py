@@ -338,6 +338,32 @@ def phase_serve(model=(), shapes=REQUEST_SHAPES) -> None:
     check_serve(engine, reqs, results, "serve", n_generate=3)
 
 
+#: the parallel block of window and full layers with sparse experts, at the
+#: debug size of ``configs.get_config`` (two periods, window 8, 8 experts
+#: top-2, 2 shared, context 64)
+MOE_DEBUG = ["--model", "command_a_plus", "--num_params", "218B", "--debug"]
+
+
+def phase_serve_moe(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
+                            (40, 16, 0.0), (9, 20, 0.7), (23, 30, 0.0))
+                    ) -> None:
+    """Rings and experts on the chip, no timing: chunked prefill (chunks of
+    4 into rings of 12, which every prompt here wraps) and the decode tick
+    of the new block, its greedy tokens held to the one-shot forward and to
+    ``generate()`` like the dense model's."""
+    reqs_path = os.path.join(WORK, "requests_moe.jsonl")
+    os.makedirs(WORK, exist_ok=True)
+    reqs = make_requests(reqs_path, shapes)
+    engine, results = serve("serve_moe", reqs_path, len(reqs),
+                            extra=["--serve_prefill_chunk", "4"],
+                            model=MOE_DEBUG)
+    layout = engine.layout()
+    check(layout["kv_positions"] == {"full": 64, "ring": 12}
+          and len(layout["experts"]["held"]) == 8,
+          f"serve_moe: the engine's layout is {layout}")
+    check_serve(engine, reqs, results, "serve_moe", n_generate=2)
+
+
 def _load_tests(name: str):
     spec = importlib.util.spec_from_file_location(
         name, os.path.join(HERE, "tests", name + ".py"))
@@ -567,9 +593,10 @@ def main(argv=None) -> int:
                     help="4: run only the multi-chip paths and what they "
                          "are compared with (builder-run)")
     ap.add_argument("--phase", action="append",
-                    choices=["train", "serve", "kernels", "train_remat"],
+                    choices=["train", "serve", "serve_moe", "kernels",
+                             "train_remat"],
                     help="run only these one-chip phases (default: train, "
-                         "serve, kernels)")
+                         "serve, serve_moe, kernels)")
     args = ap.parse_args(argv)
 
     import jax
@@ -595,9 +622,10 @@ def main(argv=None) -> int:
         phases = {"chips4": phase_chips4}
     else:
         table = {"train": phase_train, "serve": phase_serve,
-                 "kernels": phase_kernels, "train_remat": phase_train_remat}
-        phases = {n: table[n] for n in (args.phase
-                                        or ["train", "serve", "kernels"])}
+                 "serve_moe": phase_serve_moe, "kernels": phase_kernels,
+                 "train_remat": phase_train_remat}
+        phases = {n: table[n] for n in (
+            args.phase or ["train", "serve", "serve_moe", "kernels"])}
     shutil.rmtree(WORK, ignore_errors=True)
     failed = []
     t_all = time.perf_counter()
