@@ -1069,6 +1069,34 @@ def kv_append_path(cache: Params, Tq: int,
     return "scatter"
 
 
+def decode_attention_path(cache: Params, Tq: int, n_heads: int, *,
+                          layer: int = 0, ring: bool = False,
+                          backend: Optional[str] = None) -> str:
+    """THE rule for how ``decode_slots`` attends in layer ``layer``, the
+    sibling of ``kv_append_path`` and made the same way: ``"live_blocks"``
+    (ops/decode_step.live_block_attention: one kernel call a layer that
+    reads, for each row, only the lane blocks its live positions reach) on
+    a TPU backend for the shapes ``supports_live_attention`` admits,
+    ``"whole_buffer"`` (``decode_attention``: two reductions over the whole
+    buffer, the lengths a mask) for everything else: verify (Tq = k+1),
+    int8 caches and their scale sidecars, ``head_dim`` 128, a ring
+    (``ring``: the layer attends by ``kv_positions`` / ``window``), any
+    other backend. The engine reports the name
+    (``stats()["decode_attention"]``). ``backend`` is for tests."""
+    from building_llm_from_scratch_tpu.ops.decode_step import (
+        supports_live_attention,
+    )
+
+    pane = cache["k"][layer]                   # (S, Hkv, Tmax, hd)
+    S, Hkv, Tmax, hd = pane.shape
+    if ((backend or jax.default_backend()) == "tpu" and not ring
+            and not _cache_quantized(cache)
+            and supports_live_attention(Tq, Tmax, hd, S=S, Hkv=Hkv,
+                                        Hq=n_heads, dtype=pane.dtype)):
+        return "live_blocks"
+    return "whole_buffer"
+
+
 @jax.named_scope("cache_update")
 def _slot_append_kv(cache: Params, new: Params, l: int,
                     K: jnp.ndarray, V: jnp.ndarray,
@@ -1106,6 +1134,28 @@ def _slot_append_kv(cache: Params, new: Params, l: int,
     new["k"].append(K)
     new["v"].append(V)
     return K, V
+
+
+def _slot_attend(cfg: ModelConfig, cache: Params, new: Params, l: int,
+                 q: jnp.ndarray, K: jnp.ndarray, V: jnp.ndarray,
+                 lengths: jnp.ndarray, ring_kw: dict) -> jnp.ndarray:
+    """A decode tick's attention in layer ``l``: each row's one query (at
+    position ``lengths``) against its own appended prefix of (K, V). The
+    two forms (``decode_attention_path``) are the same arithmetic: the
+    kernel leaves unread what ``decode_attention`` reads and masks to 0."""
+    with _attention_scope(bool(ring_kw)):
+        if decode_attention_path(cache, 1, cfg.n_heads, layer=l,
+                                 ring=bool(ring_kw)) == "live_blocks":
+            from building_llm_from_scratch_tpu.ops.decode_step import (
+                live_block_attention,
+            )
+
+            return live_block_attention(
+                q, K, V, lengths + 1,
+                interpret=jax.default_backend() != "tpu")
+        return decode_attention(q, K, V, q_positions=lengths[:, None],
+                                kv_length=lengths + 1, **ring_kw,
+                                **_layer_scales(new, l))
 
 
 def _layer_scales(cache: Params, l: int, slot: Optional[jnp.ndarray] = None
@@ -1347,8 +1397,9 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     Appends each row's k/v at ITS offset (``_slot_append_kv``: one in-place
     ``lane_window_append`` a layer where ``kv_append_path`` admits it, else
     ``slot_cache_append``'s scatter; the pallas fused step where
-    ``_use_fused_decode`` says so) and attends
-    with per-row masks; returns
+    ``_use_fused_decode`` says so) and attends each row's own prefix
+    (``decode_attention_path``: the live-block kernel where the gate admits
+    it, else ``decode_attention`` with per-row masks); returns
     (fp32 logits (S, V), updated cache). Free/finished slots compute
     garbage rows the engine ignores — the shapes never change, so XLA
     compiles exactly one decode program.
@@ -1412,10 +1463,7 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         else:
             write_at, ring_kw = _ring_step(cfg, kind, K.shape[2], lengths)
             K, V = _slot_append_kv(cache, new, l, K, V, k, v, write_at)
-            with _attention_scope(bool(ring_kw)):
-                out = decode_attention(q, K, V, q_positions=positions,
-                                       kv_length=lengths + 1, **ring_kw,
-                                       **_layer_scales(new, l))
+            out = _slot_attend(cfg, cache, new, l, q, K, V, lengths, ring_kw)
         x = _add_branches(
             cfg, p, x, h,
             _attn_out_proj(p["attn"], out, S, 1,

@@ -117,14 +117,18 @@ TICK_IDLE_WAIT = "tick.idle_wait"
 #: of the program's ``n_slots`` rows decoded a token, after ``chunks`` prefill
 #: chunks of slots in mid-prefill (chunked prefill only, absent at 0), reading
 #: ``kv_positions`` cache positions (live positions of those rows, summed
-#: over the layers, a window layer counting at most its window). A sparse model's
+#: over the layers, a window layer counting at most its window) and touching
+#: ``kv_touched`` (the positions the program's attention did read, every row
+#: of it: block-rounded lengths in a layer on the live-block kernel's path,
+#: whole buffers in any other; ``kv_positions / kv_touched`` is the live share
+#: of what was read). A sparse model's
 #: decode tick adds ``expert_rows`` (rows each held expert computed, summed
 #: over layers) and ``experts_touched`` (held experts, counted a layer,
 #: that got a row: each read its weights once).
 TICK_RECORD_FIELDS = ("tick", "t0", "t1", "t_dispatch", "t_fetch", "phases",
                       "rows", "n_slots", "admitted", "queue_depth",
-                      "replica", "chunks", "kv_positions", "expert_rows",
-                      "experts_touched")
+                      "replica", "chunks", "kv_positions", "kv_touched",
+                      "expert_rows", "experts_touched")
 
 #: Trainer StepTimeline segments (``<segment>_s`` fields of training
 #: cadence metrics rows; obs/timeline.py owns the measurement).
@@ -389,11 +393,12 @@ _EVENT_LIST: List[EventSpec] = [
                     "kv_bytes_per_slot", "prefix_pane_tokens", "spec_k",
                     "drafter", "replica", "kv_paged", "page_tokens",
                     "pool_pages", "sp", "prompt_pane_tokens", "max_prompt",
-                    "kv_append"),
+                    "kv_append", "decode_attention"),
           doc="prefill programs + decode (or spec verify) program "
               "compiled; watchers frozen; records the KVCachePolicy "
-              "(quant/chunk/prefix), which append the tick program was "
-              "built with (kv_append), the speculative config "
+              "(quant/chunk/prefix), which append and which attention the "
+              "tick program was built with (kv_append, decode_attention), "
+              "the speculative config "
               "(spec_k/drafter) when on, and the seq-sharded prefill "
               "geometry (sp/prompt_pane_tokens/max_prompt) on "
               "--serve_sp engines"),

@@ -209,6 +209,166 @@ def _lane_append_local(k_cache, v_cache, k_new, v_new, lengths, *,
     return t(ko), t(vo)
 
 
+def _live_block_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       kbuf, vbuf, sem, done_ref, m_ref, d_ref, acc_ref, *,
+                       scale: float, block: int):
+    """One grid cell = ``rows`` slots. For each row in turn, copy its live
+    lane blocks (and no other) from HBM into one of two VMEM buffers while
+    the block before is folded into the row's running softmax state
+    (``_paged_kernel``'s max / denominator / accumulator, all heads at
+    once); the first block of the NEXT row is asked for while this row's
+    last one is computed, so the copies never stop between rows or between
+    grid cells. In the cache's own orientation (hd, block) the score
+    product contracts the sublanes and the value product the lanes: both
+    are plain matrix products, and the heads' chains are independent, so
+    the scheduler spreads them over the MXUs."""
+    rows = q_ref.shape[0]
+    n_rows = rows * pl.num_programs(0)
+    first_row = pl.program_id(0) * rows
+
+    def copies(row, b, slot):
+        at = pl.ds(pl.multiple_of(b * block, block), block)
+        return [pltpu.make_async_copy(hbm.at[row, :, :, at], buf.at[slot],
+                                      sem.at[i, slot])
+                for i, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
+
+    def start(row, b, slot):
+        for copy in copies(row, b, slot):
+            copy.start()
+
+    @pl.when(pl.program_id(0) == 0)
+    def _first_copy():
+        done_ref[0] = 0
+        start(0, 0, 0)
+
+    def one_row(r, n_done):
+        row = first_row + r
+        n = len_ref[row]
+        n_blocks = (jnp.maximum(n, 1) + block - 1) // block
+        m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
+        d_ref[...] = jnp.zeros_like(d_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q = q_ref[r]                                      # (Hkv, Rp, hd)
+
+        def one_block(b, n_done):
+            slot = n_done % 2
+            more = b + 1 < n_blocks
+
+            @pl.when(more)
+            def _next_block():
+                start(row, b + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(jnp.logical_not(more),
+                                     row + 1 < n_rows))
+            def _next_row():
+                start(row + 1, 0, 1 - slot)
+
+            for copy in copies(row, b, slot):
+                copy.wait()
+            k = kbuf[slot]                                # (Hkv, hd, block)
+            live = b * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, block), 2) < n
+            # a masked probability is exactly 0, but 0 * NaN is not: what
+            # lies past a row's length never enters a product
+            v = jnp.where(live, vbuf[slot], jnp.zeros((), vbuf.dtype))
+            sc = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
+                                     preferred_element_type=jnp.float32)
+            sc = jnp.where(live, sc * scale, _NEG_BIG)    # (Hkv, Rp, block)
+            m_new = jnp.maximum(m_ref[...],
+                                jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_ref[...] - m_new)
+            p = jnp.exp(sc - m_new)
+            m_ref[...] = m_new
+            d_ref[...] = d_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            # probabilities in the cache's dtype before the value product,
+            # as ``decode_attention`` casts them
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            return n_done + 1
+
+        n_done = jax.lax.fori_loop(0, n_blocks, one_block, n_done)
+        o_ref[r] = (acc_ref[...] / d_ref[...]).astype(o_ref.dtype)
+        return n_done
+
+    # blocks folded so far, over all cells: its parity is the buffer that
+    # the copy in flight (asked for by the cell before) lands in
+    done_ref[0] = jax.lax.fori_loop(0, rows, one_row, done_ref[0])
+
+
+def live_block_attention(q, k_cache, v_cache, kv_length, *, interpret=False):
+    """``decode_attention`` for ONE query token a row (``q`` (S, 1, Hq,
+    hd), per-row ``kv_length`` (S,): the row attends positions
+    ``[0, kv_length)``) that reads only the lane blocks each row's live
+    positions reach, where ``decode_attention`` reduces over the whole
+    (S, Hkv, Tmax, hd) buffer with the lengths as a mask: a masked
+    position contributed exactly 0 there, so leaving it unread is the
+    same arithmetic. A free slot (length 0 or 1) reads one block.
+
+    Like ``lane_window_append`` it works on the cache's own device layout
+    (positions on the lanes for ``head_dim < 128``: the ``swapaxes`` is a
+    bitcast, no pane is copied or relaid). The buffers stay in HBM and the
+    kernel copies blocks itself (``kv_length`` scalar-prefetched), so HBM
+    traffic is the block-rounded live lengths and nothing is paid for the
+    blocks past them (``LIVE_BLOCK`` positions each); ``Hq // Hkv`` query
+    heads ride one key-value head as rows of its products.
+
+    Under a mesh (``--serve_tp``) heads shard over the model axis like
+    the slot cache; ``interpret=True`` runs on CPU for parity tests."""
+    heads = (None, None, MODEL_AXIS, None)
+    panes = (None, MODEL_AXIS, None, None)
+    return mesh_kernel(
+        lambda _, *a: _live_attention_local(*a, interpret=interpret),
+        (q, k_cache, v_cache, jnp.asarray(kv_length, jnp.int32)),
+        (heads, panes, panes, (None,)), heads)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _live_attention_local(q, k_cache, v_cache, kv_length, *, interpret):
+    # jitted for the reason ``_lane_append_local`` is: one lowering a program
+    S, Tq, Hq, hd = q.shape
+    _, Hkv, Tmax, _ = k_cache.shape
+    if Tq != 1:
+        raise ValueError(f"live_block_attention is single-token only; "
+                         f"Tq={Tq}")
+    block = LIVE_BLOCK
+    G = Hq // Hkv
+    Rp = max(_MIN_ROWS, G)
+    # (S, Hkv, G, hd) query rows, padded to the sublane minimum
+    qr = q.reshape(S, Hkv, G, hd).astype(k_cache.dtype)
+    if Rp != G:
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Rp - G), (0, 0)))
+    rows = _live_attention_rows(S)
+    some_rows = pl.BlockSpec((rows, Hkv, Rp, hd),
+                             lambda i, len_ref: (i, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    t = lambda x: jnp.swapaxes(x, 2, 3)        # a bitcast of the panes
+    out = pl.pallas_call(
+        functools.partial(_live_block_kernel, scale=1.0 / float(hd) ** 0.5,
+                          block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S // rows,),
+            in_specs=[some_rows, in_hbm, in_hbm],
+            out_specs=some_rows,
+            scratch_shapes=[
+                pltpu.VMEM((2, Hkv, hd, block), k_cache.dtype),
+                pltpu.VMEM((2, Hkv, hd, block), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),                  # blocks folded
+                pltpu.VMEM((Hkv, Rp, 1), jnp.float32),        # running max
+                pltpu.VMEM((Hkv, Rp, 1), jnp.float32),        # denominator
+                pltpu.VMEM((Hkv, Rp, hd), jnp.float32),       # accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, Rp, hd), q.dtype),
+        name="live_block_attention",
+        interpret=interpret,
+    )(jnp.minimum(kv_length, Tmax), qr, t(k_cache), t(v_cache))
+    return out[:, :, :G].reshape(S, 1, Hq, hd)
+
+
 def fused_decode_step(q, k_new, v_new, k_cache, v_cache, length, *,
                       interpret=False):
     """Append k_new/v_new at ``length`` (IN PLACE via aliasing) and attend.
@@ -515,6 +675,51 @@ def supports_lane_append(Tq: int, Tmax: int, hd: int, *, Hkv: int,
     return (Tq == 1 and jnp.issubdtype(dtype, jnp.floating)
             and hd < _LANES and hd % 16 == 0 and Tmax % _LANES == 0
             and 12 * Hkv * hd * _LANES * dtype.itemsize <= _VMEM_BUDGET)
+
+
+#: positions in one lane block of ``live_block_attention``: one lane tile,
+#: which rounds a row's length up by the least. Measured on the 1.5B pane
+#: (PERF.md section 6, PR 29): blocks of 256 are slower at every mix of
+#: lengths, because the kernel's own copies pay nothing for a step but the
+#: copy itself and a larger block only moves more dead positions
+LIVE_BLOCK = _LANES
+#: rows of queries and outputs a grid cell holds in VMEM (a divisor of S)
+_LIVE_ROWS = 8
+
+
+def _live_attention_rows(S: int) -> int:
+    return max(d for d in range(1, min(S, _LIVE_ROWS) + 1) if S % d == 0)
+
+
+def _live_attention_vmem_bytes(hd: int, S: int, Hkv: int, Hq: int,
+                               itemsize: int) -> int:
+    """One grid cell of ``live_block_attention``: two K and two V blocks,
+    the query and output rows (double-buffered by the pipeline; ``hd``
+    under a lane tile pads to one), the softmax state, and the float32
+    scores and probabilities of one block."""
+    Rp = max(_MIN_ROWS, Hq // Hkv)
+    tile = 32 // itemsize                  # sublanes of a tile of this dtype
+    return (4 * Hkv * hd * LIVE_BLOCK * itemsize
+            + 4 * _live_attention_rows(S) * Hkv * -(-Rp // tile) * tile
+            * _LANES * itemsize
+            + 3 * Hkv * Rp * _LANES * 4
+            + 3 * Hkv * Rp * LIVE_BLOCK * 4)
+
+
+def supports_live_attention(Tq: int, Tmax: int, hd: int, *, S: int, Hkv: int,
+                            Hq: int, dtype) -> bool:
+    """``live_block_attention`` eligibility, ``supports_lane_append``'s
+    twin: one query token a row, a float cache whose ``head_dim`` is under
+    a lane tile (positions on the lanes, so the kernel's view is a
+    bitcast), whole lane blocks, whole groups of query heads, and a cell
+    inside the VMEM budget. Whatever this refuses keeps
+    ``decode_attention``."""
+    dtype = jnp.dtype(dtype)
+    return (Tq == 1 and jnp.issubdtype(dtype, jnp.floating)
+            and hd < _LANES and hd % 16 == 0 and Tmax % LIVE_BLOCK == 0
+            and Hq % Hkv == 0
+            and _live_attention_vmem_bytes(hd, S, Hkv, Hq, dtype.itemsize)
+            <= _VMEM_BUDGET)
 
 
 #: the fused step runs under the compiler's default 16 MiB scoped-VMEM

@@ -48,6 +48,7 @@ fault tolerance):
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from typing import List, Optional, Sequence, Union
@@ -65,6 +66,7 @@ from building_llm_from_scratch_tpu.generate import (
 )
 from building_llm_from_scratch_tpu.models.transformer import (
     _use_fused_decode,
+    decode_attention_path,
     decode_slots,
     init_slot_cache,
     kv_append_path,
@@ -100,6 +102,7 @@ from building_llm_from_scratch_tpu.obs.timeline import (
     annotate,
     annotate_step,
 )
+from building_llm_from_scratch_tpu.ops.decode_step import LIVE_BLOCK
 from building_llm_from_scratch_tpu.parallel.collectives import (
     trace_under_mesh,
 )
@@ -368,6 +371,19 @@ class DecodeEngine:
             self.kv_append = "fused_step"
         else:
             self.kv_append = kv_append_path(self.cache, self.spec_k + 1)
+        #: the same for the tick program's attention
+        #: (``decode_attention_path``): "live_blocks" (a row's live lane
+        #: blocks only) | "whole_buffer", or the other two programs' names.
+        #: ``_attn_reads``: {(block, buffer length): layers}, block 0 where a
+        #: layer reads its buffer whole: what a tick's ``kv_touched`` counts
+        self._attn_reads = collections.Counter(
+            self._attention_read(self.cache, l) for l in range(cfg.n_layers))
+        if self.kv_append in ("paged", "fused_step"):
+            self.decode_attention = self.kv_append
+        else:
+            self.decode_attention = (
+                "live_blocks" if any(b for b, _ in self._attn_reads)
+                else "whole_buffer")
         #: the weights ride every compiled program as an ARGUMENT: closed
         #: over, jit bakes them into each program as constants (GPT2-124M
         #: bf16: 0.3 GB per program, 40 s per compile on the chip, and
@@ -1880,10 +1896,24 @@ class DecodeEngine:
 
     # -- tracing / tick accounting ----------------------------------------
 
-    def _kv_positions_read(self, decoding) -> int:  # holds: _lock
-        """Cache positions this tick's attention has to read: each decoding
-        row's live positions (the one it appends included), summed over the
-        layers, a window layer counting no more than its window."""
+    def _attention_read(self, cache, l: int) -> tuple:
+        """(lane block, buffer length) of layer ``l``'s decode attention:
+        the block ``live_block_attention`` reads by, 0 where the layer
+        reads its buffer whole (``decode_attention_path``)."""
+        if self.kv_append in ("paged", "fused_step"):
+            return 0, self._cache_len
+        live = decode_attention_path(
+            cache, self.spec_k + 1, self.cfg.n_heads, layer=l,
+            ring=self.cfg.layer_kind(l) == "sliding") == "live_blocks"
+        return LIVE_BLOCK if live else 0, cache["k"][l].shape[2]
+
+    def _kv_positions_read(self, decoding) -> tuple:  # holds: _lock
+        """Cache positions this tick's attention has to read, and those it
+        does read. Has to: each decoding row's live positions (the one it
+        appends included), summed over the layers, a window layer counting
+        no more than its window. Does: every row of the fixed-shape program,
+        free slots too, a layer on the kernel's path its block-rounded
+        length and any other its whole buffer (``_attn_reads``)."""
         lengths = self._lengths.tolist()    # plain ints: a few us a tick
         live = [lengths[s] + 1 for s, _ in decoding]
         n_window = self._n_window_layers
@@ -1891,7 +1921,12 @@ class DecodeEngine:
         if n_window:
             window = self.cfg.sliding_window
             total += n_window * sum(min(n, window) for n in live)
-        return total
+        touched = 0
+        for (block, buffer), layers in self._attn_reads.items():
+            touched += layers * (
+                sum(-(-min(n + 1, buffer) // block) for n in lengths) * block
+                if block else self.n_slots * buffer)
+        return total, touched
 
     def _emit_span(self, req: Request) -> None:
         """Write the request's one terminal ``span`` row (request tree:
@@ -2057,7 +2092,8 @@ class DecodeEngine:
                     slot, int(self._lengths[slot]) + 1)  # graft-ok: GL011 host numpy
         with self._tl.span("decode_dispatch"):
             # inside the span: the phases of a tick add up to its wall
-            self._tick_rec["kv_positions"] = self._kv_positions_read(decoding)
+            (self._tick_rec["kv_positions"],
+             self._tick_rec["kv_touched"]) = self._kv_positions_read(decoding)
             nxt, ok, cache = self._decode(
                 self.cache, self._weights, self._last_tokens, self._lengths,
                 *((self._page_table,) if self._paged else ()),
@@ -2529,6 +2565,7 @@ class DecodeEngine:
             n_slots=self.n_slots, max_len=self.max_len,
             kv_bytes_per_slot=bps["total_bytes"],
             kv_append=self.kv_append,
+            decode_attention=self.decode_attention,
             prefix_pane_tokens=(self._prefix_pane_len
                                 if self.prefix_store is not None
                                 else None),
@@ -2948,6 +2985,7 @@ class DecodeEngine:
                 out["adapters_loaded"] = self.adapters.n_loaded
             out["kv_policy"] = self.kv_policy.describe()
             out["kv_append"] = self.kv_append
+            out["decode_attention"] = self.decode_attention
             out.update(self.layout())
             out["memory"] = self.memory_ledger.describe()
             if self._paged:
@@ -3116,6 +3154,7 @@ class DecodeEngine:
             "queue_capacity": self.queue.max_size,
             "warmed_up": self.warmed_up,
             "kv_append": self.kv_append,
+            "decode_attention": self.decode_attention,
             **self.layout(),
             "draining": self.draining,
             "restarts": self.n_restarts,

@@ -759,6 +759,7 @@ class EngineRouter:
                 "restarts": p["restarts"],
                 "slo_miss_ratio": p["slo_miss_ratio"],
                 "kv_append": p["kv_append"],
+                "decode_attention": p["decode_attention"],
             })
         up = [r for r in replicas if r["status"] == "serving"]
         if self._dead is not None:

@@ -264,6 +264,7 @@ def serve(label: str, reqs_path: str, n: int, extra=(), model=()):
         f"{sum(r['n_tokens'] for r in results)} tokens, "
         f"{engine.n_recompiles} bucket-miss compiles after warmup, "
         f"kv_append {engine.kv_append}, "
+        f"decode_attention {engine.decode_attention}, "
         f"{time.perf_counter() - t0:.1f}s, peak HBM {peak_hbm_gib()} GiB")
     return engine, results
 
@@ -409,6 +410,7 @@ def phase_kernels() -> None:
     from building_llm_from_scratch_tpu.ops.decode_step import (
         fused_decode_step,
         lane_window_append,
+        live_block_attention,
         slot_cache_append,
     )
     from building_llm_from_scratch_tpu.ops.fused_attention import (
@@ -500,10 +502,33 @@ def phase_kernels() -> None:
               f"kernels: lane-window append wrote the {jnp.dtype(dt).name} "
               f"cache differently from the scatter")
 
+    # the live-block attention (what the engine's tick program attends
+    # with) vs decode_attention, at the serving cells' pane (GPT2-1.5B, 32
+    # slots): block edges, both ends, a free slot, and lengths as a tick's
+    S, H = 32, 25
+    lengths = jnp.asarray(
+        [0, 1, 127, 128, 129, 255, 256, 257, 511, 512, 513, 1023, 1024]
+        + np.random.default_rng(SEED).integers(1, 1025, S - 13).tolist(),
+        jnp.int32)
+    attend = jax.jit(live_block_attention)
+    whole = jax.jit(lambda q, K, V, n: decode_attention(
+        q, K, V, q_positions=(n - 1)[:, None], kv_length=n))
+    # one tolerance: float32 products run in bfloat16 passes on this chip,
+    # in both (measured 7.8e-3 apart on float32 panes, 2e-3 on bfloat16)
+    for dt in (jnp.float32, jnp.bfloat16):
+        q1 = jax.random.normal(ks[0], (S, 1, H, D), dt)
+        K, V = (jax.random.normal(kk, (S, H, Tmax, D), dt)
+                for kk in ks[1:3])
+        got, want = (f32(fn(q1, K, V, lengths))[1:] for fn in (attend, whole))
+        check(float(np.abs(got - want).max()) < 2e-2,
+              f"kernels: live-block attention is "
+              f"{float(np.abs(got - want).max()):.2e} off decode_attention "
+              f"on {jnp.dtype(dt).name} panes")
+
     n = run_repo_tpu_tests()
     log(f"kernels: fused attention fwd/grad/dropout, fused dropout-add, "
-        f"fused decode step and lane-window append match their XLA "
-        f"references at the real shapes; "
+        f"fused decode step, lane-window append and live-block attention "
+        f"match their XLA references at the real shapes; "
         f"{n} needs_tpu repo test cases pass; "
         f"{time.perf_counter() - t0:.1f}s")
 

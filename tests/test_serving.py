@@ -168,6 +168,138 @@ def test_kv_append_gate_refusals_take_the_scatter(why, Tq, kw):
     assert "pallas_call" not in str(jax.make_jaxpr(append)(cache, k, lens))
 
 
+# -- the live-block attention kernel (interpret mode) against
+# ``decode_attention``, which stays the reference ---------------------------
+
+_ATTN_T, _ATTN_B = 384, 128
+
+
+@pytest.fixture
+def lane_blocks():
+    """The kernel's module; three of its blocks make a test's buffer."""
+    from building_llm_from_scratch_tpu.ops import decode_step as ds
+
+    assert ds.LIVE_BLOCK == _ATTN_B
+    return ds
+
+
+def _attn_case(lengths, dtype, G, Hkv=2, T=_ATTN_T, D=16, seed=0):
+    S = len(lengths)
+    ks = jax.random.split(jax.random.PRNGKey(seed + sum(lengths)), 3)
+    q = jax.random.normal(ks[0], (S, 1, Hkv * G, D)).astype(dtype)
+    K, V = (jax.random.normal(k, (S, Hkv, T, D)).astype(dtype)
+            for k in ks[1:])
+    return q, K, V, jnp.asarray(lengths, jnp.int32)
+
+
+def _attend_whole(q, K, V, lens):
+    from building_llm_from_scratch_tpu.ops.attention import decode_attention
+
+    return decode_attention(q, K, V, q_positions=(lens - 1)[:, None],
+                            kv_length=lens)
+
+
+def _close(got, want, dtype):
+    assert got.dtype == want.dtype and got.shape == want.shape
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-6
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(want.astype(jnp.float32)),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("G", [1, 4], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("lengths", [
+    [1] * 3, [_ATTN_B - 1] * 3, [_ATTN_B] * 3, [_ATTN_B + 1] * 3,
+    [_ATTN_T] * 3, [0, 200, 0, 300, 1],
+    # 32 rows are four grid cells of 8: a cell's first block is asked for
+    # by the cell before it, whatever the parity of the blocks so far
+    [int(n) for n in np.random.default_rng(0).integers(0, _ATTN_T + 1, 32)],
+], ids=["one", "block_minus_1", "block", "block_plus_1", "tmax",
+        "free_among_live", "mixed32_four_cells"])
+def test_live_block_attention_matches_decode_attention(lane_blocks, lengths,
+                                                       dtype, G):
+    """Every row's output equals ``decode_attention``'s on the same panes,
+    at each edge of a block. A free row (length 0) has nothing to attend:
+    the reference averages the whole buffer there and the kernel reads one
+    block; the engine ignores both, so only its being finite is held."""
+    q, K, V, lens = _attn_case(lengths, dtype, G)
+    got = jax.jit(lambda *a: lane_blocks.live_block_attention(
+        *a, interpret=True))(q, K, V, lens)
+    live = np.asarray(lens) > 0
+    _close(got[live], _attend_whole(q, K, V, lens)[live], dtype)
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("garbage", [np.nan, 3e38], ids=["nan", "huge"])
+def test_live_block_attention_never_reads_past_a_rows_length(lane_blocks,
+                                                             garbage, dtype):
+    """NaN, or the largest finite number, in every position past each
+    row's length (the rest of its last block and all the blocks beyond):
+    nothing of it reaches the output. ``decode_attention`` itself is held
+    to the clean panes: 0 * NaN is NaN in its value product."""
+    lengths = [1, _ATTN_B - 1, _ATTN_B, _ATTN_B + 1, 300]
+    q, K, V, lens = _attn_case(lengths, dtype, G=2, seed=1)
+    past = jnp.arange(_ATTN_T)[None, None, :, None] >= lens[:, None, None,
+                                                            None]
+    dirty = [jnp.where(past, jnp.asarray(garbage, dtype), x) for x in (K, V)]
+    got = jax.jit(lambda *a: lane_blocks.live_block_attention(
+        *a, interpret=True))(q, *dirty, lens)
+    _close(got, _attend_whole(q, K, V, lens), dtype)
+
+
+@pytest.mark.parametrize("why,Tq,kw", [
+    ("verify_tq", 3, {}),
+    ("int8_cache", 1, dict(dtype=jnp.int8, quant=True)),
+    ("head_dim_128", 1, dict(D=128)),
+    ("ring_arguments", 1, {}),
+    ("tmax_not_lane_multiple", 1, dict(T=192 + 8)),
+    ("over_vmem_budget", 1, dict(H=512, D=64)),
+    ("not_a_tpu", 1, {}),
+])
+def test_decode_attention_gate_refusals_take_decode_attention(monkeypatch,
+                                                              why, Tq, kw):
+    """Outside the gate a tick attends with ``decode_attention``, today's
+    two reductions: the name says so, and the traced attention holds no
+    kernel call; inside it, it holds one."""
+    import functools
+
+    from building_llm_from_scratch_tpu.models import transformer as tf
+
+    cfg = tiny_cfg(ctx=128)
+    cache = _append_cache(**kw)
+    ring = why == "ring_arguments"
+    backend = None if why == "not_a_tpu" else "tpu"
+    assert tf.decode_attention_path(_append_cache(), 1, 2, backend="tpu") \
+        == "live_blocks"
+    assert tf.decode_attention_path(cache, Tq, 2, ring=ring,
+                                    backend=backend) == "whole_buffer"
+    if why in ("over_vmem_budget", "verify_tq"):
+        return          # nothing small to trace; verify never asks the rule
+    if backend:
+        monkeypatch.setattr(tf, "decode_attention_path", functools.partial(
+            tf.decode_attention_path, backend=backend))
+    S, H, T, D = cache["k"][0].shape
+    q = jnp.ones((S, 1, H, D), jnp.float32)
+    lens = jnp.asarray([0, 5], jnp.int32)
+    ring_kw = ({"kv_positions": tf.ring_positions(lens, T), "window": 64}
+               if ring else {})
+
+    def attend(cache, q, lens):
+        return tf._slot_attend(cfg, cache, cache, 0, q, cache["k"][0],
+                               cache["v"][0], lens, ring_kw)
+
+    assert "live_block_attention" not in str(
+        jax.make_jaxpr(attend)(cache, q, lens))
+    if backend and not ring:
+        admitted = _append_cache()
+        assert "live_block_attention" in str(jax.make_jaxpr(attend)(
+            admitted, jnp.ones((2, 1, 2, 16), jnp.float32), lens))
+
+
 def test_decode_attention_per_row_matches_scalar():
     from building_llm_from_scratch_tpu.ops.attention import decode_attention
 
@@ -294,6 +426,66 @@ def test_engine_tokens_identical_under_lane_window_append(monkeypatch, tp):
     assert scatter[0] == "scatter" and lane[0] == "lane_window"
     assert lane[1] == scatter[1]
     assert lane[1][0] == solo_tokens(params, cfg, prompts[0], cases[0])
+
+
+@pytest.mark.parametrize("tp", [1, 2], ids=["one_device", "serve_tp2"])
+def test_engine_tokens_identical_under_live_block_attention(monkeypatch,
+                                                            lane_blocks, tp):
+    """One engine run with the tick program's attention on the live-block
+    kernel (the rule told it is on a TPU; the kernel interprets on the CPU
+    it really is on), one on ``decode_attention``: the same greedy and
+    sampled tokens with rows in the first and in the second block of their
+    buffers, each engine names its path, and the tick record counts what
+    each read. Under ``--serve_tp`` each device's kernel attends its own
+    head."""
+    import functools
+
+    from building_llm_from_scratch_tpu.models import transformer as tf
+    from building_llm_from_scratch_tpu.obs.metrics import get_metrics
+    from building_llm_from_scratch_tpu.parallel.sharding import (
+        serve_mesh_plan,
+    )
+    from building_llm_from_scratch_tpu.serving import engine as engine_mod
+
+    T, S = 256, 3
+    cfg = tiny_cfg(ctx=T)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = [np.array([5, 6, 7, 8, 9], np.int32),
+               np.arange(3, 3 + _ATTN_B - 4, dtype=np.int32) % 90 + 2]
+    cases = [SamplingParams(max_new_tokens=10, seed=3, ignore_eos=True),
+             SamplingParams(max_new_tokens=9, temperature=0.9, top_k=5,
+                            seed=3, ignore_eos=True)]
+
+    def run():
+        eng = DecodeEngine(cfg, params, n_slots=S, max_len=T,
+                           mesh_plan=serve_mesh_plan(tp=tp) if tp > 1
+                           else None)
+        handles = [eng.submit(p, sp) for p, sp in zip(prompts, cases)]
+        eng.run_until_idle()
+        assert all(h.done and h.finish_reason == "length" for h in handles)
+        assert eng.stats()["decode_attention"] == eng.decode_attention
+        assert eng.healthz_payload()["decode_attention"] \
+            == eng.decode_attention
+        tick = [t for t in get_metrics().recent("tick")
+                if t.get("rows") == 2][-1]
+        return (eng.decode_attention, [h.output_ids for h in handles],
+                tick["kv_positions"], tick["kv_touched"])
+
+    whole = run()
+    on_tpu = functools.partial(tf.decode_attention_path, backend="tpu")
+    monkeypatch.setattr(tf, "decode_attention_path", on_tpu)
+    monkeypatch.setattr(engine_mod, "decode_attention_path", on_tpu)
+    live = run()
+    assert whole[0] == "whole_buffer" and live[0] == "live_blocks"
+    assert live[1] == whole[1]
+    assert live[1][0] == solo_tokens(params, cfg, prompts[0], cases[0])
+    # the last tick both rows decoded in (the long one's eighth): the short
+    # row holds 5 + 7 positions and appends one, the long one 124 + 7 and
+    # one, which is in its second block; two layers
+    L = cfg.n_layers
+    assert whole[2] == live[2] == L * (13 + 132)
+    assert whole[3] == L * S * T                # three whole buffers
+    assert live[3] == L * (1 + 2 + 1) * _ATTN_B     # the free slot: one
 
 
 def test_slot_reuse_and_seed_reproducibility(model):

@@ -141,13 +141,15 @@ def test_decode_slots_appends_in_place_at_full_depth(one_chip, monkeypatch,
                                                      size, S, dtype):
     """``decode_slots`` itself at the model's own depth (48 and 12 layers),
     as the engine jits it (cache donated): the append is ONE custom call a
-    layer on the cache's own layout (positions on the lanes), every pane
-    aliased through, no ``while`` (the scatter's S-trip loops), and no
-    pane copied or relaid. A kernel handed the logical shape gets a
-    relayout copy of every pane in and out; the fused step one case down
-    is refused outright. (Full depth on purpose: a six-layer cut of the
-    1.5B program leaves the compiler VMEM to spare, and it then parks
-    whole panes there, which the real program never does.)"""
+    layer on the cache's own layout (positions on the lanes) and the
+    attention ONE more on the same view, every pane aliased through, no
+    ``while`` (the scatter's S-trip loops), no pane copied or relaid, and
+    no reduction over all (S, H, Tmax) positions left (``decode_attention``'s
+    two a layer). A kernel handed the logical shape gets a relayout copy
+    of every pane in and out; the fused step one case down is refused
+    outright. (Full depth on purpose: a six-layer cut of the 1.5B program
+    leaves the compiler VMEM to spare, and it then parks whole panes
+    there, which the real program never does.)"""
     import dataclasses
     import re
 
@@ -164,6 +166,7 @@ def test_decode_slots_appends_in_place_at_full_depth(one_chip, monkeypatch,
     blocks = shapes(lambda p: tf.unstack_blocks(p, cfg), params)
     cache = shapes(lambda: tf.init_slot_cache(cfg, S, cfg.context_length))
     assert tf.kv_append_path(cache, 1) == "lane_window"
+    assert tf.decode_attention_path(cache, 1, cfg.n_heads) == "live_blocks"
     row = jax.ShapeDtypeStruct((S,), I32, sharding=one_chip)
 
     def tick(cache, params, blocks, tokens, lengths):
@@ -172,10 +175,13 @@ def test_decode_slots_appends_in_place_at_full_depth(one_chip, monkeypatch,
 
     hlo = jax.jit(tick, donate_argnums=(0,)).lower(
         cache, params, blocks, row, row).compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == L
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 * L
     assert not re.search(r" while\(", hlo)
     H, T, hd = cfg.n_kv_groups, cfg.context_length, cfg.head_dim
     el = "bf16" if dtype == "bf16" else "f32"
+    # scores or probabilities of every position of every row: the whole-
+    # buffer attention's mark (a float32 [S,H,Tmax] or [S,H,1,1,Tmax])
+    assert not re.search(rf"f32\[{S},{H},(?:1,)*{T}\]", hlo)
     # a pane, as the runtime keeps it and as the kernel views it (a bitcast)
     native = re.escape(f"{el}[{S},{H},{T},{hd}]") + r"\{2,3,1,0:"
     viewed = re.escape(f"{el}[{S},{H},{hd},{T}]") + r"\{3,2,1,0:"
@@ -336,3 +342,38 @@ def test_sharded_lane_window_append_on_four_devices(topo):
     assert "bf16[8,3,1024,64]" in hlo and " copy(" not in "".join(
         ln for ln in hlo.split("\n") if "bf16[8,3,1024,64]" in ln
         or "bf16[8,3,64,1024]" in ln)
+
+
+def test_sharded_live_block_attention_on_four_devices(topo):
+    """The tick program's attention under ``--serve_tp 4``: each device
+    reads the live blocks of its own three heads, and no pane is copied."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
+                ("data", "seq", "model"))
+    heads = _spec(NamedSharding(mesh, P(None, None, "model")))
+    panes = _spec(NamedSharding(mesh, P(None, "model")))
+    q, pane = heads((8, 1, 12, 64)), panes((8, 12, 1024, 64))
+    lens = jax.ShapeDtypeStruct((8,), I32, sharding=NamedSharding(mesh, P()))
+    assert ds.supports_live_attention(1, 1024, 64, S=8, Hkv=12, Hq=12,
+                                      dtype=BF16)
+    hlo = _compile(trace_under_mesh(ds.live_block_attention, mesh),
+                   q, pane, pane, lens)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "bf16[8,3,64,1024]" in hlo and " copy(" not in "".join(
+        ln for ln in hlo.split("\n") if "bf16[8,3,1024,64]" in ln
+        or "bf16[8,3,64,1024]" in ln)
+
+
+@pytest.mark.parametrize("S,Hq,Hkv,dtype", [
+    (32, 25, 25, BF16),          # GPT2-1.5B, the serving cells
+    (32, 25, 25, jnp.float32),
+    (8, 32, 8, BF16),            # Llama-3.2-1B: four query heads a group
+    (48, 12, 12, BF16),          # six grid cells of 8 rows
+])
+def test_live_block_attention_real_widths(one_chip, S, Hq, Hkv, dtype):
+    assert ds.supports_live_attention(1, 1024, 64, S=S, Hkv=Hkv, Hq=Hq,
+                                      dtype=dtype)
+    s = _spec(one_chip)
+    pane = s((S, Hkv, 1024, 64), dtype)
+    hlo = _compile(ds.live_block_attention, s((S, 1, Hq, 64), dtype), pane,
+                   pane, s((S,), I32))
+    assert hlo.count("tpu_custom_call") == 1
