@@ -223,6 +223,9 @@ UNSUPPORTED = (
     ("speculation", "window",
      "a verify tick appends k+1 positions that may wrap a ring and rejected "
      "ones cannot be taken back from it: serve it with spec_k 0"),
+    ("speculation", "moe",
+     "a verify tick would route its rejected drafts to experts and count "
+     "them in the experts' rows: serve it with spec_k 0"),
     ("tensor_parallel", "either",
      "experts and rings have no tensor-parallel split yet: run it on one "
      "chip, or under dp, fsdp or zero1, which shard it like any leaf "

@@ -51,6 +51,7 @@ its positions and, in the slot cache, whether its buffer is a ring.
 
 from __future__ import annotations
 
+from functools import cached_property
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -92,7 +93,7 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 def _block_adp(lb: Params, s) -> Params:
-    """Per-layer adapter argument for ``_block``/the slot loops: the lora
+    """Per-layer adapter argument for ``_block``/``_slot_pass``: the lora
     blocks node (attn/mlp, each projection a {"A","B"}) + the scale."""
     return {"attn": dict(lb["attn"], s=s), "mlp": dict(lb["mlp"], s=s)}
 
@@ -282,38 +283,6 @@ def _use_fused_dropout(shape) -> bool:
     return supports_shape(shape)
 
 
-def _use_fused_decode(cfg: ModelConfig, cache: Params, Tq: int) -> bool:
-    """THE rule for the pallas fused append+attend decode kernel
-    (ops/decode_step.fused_decode_step), shared by one-shot
-    ``forward_with_cache`` and the engine's ``decode_slots``: opt-in with
-    ``BLLM_FUSED_DECODE=1``, on TPU, for unquantized caches (int8 caches
-    keep the XLA path: ``decode_attention`` folds the scale sidecars into
-    its einsums, the kernel has no dequant pass) of a shape
-    ``supports_shape`` admits.
-
-    Off by default, on the evidence there is. The kernel compiles for
-    v5e and matches the XLA path alone (chip_smoke.py's kernels phase),
-    but inside a whole GPT2-124M decode program — six layers or more —
-    the compiler assigns whole (S, Hkv, Tmax, hd) cache arrays to VMEM
-    beside the kernel's own scope and refuses the program ("scoped
-    allocation 24.00M, limit 16.00M"; with a raised ``vmem_limit_bytes``
-    it only assigns more). The engine took this kernel unconditionally
-    before it had ever been compiled for a chip. For one shared scalar
-    length the one A/B on record measured it 3% slower on GPT2-124M bs8.
-    ROADMAP S1/S5 own the re-measurement."""
-    import os
-
-    from building_llm_from_scratch_tpu.ops.decode_step import supports_shape
-
-    pane = cache["k"][0]                       # (B, Hkv, Tmax, hd)
-    return (os.environ.get("BLLM_FUSED_DECODE", "0") == "1"
-            and jax.default_backend() == "tpu"
-            and not _cache_quantized(cache)
-            and supports_shape(Tq, pane.shape[2], cfg.head_dim,
-                               Hkv=cfg.n_kv_groups, Hq=cfg.n_heads,
-                               itemsize=pane.dtype.itemsize))
-
-
 def _dropout(x: jnp.ndarray, rate: float, rng: Optional[jax.Array],
              deterministic: bool) -> jnp.ndarray:
     if rate <= 0.0 or deterministic:
@@ -350,8 +319,8 @@ def _qkv_proj(cfg: ModelConfig, p: Params, x: jnp.ndarray,
               rope, positions, adp: Optional[Params] = None):
     """Shared q/k/v projection (+biases, head reshape, RoPE) — the single
     source of truth for the attention parameterization, used by BOTH the
-    training path (_attention) and the KV-cache decode body
-    (forward_with_cache); divergence here would silently break decode.
+    training path (_attention) and every cached program's body
+    (_slot_pass); divergence here would silently break decode.
     ``adp``: optional unmerged LoRA nodes (wq/wk/wv + ``"s"``), applied
     BEFORE the head reshape and RoPE — exactly where a merged weight's
     delta would land."""
@@ -402,12 +371,11 @@ def _attn_out_proj(p: Params, out: jnp.ndarray, B: int, Tq: int,
 def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]],
                positions: Optional[jnp.ndarray],
-               cache_kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]],
-               cache_len: Optional[jnp.ndarray],
                rng: Optional[jax.Array], deterministic: bool,
                sp_mesh=None, sp_inside=None, tp_axis=None, adp=None,
-               window: Optional[int] = None):
-    """Per-block attention; returns (out, new_cache_kv)."""
+               window: Optional[int] = None) -> jnp.ndarray:
+    """Per-block causal self-attention over the sequence itself (the
+    cached programs attend through ``_slot_pass``'s access objects)."""
     if window is not None and (sp_mesh is not None or sp_inside is not None):
         raise ValueError("the ring schedule of sequence parallelism has no "
                          "window term: a model with 'sliding' layers "
@@ -417,24 +385,7 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
 
     q, k, v = _qkv_proj(cfg, p, x, rope, positions, adp=adp)
 
-    new_cache = None
-    if cache_kv is not None:
-        # write current k/v into the cache at offset cache_len, attend to the
-        # full valid prefix
-        ck, cv = cache_kv                        # (B, Tmax, Hkv, hd)
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, cache_len, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, cache_len, 0, 0))
-        new_cache = (ck, cv)
-        k, v = ck, cv
-        kv_length = cache_len + Tq
-        q_positions = positions
-    else:
-        kv_length = None
-        q_positions = None
-
-    if sp_inside is not None and cache_kv is None:
+    if sp_inside is not None:
         # already INSIDE a shard_map that mapped the seq axis (the explicit
         # bf16_hybrid step): run the local ring body directly
         from building_llm_from_scratch_tpu.ops.ring_attention import (
@@ -450,7 +401,7 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
             dropout_rate=cfg.drop_rate if dropout_on else 0.0,
             dropout_rng=rng if dropout_on else None,
             shard_fold_axes=(DATA_AXIS,))
-    elif sp_mesh is not None and cache_kv is None:
+    elif sp_mesh is not None:
         # sequence parallelism: the ring schedule owns the communication;
         # attention dropout folds shard indices into the mask PRNG (the
         # round-3 restriction is lifted — ring_attention.py)
@@ -467,8 +418,6 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
         with _attention_scope(window is not None):
             out = causal_attention(
                 q, k, v,
-                q_positions=q_positions,
-                kv_length=kv_length,
                 dropout_rate=cfg.drop_rate,
                 dropout_rng=rng,
                 deterministic=deterministic,
@@ -476,8 +425,7 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                 window=window,
             )
     out = checkpoint_name(out, "attn_out")
-    out = _attn_out_proj(p, out, B, Tq, tp_axis=tp_axis, adp=adp)
-    return out, new_cache
+    return _attn_out_proj(p, out, B, Tq, tp_axis=tp_axis, adp=adp)
 
 
 def _layer_rope(cfg: ModelConfig, rope, kind: str):
@@ -516,7 +464,7 @@ def _ffn(cfg: ModelConfig, p: Params, h: jnp.ndarray, *, tp_axis=None,
 def _add_branches(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                   h: jnp.ndarray, attn_out: jnp.ndarray, **ffn_kw
                   ) -> jnp.ndarray:
-    """The residual updates of the slot loops' blocks, from a block's input
+    """The residual updates of ``_slot_pass``'s blocks, from a block's input
     ``x``, its first norm ``h`` and its projected attention output. Serial:
     the feed-forward reads a second norm of ``x + attention``. Parallel
     (``cfg.parallel_block``): it reads ``h`` too, and both add to ``x``."""
@@ -527,9 +475,9 @@ def _add_branches(cfg: ModelConfig, p: Params, x: jnp.ndarray,
 
 
 def _block(cfg: ModelConfig, p: Params, x: jnp.ndarray,
-           rope, positions, cache_kv, cache_len, rng, deterministic,
+           rope, positions, rng, deterministic,
            sp_mesh=None, sp_inside=None, tp_axis=None, adp=None,
-           kind: str = "full"):
+           kind: str = "full") -> jnp.ndarray:
     """Pre-norm transformer block (reference GPT2.py:68-88, Llama3.py:159-181)
     of one ``kind`` ('sliding': windowed attention; ``_layer_rope`` says
     which kinds rotate). With ``cfg.parallel_block`` attention and
@@ -554,22 +502,18 @@ def _block(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     else:
         r_attn = r_res1 = r_res2 = None
     n1 = _norm(cfg, p["norm1"], x)
-    h, new_cache = _attention(cfg, p["attn"], n1,
-                              _layer_rope(cfg, rope, kind), positions,
-                              cache_kv, cache_len,
-                              r_attn, deterministic, sp_mesh=sp_mesh,
-                              sp_inside=sp_inside, tp_axis=tp_axis,
-                              adp=adp["attn"] if adp is not None else None,
-                              window=_layer_window(cfg, kind))
+    h = _attention(cfg, p["attn"], n1, _layer_rope(cfg, rope, kind),
+                   positions, r_attn, deterministic, sp_mesh=sp_mesh,
+                   sp_inside=sp_inside, tp_axis=tp_axis,
+                   adp=adp["attn"] if adp is not None else None,
+                   window=_layer_window(cfg, kind))
     if cfg.parallel_block:
         h = h + _ffn(cfg, p, n1, tp_axis=tp_axis, adp=adp)
-        return (_residual_dropout(x, h, cfg.drop_rate, r_res1, deterministic),
-                new_cache)
+        return _residual_dropout(x, h, cfg.drop_rate, r_res1, deterministic)
     x = _residual_dropout(x, h, cfg.drop_rate, r_res1, deterministic)
     x = checkpoint_name(x, "resid_mid")
     h = _ffn(cfg, p, _norm(cfg, p["norm2"], x), tp_axis=tp_axis, adp=adp)
-    x = _residual_dropout(x, h, cfg.drop_rate, r_res2, deterministic)
-    return x, new_cache
+    return _residual_dropout(x, h, cfg.drop_rate, r_res2, deterministic)
 
 
 # ---------------------------------------------------------------------------
@@ -717,10 +661,9 @@ def forward_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
             p, lrng = layer
             adp = None
         r = None if deterministic else lrng
-        y, _ = _block(cfg, p, carry, rope, positions, None, None, r,
-                      deterministic, sp_mesh=sp_mesh, sp_inside=sp_inside,
-                      adp=adp, kind=kind)
-        return y
+        return _block(cfg, p, carry, rope, positions, r, deterministic,
+                      sp_mesh=sp_mesh, sp_inside=sp_inside, adp=adp,
+                      kind=kind)
 
     # the scan runs over PERIODS of layers: one layer where all are of one
     # kind, else ``cfg.layer_kinds`` unlike layers in a row, their stacked
@@ -881,103 +824,19 @@ def unstack_blocks(params: Params, cfg: ModelConfig) -> list:
     return out
 
 
-def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                       cache: Params,
-                       blocks_list: Optional[list] = None,
-                       lora: Optional[Params] = None,
-                       lora_scaling=1.0,
-                       lora_blocks_list: Optional[list] = None
-                       ) -> Tuple[jnp.ndarray, Params]:
-    """Decode forward: process ``tokens`` (B, Tq) given ``cache`` holding
-    ``cache['length']`` valid positions; returns (fp32 logits (B, Tq, V),
-    updated cache). Static shapes throughout — jit-friendly.
-
-    The layer loop is a plain Python loop (decode bodies are small; the
-    r4 scan-unroll measured +14% over the rolled loop, and the explicit
-    loop additionally lets per-layer cache buffers alias — see
-    ``init_cache``). Pass ``blocks_list`` (from ``unstack_blocks``) when
-    calling inside a sampling loop so the per-layer weight slices are
-    hoisted out of it.
-
-    Contract: the caller must ensure ``cache['length'] + Tq <= max_length``
-    (the cache allocation). Under jit an overflow cannot raise —
-    ``dynamic_update_slice`` would clamp the write offset and silently
-    overwrite the newest entries. The generation loop sizes its cache to
-    cover the full decode so this never triggers.
-    """
-    rope = _rope_tables(cfg)
-    length = cache["length"]
-    B, Tq = tokens.shape
-    positions = length + jnp.arange(Tq)
-
-    x = _embed(cfg, params, tokens, positions, None, True)
-
-    if blocks_list is None:
-        blocks_list = unstack_blocks(params, cfg)
-    if lora is not None and lora_blocks_list is None:
-        lora_blocks_list = unstack_lora_blocks(lora, cfg)
-
-    use_fused_step = (not cfg.has_window_layers
-                      and _use_fused_decode(cfg, cache, Tq))
-
-    new_k, new_v = [], []
-    for l, (p, K, V) in enumerate(zip(blocks_list, cache["k"], cache["v"])):
-        adp = (_block_adp(lora_blocks_list[l], lora_scaling)
-               if lora_blocks_list is not None else None)
-        kind = cfg.layer_kind(l)
-        h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
-                            positions,
-                            adp=adp["attn"] if adp is not None else None)
-        if use_fused_step:
-            # fused in-place append + attention (ops/decode_step.py): the
-            # pallas input_output_aliases declaration is what finally stops
-            # XLA from copying the whole cache every token (r5 profiles)
-            from building_llm_from_scratch_tpu.ops.decode_step import (
-                fused_decode_step,
-            )
-
-            out, K, V = fused_decode_step(q, k.astype(K.dtype),
-                                          v.astype(V.dtype), K, V, length)
-        else:
-            # (B, Tq, Hkv, hd) -> cache-native (B, Hkv, Tq, hd) — tiny
-            K = jax.lax.dynamic_update_slice(
-                K, k.transpose(0, 2, 1, 3).astype(K.dtype),
-                (0, 0, length, 0))
-            V = jax.lax.dynamic_update_slice(
-                V, v.transpose(0, 2, 1, 3).astype(V.dtype),
-                (0, 0, length, 0))
-            # (this cache's buffers are all as long as the sequence, so a
-            # 'sliding' layer needs its window in the mask and no ring)
-            out = decode_attention(q, K, V, q_positions=positions,
-                                   kv_length=length + Tq,
-                                   window=_layer_window(cfg, kind))
-        new_k.append(K)
-        new_v.append(V)
-        x = _add_branches(
-            cfg, p, x, h,
-            _attn_out_proj(p["attn"], out, B, Tq,
-                           adp=adp["attn"] if adp is not None else None),
-            adp=adp)
-    x = _norm(cfg, params["final_norm"], x)
-    logits = _logits(params, x,
-                     lora["head"]["weight"] if lora is not None else None,
-                     lora_scaling)
-    new_cache = {"k": new_k, "v": new_v, "length": length + Tq}
-    return logits, new_cache
-
-
 # ---------------------------------------------------------------------------
-# Slot-batched decode path (serving/engine.py)
+# The slot pass: ONE block loop for every cached program
 #
-# The one-shot decode above shares ONE scalar ``length`` across the whole
+# ``forward_with_cache`` shares ONE scalar ``length`` across the whole
 # batch — every row is the same request family. The continuous-batching
-# engine instead keeps a fixed (n_slots, Tmax) cache where every row is an
-# INDEPENDENT request at its own sequence length: prefill writes one
-# request's prompt k/v into one slot, and a decode tick advances all active
-# slots by one token with per-row positions/lengths. Both are static-shape
-# programs: XLA compiles one prefill per prompt-length bucket and exactly
-# one decode step.
+# engine (serving/engine.py) instead keeps a fixed (n_slots, Tmax) cache
+# where every row is an INDEPENDENT request at its own sequence length:
+# prefill writes one request's prompt k/v into one slot, and a decode tick
+# advances all active slots by one token with per-row positions/lengths.
+# All are static-shape programs (one prefill per prompt-length bucket or
+# ONE chunk program, and exactly one tick program) of the same transformer;
+# only where a layer WRITES its keys and values and how it ATTENDS over
+# them differ, and an access object (``_SlotKV``) owns both.
 # ---------------------------------------------------------------------------
 
 def init_slot_cache(cfg: ModelConfig, n_slots: int, max_length: int,
@@ -988,7 +847,7 @@ def init_slot_cache(cfg: ModelConfig, n_slots: int, max_length: int,
     ``policy`` (serving.kvcache.KVCachePolicy) owns layout and dtype:
     the default reproduces the historical model-dtype cache; the int8
     policy allocates int8 k/v plus fp32 per-position scale sidecars
-    (``k_scale``/``v_scale`` lists) that the slot paths below fill on
+    (``k_scale``/``v_scale`` lists) that the access objects below fill on
     append and ``decode_attention`` folds back in."""
     from building_llm_from_scratch_tpu.serving.kvcache import (
         DEFAULT_POLICY,
@@ -997,64 +856,145 @@ def init_slot_cache(cfg: ModelConfig, n_slots: int, max_length: int,
     return (policy or DEFAULT_POLICY).alloc(cfg, n_slots, max_length)
 
 
-def _slot_adapter_layers(adapter, cfg: ModelConfig):
-    """Gather the batch's per-row adapter matrices from the stacked pool
-    and return (per-layer adp dicts, head node, scales) for the slot
-    loops. ``adapter`` = {"pool": stacked lora tree, "scaling": (N,),
-    "ids": (B,)}; ``None`` -> all-None (exact base path)."""
+def _use_bgmv(adapter, cfg: ModelConfig) -> bool:
+    """Route per-row adapter deltas through the fused pallas BGMV kernel
+    (ops/decode_step.lora_bgmv). Opt-in via BLLM_BGMV=1 on TPU, kept off
+    by default until a hardware A/B proves it, and only when EVERY adapted
+    projection's (in, rank, out) is kernel-eligible; the XLA gather+einsum
+    path is the reference."""
+    import os as _os
+
+    if adapter is None or jax.default_backend() != "tpu":
+        return False
+    if _os.environ.get("BLLM_BGMV", "0") != "1":
+        return False
+    from building_llm_from_scratch_tpu.ops.decode_step import (
+        supports_lora_shape,
+    )
+
+    r = adapter["pool"]["blocks"]["attn"]["wq"]["A"].shape[-1]
+    D, F = cfg.emb_dim, cfg.hidden_dim
+    wq, wkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_groups * cfg.head_dim
+    dims = [(D, wq), (D, wkv), (wq, D), (D, F), (F, D)]
+    return all(supports_lora_shape(i, r, o) for i, o in dims)
+
+
+def _bgmv_block_adp(pool_blocks_l, ids, scaling) -> Params:
+    """Per-layer adp dict whose nodes route through the fused kernel:
+    each projection carries its (N, in, r)/(N, r, out) pool panes — the
+    kernel gathers per-row inside, driven by ``ids``."""
+    def node(n):
+        return {"bgmv": (n["A"], n["B"], ids, scaling)}
+
+    out = {}
+    for group in ("attn", "mlp"):
+        out[group] = {name: node(n)
+                      for name, n in pool_blocks_l[group].items()}
+        out[group]["s"] = None
+    return out
+
+
+def _slot_adapter_layers(adapter, cfg: ModelConfig, Tq: int):
+    """``_slot_pass``'s adapter nodes (per-layer adp dicts, head node, head
+    scale) from ``adapter``; ``None`` -> all-None (exact base path). A POOL,
+    {"pool": stacked lora tree, "scaling": (N,), "ids": (B,)}: the batch's
+    per-row matrices are gathered from it ONCE, here; under ``_use_bgmv``
+    (the kernel is single-token-only) each projection gets its pool panes
+    instead and the kernel gathers inside. ONE shared adapter
+    (``forward_with_cache``), {"lora_blocks": per-layer lora nodes or None,
+    "head": its node or None, "scaling"}: every row the same nodes."""
     if adapter is None:
         return None, None, None
-    rows, s = _adapter_rows(adapter["pool"], adapter["scaling"],
-                            adapter["ids"])
-    # rows["blocks"] leaves are (B, L, in, r): slice each layer's view
-    # once, trace-time (the gather itself happened once, above)
-    layers = [
-        _block_adp(jax.tree_util.tree_map(lambda a, l=l: a[:, l],
-                                          rows["blocks"]), s)
-        for l in range(cfg.n_layers)
-    ]
+    if "lora_blocks" in adapter:
+        s = adapter["scaling"]
+        layers = adapter["lora_blocks"]
+        return (None if layers is None else [_block_adp(lb, s)
+                                             for lb in layers],
+                adapter["head"], s)
+    scaling, L = adapter["scaling"], cfg.n_layers
+    # a stacked tree's leaves are (rows, L, ...): slice each layer's view
+    # once, trace-time (a gather happens once, before)
+    layer = lambda tree, l: jax.tree_util.tree_map(lambda a: a[:, l], tree)
+    if Tq == 1 and _use_bgmv(adapter, cfg):
+        ids = adapter["ids"].astype(jnp.int32)
+        layers = [_bgmv_block_adp(layer(adapter["pool"]["blocks"], l), ids,
+                                  scaling) for l in range(L)]
+        # head delta stays on the gathered path (vocab width is not
+        # kernel-eligible); the gather is tiny at (S, D, r)/(S, r, V)
+        rows, s = _adapter_rows({"head": adapter["pool"]["head"]}, scaling,
+                                ids)
+    else:
+        rows, s = _adapter_rows(adapter["pool"], scaling, adapter["ids"])
+        layers = [_block_adp(layer(rows["blocks"], l), s) for l in range(L)]
     return layers, rows["head"]["weight"], s
 
+
+def _slot_pass(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+               kv: "_SlotKV", *, blocks_list: Optional[list] = None,
+               adapter: Optional[Params] = None,
+               expert_rows: Optional[list] = None
+               ) -> Tuple[jnp.ndarray, Params]:
+    """THE cached forward: ``tokens`` (B, Tq) at ``kv.positions`` through
+    every block, each layer's keys and values written and read through
+    ``kv``; returns (fp32 logits (B, Tq, V), or (B, 1, V) at the one
+    position ``kv.logits_at``; the updated cache ``kv.result()``).
+
+    The layer loop is a plain Python loop (decode bodies are small; the
+    r4 scan-unroll measured +14% over the rolled loop, and the explicit
+    loop additionally lets per-layer cache buffers alias — see
+    ``init_cache``). ``blocks_list`` (from ``unstack_blocks``) hoists the
+    per-layer weight slices out of a caller's sampling loop. ``adapter``:
+    see ``_slot_adapter_layers``. ``kv.live`` and ``expert_rows`` are the
+    expert layer's (``_ffn``).
+
+    The ORDER in which this body first asks ``kv`` for its positions, its
+    live mask and its logits position is the order in which the programs
+    create those operations, and XLA names instructions in that order:
+    ``scripts/serving_hlo.py`` holds the cells' five programs to identical
+    text across a refactor, so move a line here only with that check."""
+    B, Tq = tokens.shape
+    rope = _rope_tables(cfg)
+    positions = kv.positions
+    x = _embed(cfg, params, tokens, positions, None, True)
+    if blocks_list is None:
+        blocks_list = unstack_blocks(params, cfg)
+    adp_layers, head_node, head_s = _slot_adapter_layers(adapter, cfg, Tq)
+    live = kv.live
+    for l, p in enumerate(blocks_list):
+        adp = adp_layers[l] if adp_layers is not None else None
+        attn_adp = adp["attn"] if adp is not None else None
+        kind = cfg.layer_kind(l)
+        h = _norm(cfg, p["norm1"], x)
+        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
+                            positions, adp=attn_adp)
+        out = kv.append_and_attend(l, kind, q, k, v)
+        x = _add_branches(
+            cfg, p, x, h,
+            _attn_out_proj(p["attn"], out, B, Tq, adp=attn_adp),
+            adp=adp, live=live, expert_rows=expert_rows)
+    x = _norm(cfg, params["final_norm"], x)
+    if kv.logits_at is not None:
+        x = jax.lax.dynamic_slice(x, (0, kv.logits_at, 0),
+                                  (1, 1, x.shape[-1]))
+    return _logits(params, x, head_node, head_s), kv.result()
+
+
+# -- where the keys and values live ----------------------------------------
 
 def _cache_quantized(cache: Params) -> bool:
     return "k_scale" in cache
 
 
-@jax.named_scope("cache_update")
-def _slot_write(cache: Params, name: str, pane: jnp.ndarray, offsets: tuple,
-                new: Params) -> None:
-    """Append one layer's cache write into the ``new`` accumulator:
-    plain dynamic-update-slice for float caches; quantize-then-write
-    (int8 codes + the fp32 scale sidecar) for int8 caches. ``pane`` is
-    cache-native (1, Hkv, T, hd); ``offsets`` the 4-d DUS origin."""
-    buf = cache[name][len(new[name])]
-    if _cache_quantized(cache):
-        from building_llm_from_scratch_tpu.ops.decode_step import quantize_kv
-
-        codes, scale = quantize_kv(pane)
-        sbuf = cache[name + "_scale"][len(new[name + "_scale"])]
-        new[name + "_scale"].append(
-            jax.lax.dynamic_update_slice(sbuf, scale, offsets))
-        pane = codes
-    new[name].append(
-        jax.lax.dynamic_update_slice(buf, pane.astype(buf.dtype), offsets))
-
-
-def _new_cache_acc(cache: Params) -> Params:
-    return {name: [] for name in cache}
-
-
 def kv_append_path(cache: Params, Tq: int,
                    backend: Optional[str] = None) -> str:
-    """THE rule for how ``_slot_append_kv`` writes a tick's keys and
-    values, made once, at trace time, on what the code can observe:
-    ``"lane_window"`` (ops/decode_step.lane_window_append: one in-place
-    kernel call a layer) on a TPU backend for the shapes
-    ``supports_lane_append`` admits, ``"scatter"`` (the per-row
-    ``slot_cache_append``) for everything else: verify (Tq = k+1), int8
-    caches, ``head_dim`` 128, any other backend. The engine reports the
-    name (``stats()["kv_append"]``). ``backend`` is for tests, which
-    have no TPU to ask about."""
+    """THE rule for how ``_RowsKV`` writes a tick's keys and values, made
+    once, at trace time, on what the code can observe: ``"lane_window"``
+    (ops/decode_step.lane_window_append: one in-place kernel call a layer)
+    on a TPU backend for the shapes ``supports_lane_append`` admits,
+    ``"scatter"`` (the per-row ``slot_cache_append``) for everything else:
+    verify (Tq = k+1), int8 caches, ``head_dim`` 128, any other backend.
+    The engine reports the name (``stats()["kv_append"]``). ``backend`` is
+    for tests, which have no TPU to ask about."""
     from building_llm_from_scratch_tpu.ops.decode_step import (
         supports_lane_append,
     )
@@ -1072,8 +1012,8 @@ def kv_append_path(cache: Params, Tq: int,
 def decode_attention_path(cache: Params, Tq: int, n_heads: int, *,
                           layer: int = 0, ring: bool = False,
                           backend: Optional[str] = None) -> str:
-    """THE rule for how ``decode_slots`` attends in layer ``layer``, the
-    sibling of ``kv_append_path`` and made the same way: ``"live_blocks"``
+    """THE rule for how ``_RowsKV`` attends in layer ``layer``, the sibling
+    of ``kv_append_path`` and made the same way: ``"live_blocks"``
     (ops/decode_step.live_block_attention: one kernel call a layer that
     reads, for each row, only the lane blocks its live positions reach) on
     a TPU backend for the shapes ``supports_live_attention`` admits,
@@ -1098,64 +1038,23 @@ def decode_attention_path(cache: Params, Tq: int, n_heads: int, *,
 
 
 @jax.named_scope("cache_update")
-def _slot_append_kv(cache: Params, new: Params, l: int,
-                    K: jnp.ndarray, V: jnp.ndarray,
-                    k: jnp.ndarray, v: jnp.ndarray,
-                    lengths: jnp.ndarray):
-    """Per-row append of one layer's fresh k/v (model layout (S, Tq,
-    Hkv, hd)) into the slot cache at each row's offset, quantizing on
-    write under the int8 policy (codes + fp32 scale sidecars). THE one
-    inner write rule shared by ``decode_slots`` (Tq=1) and
-    ``verify_slots`` (Tq=k+1): the speculative path's bit-parity with
-    plain decode depends on these two appends never drifting, and the
-    two forms of the write (``kv_append_path``) leave the same bits.
-    Returns the appended (K, V) buffers (also pushed onto ``new``)."""
-    from building_llm_from_scratch_tpu.ops.decode_step import (
-        lane_window_append,
-        quantize_kv,
-        slot_cache_append,
-    )
+def _slot_write(cache: Params, name: str, pane: jnp.ndarray, offsets: tuple,
+                new: Params) -> None:
+    """Append one layer's cache write into the ``new`` accumulator:
+    plain dynamic-update-slice for float caches; quantize-then-write
+    (int8 codes + the fp32 scale sidecar) for int8 caches. ``pane`` is
+    cache-native (B, Hkv, T, hd); ``offsets`` the 4-d DUS origin."""
+    buf = cache[name][len(new[name])]
+    if _cache_quantized(cache):
+        from building_llm_from_scratch_tpu.ops.decode_step import quantize_kv
 
-    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    if kv_append_path(cache, k.shape[1]) == "lane_window":
-        K, V = lane_window_append(
-            K, V, kt, vt, lengths,
-            interpret=jax.default_backend() != "tpu")
-    else:
-        if _cache_quantized(cache):
-            kt, ks = quantize_kv(kt)
-            vt, vs = quantize_kv(vt)
-            new["k_scale"].append(slot_cache_append(
-                cache["k_scale"][l], ks, lengths))
-            new["v_scale"].append(slot_cache_append(
-                cache["v_scale"][l], vs, lengths))
-        K = slot_cache_append(K, kt, lengths)
-        V = slot_cache_append(V, vt, lengths)
-    new["k"].append(K)
-    new["v"].append(V)
-    return K, V
-
-
-def _slot_attend(cfg: ModelConfig, cache: Params, new: Params, l: int,
-                 q: jnp.ndarray, K: jnp.ndarray, V: jnp.ndarray,
-                 lengths: jnp.ndarray, ring_kw: dict) -> jnp.ndarray:
-    """A decode tick's attention in layer ``l``: each row's one query (at
-    position ``lengths``) against its own appended prefix of (K, V). The
-    two forms (``decode_attention_path``) are the same arithmetic: the
-    kernel leaves unread what ``decode_attention`` reads and masks to 0."""
-    with _attention_scope(bool(ring_kw)):
-        if decode_attention_path(cache, 1, cfg.n_heads, layer=l,
-                                 ring=bool(ring_kw)) == "live_blocks":
-            from building_llm_from_scratch_tpu.ops.decode_step import (
-                live_block_attention,
-            )
-
-            return live_block_attention(
-                q, K, V, lengths + 1,
-                interpret=jax.default_backend() != "tpu")
-        return decode_attention(q, K, V, q_positions=lengths[:, None],
-                                kv_length=lengths + 1, **ring_kw,
-                                **_layer_scales(new, l))
+        codes, scale = quantize_kv(pane)
+        sbuf = cache[name + "_scale"][len(new[name + "_scale"])]
+        new[name + "_scale"].append(
+            jax.lax.dynamic_update_slice(sbuf, scale, offsets))
+        pane = codes
+    new[name].append(
+        jax.lax.dynamic_update_slice(buf, pane.astype(buf.dtype), offsets))
 
 
 def _layer_scales(cache: Params, l: int, slot: Optional[jnp.ndarray] = None
@@ -1204,362 +1103,354 @@ def _ring_step(cfg: ModelConfig, kind: str, R: int, lengths: jnp.ndarray):
                          "window": cfg.sliding_window}
 
 
-def prefill_into_slot(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                      prompt_len: jnp.ndarray, slot: jnp.ndarray,
-                      cache: Params, blocks_list: Optional[list] = None,
-                      adapter: Optional[Params] = None
-                      ) -> Tuple[jnp.ndarray, Params]:
-    """Run one request's prompt (``tokens`` (1, Tpb), right-padded to its
-    length bucket) and write its k/v panes into row ``slot`` of the slot
-    cache; returns (last-real-position logits (V,), updated cache).
+def _refuse_ring(kind: str, what: str) -> None:
+    """The layouts that have no ring say so (``configs.UNSUPPORTED`` has
+    the engine refuse them first, by what the config is)."""
+    if kind == "sliding":
+        raise ValueError(f"{what} has no ring for a 'sliding' layer")
 
-    Attention here is plain causal self-attention over the prompt itself
-    (nothing earlier lives in the slot), with ``kv_length=prompt_len``
-    masking the pad keys. Pad-position k/v are ZEROED before the write —
-    they used to land as garbage masked only by the engine's host-side
-    lengths, which was fine while slot contents stayed request-private;
-    prefix panes (serving/kvcache.py) make them shareable state, so
-    every cache write must be a deterministic function of the prompt.
 
-    ``adapter``: {"pool", "scaling", "ids" (1,)} — the request's LoRA
-    adapter applied unmerged at every adapted projection (id −1 = base).
-    The prompt's k/v land in the slot ALREADY adapter-transformed, so
-    decode ticks attend to a prefix consistent with the same adapter.
-    """
-    _, Tpb = tokens.shape
-    rope = _rope_tables(cfg)
-    positions = jnp.arange(Tpb)
-    x = _embed(cfg, params, tokens, positions, None, True)
-    if blocks_list is None:
-        blocks_list = unstack_blocks(params, cfg)
-    adp_layers, head_node, head_s = _slot_adapter_layers(adapter, cfg)
-    # pad-position zero mask, model layout (1, Tpb, 1, 1)
-    valid = (positions < prompt_len)[None, :, None, None]
-    new = _new_cache_acc(cache)
-    for l, p in enumerate(blocks_list):
-        adp = adp_layers[l] if adp_layers is not None else None
-        kind = cfg.layer_kind(l)
-        window = _layer_window(cfg, kind)
-        if Tpb > cache["k"][l].shape[2]:
+def _zero_pads(valid: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray):
+    """Pad-position k/v are ZEROED before any cache write. They used to land
+    as garbage masked only by the engine's host-side lengths, which was
+    fine while slot contents stayed request-private; prefix panes
+    (serving/kvcache.py) make them shareable state, so every cache write
+    must be a deterministic function of the prompt."""
+    return (jnp.where(valid, k, jnp.zeros((), k.dtype)),
+            jnp.where(valid, v, jnp.zeros((), v.dtype)))
+
+
+class _SlotKV:
+    """The span of a cache that one pass covers: where its keys and values
+    live and how a query reads them, the one thing the cached programs
+    differ in. ``_slot_pass`` calls ``append_and_attend`` once a layer, in
+    layer order, with the layer's fresh q/k/v in model layout (B, Tq, H,
+    hd); the object writes k/v into its accumulator ``new``, which
+    ``result()`` returns as the updated cache, and returns the attention
+    output (B, Tq, Hq, hd). Everything about a cache's layout is in its
+    object and nowhere else: the write (pane, per-row scatter, lane kernel,
+    page table; int8 quantise-on-write with its sidecars), the read, the
+    ring arithmetic, the pad-zeroing. A later layout (a latent cache, a
+    ``head_dim``-128 twin of the lane kernels, an append that rides the
+    attention call) is one more of these, or a change inside one.
+
+    With it the span's geometry, which the same facts decide: ``positions``
+    of the pass's tokens, ``live`` ((B, Tq) bool or None: the positions that
+    are real, for the expert layer) and ``logits_at`` (the one position
+    whose logits a prefill serves; None: all). Each is computed when
+    ``_slot_pass`` first asks (``cached_property``), not at construction:
+    see its note on the order of operations."""
+
+    valid = None        # (1, T, 1, 1): the real positions of a padded span
+    logits_at = None
+
+    def __init__(self, cfg: ModelConfig, cache: Params):
+        self.cfg, self.cache = cfg, cache
+        self.new: Params = {name: [] for name in cache if name != "length"}
+
+    def append_and_attend(self, l: int, kind: str, q, k, v) -> jnp.ndarray:
+        raise NotImplementedError
+
+    def result(self) -> Params:
+        return self.new
+
+    @property
+    def live(self):
+        return None if self.valid is None else self.valid[:, :, 0, 0]
+
+    def _write_panes(self, k, v, offsets: tuple) -> None:
+        # (B, T, Hkv, hd) -> cache-native (B, Hkv, T, hd) panes — tiny
+        _slot_write(self.cache, "k", k.transpose(0, 2, 1, 3), offsets,
+                    self.new)
+        _slot_write(self.cache, "v", v.transpose(0, 2, 1, 3), offsets,
+                    self.new)
+
+
+class _SharedLengthKV(_SlotKV):
+    """``forward_with_cache``: ONE scalar length (``cache['length']``) for
+    the whole batch; k/v land at it in every row and each query attends the
+    prefix to itself. This cache's buffers are all as long as the sequence,
+    so a 'sliding' layer needs its window in the mask and no ring."""
+
+    def __init__(self, cfg, cache, Tq: int):
+        super().__init__(cfg, cache)
+        self.Tq = Tq
+
+    @cached_property
+    def positions(self):
+        return self.cache["length"] + jnp.arange(self.Tq)
+
+    def append_and_attend(self, l, kind, q, k, v):
+        length = self.cache["length"]
+        self._write_panes(k, v, (0, 0, length, 0))
+        return decode_attention(q, self.new["k"][l], self.new["v"][l],
+                                q_positions=self.positions,
+                                kv_length=length + self.Tq,
+                                window=_layer_window(self.cfg, kind))
+
+    def result(self):
+        return dict(self.new, length=self.cache["length"] + self.Tq)
+
+
+class _PromptKV(_SlotKV):
+    """One slot's whole prompt, right-padded to its bucket of Tpb
+    (``prefill_into_slot``): attention is plain causal self-attention over
+    the prompt itself (nothing earlier lives in the slot), with
+    ``kv_length=prompt_len`` masking the pad keys; the zeroed k/v
+    (``_zero_pads``) land as one pane at row ``slot``."""
+
+    def __init__(self, cfg, cache, Tpb: int, slot, prompt_len):
+        super().__init__(cfg, cache)
+        self.Tpb, self.slot, self.prompt_len = Tpb, slot, prompt_len
+
+    @cached_property
+    def positions(self):
+        return jnp.arange(self.Tpb)
+
+    @cached_property
+    def valid(self):
+        # pad-position zero mask, model layout (1, Tpb, 1, 1)
+        return (self.positions < self.prompt_len)[None, :, None, None]
+
+    @cached_property
+    def logits_at(self):
+        return self.prompt_len - 1
+
+    def append_and_attend(self, l, kind, q, k, v):
+        R = self.cache["k"][l].shape[2]
+        if self.Tpb > R:
             raise ValueError(
-                f"a {Tpb}-token prompt bucket does not fit layer {l}'s "
-                f"ring of {cache['k'][l].shape[2]} positions: rings are "
+                f"a {self.Tpb}-token prompt bucket does not fit layer {l}'s "
+                f"ring of {R} positions: rings are "
                 "filled by chunked prefill (KVCachePolicy.prefill_chunk)")
-        h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
-                            positions,
-                            adp=adp["attn"] if adp is not None else None)
+        window = _layer_window(self.cfg, kind)
         with _attention_scope(window is not None):
-            out = causal_attention(q, k, v, q_positions=positions,
-                                   kv_length=prompt_len, window=window)
-        # (1, Tpb, Hkv, hd) -> cache-native (1, Hkv, Tpb, hd) pane at
-        # (slot, 0, 0, 0); Tpb <= Tmax by the engine's admission check
-        k = jnp.where(valid, k, jnp.zeros((), k.dtype))
-        v = jnp.where(valid, v, jnp.zeros((), v.dtype))
-        _slot_write(cache, "k", k.transpose(0, 2, 1, 3), (slot, 0, 0, 0),
-                    new)
-        _slot_write(cache, "v", v.transpose(0, 2, 1, 3), (slot, 0, 0, 0),
-                    new)
-        x = _add_branches(
-            cfg, p, x, h,
-            _attn_out_proj(p["attn"], out, 1, Tpb,
-                           adp=adp["attn"] if adp is not None else None),
-            adp=adp, live=valid[:, :, 0, 0])
-    x = _norm(cfg, params["final_norm"], x)
-    last = jax.lax.dynamic_slice(x, (0, prompt_len - 1, 0),
-                                 (1, 1, x.shape[-1]))
-    logits = _logits(params, last, head_node, head_s)
-    return logits[0, 0], new
+            out = causal_attention(q, k, v, q_positions=self.positions,
+                                   kv_length=self.prompt_len, window=window)
+        # a pane at (slot, 0, 0, 0); Tpb <= Tmax by the engine's admission
+        self._write_panes(*_zero_pads(self.valid, k, v),
+                          (self.slot, 0, 0, 0))
+        return out
 
 
-def prefill_chunk_into_slot(params: Params, cfg: ModelConfig,
-                            tokens: jnp.ndarray, chunk_start: jnp.ndarray,
-                            prompt_len: jnp.ndarray, slot: jnp.ndarray,
-                            cache: Params,
-                            blocks_list: Optional[list] = None,
-                            adapter: Optional[Params] = None
-                            ) -> Tuple[jnp.ndarray, Params]:
-    """Chunked prefill: process ``tokens`` (1, C) — the prompt span
-    [chunk_start, chunk_start + C), right-padded past ``prompt_len`` —
-    against row ``slot`` whose positions [0, chunk_start) already hold
-    valid KV (earlier chunks, or a copied prefix pane,
-    serving/kvcache.py). Returns (logits at the clamped position
-    ``prompt_len - 1 - chunk_start`` (V,), updated cache).
+class _ChunkKV(_SlotKV):
+    """One slot's prompt span [chunk_start, chunk_start + C), right-padded
+    past ``prompt_len`` (``prefill_chunk_into_slot``): the zeroed chunk
+    lands in row ``slot`` (in a ring: at ``chunk_start mod R``), then the
+    chunk attends over THAT row, freshly including itself: earlier chunks /
+    a copied prefix pane are the context. The logits read is clamped to a
+    valid row of the chunk. With ``table`` the row is reached through its
+    lane of the page table (below): the C positions scatter into its pages
+    and attention gathers that one row's view; any position past the row's
+    allocated frontier lands on the trash page — never read unmasked."""
 
-    The chunk width C is STATIC: every prompt of every length prefills
-    through this ONE compiled program (chunk_start/prompt_len/slot are
-    data) — both the one-compiled-program invariant and the per-tick
-    prefill bound. A 2k-token prompt becomes 2k/C short calls the
-    engine interleaves with decode ticks instead of one tick-stalling
-    program.
+    def __init__(self, cfg, cache, C: int, slot, chunk_start, prompt_len,
+                 table=None, cache_len=None):
+        super().__init__(cfg, cache)
+        self.C, self.slot = C, slot
+        self.chunk_start, self.prompt_len = chunk_start, prompt_len
+        self.table, self.cache_len = table, cache_len
 
-    Masking: the chunk's own k/v zero at pad positions (>= prompt_len)
-    BEFORE the cache write, and attention clamps ``kv_length`` to
-    ``prompt_len`` so the zeros are never attended either. Pad QUERY
-    rows compute garbage that stays in their own (position-wise) lanes;
-    the logits read is clamped to a valid row.
-    """
-    _, C = tokens.shape
-    rope = _rope_tables(cfg)
-    positions = chunk_start + jnp.arange(C)
-    x = _embed(cfg, params, tokens, positions, None, True)
-    if blocks_list is None:
-        blocks_list = unstack_blocks(params, cfg)
-    adp_layers, head_node, head_s = _slot_adapter_layers(adapter, cfg)
-    valid = (positions < prompt_len)[None, :, None, None]
-    kv_len = jnp.reshape(jnp.minimum(chunk_start + C, prompt_len), (1,))
-    q_pos = positions[None, :]                       # (1, C) per-row form
-    new = _new_cache_acc(cache)
-    for l, p in enumerate(blocks_list):
-        adp = adp_layers[l] if adp_layers is not None else None
-        kind = cfg.layer_kind(l)
-        h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
-                            positions,
-                            adp=adp["attn"] if adp is not None else None)
-        k = jnp.where(valid, k, jnp.zeros((), k.dtype))
-        v = jnp.where(valid, v, jnp.zeros((), v.dtype))
-        write_at, ring_kw = _ring_chunk(cfg, kind, cache["k"][l].shape[2],
-                                        chunk_start, C)
-        _slot_write(cache, "k", k.transpose(0, 2, 1, 3),
-                    (slot, 0, write_at, 0), new)
-        _slot_write(cache, "v", v.transpose(0, 2, 1, 3),
-                    (slot, 0, write_at, 0), new)
-        # attend over THIS slot's full row, freshly including the chunk:
-        # earlier chunks / the copied prefix pane are the context
-        K_row = jax.lax.dynamic_slice(
-            new["k"][l], (slot, 0, 0, 0), (1,) + new["k"][l].shape[1:])
-        V_row = jax.lax.dynamic_slice(
-            new["v"][l], (slot, 0, 0, 0), (1,) + new["v"][l].shape[1:])
+    @cached_property
+    def positions(self):
+        return self.chunk_start + jnp.arange(self.C)
+
+    @cached_property
+    def masks(self):
+        """``valid`` (1, C, 1, 1) marks the span's real positions (model
+        layout, for the pad-zeroing), ``kv_len`` (1,) clamps attention to
+        the prompt so the zeros are never attended either, and the per-row
+        form (1, C) of the query positions. Pad QUERY rows compute garbage
+        that stays in their own (position-wise) lanes."""
+        valid = (self.positions < self.prompt_len)[None, :, None, None]
+        kv_len = jnp.reshape(jnp.minimum(self.chunk_start + self.C,
+                                         self.prompt_len), (1,))
+        return valid, kv_len, self.positions[None, :]
+
+    @property
+    def valid(self):
+        return self.masks[0]
+
+    @cached_property
+    def logits_at(self):
+        return jnp.clip(self.prompt_len - 1 - self.chunk_start, 0,
+                        self.C - 1)
+
+    @cached_property
+    def pages(self):
+        """The row's table lane (1, M) and where the chunk's positions land
+        in it: page ids and in-page offsets (C,)."""
+        P = self.cache["k"][0].shape[2]
+        row_tab = jax.lax.dynamic_slice(
+            self.table.astype(jnp.int32), (self.slot, 0),
+            (1, self.table.shape[1]))
+        pos = jnp.minimum(self.positions, self.cache_len - 1)
+        return row_tab, row_tab[0, pos // P], pos % P
+
+    def append_and_attend(self, l, kind, q, k, v):
+        valid, kv_len, q_pos = self.masks
+        k, v = _zero_pads(valid, k, v)
+        if self.table is not None:
+            _refuse_ring(kind, "the paged pool")
+            row_tab, phys, off = self.pages
+            _paged_scatter(self.cache, "k", k[0], phys, off, self.new)
+            _paged_scatter(self.cache, "v", v[0], phys, off, self.new)
+            return _paged_attend(self.new, l, q, row_tab, self.cache_len,
+                                 q_pos, kv_len)
+        write_at, ring_kw = _ring_chunk(
+            self.cfg, kind, self.cache["k"][l].shape[2], self.chunk_start,
+            self.C)
+        self._write_panes(k, v, (self.slot, 0, write_at, 0))
+        K_row, V_row = (jax.lax.dynamic_slice(
+            a, (self.slot, 0, 0, 0), (1,) + a.shape[1:])
+            for a in (self.new["k"][l], self.new["v"][l]))
         with _attention_scope(bool(ring_kw)):
-            out = decode_attention(q, K_row, V_row, q_positions=q_pos,
-                                   kv_length=kv_len, **ring_kw,
-                                   **_layer_scales(new, l, slot))
-        x = _add_branches(
-            cfg, p, x, h,
-            _attn_out_proj(p["attn"], out, 1, C,
-                           adp=adp["attn"] if adp is not None else None),
-            adp=adp, live=valid[:, :, 0, 0])
-    x = _norm(cfg, params["final_norm"], x)
-    idx = jnp.clip(prompt_len - 1 - chunk_start, 0, C - 1)
-    last = jax.lax.dynamic_slice(x, (0, idx, 0), (1, 1, x.shape[-1]))
-    logits = _logits(params, last, head_node, head_s)
-    return logits[0, 0], new
+            return decode_attention(
+                q, K_row, V_row, q_positions=q_pos, kv_length=kv_len,
+                **ring_kw, **_layer_scales(self.new, l, self.slot))
 
 
-def _use_bgmv(adapter, cfg: ModelConfig) -> bool:
-    """Route per-row adapter deltas through the fused pallas BGMV kernel
-    (ops/decode_step.lora_bgmv). Opt-in via BLLM_BGMV=1 on TPU — like
-    BLLM_FUSED_DECODE, kept off by default until a hardware A/B proves it
-    — and only when EVERY adapted projection's (in, rank, out) is
-    kernel-eligible; the XLA gather+einsum path is the reference."""
-    import os as _os
+class _RowsKV(_SlotKV):
+    """Every row of the contiguous slot cache at once (``decode_slots``,
+    Tq = 1; ``verify_slots``, Tq = k+1): each row appends its Tq fresh
+    positions at ITS length and each query attends its own row's prefix to
+    itself. THE one write rule and the one read rule of both: the
+    speculative path's bit-parity with plain decode depends on the two
+    never drifting. The two trace-time rules choose each one's form. With
+    ``table`` ((S, max_pages) int32) rows are reached through the page
+    table (below): candidate k/v scatter at per-row logical offsets,
+    rejected tails sit past ``kv_length`` exactly as in the slot cache."""
 
-    if adapter is None or jax.default_backend() != "tpu":
-        return False
-    if _os.environ.get("BLLM_BGMV", "0") != "1":
-        return False
-    from building_llm_from_scratch_tpu.ops.decode_step import (
-        supports_lora_shape,
-    )
+    def __init__(self, cfg, cache, Tq: int, lengths, live=None, table=None,
+                 cache_len=None):
+        super().__init__(cfg, cache)
+        self.Tq, self.lengths, self._live = Tq, lengths.astype(jnp.int32), live
+        self.table = None if table is None else table.astype(jnp.int32)
+        self.cache_len = cache_len
 
-    r = adapter["pool"]["blocks"]["attn"]["wq"]["A"].shape[-1]
-    D, F = cfg.emb_dim, cfg.hidden_dim
-    wq, wkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_groups * cfg.head_dim
-    dims = [(D, wq), (D, wkv), (wq, D), (D, F), (F, D)]
-    return all(supports_lora_shape(i, r, o) for i, o in dims)
+    @cached_property
+    def positions(self):
+        """Each row's Tq positions from its length on, (S, Tq). At Tq > 1
+        they are CLAMPED: a row near capacity has draft positions past
+        context_length-1; unclamped they would index past the positional
+        tables (jnp.take's out-of-bounds fill is NaN) and the NaN v-pane
+        poisons every query through the value einsum's 0*NaN. Clamped
+        positions only ever affect TAIL candidates that can never be
+        committed (prompt + budget <= max_len by admission), so every
+        committable position keeps its exact positional encoding."""
+        positions = self.lengths[:, None]
+        if self.Tq > 1:
+            positions = jnp.minimum(positions + jnp.arange(self.Tq)[None, :],
+                                    self.cfg.context_length - 1)
+        return positions
 
+    @property
+    def live(self):          # (S,) rows that decode -> their (S, Tq) positions
+        return (None if self._live is None else jnp.broadcast_to(
+            self._live[:, None], (self._live.shape[0], self.Tq)))
 
-def _bgmv_block_adp(pool_blocks_l, ids, scaling) -> Params:
-    """Per-layer adp dict whose nodes route through the fused kernel:
-    each projection carries its (N, in, r)/(N, r, out) pool panes — the
-    kernel gathers per-row inside, driven by ``ids``."""
-    def node(n):
-        return {"bgmv": (n["A"], n["B"], ids, scaling)}
+    def append_and_attend(self, l, kind, q, k, v):
+        if self.table is not None:
+            _refuse_ring(kind, "the paged pool")
+            return self._through_pages(l, q, k, v)
+        if self.Tq > 1:
+            # k+1 positions may wrap a ring, and rejected ones cannot be
+            # taken back from it
+            _refuse_ring(kind, "a verify tick")
+        write_at, ring_kw = _ring_step(
+            self.cfg, kind, self.cache["k"][l].shape[2], self.lengths)
+        K, V = self._append(l, k, v, write_at)
+        return self._attend(l, q, K, V, ring_kw)
 
-    out = {}
-    for group in ("attn", "mlp"):
-        out[group] = {name: node(n)
-                      for name, n in pool_blocks_l[group].items()}
-        out[group]["s"] = None
-    return out
+    @jax.named_scope("cache_update")
+    def _append(self, l, k, v, write_at):
+        """Per-row append of one layer's fresh k/v at each row's offset,
+        quantizing on write under the int8 policy (codes + fp32 scale
+        sidecars); the two forms of the write (``kv_append_path``) leave the
+        same bits. Returns the appended (K, V) buffers."""
+        from building_llm_from_scratch_tpu.ops.decode_step import (
+            lane_window_append,
+            quantize_kv,
+            slot_cache_append,
+        )
 
+        cache, new = self.cache, self.new
+        K, V = cache["k"][l], cache["v"][l]
+        kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        if kv_append_path(cache, self.Tq) == "lane_window":
+            K, V = lane_window_append(
+                K, V, kt, vt, write_at,
+                interpret=jax.default_backend() != "tpu")
+        else:
+            if _cache_quantized(cache):
+                kt, ks = quantize_kv(kt)
+                vt, vs = quantize_kv(vt)
+                new["k_scale"].append(slot_cache_append(
+                    cache["k_scale"][l], ks, write_at))
+                new["v_scale"].append(slot_cache_append(
+                    cache["v_scale"][l], vs, write_at))
+            K = slot_cache_append(K, kt, write_at)
+            V = slot_cache_append(V, vt, write_at)
+        new["k"].append(K)
+        new["v"].append(V)
+        return K, V
 
-def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                 lengths: jnp.ndarray, cache: Params,
-                 blocks_list: Optional[list] = None,
-                 adapter: Optional[Params] = None,
-                 live: Optional[jnp.ndarray] = None,
-                 expert_rows: Optional[list] = None
-                 ) -> Tuple[jnp.ndarray, Params]:
-    """One decode tick for the whole slot batch: ``tokens`` (S, 1) are each
-    slot's last accepted token, ``lengths`` (S,) its valid cache prefix.
-    Appends each row's k/v at ITS offset (``_slot_append_kv``: one in-place
-    ``lane_window_append`` a layer where ``kv_append_path`` admits it, else
-    ``slot_cache_append``'s scatter; the pallas fused step where
-    ``_use_fused_decode`` says so) and attends each row's own prefix
-    (``decode_attention_path``: the live-block kernel where the gate admits
-    it, else ``decode_attention`` with per-row masks); returns
-    (fp32 logits (S, V), updated cache). Free/finished slots compute
-    garbage rows the engine ignores — the shapes never change, so XLA
-    compiles exactly one decode program.
+    def _attend(self, l, q, K, V, ring_kw):
+        """Each row's queries (at ``positions``) against its own appended
+        prefix of (K, V). The two forms (``decode_attention_path``) are the
+        same arithmetic: the kernel leaves unread what ``decode_attention``
+        reads and masks to 0."""
+        with _attention_scope(bool(ring_kw)):
+            if decode_attention_path(self.cache, self.Tq, self.cfg.n_heads,
+                                     layer=l, ring=bool(ring_kw)
+                                     ) == "live_blocks":
+                from building_llm_from_scratch_tpu.ops.decode_step import (
+                    live_block_attention,
+                )
 
-    ``adapter``: {"pool", "scaling", "ids" (S,)} — per-SLOT LoRA adapters
-    applied as a batched gather + einsum (BGMV) fused into the existing
-    projections. Adapter identity is a data dimension: any mix of ids
-    (−1 = base model) runs through this same one compiled program, so
-    hot-loading/evicting adapters never recompiles.
+                return live_block_attention(
+                    q, K, V, self.lengths + 1,
+                    interpret=jax.default_backend() != "tpu")
+            return decode_attention(q, K, V, q_positions=self.positions,
+                                    kv_length=self.lengths + self.Tq,
+                                    **ring_kw, **_layer_scales(self.new, l))
 
-    ``live`` (S,) bool, for a sparse model: the rows that decode a token.
-    The others (free slots, slots in mid-prefill) are routed to no expert,
-    so they read no expert's weights and count for none. ``expert_rows``:
-    a list that collects each sparse layer's rows per held expert (H,).
-    """
-    rope = _rope_tables(cfg)
-    S = tokens.shape[0]
-    lengths = lengths.astype(jnp.int32)
-    positions = lengths[:, None]                       # (S, 1)
-    x = _embed(cfg, params, tokens, positions, None, True)
-    if blocks_list is None:
-        blocks_list = unstack_blocks(params, cfg)
-
-    use_fused_step = (not cfg.has_window_layers
-                      and _use_fused_decode(cfg, cache, 1))
-
-    if _use_bgmv(adapter, cfg):
-        ids = adapter["ids"].astype(jnp.int32)
-        pool_blocks = adapter["pool"]["blocks"]
-        adp_layers = [
-            _bgmv_block_adp(
-                jax.tree_util.tree_map(lambda a, l=l: a[:, l], pool_blocks),
-                ids, adapter["scaling"])
-            for l in range(cfg.n_layers)
-        ]
-        # head delta stays on the gathered path (vocab width is not
-        # kernel-eligible); the gather is tiny at (S, D, r)/(S, r, V)
-        head_rows, head_s = _adapter_rows(
-            {"head": adapter["pool"]["head"]}, adapter["scaling"], ids)
-        head_node = head_rows["head"]["weight"]
-    else:
-        adp_layers, head_node, head_s = _slot_adapter_layers(adapter, cfg)
-
-    new = _new_cache_acc(cache)
-    for l, (p, K, V) in enumerate(zip(blocks_list, cache["k"], cache["v"])):
-        adp = adp_layers[l] if adp_layers is not None else None
-        kind = cfg.layer_kind(l)
-        h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
-                            positions,
-                            adp=adp["attn"] if adp is not None else None)
-        if use_fused_step:
+    def _through_pages(self, l, q, k, v):
+        """The same append and read through ``table``. Write positions
+        clamp to ``cache_len - 1`` (only ever binding for garbage lanes
+        that are masked everywhere, mirroring ``positions``' clamp)."""
+        S, Tq = k.shape[:2]
+        P = self.cache["k"][l].shape[2]
+        pos = jnp.minimum(self.lengths[:, None] + jnp.arange(Tq)[None, :],
+                          self.cache_len - 1)                   # (S, Tq)
+        phys = jnp.take_along_axis(self.table, pos // P, axis=1).reshape(-1)
+        off = (pos % P).reshape(-1)
+        for name, fresh in (("k", k), ("v", v)):
+            _paged_scatter(self.cache, name,
+                           fresh.reshape(S * Tq, *fresh.shape[2:]), phys,
+                           off, self.new)
+        if _use_paged_attn(self.cache, self.cfg, Tq):
             from building_llm_from_scratch_tpu.ops.decode_step import (
-                fused_decode_step,
+                paged_decode_attention,
             )
 
-            out, K, V = fused_decode_step(q, k.astype(K.dtype),
-                                          v.astype(V.dtype), K, V, lengths)
-            new["k"].append(K)
-            new["v"].append(V)
-        else:
-            write_at, ring_kw = _ring_step(cfg, kind, K.shape[2], lengths)
-            K, V = _slot_append_kv(cache, new, l, K, V, k, v, write_at)
-            out = _slot_attend(cfg, cache, new, l, q, K, V, lengths, ring_kw)
-        x = _add_branches(
-            cfg, p, x, h,
-            _attn_out_proj(p["attn"], out, S, 1,
-                           adp=adp["attn"] if adp is not None else None),
-            adp=adp, live=None if live is None else live[:, None],
-            expert_rows=expert_rows)
-    x = _norm(cfg, params["final_norm"], x)
-    logits = _logits(params, x, head_node, head_s)
-    return logits[:, 0], new
+            return paged_decode_attention(q, self.new["k"][l],
+                                          self.new["v"][l], self.table,
+                                          self.lengths)
+        return _paged_attend(self.new, l, q, self.table, self.cache_len,
+                             self.positions, self.lengths + Tq)
 
 
-def verify_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                 lengths: jnp.ndarray, cache: Params,
-                 blocks_list: Optional[list] = None,
-                 adapter: Optional[Params] = None
-                 ) -> Tuple[jnp.ndarray, Params]:
-    """Speculative verify: the Tq = k+1 sibling of ``decode_slots``.
-
-    ``tokens`` (S, Tq) is each slot's last accepted token followed by its
-    k drafted candidates; ``lengths`` (S,) the valid cache prefix per row.
-    ONE forward scores all Tq positions: position j's logits condition on
-    [cache, tokens[:, :j+1]], so they are the model's true next-token
-    distribution exactly when the drafts before j were all accepted — the
-    accept rule (generate.accept_draft_tokens) commits only such
-    prefixes. Appends all Tq candidate k/v panes at per-row offsets (the
-    same ``_slot_append_kv`` rule decode uses: Tq > 1 always takes
-    ``slot_cache_append``'s per-row scatter, quantize-on-write
-    under the int8 policy); the engine advances ``lengths`` by the
-    ACCEPTED count only, so a rejected tail's entries sit past the valid
-    prefix — masked by ``kv_length`` everywhere and overwritten by the
-    next tick's append. No rollback copy exists because none is needed.
-
-    Per-query causality rides the existing ``decode_attention`` per-row
-    masks: query j at absolute position lengths+j attends keys at
-    positions <= lengths+j, i.e. the real prefix plus the drafts before
-    it — never the drafts after it. k is STATIC: every acceptance count
-    0..k+1 flows through this one compiled program, preserving the
-    engine's one-compiled-program invariant.
-
-    Free/mid-prefill slots ride as ignored rows exactly as in
-    ``decode_slots``: their appends land at the row's next write
-    position and are overwritten before anything reads them.
-
-    Returns (fp32 logits (S, Tq, V), updated cache).
-    """
-    rope = _rope_tables(cfg)
-    S, Tq = tokens.shape
-    lengths = lengths.astype(jnp.int32)
-    # position CLAMP: a row near capacity has draft positions past
-    # context_length-1; unclamped they would index past the positional
-    # tables (jnp.take's out-of-bounds fill is NaN) and the NaN v-pane
-    # poisons every query through the value einsum's 0*NaN. Clamped
-    # positions only ever affect TAIL candidates that can never be
-    # committed (prompt + budget <= max_len by admission), so every
-    # committable position keeps its exact positional encoding.
-    positions = jnp.minimum(
-        lengths[:, None] + jnp.arange(Tq)[None, :],
-        cfg.context_length - 1)                                # (S, Tq)
-    x = _embed(cfg, params, tokens, positions, None, True)
-    if blocks_list is None:
-        blocks_list = unstack_blocks(params, cfg)
-
-    # adapter application mirrors decode_slots' gathered path (the pallas
-    # BGMV kernel is single-token-only; a Tq-wide variant is a TPU
-    # follow-up — the XLA gather+einsum is the reference either way)
-    adp_layers, head_node, head_s = _slot_adapter_layers(adapter, cfg)
-
-    new = _new_cache_acc(cache)
-    for l, (p, K, V) in enumerate(zip(blocks_list, cache["k"], cache["v"])):
-        adp = adp_layers[l] if adp_layers is not None else None
-        h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
-                            adp=adp["attn"] if adp is not None else None)
-        K, V = _slot_append_kv(cache, new, l, K, V, k, v, lengths)
-        out = decode_attention(q, K, V, q_positions=positions,
-                               kv_length=lengths + Tq,
-                               **_layer_scales(new, l))
-        x = x + _attn_out_proj(p["attn"], out, S, Tq,
-                               adp=adp["attn"] if adp is not None else None)
-        x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),
-                     adp=adp["mlp"] if adp is not None else None)
-    x = _norm(cfg, params["final_norm"], x)
-    logits = _head_logits(x, params["head"]["weight"], head_node, head_s)
-    return logits, new
-
-
-# ---------------------------------------------------------------------------
-# Paged slot paths (KVCachePolicy.paged; serving/engine.py)
+# -- through a page table (KVCachePolicy.paged) ------------------------------
 #
-# Same programs as the contiguous slot paths above with ONE layout change:
-# a row no longer owns a contiguous (Tmax,) lane — a per-slot int32 page
-# table maps each row's logical positions onto fixed-size pages of a
-# shared pool (cache leaves are (n_pages, Hkv, page_tokens, hd)). The
-# table rides every call as traced DATA against static shapes (the
-# adapter-pool trick), so page churn — prefix hits, frees, eviction,
-# oversubscription — never recompiles anything.
+# The same programs with ONE layout change: a row no longer owns a
+# contiguous (Tmax,) lane — a per-slot int32 page table maps each row's
+# logical positions onto fixed-size pages of a shared pool (cache leaves are
+# (n_pages, Hkv, page_tokens, hd)). The table rides every call as traced
+# DATA against static shapes (the adapter-pool trick), so page churn —
+# prefix hits, frees, eviction, oversubscription — never recompiles
+# anything. ``cache_len`` is the static logical row length (the engine's
+# ``_cache_len``), identical to the contiguous buffer width.
 #
 # Bit-parity with the contiguous layout is by construction: appends write
 # identical values at identical logical positions (the int8 quantization
@@ -1576,7 +1467,6 @@ def verify_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 # scatter there and are only ever read masked. Duplicate scatter indices
 # therefore only ever collide on the trash page or on pad zeros — the
 # nondeterminism XLA allows for them can never reach an unmasked read.
-# ---------------------------------------------------------------------------
 
 def _paged_scatter(cache: Params, name: str, vals: jnp.ndarray,
                    phys: jnp.ndarray, off: jnp.ndarray, new: Params) -> None:
@@ -1596,35 +1486,15 @@ def _paged_scatter(cache: Params, name: str, vals: jnp.ndarray,
     new[name].append(buf.at[phys, :, off].set(vals.astype(buf.dtype)))
 
 
-def _paged_append_kv(cache: Params, new: Params, l: int,
-                     k: jnp.ndarray, v: jnp.ndarray,
-                     lengths: jnp.ndarray, page_table: jnp.ndarray,
-                     cache_len: int) -> None:
-    """Paged sibling of ``_slot_append_kv``: append one layer's fresh
-    k/v (model layout (S, Tq, Hkv, hd)) at each row's logical offsets,
-    routed through the page table. Positions clamp to ``cache_len - 1``
-    (only ever binding for garbage lanes that are masked everywhere,
-    mirroring ``verify_slots``' position clamp)."""
-    S, Tq = k.shape[:2]
-    P = cache["k"][l].shape[2]
-    pos = jnp.minimum(lengths[:, None] + jnp.arange(Tq)[None, :],
-                      cache_len - 1)                        # (S, Tq)
-    phys = jnp.take_along_axis(page_table, pos // P, axis=1).reshape(-1)
-    off = (pos % P).reshape(-1)
-    _paged_scatter(cache, "k", k.reshape(S * Tq, *k.shape[2:]), phys, off,
-                   new)
-    _paged_scatter(cache, "v", v.reshape(S * Tq, *v.shape[2:]), phys, off,
-                   new)
-
-
 def _paged_view(leaf: jnp.ndarray, page_table: jnp.ndarray,
                 cache_len: int) -> jnp.ndarray:
     """Gather a (rows, Hkv, cache_len, ...) row-major view out of the
     pool leaf (n_pages, Hkv, P, ...) through the page table (rows, M):
     the XLA reference for page-table attention — downstream
     ``decode_attention`` is completely unchanged, which is what pins
-    bit-parity. The TPU pallas kernel (ops/decode_step.paged_gather_kv)
-    computes the same gather without materializing it per layer."""
+    bit-parity. The TPU pallas kernel
+    (ops/decode_step.paged_decode_attention) computes the same gather
+    without materializing it per layer."""
     g = leaf[page_table]                    # (rows, M, Hkv, P, ...)
     g = jnp.moveaxis(g, 2, 1)               # (rows, Hkv, M, P, ...)
     shape = g.shape
@@ -1632,28 +1502,12 @@ def _paged_view(leaf: jnp.ndarray, page_table: jnp.ndarray,
     return g[:, :, :cache_len]
 
 
-def _paged_layer_kv(new: Params, l: int, page_table: jnp.ndarray,
-                    cache_len: int):
-    """(K, V, scale kwargs) row views for layer ``l`` AFTER its paged
-    append — the paged sibling of slicing ``new['k'][l]`` directly plus
-    ``_layer_scales``."""
-    K = _paged_view(new["k"][l], page_table, cache_len)
-    V = _paged_view(new["v"][l], page_table, cache_len)
-    scales = {}
-    if "k_scale" in new:
-        scales = {
-            "k_scale": _paged_view(new["k_scale"][l], page_table, cache_len),
-            "v_scale": _paged_view(new["v_scale"][l], page_table, cache_len),
-        }
-    return K, V, scales
-
-
-def _use_paged_attn(cache: Params, cfg: ModelConfig) -> bool:
+def _use_paged_attn(cache: Params, cfg: ModelConfig, Tq: int) -> bool:
     """Route decode attention through the pallas page-gather kernel
     (ops/decode_step.paged_decode_attention). Opt-in via BLLM_PAGED_ATTN=1
-    on TPU — the same off-until-hardware-A/B discipline as
-    BLLM_FUSED_DECODE/BLLM_BGMV — and only for unquantized pools of
-    kernel-eligible shape; the XLA gather view is the reference."""
+    on TPU — the same off-until-hardware-A/B discipline as BLLM_BGMV — and
+    only for unquantized pools of kernel-eligible shape; the XLA gather
+    view is the reference."""
     import os as _os
 
     if jax.default_backend() != "tpu" or _cache_quantized(cache):
@@ -1664,154 +1518,165 @@ def _use_paged_attn(cache: Params, cfg: ModelConfig) -> bool:
         supports_paged_shape,
     )
 
-    return supports_paged_shape(1, cache["k"][0].shape[2], cfg.head_dim)
+    return supports_paged_shape(Tq, cache["k"][0].shape[2], cfg.head_dim)
 
 
-def paged_decode_slots(params: Params, cfg: ModelConfig,
-                       tokens: jnp.ndarray, lengths: jnp.ndarray,
-                       page_table: jnp.ndarray, cache: Params,
+def _paged_attend(new: Params, l: int, q, table, cache_len: int,
+                  q_positions, kv_length) -> jnp.ndarray:
+    """Attend over layer ``l``'s row views AFTER its paged append (the
+    paged sibling of reading ``new['k'][l]`` plus ``_layer_scales``)."""
+    view = lambda name: _paged_view(new[name][l], table, cache_len)
+    scales = ({"k_scale": view("k_scale"), "v_scale": view("v_scale")}
+              if _cache_quantized(new) else {})
+    with _attention_scope(False):
+        return decode_attention(q, view("k"), view("v"),
+                                q_positions=q_positions,
+                                kv_length=kv_length, **scales)
+
+
+# -- the entry points: one access object, one pass --------------------------
+
+def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+                       cache: Params,
                        blocks_list: Optional[list] = None,
-                       adapter: Optional[Params] = None, *,
-                       cache_len: int) -> Tuple[jnp.ndarray, Params]:
-    """Paged sibling of ``decode_slots``: one decode tick over the slot
-    batch with every cache read/write routed through ``page_table``
-    ((S, max_pages) int32, traced data). ``cache_len`` is the static
-    logical row length (the engine's ``_cache_len``), identical to the
-    contiguous buffer width — so the reassembled row views, masks, and
-    therefore logits are bit-identical to the contiguous program's."""
-    rope = _rope_tables(cfg)
-    S = tokens.shape[0]
-    lengths = lengths.astype(jnp.int32)
-    page_table = page_table.astype(jnp.int32)
-    positions = lengths[:, None]                       # (S, 1)
-    x = _embed(cfg, params, tokens, positions, None, True)
-    if blocks_list is None:
-        blocks_list = unstack_blocks(params, cfg)
-    adp_layers, head_node, head_s = _slot_adapter_layers(adapter, cfg)
-    use_paged_attn = _use_paged_attn(cache, cfg)
+                       lora: Optional[Params] = None,
+                       lora_scaling=1.0,
+                       lora_blocks_list: Optional[list] = None
+                       ) -> Tuple[jnp.ndarray, Params]:
+    """Decode forward: process ``tokens`` (B, Tq) given ``cache`` holding
+    ``cache['length']`` valid positions; returns (fp32 logits (B, Tq, V),
+    updated cache). Static shapes throughout — jit-friendly. Pass
+    ``blocks_list`` (from ``unstack_blocks``; ``lora_blocks_list`` from
+    ``unstack_lora_blocks``) when calling inside a sampling loop so the
+    per-layer weight slices are hoisted out of it.
 
-    new = _new_cache_acc(cache)
-    for l, p in enumerate(blocks_list):
-        adp = adp_layers[l] if adp_layers is not None else None
-        h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
-                            adp=adp["attn"] if adp is not None else None)
-        _paged_append_kv(cache, new, l, k, v, lengths, page_table,
-                         cache_len)
-        if use_paged_attn:
-            from building_llm_from_scratch_tpu.ops.decode_step import (
-                paged_decode_attention,
-            )
-
-            out = paged_decode_attention(q, new["k"][l], new["v"][l],
-                                         page_table, lengths)
-        else:
-            K, V, scales = _paged_layer_kv(new, l, page_table, cache_len)
-            out = decode_attention(q, K, V, q_positions=positions,
-                                   kv_length=lengths + 1, **scales)
-        x = x + _attn_out_proj(p["attn"], out, S, 1,
-                               adp=adp["attn"] if adp is not None else None)
-        x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),
-                     adp=adp["mlp"] if adp is not None else None)
-    x = _norm(cfg, params["final_norm"], x)
-    logits = _head_logits(x, params["head"]["weight"], head_node, head_s)
-    return logits[:, 0], new
+    Contract: the caller must ensure ``cache['length'] + Tq <= max_length``
+    (the cache allocation). Under jit an overflow cannot raise —
+    ``dynamic_update_slice`` would clamp the write offset and silently
+    overwrite the newest entries. The generation loop sizes its cache to
+    cover the full decode so this never triggers.
+    """
+    adapter = None
+    if lora is not None or lora_blocks_list is not None:
+        if lora_blocks_list is None:
+            lora_blocks_list = unstack_lora_blocks(lora, cfg)
+        adapter = {"lora_blocks": lora_blocks_list, "scaling": lora_scaling,
+                   "head": lora["head"]["weight"] if lora is not None
+                   else None}
+    return _slot_pass(params, cfg, tokens,
+                      _SharedLengthKV(cfg, cache, tokens.shape[1]),
+                      blocks_list=blocks_list, adapter=adapter)
 
 
-def paged_verify_slots(params: Params, cfg: ModelConfig,
-                       tokens: jnp.ndarray, lengths: jnp.ndarray,
-                       page_table: jnp.ndarray, cache: Params,
-                       blocks_list: Optional[list] = None,
-                       adapter: Optional[Params] = None, *,
-                       cache_len: int) -> Tuple[jnp.ndarray, Params]:
-    """Paged sibling of ``verify_slots`` (Tq = k+1 speculative verify):
-    candidate k/v scatter at per-row logical offsets through the table,
-    rejected tails sit past ``kv_length`` exactly as before — masked
-    everywhere and overwritten by the next tick's append."""
-    rope = _rope_tables(cfg)
-    S, Tq = tokens.shape
-    lengths = lengths.astype(jnp.int32)
-    page_table = page_table.astype(jnp.int32)
-    positions = jnp.minimum(
-        lengths[:, None] + jnp.arange(Tq)[None, :],
-        cfg.context_length - 1)                                # (S, Tq)
-    x = _embed(cfg, params, tokens, positions, None, True)
-    if blocks_list is None:
-        blocks_list = unstack_blocks(params, cfg)
-    adp_layers, head_node, head_s = _slot_adapter_layers(adapter, cfg)
+def prefill_into_slot(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+                      prompt_len: jnp.ndarray, slot: jnp.ndarray,
+                      cache: Params, blocks_list: Optional[list] = None,
+                      adapter: Optional[Params] = None
+                      ) -> Tuple[jnp.ndarray, Params]:
+    """Run one request's prompt (``tokens`` (1, Tpb), right-padded to its
+    length bucket) and write its k/v panes into row ``slot`` of the slot
+    cache (``_PromptKV``); returns (last-real-position logits (V,),
+    updated cache).
 
-    new = _new_cache_acc(cache)
-    for l, p in enumerate(blocks_list):
-        adp = adp_layers[l] if adp_layers is not None else None
-        h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
-                            adp=adp["attn"] if adp is not None else None)
-        _paged_append_kv(cache, new, l, k, v, lengths, page_table,
-                         cache_len)
-        K, V, scales = _paged_layer_kv(new, l, page_table, cache_len)
-        out = decode_attention(q, K, V, q_positions=positions,
-                               kv_length=lengths + Tq, **scales)
-        x = x + _attn_out_proj(p["attn"], out, S, Tq,
-                               adp=adp["attn"] if adp is not None else None)
-        x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),
-                     adp=adp["mlp"] if adp is not None else None)
-    x = _norm(cfg, params["final_norm"], x)
-    logits = _head_logits(x, params["head"]["weight"], head_node, head_s)
-    return logits, new
-
-
-def paged_prefill_chunk_into_slot(params: Params, cfg: ModelConfig,
-                                  tokens: jnp.ndarray,
-                                  chunk_start: jnp.ndarray,
-                                  prompt_len: jnp.ndarray,
-                                  slot: jnp.ndarray,
-                                  page_table: jnp.ndarray, cache: Params,
-                                  blocks_list: Optional[list] = None,
-                                  adapter: Optional[Params] = None, *,
-                                  cache_len: int
-                                  ) -> Tuple[jnp.ndarray, Params]:
-    """Paged sibling of ``prefill_chunk_into_slot``: the chunk's C
-    positions scatter into row ``slot``'s pages, and attention gathers
-    that one row's view through its table lane. Pad positions past the
-    prompt write zeros (the same determinism rule as contiguous); any
-    position past the row's allocated frontier lands on the trash page
-    — never read unmasked either way."""
-    _, C = tokens.shape
-    rope = _rope_tables(cfg)
-    positions = chunk_start + jnp.arange(C)
-    x = _embed(cfg, params, tokens, positions, None, True)
-    if blocks_list is None:
-        blocks_list = unstack_blocks(params, cfg)
-    adp_layers, head_node, head_s = _slot_adapter_layers(adapter, cfg)
-    valid = (positions < prompt_len)[None, :, None, None]
-    kv_len = jnp.reshape(jnp.minimum(chunk_start + C, prompt_len), (1,))
-    q_pos = positions[None, :]                       # (1, C) per-row form
-    page_table = page_table.astype(jnp.int32)
-    P = cache["k"][0].shape[2]
-    row_tab = jax.lax.dynamic_slice(
-        page_table, (slot, 0), (1, page_table.shape[1]))     # (1, M)
-    pos = jnp.minimum(positions, cache_len - 1)              # (C,)
-    phys = row_tab[0, pos // P]
-    off = pos % P
-    new = _new_cache_acc(cache)
-    for l, p in enumerate(blocks_list):
-        adp = adp_layers[l] if adp_layers is not None else None
-        h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, rope, positions,
-                            adp=adp["attn"] if adp is not None else None)
-        k = jnp.where(valid, k, jnp.zeros((), k.dtype))
-        v = jnp.where(valid, v, jnp.zeros((), v.dtype))
-        _paged_scatter(cache, "k", k[0], phys, off, new)
-        _paged_scatter(cache, "v", v[0], phys, off, new)
-        K_row, V_row, scales = _paged_layer_kv(new, l, row_tab, cache_len)
-        out = decode_attention(q, K_row, V_row, q_positions=q_pos,
-                               kv_length=kv_len, **scales)
-        x = x + _attn_out_proj(p["attn"], out, 1, C,
-                               adp=adp["attn"] if adp is not None else None)
-        x = x + _mlp(cfg, p["mlp"], _norm(cfg, p["norm2"], x),
-                     adp=adp["mlp"] if adp is not None else None)
-    x = _norm(cfg, params["final_norm"], x)
-    idx = jnp.clip(prompt_len - 1 - chunk_start, 0, C - 1)
-    last = jax.lax.dynamic_slice(x, (0, idx, 0), (1, 1, x.shape[-1]))
-    logits = _head_logits(last, params["head"]["weight"], head_node, head_s)
+    ``adapter``: {"pool", "scaling", "ids" (1,)} — the request's LoRA
+    adapter applied unmerged at every adapted projection (id −1 = base).
+    The prompt's k/v land in the slot ALREADY adapter-transformed, so
+    decode ticks attend to a prefix consistent with the same adapter.
+    """
+    kv = _PromptKV(cfg, cache, tokens.shape[1], slot, prompt_len)
+    logits, new = _slot_pass(params, cfg, tokens, kv,
+                             blocks_list=blocks_list, adapter=adapter)
     return logits[0, 0], new
+
+
+def prefill_chunk_into_slot(params: Params, cfg: ModelConfig,
+                            tokens: jnp.ndarray, chunk_start: jnp.ndarray,
+                            prompt_len: jnp.ndarray, slot: jnp.ndarray,
+                            cache: Params,
+                            blocks_list: Optional[list] = None,
+                            adapter: Optional[Params] = None, *,
+                            page_table: Optional[jnp.ndarray] = None,
+                            cache_len: Optional[int] = None
+                            ) -> Tuple[jnp.ndarray, Params]:
+    """Chunked prefill: process ``tokens`` (1, C) — the prompt span
+    [chunk_start, chunk_start + C), right-padded past ``prompt_len`` —
+    against row ``slot`` whose positions [0, chunk_start) already hold
+    valid KV (earlier chunks, or a copied prefix pane,
+    serving/kvcache.py). Returns (logits at the clamped position
+    ``prompt_len - 1 - chunk_start`` (V,), updated cache). With
+    ``page_table`` ((n_slots, max_pages) int32, and ``cache_len``) the row
+    is reached through it, else it is the slot cache's own (``_ChunkKV``).
+
+    The chunk width C is STATIC: every prompt of every length prefills
+    through this ONE compiled program (chunk_start/prompt_len/slot are
+    data) — both the one-compiled-program invariant and the per-tick
+    prefill bound. A 2k-token prompt becomes 2k/C short calls the
+    engine interleaves with decode ticks instead of one tick-stalling
+    program.
+    """
+    kv = _ChunkKV(cfg, cache, tokens.shape[1], slot, chunk_start, prompt_len,
+                  page_table, cache_len)
+    logits, new = _slot_pass(params, cfg, tokens, kv,
+                             blocks_list=blocks_list, adapter=adapter)
+    return logits[0, 0], new
+
+
+def verify_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+                 lengths: jnp.ndarray, cache: Params,
+                 blocks_list: Optional[list] = None,
+                 adapter: Optional[Params] = None,
+                 live: Optional[jnp.ndarray] = None,
+                 expert_rows: Optional[list] = None, *,
+                 page_table: Optional[jnp.ndarray] = None,
+                 cache_len: Optional[int] = None
+                 ) -> Tuple[jnp.ndarray, Params]:
+    """The tick over the whole slot batch, Tq >= 1 tokens a row:
+    ``tokens`` (S, Tq) is each slot's last accepted token followed, under
+    speculation, by its k drafted candidates; ``lengths`` (S,) the valid
+    cache prefix per row. Returns (fp32 logits (S, Tq, V), updated cache).
+    With ``page_table`` ((S, max_pages) int32, and ``cache_len``) rows are
+    reached through it, else they are the slot cache's own (``_RowsKV``).
+
+    ONE forward scores all Tq positions: position j's logits condition on
+    [cache, tokens[:, :j+1]], so they are the model's true next-token
+    distribution exactly when the drafts before j were all accepted — the
+    accept rule (generate.accept_draft_tokens) commits only such
+    prefixes. All Tq candidate k/v are appended at per-row offsets; the
+    engine advances ``lengths`` by the ACCEPTED count only, so a rejected
+    tail's entries sit past the valid prefix — masked by ``kv_length``
+    everywhere and overwritten by the next tick's append. No rollback copy
+    exists because none is needed.
+
+    Per-query causality rides ``decode_attention``'s per-row masks: query
+    j at absolute position lengths+j attends keys at positions <=
+    lengths+j, i.e. the real prefix plus the drafts before it — never the
+    drafts after it. k is STATIC: every acceptance count 0..k+1 flows
+    through this one compiled program, and free or finished slots compute
+    garbage rows the engine ignores (their appends land at the row's next
+    write position and are overwritten before anything reads them), so the
+    shapes never change and XLA compiles exactly one tick program.
+
+    ``adapter``: {"pool", "scaling", "ids" (S,)} — per-SLOT LoRA adapters,
+    their identity DATA (the note at the top of this file): any mix of ids
+    runs through this one compiled program.
+
+    ``live`` (S,) bool, for a sparse model: the rows that decode. The
+    others (free slots, slots in mid-prefill) are routed to no expert, so
+    they read no expert's weights and count for none. ``expert_rows``: a
+    list that collects each sparse layer's rows per held expert (H,).
+    """
+    kv = _RowsKV(cfg, cache, tokens.shape[1], lengths, live, page_table,
+                 cache_len)
+    return _slot_pass(params, cfg, tokens, kv, blocks_list=blocks_list,
+                      adapter=adapter, expert_rows=expert_rows)
+
+
+def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+                 *args, **kw) -> Tuple[jnp.ndarray, Params]:
+    """One decode tick: ``verify_slots`` (its arguments) at Tq = 1
+    (``tokens`` (S, 1) are each slot's last accepted token), where the
+    append is one in-place ``lane_window_append`` a layer and the attention
+    the live-block kernel wherever the two rules admit them; returns (fp32
+    logits (S, V), updated cache)."""
+    logits, new = verify_slots(params, cfg, tokens, *args, **kw)
+    return logits[:, 0], new

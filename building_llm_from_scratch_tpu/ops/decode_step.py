@@ -1,23 +1,28 @@
-"""Fused KV-cache update + attention for decode (pallas, TPU).
+"""The decode tick's kernels on the slot cache (pallas, TPU), and the
+cache's plain writers.
 
-Why this kernel exists: the decode loop carries the KV cache through a
-``lax.while_loop`` and appends one position per step with
-``dynamic_update_slice``. XLA's buffer assignment refuses to alias that
-update in place — every layer of every decoded token paid a full-cache
-copy (r5 profiles: ~40% of GPT2-124M bs8 step time as copy-start/copy-done
-pairs, surviving both cache layouts and per-layer buffer splits). A pallas
-kernel with ``input_output_aliases`` DECLARES the in-place update, so the
-cache never copies; as a bonus the new k/v rows are written in the same
-pass that computes attention, and masked scores never leave VMEM.
+Why kernels at all: a decode tick appends ONE position a row and reads each
+row's prefix. As XLA ops on the (S, Hkv, Tmax, hd) buffers the append is a
+loop of per-row updates and the attention two reductions over every position
+of every row, live or not. A pallas call with ``input_output_aliases``
+DECLARES the in-place update, and a kernel handed the cache in the layout
+the runtime keeps it in (positions on the lanes) neither copies nor relays
+a pane.
 
-Semantics (exactly ``ops.attention.decode_attention``):
-  - cache layout (B, Hkv, Tmax, hd); valid prefix ``length``; the kernel
-    writes k/v for positions [length, length+Tq) and attends with the
-    causal mask  kv_pos <= length + row  (row < Tq).
-  - eval-only (no dropout, no grad) — generation never trains.
+  - ``lane_window_append``: a tick's keys and values into every row at its
+    own length, one aliased call a layer (PR 26).
+  - ``live_block_attention``: each row's one query against the lane blocks
+    its live positions reach, and no others (PR 29).
+  - ``slot_cache_append`` / ``quantize_kv``: the per-row scatter and the
+    int8 quantise-on-write that everything outside the two gates keeps
+    (``supports_lane_append``, ``supports_live_attention``).
+  - ``lora_bgmv``, ``paged_decode_attention``: per-row adapter deltas and
+    page-table attention, both opt-in until measured on a chip.
 
-Grid (B, Hkv): each cell streams one (Tmax, hd) K and V pane through VMEM
-once — the HBM-roofline minimum for un-paged decode.
+Semantics are ``ops.attention.decode_attention``'s throughout: cache layout
+(B, Hkv, Tmax, hd), a valid prefix per row, eval-only (no dropout, no grad
+— generation never trains). ``models/transformer.py``'s access objects
+(``_SlotKV``) are the only callers.
 """
 
 from __future__ import annotations
@@ -35,47 +40,11 @@ from building_llm_from_scratch_tpu.parallel.mesh import MODEL_AXIS
 _NEG_BIG = -1e30
 # mosaic wants >= 8 sublanes; decode's G*Tq is often 1 — pad the query rows
 _MIN_ROWS = 8
-
-
-def _kernel(len_ref, q_ref, kn_ref, vn_ref, K_ref, V_ref,
-            Ko_ref, Vo_ref, o_ref, *, scale: float):
-    """Single-token (Tq=1) append + attend for one batch-row grid cell
-    (all Hkv heads per cell — big DMAs keep HBM busy; the first kernel
-    revision's (B, Hkv) grid moved 40KB blocks and ran 8x off roofline).
-
-    The append stores only the 8-row aligned window containing position
-    ``t`` (mosaic requires provably 8-aligned dynamic sublane offsets —
-    ``pl.multiple_of((t // 8) * 8, 8)`` supplies the proof), merging the
-    new row into it; the attention then reads the full pane from VMEM.
-    """
-    t = len_ref[pl.program_id(0)]
-    t8 = pl.multiple_of((t // 8) * 8, 8)
-    Hkv, Tmax, hd = K_ref.shape[1:]
-
-    def merge_store(new_ref, ref):
-        old = ref[0, :, pl.ds(t8, 8), :]              # (Hkv, 8, hd)
-        row = t8 + jax.lax.broadcasted_iota(jnp.int32, (Hkv, 8, hd), 1)
-        new = jnp.broadcast_to(new_ref[0], (Hkv, 8, hd))
-        ref[0, :, pl.ds(t8, 8), :] = jnp.where(row == t, new, old)
-
-    merge_store(kn_ref, Ko_ref)
-    merge_store(vn_ref, Vo_ref)
-
-    q = q_ref[0]                                      # (Hkv, R, hd)
-    k = Ko_ref[0]                                     # (Hkv, Tmax, hd)
-    v = Vo_ref[0]
-    R = q.shape[1]
-    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * scale
-    kv_pos = jax.lax.broadcasted_iota(jnp.int32, (Hkv, R, Tmax), 2)
-    s = jnp.where(kv_pos <= t, s, _NEG_BIG)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    o_ref[0] = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
-
+#: what one grid cell of a kernel here may hold: under the compiler's default
+#: 16 MiB scoped-VMEM limit (asking for more does not help where it matters:
+#: inside a whole decode program the v5e compiler then assigns whole cache
+#: arrays to VMEM beside the kernel's own scope)
+_VMEM_BUDGET = 14 * 2 ** 20
 
 #: symmetric int8 KV quantization floor: an all-zero position (zeroed
 #: pad, never-written cache row) quantizes to scale EPS and exact-zero
@@ -369,79 +338,6 @@ def _live_attention_local(q, k_cache, v_cache, kv_length, *, interpret):
     return out[:, :, :G].reshape(S, 1, Hq, hd)
 
 
-def fused_decode_step(q, k_new, v_new, k_cache, v_cache, length, *,
-                      interpret=False):
-    """Append k_new/v_new at ``length`` (IN PLACE via aliasing) and attend.
-
-    q:                (B, Tq, Hq, hd)   — model layout, Tq small
-    k_new, v_new:     (B, Tq, Hkv, hd)
-    k_cache, v_cache: (B, Hkv, Tmax, hd)
-    length:           scalar int32 (valid prefix), or (B,) per-row
-                      lengths for the slot-batched serving engine — the
-                      grid already runs one cell per batch row, so each
-                      cell simply reads ITS row's length from SMEM.
-
-    Returns (out (B, Tq, Hq, hd), k_cache', v_cache'). Under a mesh
-    (``--serve_tp``) heads shard over the model axis, like the slot
-    cache (``MeshPlan.cache_spec``); ``interpret=True`` runs the kernel
-    on CPU for parity tests.
-    """
-    length = jnp.asarray(length, jnp.int32)
-    heads = (None, None, MODEL_AXIS, None)
-    panes = (None, MODEL_AXIS, None, None)
-    return mesh_kernel(
-        lambda _, *a: _decode_step_local(*a, interpret=interpret),
-        (q, k_new, v_new, k_cache, v_cache, length),
-        (heads, heads, heads, panes, panes, (None,) * length.ndim),
-        (heads, panes, panes))
-
-
-def _decode_step_local(q, k_new, v_new, k_cache, v_cache, length, *,
-                       interpret):
-    B, Tq, Hq, hd = q.shape
-    _, Hkv, Tmax, _ = k_cache.shape
-    if Tq != 1:
-        raise ValueError(f"fused_decode_step is single-token only; Tq={Tq}")
-    G = Hq // Hkv
-    R = G * Tq
-    Rp = max(_MIN_ROWS, R)
-    # (B, Hkv, G*Tq, hd) query rows, padded to the sublane minimum
-    qr = q.reshape(B, Tq, Hkv, G, hd).transpose(0, 2, 3, 1, 4)
-    qr = qr.reshape(B, Hkv, R, hd)
-    if Rp != R:
-        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
-    knt = k_new.transpose(0, 2, 1, 3)                 # (B, Hkv, Tq, hd)
-    vnt = v_new.transpose(0, 2, 1, 3)
-    # (B,) per-row lengths, scalar-prefetched whole into SMEM (a (1, 1)
-    # SMEM block of a (B, 1) array is not a legal mosaic tile); a scalar
-    # broadcasts to every row
-    lens = jnp.broadcast_to(jnp.reshape(length, (-1,)), (B,))
-
-    blk = lambda rows: pl.BlockSpec((1, Hkv, rows, hd),
-                                    lambda b, len_ref: (b, 0, 0, 0))
-    ko, vo, out = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / float(hd) ** 0.5),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B,),
-            in_specs=[blk(Rp), blk(Tq), blk(Tq), blk(Tmax), blk(Tmax)],
-            out_specs=[blk(Tmax), blk(Tmax), blk(Rp)],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-            jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, Rp, hd), q.dtype),
-        ],
-        # K->Ko, V->Vo in place (operand indices count the prefetch arg)
-        input_output_aliases={4: 0, 5: 1},
-        interpret=interpret,
-    )(lens, qr, knt, vnt, k_cache, v_cache)
-    out = out[:, :, :R]                               # drop row padding
-    # (B, Hkv, G, Tq, hd) -> (B, Tq, Hq, hd)
-    out = out.reshape(B, Hkv, G, Tq, hd).transpose(0, 3, 1, 2, 4)
-    return out.reshape(B, Tq, Hq, hd), ko, vo
-
-
 def _bgmv_kernel(ids_ref, x_ref, a_ref, b_ref, scale_ref, o_ref, *,
                  n_pool: int):
     """One batch row per grid cell: the row's adapter id (scalar-prefetched
@@ -654,7 +550,7 @@ def supports_paged_shape(Tq: int, page_tokens: int, hd: int) -> bool:
     """Paged-attention kernel eligibility: single-token decode,
     lane-aligned head dim, sublane-aligned page length (each page is one
     VMEM pane). Ineligible shapes — and int8 pools, gated off by the
-    caller exactly like ``supports_shape`` — keep the XLA gather
+    caller — keep the XLA gather
     reference path."""
     return (Tq == 1 and hd % 64 == 0 and hd <= 256
             and page_tokens % 8 == 0)
@@ -719,39 +615,4 @@ def supports_live_attention(Tq: int, Tmax: int, hd: int, *, S: int, Hkv: int,
             and hd < _LANES and hd % 16 == 0 and Tmax % LIVE_BLOCK == 0
             and Hq % Hkv == 0
             and _live_attention_vmem_bytes(hd, S, Hkv, Hq, dtype.itemsize)
-            <= _VMEM_BUDGET)
-
-
-#: the fused step runs under the compiler's default 16 MiB scoped-VMEM
-#: limit. Asking for more (``vmem_limit_bytes``) does not help where it
-#: matters: inside a whole 12-layer decode program the v5e compiler then
-#: assigns whole cache arrays to VMEM beside the kernel's own scope and
-#: refuses the program (see ``transformer._use_fused_decode``).
-_VMEM_BUDGET = 14 * 2 ** 20
-
-
-def _step_vmem_bytes(Hkv: int, rows: int, Tmax: int, hd: int,
-                     itemsize: int) -> int:
-    """One grid cell of ``fused_decode_step``: the K and V panes, in and
-    (aliased) out, each double-buffered by the pipeline, plus the fp32
-    score / probability rows."""
-    return 8 * Hkv * Tmax * hd * itemsize + 3 * Hkv * rows * Tmax * 4
-
-
-def supports_shape(Tq: int, Tmax: int, hd: int, *, Hkv: int, Hq: int,
-                   itemsize: int = 2) -> bool:
-    """Kernel eligibility: single-token decode, lane-aligned head dim,
-    all Hkv cache panes of one batch row within the VMEM budget, and
-    8-row-aligned Tmax (the merge_store window [t8, t8+8) must stay
-    inside the pane for every t < Tmax). Prefill (Tq > 1) keeps the
-    dynamic-update-slice +
-    ``decode_attention`` path — it runs once per generation, so its
-    copies don't matter. int8-quantized caches (serving/kvcache.py) are
-    additionally gated OFF by the caller: the kernel would need an
-    in-VMEM dequant pass (quantize on merge_store, fold scales into the
-    score/value dots) that has no hardware to be A/B'd against in this
-    container — the XLA path carries the scales instead."""
-    rows = max(_MIN_ROWS, Hq // Hkv)
-    return (Tq == 1 and hd % 64 == 0 and hd <= 256 and Tmax % 8 == 0
-            and _step_vmem_bytes(Hkv, rows, Tmax, hd, itemsize)
             <= _VMEM_BUDGET)

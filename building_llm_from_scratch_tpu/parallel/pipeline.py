@@ -201,8 +201,8 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh: Mesh, n_micro: int
         def body(carry, xs):
             p, j = xs
             r = None if deterministic else jax.random.fold_in(key, j)
-            y, _ = _block(cfg, p, carry, rope, None, None, None, r,
-                          deterministic, tp_axis=tp_axis)
+            y = _block(cfg, p, carry, rope, None, r, deterministic,
+                       tp_axis=tp_axis)
             return y, None
 
         if cfg.use_actv_ckpt:

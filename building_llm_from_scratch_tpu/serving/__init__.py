@@ -2,7 +2,7 @@
 
 The reference stops at one-shot batch sampling (generate.py:4-75); this
 package is the runtime that turns the repo's decode primitives (static
-KV cache, fused decode step) into a server: a bounded ``RequestQueue``,
+KV cache, slot-batched decode step) into a server: a bounded ``RequestQueue``,
 an FCFS slot ``Scheduler``, the ``DecodeEngine`` tick loop, and two
 dependency-free frontends (JSONL batch, stdlib HTTP).
 

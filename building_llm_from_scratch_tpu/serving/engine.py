@@ -6,7 +6,7 @@ compiles exactly ONE decode program (and one prefill per prompt-length
 bucket) no matter how traffic arrives. The host loop per tick:
 
     retire finished -> admit queued into free slots (prefill, bucketed)
-    -> one fused decode step for ALL slots (per-slot masks) -> stream
+    -> one decode step for ALL slots (per-slot masks) -> stream
 
 Slot independence is total: every row carries its own length, sampling
 params and PRNG stream (``generate.token_rng`` fold-in on the request
@@ -65,14 +65,9 @@ from building_llm_from_scratch_tpu.generate import (
     token_rng,
 )
 from building_llm_from_scratch_tpu.models.transformer import (
-    _use_fused_decode,
     decode_attention_path,
-    decode_slots,
     init_slot_cache,
     kv_append_path,
-    paged_decode_slots,
-    paged_prefill_chunk_into_slot,
-    paged_verify_slots,
     prefill_chunk_into_slot,
     prefill_into_slot,
     unstack_blocks,
@@ -363,23 +358,20 @@ class DecodeEngine:
             if mesh_plan is not None else None)
         #: which write the tick program's append was built with, chosen
         #: once, at trace time, by the rule the program itself asks
-        #: (``kv_append_path``): "lane_window" | "scatter", or the other
-        #: two writers' names. In ``stats()``, ``/healthz``, the warm-up event
-        if self._paged:
-            self.kv_append = "paged"
-        elif not self.spec_k and _use_fused_decode(cfg, self.cache, 1):
-            self.kv_append = "fused_step"
-        else:
-            self.kv_append = kv_append_path(self.cache, self.spec_k + 1)
+        #: (``kv_append_path``): "lane_window" | "scatter", or "paged" for
+        #: the page table's writer. In ``stats()``, ``/healthz``, the warm-up
+        #: event
+        self.kv_append = ("paged" if self._paged else
+                          kv_append_path(self.cache, self.spec_k + 1))
         #: the same for the tick program's attention
         #: (``decode_attention_path``): "live_blocks" (a row's live lane
-        #: blocks only) | "whole_buffer", or the other two programs' names.
+        #: blocks only) | "whole_buffer", or "paged".
         #: ``_attn_reads``: {(block, buffer length): layers}, block 0 where a
         #: layer reads its buffer whole: what a tick's ``kv_touched`` counts
         self._attn_reads = collections.Counter(
             self._attention_read(self.cache, l) for l in range(cfg.n_layers))
-        if self.kv_append in ("paged", "fused_step"):
-            self.decode_attention = self.kv_append
+        if self._paged:
+            self.decode_attention = "paged"
         else:
             self.decode_attention = (
                 "live_blocks" if any(b for b, _ in self._attn_reads)
@@ -461,7 +453,7 @@ class DecodeEngine:
             self.adapters.set_in_use_probe(self._adapter_rows_in_use)
 
         # donate the cache pytree: the caller always rebinds self.cache
-        # to the outputs, so XLA may alias input->output (and the opt-in
+        # to the outputs, so XLA may alias input->output (and the tick's
         # pallas append is in place: no per-tick full-cache copy). The
         # prefix-EXTRACT program deliberately does NOT donate
         # — it only reads the cache (the next donating call reuses the
@@ -475,12 +467,11 @@ class DecodeEngine:
 
         prefill_jit = jit(self._prefill_impl, donate_argnums=(0,))
         # paged: the chunk/step programs take the page table as one more
-        # traced argument and write/read through it; the monolithic
-        # prefill and the prefix copy/extract pair are never CALLED
-        # (paged implies chunked prefill, and a paged hit is a host
+        # traced argument (``_paged_tail``) and write/read through it; the
+        # monolithic prefill and the prefix copy/extract pair are never
+        # CALLED (paged implies chunked prefill, and a paged hit is a host
         # table write) — they stay built so the watcher set is stable
-        chunk_jit = jit(self._paged_chunk_impl if self._paged
-                        else self._chunk_impl, donate_argnums=(0,))
+        chunk_jit = jit(self._chunk_impl, donate_argnums=(0,))
         copy_jit = jit(self._copy_impl, donate_argnums=(0,))
         extract_jit = jit(functools.partial(
             extract_prefix_panes, pane_len=self._prefix_pane_len))
@@ -488,13 +479,7 @@ class DecodeEngine:
         # plain decode step is never built (every slot, spec-opted-out
         # rows included, rides verify; their commit count is clamped to 1
         # on the host). spec off: the historical decode step, untouched.
-        if self._paged:
-            step_impl = (self._paged_verify_impl if self.spec_k
-                         else self._paged_decode_impl)
-        else:
-            step_impl = (self._verify_impl if self.spec_k
-                         else self._decode_impl)
-        step_jit = jit(step_impl, donate_argnums=(0,))
+        step_jit = jit(self._decode_impl, donate_argnums=(0,))
         step_label = "serve_verify" if self.spec_k else "serve_decode"
         if watch_compiles:
             self._prefill = CompileWatcher(prefill_jit,
@@ -767,18 +752,31 @@ class DecodeEngine:
     # -- jitted programs (close over params/cfg/blocks so per-tick call
     # signatures carry only the small mutable state + caches) -------------
 
-    def _prefill_impl(self, cache, weights, tokens, prompt_len, slot,
-                      base_key, temp, topk, pool=None, pool_scale=None,
-                      adapter_id=None):
+    def _adapter_arg(self, pool, pool_scale, ids):
+        """The model passes' ``adapter=``: the registry's stacked pool and
+        the batch's rows of it (one request's row, or a row a slot), or
+        None for a registry-less engine."""
         import jax.numpy as jnp
 
-        adapter = None
-        if pool is not None:
-            adapter = {"pool": pool, "scaling": pool_scale,
-                       "ids": jnp.reshape(adapter_id, (1,))}
-        logits, cache = prefill_into_slot(
-            weights[0], self.cfg, tokens, prompt_len, slot,
-            cache, weights[1], adapter=adapter)
+        if pool is None:
+            return None
+        return {"pool": pool, "scaling": pool_scale,
+                "ids": jnp.reshape(ids, (-1,))}
+
+    def _paged_kw(self, page_table) -> dict:
+        """The model passes' keywords that reach a row through the page
+        table: the KV cache is the shared page pool and the per-slot int32
+        table rides each call as TRACED DATA (one (S, max_pages) signature
+        — page churn never recompiles, mirroring the adapter-pool trick)."""
+        if page_table is None:
+            return {}
+        return {"page_table": page_table, "cache_len": self._cache_len}
+
+    def _first_token(self, logits, cache, base_key, temp, topk):
+        """A prefill program's tail: sample the request's first token
+        (fold-in index 0) from its last position's logits."""
+        import jax.numpy as jnp
+
         key0 = token_rng(base_key, 0)
         tok = sample_tokens_dynamic(
             logits[None], key0[None], jnp.reshape(temp, (1,)),
@@ -789,9 +787,18 @@ class DecodeEngine:
         ok = jnp.all(jnp.isfinite(logits))
         return tok, ok, self._pin_cache(cache)
 
+    def _prefill_impl(self, cache, weights, tokens, prompt_len, slot,
+                      base_key, temp, topk, pool=None, pool_scale=None,
+                      adapter_id=None):
+        logits, cache = prefill_into_slot(
+            weights[0], self.cfg, tokens, prompt_len, slot,
+            cache, weights[1],
+            adapter=self._adapter_arg(pool, pool_scale, adapter_id))
+        return self._first_token(logits, cache, base_key, temp, topk)
+
     def _chunk_impl(self, cache, weights, tokens, chunk_start, prompt_len,
                     slot, base_key, temp, topk, pool=None, pool_scale=None,
-                    adapter_id=None):
+                    adapter_id=None, page_table=None):
         """One C-token prefill chunk (the chunked tier's ONE compiled
         prefill program). Samples the would-be first token every call —
         the host only reads it (and the finite flag) on the FINAL chunk,
@@ -801,28 +808,21 @@ class DecodeEngine:
         constrained onto the ``seq`` mesh axis and GSPMD propagates the
         split through the whole chunk forward — each device embeds,
         normalizes and attends its C/sp queries against the replicated
-        slot KV (per-query math identical to unsharded, so tokens stay
-        bit-exact), then the chunk's new KV is gathered back into the
-        replicated slot row by the output's pinned sharding."""
+        slot KV or page pool (per-query math identical to unsharded, so
+        tokens stay bit-exact), then the chunk's new KV is gathered back
+        into the replicated slot row (its page scatters) by the output's
+        pinned sharding."""
         import jax
-        import jax.numpy as jnp
 
         if self._sp_sharding is not None:
             tokens = jax.lax.with_sharding_constraint(tokens,
                                                       self._sp_sharding)
-        adapter = None
-        if pool is not None:
-            adapter = {"pool": pool, "scaling": pool_scale,
-                       "ids": jnp.reshape(adapter_id, (1,))}
         logits, cache = prefill_chunk_into_slot(
             weights[0], self.cfg, tokens, chunk_start, prompt_len, slot,
-            cache, weights[1], adapter=adapter)
-        key0 = token_rng(base_key, 0)
-        tok = sample_tokens_dynamic(
-            logits[None], key0[None], jnp.reshape(temp, (1,)),
-            jnp.reshape(topk, (1,)), self.max_top_k)[0]
-        ok = jnp.all(jnp.isfinite(logits))
-        return tok, ok, self._pin_cache(cache)
+            cache, weights[1],
+            adapter=self._adapter_arg(pool, pool_scale, adapter_id),
+            **self._paged_kw(page_table))
+        return self._first_token(logits, cache, base_key, temp, topk)
 
     def _copy_impl(self, cache, panes, slot):
         """Prefix HIT: one batched DUS per layer writes the stored panes
@@ -831,23 +831,30 @@ class DecodeEngine:
 
     def _decode_impl(self, cache, weights, tokens, lengths, base_keys,
                      n_gen, temps, topks, pool=None, pool_scale=None,
-                     adapter_ids=None, live=None):
-        """``live`` (S,) bool rides a sparse model's tick only
-        (``_step_tail``): the rows that decode."""
+                     adapter_ids=None, live=None, page_table=None):
+        """THE tick program: what the engine IS (``spec_k``, fixed at
+        construction) says whether it is the plain decode step (``tokens``
+        (S,): each slot's last token; returns (next tokens (S,), ok (S,),
+        cache)) or the speculative one (``_accept``). ``live`` (S,) bool
+        rides a sparse model's tick only (``_step_tail``): the rows that
+        decode."""
         import jax
         import jax.numpy as jnp
 
-        adapter = None
-        if pool is not None:
-            adapter = {"pool": pool, "scaling": pool_scale,
-                       "ids": adapter_ids}
         # a sparse model's tick hands back, with the two arrays the host
         # fetches anyway, the rows each held expert computed, a layer a row
         expert_rows = [] if self.cfg.is_moe else None
-        logits, cache = decode_slots(
-            weights[0], self.cfg, tokens[:, None], lengths,
-            cache, weights[1], adapter=adapter, live=live,
-            expert_rows=expert_rows)
+        logits, cache = verify_slots(
+            weights[0], self.cfg,
+            tokens if self.spec_k else tokens[:, None], lengths,
+            cache, weights[1],
+            adapter=self._adapter_arg(pool, pool_scale, adapter_ids),
+            live=live, expert_rows=expert_rows,
+            **self._paged_kw(page_table))
+        if self.spec_k:
+            return (*self._accept(logits, tokens, base_keys, n_gen, temps,
+                                  topks), self._pin_cache(cache))
+        logits = logits[:, 0]              # decode is verify at Tq = 1
         keys = jax.vmap(token_rng)(base_keys, n_gen)
         nxt = sample_tokens_dynamic(logits, keys, temps, topks,
                                     self.max_top_k)
@@ -859,16 +866,14 @@ class DecodeEngine:
             ok = (ok, jnp.stack(expert_rows))
         return nxt, ok, self._pin_cache(cache)
 
-    def _verify_impl(self, cache, weights, tokens, lengths, base_keys,
-                     n_gen, temps, topks, pool=None, pool_scale=None,
-                     adapter_ids=None):
-        """Speculative tick: ONE Tq=k+1 forward scores every slot's
-        [last_token, d_1..d_k] and the in-graph accept rule commits the
-        longest valid prefix. Position j of row s samples with the
+    def _accept(self, logits, tokens, base_keys, n_gen, temps, topks):
+        """The speculative tick's tail: ONE Tq=k+1 forward scored every
+        slot's [last_token, d_1..d_k] and the in-graph accept rule commits
+        the longest valid prefix. Position j of row s samples with the
         fold-in key for token index n_gen[s]+j — the exact key the
         non-speculative path would use for that token — so committed
         tokens are bit-identical to spec-off at any acceptance rate.
-        Returns (tokens (S, k+1), n_accepted (S,), ok (S,), cache)."""
+        Returns (tokens (S, k+1), n_accepted (S,), ok (S,))."""
         import jax
         import jax.numpy as jnp
 
@@ -876,97 +881,12 @@ class DecodeEngine:
             accept_draft_tokens,
         )
 
-        adapter = None
-        if pool is not None:
-            adapter = {"pool": pool, "scaling": pool_scale,
-                       "ids": adapter_ids}
-        logits, cache = verify_slots(
-            weights[0], self.cfg, tokens, lengths, cache, weights[1],
-            adapter=adapter)
         Tq = tokens.shape[1]
         offsets = n_gen[:, None] + jnp.arange(Tq)[None, :]     # (S, Tq)
         keys = jax.vmap(jax.vmap(token_rng, in_axes=(None, 0)))(
             base_keys, offsets)
-        toks, n_acc, ok = accept_draft_tokens(
+        return accept_draft_tokens(
             logits, tokens[:, 1:], keys, temps, topks, self.max_top_k)
-        return toks, n_acc, ok, self._pin_cache(cache)
-
-    # -- paged variants: identical sampling/accept tails, but the KV
-    # cache is the shared page pool and a per-slot int32 page table rides
-    # each call as TRACED DATA (one (S, max_pages) signature — page churn
-    # never recompiles, mirroring the adapter-pool trick) ----------------
-
-    def _paged_chunk_impl(self, cache, weights, tokens, chunk_start,
-                          prompt_len, slot, page_table, base_key, temp, topk,
-                          pool=None, pool_scale=None, adapter_id=None):
-        import jax
-        import jax.numpy as jnp
-
-        if self._sp_sharding is not None:
-            # seq-sharded chunk (see _chunk_impl): queries split over
-            # the seq axis, the page-pool KV stays replicated, the
-            # chunk's page scatters gather back via the pinned output
-            tokens = jax.lax.with_sharding_constraint(tokens,
-                                                      self._sp_sharding)
-        adapter = None
-        if pool is not None:
-            adapter = {"pool": pool, "scaling": pool_scale,
-                       "ids": jnp.reshape(adapter_id, (1,))}
-        logits, cache = paged_prefill_chunk_into_slot(
-            weights[0], self.cfg, tokens, chunk_start, prompt_len, slot,
-            page_table, cache, weights[1], adapter=adapter,
-            cache_len=self._cache_len)
-        key0 = token_rng(base_key, 0)
-        tok = sample_tokens_dynamic(
-            logits[None], key0[None], jnp.reshape(temp, (1,)),
-            jnp.reshape(topk, (1,)), self.max_top_k)[0]
-        ok = jnp.all(jnp.isfinite(logits))
-        return tok, ok, self._pin_cache(cache)
-
-    def _paged_decode_impl(self, cache, weights, tokens, lengths, page_table,
-                           base_keys, n_gen, temps, topks, pool=None,
-                           pool_scale=None, adapter_ids=None):
-        import jax
-        import jax.numpy as jnp
-
-        adapter = None
-        if pool is not None:
-            adapter = {"pool": pool, "scaling": pool_scale,
-                       "ids": adapter_ids}
-        logits, cache = paged_decode_slots(
-            weights[0], self.cfg, tokens[:, None], lengths, page_table,
-            cache, weights[1], adapter=adapter,
-            cache_len=self._cache_len)
-        keys = jax.vmap(token_rng)(base_keys, n_gen)
-        nxt = sample_tokens_dynamic(logits, keys, temps, topks,
-                                    self.max_top_k)
-        ok = jnp.all(jnp.isfinite(logits), axis=-1)
-        return nxt, ok, self._pin_cache(cache)
-
-    def _paged_verify_impl(self, cache, weights, tokens, lengths, page_table,
-                           base_keys, n_gen, temps, topks, pool=None,
-                           pool_scale=None, adapter_ids=None):
-        import jax
-        import jax.numpy as jnp
-
-        from building_llm_from_scratch_tpu.generate import (
-            accept_draft_tokens,
-        )
-
-        adapter = None
-        if pool is not None:
-            adapter = {"pool": pool, "scaling": pool_scale,
-                       "ids": adapter_ids}
-        logits, cache = paged_verify_slots(
-            weights[0], self.cfg, tokens, lengths, page_table, cache,
-            weights[1], adapter=adapter, cache_len=self._cache_len)
-        Tq = tokens.shape[1]
-        offsets = n_gen[:, None] + jnp.arange(Tq)[None, :]     # (S, Tq)
-        keys = jax.vmap(jax.vmap(token_rng, in_axes=(None, 0)))(
-            base_keys, offsets)
-        toks, n_acc, ok = accept_draft_tokens(
-            logits, tokens[:, 1:], keys, temps, topks, self.max_top_k)
-        return toks, n_acc, ok, self._pin_cache(cache)
 
     def _pool_args(self) -> tuple:
         """Positional tail for the compiled programs: the registry's
@@ -979,18 +899,28 @@ class DecodeEngine:
         pool, scale = self.adapters.pool_args()
         return (pool, scale)
 
+    def _paged_tail(self, tail: tuple, width: int) -> tuple:  # holds: _lock
+        """A paged engine's programs take the page table LAST, behind
+        their ``width`` optional arguments (the other engines' signatures
+        stay as they were)."""
+        if not self._paged:
+            return tail
+        return tail + (None,) * (width - len(tail)) + (self._page_table,)
+
     def _step_tail(self) -> tuple:  # holds: _lock
-        """The decode tick's positional tail: the adapter pool and the
+        """The tick program's positional tail: the adapter pool and the
         slots' rows of it; for a sparse model (which takes no adapter)
         the rows that decode this tick."""
         if self.cfg.is_moe:
             live = np.zeros((self.n_slots,), np.bool_)
             live[[s for s, _ in self.scheduler.active()
                   if s not in self._prefill_state]] = True
-            return (None, None, None, live)
-        if self.adapters is None:
-            return ()
-        return self._pool_args() + (self._adapter_ids,)
+            tail = (None, None, None, live)
+        elif self.adapters is None:
+            tail = ()
+        else:
+            tail = self._pool_args() + (self._adapter_ids,)
+        return self._paged_tail(tail, 4)
 
     def _pool_args_for(self, adapter_row) -> tuple:
         """Prefill's positional tail: pool + scaling + THIS request's row."""
@@ -1643,9 +1573,8 @@ class DecodeEngine:
             tok, ok, cache = self._prefill_chunk(
                 self.cache, self._weights, chunk, np.int32(lo),
                 np.int32(Tp), np.int32(slot),
-                *((self._page_table,) if self._paged else ()),
                 st["base_key"], st["temp"], st["topk"],
-                *self._pool_args_for(st["adapter_row"]))
+                *self._paged_tail(self._pool_args_for(st["adapter_row"]), 3))
             if self._generation != gen:
                 return False    # abandoned mid-chunk: commit nothing
             self.cache = cache
@@ -1900,7 +1829,7 @@ class DecodeEngine:
         """(lane block, buffer length) of layer ``l``'s decode attention:
         the block ``live_block_attention`` reads by, 0 where the layer
         reads its buffer whole (``decode_attention_path``)."""
-        if self.kv_append in ("paged", "fused_step"):
+        if self._paged:
             return 0, self._cache_len
         live = decode_attention_path(
             cache, self.spec_k + 1, self.cfg.n_heads, layer=l,
@@ -1968,7 +1897,7 @@ class DecodeEngine:
     # -- the tick ---------------------------------------------------------
 
     def step(self) -> bool:
-        """One engine tick: admit into free slots, then one fused decode
+        """One engine tick: admit into free slots, then one batched decode
         step over the slot batch. Returns False when fully idle (no active
         slots and nothing queued).
 
@@ -2096,7 +2025,6 @@ class DecodeEngine:
              self._tick_rec["kv_touched"]) = self._kv_positions_read(decoding)
             nxt, ok, cache = self._decode(
                 self.cache, self._weights, self._last_tokens, self._lengths,
-                *((self._page_table,) if self._paged else ()),
                 self._base_keys, self._n_gen, self._temps,
                 self._topks, *self._step_tail())
         if self._generation != gen:
@@ -2183,10 +2111,8 @@ class DecodeEngine:
         with self._tl.span("decode_dispatch"):
             toks, n_acc, ok, cache = self._verify(
                 self.cache, self._weights, tokens_in, self._lengths,
-                *((self._page_table,) if self._paged else ()),
                 self._base_keys, self._n_gen, self._temps, self._topks,
-                *(self._pool_args() + (self._adapter_ids,)
-                  if self.adapters is not None else ()))
+                *self._step_tail())
         if self._generation != gen:
             return False
         # ONE explicit fetch for the tick's three results (+ the donated
@@ -2493,9 +2419,8 @@ class DecodeEngine:
                 tok, _ok, cache = self._prefill_chunk(
                     self.cache, self._weights, dummy, np.int32(0),
                     np.int32(1), np.int32(0),
-                    *((self._page_table,) if self._paged else ()),
                     zero_key, np.float32(0.0), np.int32(0),
-                    *self._pool_args_for(np.int32(-1)))
+                    *self._paged_tail(self._pool_args_for(np.int32(-1)), 3))
                 self.cache = cache
                 if self.prefix_store is not None and not self._paged:
                     # paged hit/store are host table writes — the copy/
@@ -2513,26 +2438,15 @@ class DecodeEngine:
                         np.int32(0), zero_key, np.float32(0.0),
                         np.int32(0), *self._pool_args_for(np.int32(-1)))
                     self.cache = cache
-            if self.spec_k:
-                # the Tq=k+1 verify program IS the tick program when
-                # speculation is on — warm (and freeze) it instead of a
-                # plain decode step that would never run
-                warm_tokens = np.zeros((self.n_slots, self.spec_k + 1),
-                                       np.int32)
-                nxt, _n_acc, _ok, cache = self._verify(
-                    self.cache, self._weights, warm_tokens, self._lengths,
-                    *((self._page_table,) if self._paged else ()),
-                    self._base_keys, self._n_gen, self._temps,
-                    self._topks, *(self._pool_args()
-                                   + (self._adapter_ids,)
-                                   if self.adapters is not None else ()))
-            else:
-                nxt, _ok, cache = self._decode(
-                    self.cache, self._weights, self._last_tokens,
-                    self._lengths,
-                    *((self._page_table,) if self._paged else ()),
-                    self._base_keys, self._n_gen,
-                    self._temps, self._topks, *self._step_tail())
+            # the Tq=k+1 verify program IS the tick program when
+            # speculation is on — warm (and freeze) it instead of a
+            # plain decode step that would never run
+            warm_tokens = (np.zeros((self.n_slots, self.spec_k + 1), np.int32)
+                           if self.spec_k else self._last_tokens)
+            nxt, *_, cache = (self._verify or self._decode)(
+                self.cache, self._weights, warm_tokens, self._lengths,
+                self._base_keys, self._n_gen, self._temps, self._topks,
+                *self._step_tail())
             self.cache = cache
             jax.device_get(nxt)               # block until compiled + ran
             if isinstance(self._prefill, CompileWatcher):

@@ -15,9 +15,10 @@ random-init weights and synthetic data made from a fixed seed:
            sampled requests; greedy output is checked against one-shot
            ``generate()`` and against the one-shot forward's logits.
   kernels  the pallas kernels of the default training path (fused
-           attention, fused dropout) and the opt-in fused decode step
-           against their XLA references at the real shapes, plus the
-           repo's own ``needs_tpu`` test cases, in this same process.
+           attention, fused dropout) and of the decode tick (lane-window
+           append, live-block attention) against their XLA references at
+           the real shapes, plus the repo's own ``needs_tpu`` test cases,
+           in this same process.
 
 ``--chips 4`` (builder-run; the driver never passes it) runs ONLY the
 multi-chip paths and what they are compared with: the train steps on a
@@ -375,8 +376,7 @@ def _load_tests(name: str):
 
 def run_repo_tpu_tests() -> int:
     """The repo's own ``needs_tpu`` test cases (pytest skips them off-chip;
-    here they run in the process that holds the chip), and the decode-step
-    parity cases, which the CPU runs interpreted and the chip compiled."""
+    here they run in the process that holds the chip)."""
     def cases(fn):
         marks = {m.name: m for m in getattr(fn, "pytestmark", [])}
         if "parametrize" not in marks:
@@ -389,9 +389,7 @@ def run_repo_tpu_tests() -> int:
                  "test_attention_impls"):
         for name, fn in sorted(vars(_load_tests(file)).items()):
             marks, args = cases(fn)
-            if name.startswith("test_") and (
-                    "needs_tpu" in marks
-                    or name.startswith("test_fused_decode_step")):
+            if name.startswith("test_") and "needs_tpu" in marks:
                 for case in args:
                     fn(*case)
                     n += 1
@@ -408,7 +406,6 @@ def phase_kernels() -> None:
         decode_attention,
     )
     from building_llm_from_scratch_tpu.ops.decode_step import (
-        fused_decode_step,
         lane_window_append,
         live_block_attention,
         slot_cache_append,
@@ -466,28 +463,16 @@ def phase_kernels() -> None:
     want = f32(x) + np.where(kept, f32(h) / 0.9, 0.0)
     np.testing.assert_allclose(fwd, want, atol=6e-2, rtol=2e-2)
 
-    # fused decode step at the engine's shape (8 slots, Tmax 1024, per-row
-    # lengths) vs slot_cache_append + decode_attention
-    S, Tmax = 8, 1024
-    q1, kn, vn = (jax.random.normal(kk, (S, 1, H, D), jnp.bfloat16)
-                  for kk in ks[:3])
-    K, V = (jax.random.normal(kk, (S, H, Tmax, D), jnp.bfloat16)
-            for kk in ks[3:5])
-    lengths = jnp.asarray([0, 1, 7, 8, 133, 512, 1000, 1023], jnp.int32)
-    K2 = slot_cache_append(K, kn.transpose(0, 2, 1, 3), lengths)
-    V2 = slot_cache_append(V, vn.transpose(0, 2, 1, 3), lengths)
-    ref = decode_attention(q1, K2, V2, q_positions=lengths[:, None],
-                           kv_length=lengths + 1)
-    o, Ko, Vo = jax.jit(fused_decode_step)(q1, kn, vn, K, V, lengths)
-    check(np.array_equal(f32(Ko), f32(K2)) and np.array_equal(f32(Vo),
-                                                              f32(V2)),
-          "kernels: fused decode step wrote the cache differently")
-    np.testing.assert_allclose(f32(o), f32(ref), atol=2e-2, rtol=2e-2)
-
     # the lane-window append (what the engine's tick program writes the
-    # cache with) vs the scatter it replaces: pure data movement, so exact,
+    # cache with) at the engine's shape (8 slots, Tmax 1024, per-row
+    # lengths) vs the scatter it replaces: pure data movement, so exact,
     # with the caches donated as the engine donates them. Window edges,
     # both ends, and fp32 as well as bf16
+    S, Tmax = 8, 1024
+    kn, vn = (jax.random.normal(kk, (S, 1, H, D), jnp.bfloat16)
+              for kk in ks[1:3])
+    K, V = (jax.random.normal(kk, (S, H, Tmax, D), jnp.bfloat16)
+            for kk in ks[3:5])
     lengths = jnp.asarray([0, 127, 128, 255, 133, 512, 1000, 1023],
                           jnp.int32)
     append = jax.jit(lane_window_append, donate_argnums=(0, 1))
@@ -527,7 +512,7 @@ def phase_kernels() -> None:
 
     n = run_repo_tpu_tests()
     log(f"kernels: fused attention fwd/grad/dropout, fused dropout-add, "
-        f"fused decode step, lane-window append and live-block attention "
+        f"lane-window append and live-block attention "
         f"match their XLA references at the real shapes; "
         f"{n} needs_tpu repo test cases pass; "
         f"{time.perf_counter() - t0:.1f}s")

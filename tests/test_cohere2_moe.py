@@ -165,8 +165,8 @@ def test_shares_of_disjoint_held_sets_add_up_to_the_uncut_layer(model):
             cut = cfg.replace(experts_held=held)
             p_cut = dict(p0, moe=dict(p0["moe"], experts=jax.tree_util.tree_map(
                 lambda a: a[jnp.asarray(held)], p0["moe"]["experts"])))
-            full, _ = tf._block(cut, p_cut, x, tf._rope_tables(cfg), None,
-                                None, None, None, True, kind="sliding")
+            full = tf._block(cut, p_cut, x, tf._rope_tables(cfg), None,
+                             None, True, kind="sliding")
             routed = moe.moe_ffn(cut, p_cut["moe"], n)[0][0] - shared
             once = full[0] - x[0] - routed        # attention + shared experts
             total = x[0] + once if total is None else total
@@ -367,12 +367,16 @@ def test_repeated_first_layer_tie_is_read_under_both_resolutions(
      "prefix"),
     (dict(kv_policy=KVCachePolicy(kv_quant="int8")), "int8"),
     (dict(spec_k=2), "spec_k"),
+    (dict(spec_k=2, no_rings=True), "rejected drafts to experts"),
     (dict(kv_policy=KVCachePolicy(prefill_chunk=3)), "whole prefill chunks"),
     (dict(adapters=object()), "LoRA"),
 ])
 def test_engine_refuses_what_rings_and_experts_do_not_support(model, kw,
                                                               match):
     cfg, params, _ = model
+    kw = dict(kw)
+    if kw.pop("no_rings", False):          # the experts alone refuse it too
+        cfg = cfg.replace(layer_kinds=(), sliding_window=0)
     with pytest.raises(ValueError, match=match):
         DecodeEngine(cfg, params, None, n_slots=2, **kw)
 

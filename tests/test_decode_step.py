@@ -1,12 +1,12 @@
-"""Fused decode-step kernel (ops/decode_step.py) parity vs the jnp
-decode path, plus the custom-VJP norm gradient checks (round 5).
+"""The custom-VJP norm gradient checks (round 5) and the pallas xent and
+dropout kernels' cases and gates.
 
-The decode-step kernel runs compiled on the chip and interpreted on the
-CPU; the xent kernel case needs the real chip (chip_smoke.py runs it
-there); the norm gradient tests run everywhere.
+The xent kernel case needs the real chip (chip_smoke.py runs it there);
+the norm gradient tests and the gates run everywhere. The decode tick's
+kernels (ops/decode_step.py) are held by tests/test_serving.py and
+tests/test_tpu_compile.py.
 """
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,94 +14,6 @@ import numpy as np
 import pytest
 
 needs_tpu = pytest.mark.needs_tpu      # skipped off-chip by conftest.py
-
-
-def _decode_step():
-    """The fused kernel, jitted: compiled on the chip, interpreted on the
-    CPU (same kernel body, so the parity cases run everywhere)."""
-    from building_llm_from_scratch_tpu.ops.decode_step import (
-        fused_decode_step,
-    )
-
-    return jax.jit(functools.partial(
-        fused_decode_step, interpret=jax.default_backend() != "tpu"))
-
-
-@pytest.mark.parametrize("B,Hq,Hkv,hd,Tmax,t", [
-    (2, 12, 12, 64, 320, 5),      # GPT2-ish MHA
-    (2, 32, 8, 64, 320, 17),      # GQA
-    (8, 12, 12, 64, 320, 0),      # append at the very start
-    (1, 32, 8, 128, 256, 100),    # large head dim
-])
-def test_fused_decode_step_matches_jnp_path(B, Hq, Hkv, hd, Tmax, t):
-    from building_llm_from_scratch_tpu.ops.attention import decode_attention
-
-    Tq = 1
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    q = jax.random.normal(ks[0], (B, Tq, Hq, hd), jnp.bfloat16)
-    kn = jax.random.normal(ks[1], (B, Tq, Hkv, hd), jnp.bfloat16)
-    vn = jax.random.normal(ks[2], (B, Tq, Hkv, hd), jnp.bfloat16)
-    K = jax.random.normal(ks[3], (B, Hkv, Tmax, hd), jnp.bfloat16)
-    V = jax.random.normal(ks[4], (B, Hkv, Tmax, hd), jnp.bfloat16)
-    length = jnp.asarray(t, jnp.int32)
-    positions = t + jnp.arange(Tq)
-
-    K2 = jax.lax.dynamic_update_slice(K, kn.transpose(0, 2, 1, 3),
-                                      (0, 0, t, 0))
-    V2 = jax.lax.dynamic_update_slice(V, vn.transpose(0, 2, 1, 3),
-                                      (0, 0, t, 0))
-    ref = decode_attention(q, K2, V2, q_positions=positions,
-                           kv_length=length + Tq)
-
-    out, Ko, Vo = _decode_step()(q, kn, vn, K, V, length)
-    np.testing.assert_allclose(np.asarray(Ko, np.float32),
-                               np.asarray(K2, np.float32))
-    np.testing.assert_allclose(np.asarray(Vo, np.float32),
-                               np.asarray(V2, np.float32))
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               atol=2e-2, rtol=2e-2)
-
-
-def test_fused_decode_step_per_row_lengths():
-    """Per-row lengths (the serving engine's slot batch, ops/decode_step
-    slot semantics): each row appends at ITS offset and attends its own
-    valid prefix — must match running each row alone at a scalar length."""
-    B, Hq, Hkv, hd, Tmax = 3, 12, 12, 64, 320
-    ks = jax.random.split(jax.random.PRNGKey(7), 5)
-    q = jax.random.normal(ks[0], (B, 1, Hq, hd), jnp.bfloat16)
-    kn = jax.random.normal(ks[1], (B, 1, Hkv, hd), jnp.bfloat16)
-    vn = jax.random.normal(ks[2], (B, 1, Hkv, hd), jnp.bfloat16)
-    K = jax.random.normal(ks[3], (B, Hkv, Tmax, hd), jnp.bfloat16)
-    V = jax.random.normal(ks[4], (B, Hkv, Tmax, hd), jnp.bfloat16)
-    lengths = jnp.asarray([0, 7, 133], jnp.int32)
-
-    out, Ko, Vo = _decode_step()(q, kn, vn, K, V, lengths)
-    for b in range(B):
-        ob, Kb, Vb = _decode_step()(
-            q[b:b + 1], kn[b:b + 1], vn[b:b + 1], K[b:b + 1], V[b:b + 1],
-            lengths[b])
-        np.testing.assert_allclose(np.asarray(Ko[b:b + 1], np.float32),
-                                   np.asarray(Kb, np.float32))
-        np.testing.assert_allclose(np.asarray(Vo[b:b + 1], np.float32),
-                                   np.asarray(Vb, np.float32))
-        np.testing.assert_allclose(np.asarray(out[b:b + 1], np.float32),
-                                   np.asarray(ob, np.float32),
-                                   atol=2e-2, rtol=2e-2)
-
-
-def test_decode_step_supports_shape_gates():
-    from building_llm_from_scratch_tpu.ops.decode_step import supports_shape
-
-    mha = dict(Hkv=12, Hq=12)
-    assert supports_shape(1, 320, 64, **mha)
-    assert not supports_shape(2, 320, 64, **mha)   # single-token only
-    assert not supports_shape(1, 60, 64, **mha)    # Tmax must be 8-aligned
-    assert not supports_shape(1, 320, 96, **mha)   # head dim lane alignment
-    # every Hkv pane of a row sits in VMEM at once: the engine's GPT2-124M
-    # shape fits, a 32-head 8k-token fp32 cache does not
-    assert supports_shape(1, 1024, 64, **mha)
-    assert not supports_shape(1, 8192, 128, Hkv=32, Hq=32, itemsize=4)
 
 
 # ---------------------------------------------------------------------------

@@ -160,10 +160,9 @@ def test_kv_append_gate_refusals_take_the_scatter(why, Tq, kw):
     lens = jnp.asarray([0, 5], jnp.int32)
 
     def append(cache, k, lens):
-        new = tf._new_cache_acc(cache)
-        tf._slot_append_kv(cache, new, 0, cache["k"][0], cache["v"][0],
-                           k, k, lens)
-        return new
+        kv = tf._RowsKV(tiny_cfg(ctx=128), cache, k.shape[1], lens)
+        kv._append(0, k, k, lens)
+        return kv.result()
 
     assert "pallas_call" not in str(jax.make_jaxpr(append)(cache, k, lens))
 
@@ -289,8 +288,9 @@ def test_decode_attention_gate_refusals_take_decode_attention(monkeypatch,
                if ring else {})
 
     def attend(cache, q, lens):
-        return tf._slot_attend(cfg, cache, cache, 0, q, cache["k"][0],
-                               cache["v"][0], lens, ring_kw)
+        kv = tf._RowsKV(cfg, cache, 1, lens)
+        kv.new = cache                     # as after the layer's append
+        return kv._attend(0, q, cache["k"][0], cache["v"][0], ring_kw)
 
     assert "live_block_attention" not in str(
         jax.make_jaxpr(attend)(cache, q, lens))

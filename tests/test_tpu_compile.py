@@ -99,39 +99,6 @@ def test_fused_dropout_add_fwd_grad(one_chip):
     assert hlo.count("tpu_custom_call") == 2     # forward, mask redraw
 
 
-def _decode_specs(s, S=8, H=12, Tmax=1024, hd=64):
-    new, pane = s((S, 1, H, hd)), s((S, H, Tmax, hd))
-    return new, new, new, pane, pane, s((S,), I32)
-
-
-def test_fused_decode_step_engine_shape(one_chip):
-    """The serving engine's decode tick: 8 slots, Tmax 1024, PER-ROW
-    lengths (the (1, 1) SMEM block this call used to pass was refused)."""
-    assert ds.supports_shape(1, 1024, 64, Hkv=12, Hq=12)
-    hlo = _compile(ds.fused_decode_step, *_decode_specs(_spec(one_chip)))
-    assert hlo.count("tpu_custom_call") == 1
-
-
-def test_fused_decode_step_refused_inside_a_deep_program(one_chip):
-    """Why ``transformer._use_fused_decode`` is opt-in: from six layers up
-    the compiler assigns whole cache arrays to VMEM beside the kernel's own
-    scope and refuses the program. When this case starts FAILING (the
-    program compiles), the default can be revisited (ROADMAP S1/S5)."""
-    s = _spec(one_chip)
-    q, kn, vn, pane, _, lens = _decode_specs(s)
-
-    def six_layers(Ks, Vs, q, kn, vn, lens):
-        caches = []
-        for K, V in zip(Ks, Vs):
-            o, K, V = ds.fused_decode_step(q, kn, vn, K, V, lens)
-            q = q + o
-            caches.append((K, V))
-        return q, caches
-
-    with pytest.raises(Exception, match="vmem"):
-        _compile(six_layers, [pane] * 6, [pane] * 6, q, kn, vn, lens)
-
-
 @pytest.mark.parametrize("size,S,dtype", [
     ("1.5B", 32, "bf16"),        # the serving cells: panes (32, 25, 1024, 64)
     ("124M", 8, "bf16"),         # (8, 12, 1024, 64)
@@ -146,8 +113,7 @@ def test_decode_slots_appends_in_place_at_full_depth(one_chip, monkeypatch,
     ``while`` (the scatter's S-trip loops), no pane copied or relaid, and
     no reduction over all (S, H, Tmax) positions left (``decode_attention``'s
     two a layer). A kernel handed the logical shape gets a relayout copy
-    of every pane in and out; the fused step one case down is refused
-    outright. (Full depth on purpose: a six-layer cut of the 1.5B program
+    of every pane in and out. (Full depth on purpose: a six-layer cut of the 1.5B program
     leaves the compiler VMEM to spare, and it then parks whole panes
     there, which the real program never does.)"""
     import dataclasses
@@ -310,20 +276,6 @@ def test_sharded_attention_on_four_devices(topo):
     assert hlo.count("tpu_custom_call") == 3
     with pytest.raises(NotImplementedError, match="shard_map"):
         _compile(_attention_grad, qkv, qkv, qkv, rng)   # no mesh in scope
-
-
-def test_sharded_decode_step_on_four_devices(topo):
-    """``--serve_tp 4``: heads (and the slot cache's panes) shard over the
-    model axis; each device appends and attends its own three heads."""
-    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
-                ("data", "seq", "model"))
-    heads = _spec(NamedSharding(mesh, P(None, None, "model")))
-    panes = _spec(NamedSharding(mesh, P(None, "model")))
-    new, pane = heads((8, 1, 12, 64)), panes((8, 12, 1024, 64))
-    lens = jax.ShapeDtypeStruct((8,), I32, sharding=NamedSharding(mesh, P()))
-    hlo = _compile(trace_under_mesh(ds.fused_decode_step, mesh),
-                   new, new, new, pane, pane, lens)
-    assert hlo.count("tpu_custom_call") == 1
 
 
 def test_sharded_lane_window_append_on_four_devices(topo):
