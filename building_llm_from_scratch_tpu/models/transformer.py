@@ -1037,6 +1037,40 @@ def decode_attention_path(cache: Params, Tq: int, n_heads: int, *,
     return "whole_buffer"
 
 
+def chunk_attention_path(cache: Params, C: int, n_heads: int, *,
+                         layer: int = 0,
+                         backend: Optional[str] = None) -> str:
+    """THE rule for how ``_ChunkKV`` attends in layer ``layer``, the third of
+    the family and made the same way: ``"live_blocks"``
+    (ops/chunk_attention.chunk_live_attention: one kernel call a layer over
+    the key blocks that hold the row's live positions, a ring's by position)
+    on a TPU backend for the shapes ``supports_chunk_attention`` admits,
+    ``"materialised"`` (``decode_attention`` over the row sliced out: every
+    score written out, whole buffers) for everything else: int8 caches and
+    their scale sidecars, ``head_dim`` under 128 (the runtime keeps
+    positions on the lanes there: the other layout), a chunk or a buffer
+    that is not whole key blocks, any other backend. A page table never
+    asks (``_ChunkKV`` reads through it before it gets here, as ``_RowsKV``
+    does). A mesh is no refusal: the kernel shard_maps itself over the
+    ambient one like its siblings (``mesh_kernel``: heads over the model
+    axis, whole over any other, so a sequence-sharded chunk is gathered for
+    it). Nor is a ring: the kernel reads one by position. The engine
+    reports the name (``stats()["chunk_attention"]``). ``backend`` is for
+    tests, which have no TPU to ask about."""
+    from building_llm_from_scratch_tpu.ops.chunk_attention import (
+        supports_chunk_attention,
+    )
+
+    pane = cache["k"][layer]                   # (S, Hkv, Tmax, hd)
+    _, Hkv, Tmax, hd = pane.shape
+    if ((backend or jax.default_backend()) == "tpu"
+            and not _cache_quantized(cache)
+            and supports_chunk_attention(C, Tmax, hd, Hkv=Hkv, Hq=n_heads,
+                                         dtype=pane.dtype)):
+        return "live_blocks"
+    return "materialised"
+
+
 @jax.named_scope("cache_update")
 def _slot_write(cache: Params, name: str, pane: jnp.ndarray, offsets: tuple,
                 new: Params) -> None:
@@ -1238,8 +1272,10 @@ class _ChunkKV(_SlotKV):
     past ``prompt_len`` (``prefill_chunk_into_slot``): the zeroed chunk
     lands in row ``slot`` (in a ring: at ``chunk_start mod R``), then the
     chunk attends over THAT row, freshly including itself: earlier chunks /
-    a copied prefix pane are the context. The logits read is clamped to a
-    valid row of the chunk. With ``table`` the row is reached through its
+    a copied prefix pane are the context (``chunk_attention_path`` chooses
+    the read's form: one kernel over the row's live key blocks, or
+    ``decode_attention`` over the row sliced out). The logits read is
+    clamped to a valid row of the chunk. With ``table`` the row is reached through its
     lane of the page table (below): the C positions scatter into its pages
     and attention gathers that one row's view; any position past the row's
     allocated frontier lands on the trash page — never read unmasked."""
@@ -1301,6 +1337,18 @@ class _ChunkKV(_SlotKV):
             self.cfg, kind, self.cache["k"][l].shape[2], self.chunk_start,
             self.C)
         self._write_panes(k, v, (self.slot, 0, write_at, 0))
+        if chunk_attention_path(self.cache, self.C, self.cfg.n_heads,
+                                layer=l) == "live_blocks":
+            from building_llm_from_scratch_tpu.ops.chunk_attention import (
+                chunk_live_attention,
+            )
+
+            with _attention_scope(bool(ring_kw)):
+                return chunk_live_attention(
+                    q, self.new["k"][l], self.new["v"][l], self.slot,
+                    self.chunk_start, kv_len[0],
+                    window=ring_kw.get("window"),
+                    interpret=jax.default_backend() != "tpu")
         K_row, V_row = (jax.lax.dynamic_slice(
             a, (self.slot, 0, 0, 0), (1,) + a.shape[1:])
             for a in (self.new["k"][l], self.new["v"][l]))

@@ -115,7 +115,10 @@ TICK_IDLE_WAIT = "tick.idle_wait"
 #: row is the one anchor to unix time. ``phases`` holds the self seconds
 #: of each phase that ran; ``tick`` is ``n_ticks`` after the tick; ``rows``
 #: of the program's ``n_slots`` rows decoded a token, after ``chunks`` prefill
-#: chunks of slots in mid-prefill (chunked prefill only, absent at 0), reading
+#: chunks of slots in mid-prefill (chunked prefill only, absent at 0) whose
+#: attention read ``chunk_kv_touched`` key positions (summed over the layers:
+#: the live key blocks in a layer on the chunk kernel's path, whole buffers in
+#: any other), reading
 #: ``kv_positions`` cache positions (live positions of those rows, summed
 #: over the layers, a window layer counting at most its window) and touching
 #: ``kv_touched`` (the positions the program's attention did read, every row
@@ -127,7 +130,8 @@ TICK_IDLE_WAIT = "tick.idle_wait"
 #: that got a row: each read its weights once).
 TICK_RECORD_FIELDS = ("tick", "t0", "t1", "t_dispatch", "t_fetch", "phases",
                       "rows", "n_slots", "admitted", "queue_depth",
-                      "replica", "chunks", "kv_positions", "kv_touched",
+                      "replica", "chunks", "chunk_kv_touched",
+                      "kv_positions", "kv_touched",
                       "expert_rows", "experts_touched")
 
 #: Trainer StepTimeline segments (``<segment>_s`` fields of training
@@ -393,11 +397,12 @@ _EVENT_LIST: List[EventSpec] = [
                     "kv_bytes_per_slot", "prefix_pane_tokens", "spec_k",
                     "drafter", "replica", "kv_paged", "page_tokens",
                     "pool_pages", "sp", "prompt_pane_tokens", "max_prompt",
-                    "kv_append", "decode_attention"),
+                    "kv_append", "decode_attention", "chunk_attention"),
           doc="prefill programs + decode (or spec verify) program "
               "compiled; watchers frozen; records the KVCachePolicy "
               "(quant/chunk/prefix), which append and which attention the "
-              "tick program was built with (kv_append, decode_attention), "
+              "tick program was built with (kv_append, decode_attention) "
+              "and which attention the chunk program (chunk_attention), "
               "the speculative config "
               "(spec_k/drafter) when on, and the seq-sharded prefill "
               "geometry (sp/prompt_pane_tokens/max_prompt) on "

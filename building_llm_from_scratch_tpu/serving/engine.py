@@ -65,6 +65,7 @@ from building_llm_from_scratch_tpu.generate import (
     token_rng,
 )
 from building_llm_from_scratch_tpu.models.transformer import (
+    chunk_attention_path,
     decode_attention_path,
     init_slot_cache,
     kv_append_path,
@@ -96,6 +97,9 @@ from building_llm_from_scratch_tpu.obs.timeline import (
     StepTimeline,
     annotate,
     annotate_step,
+)
+from building_llm_from_scratch_tpu.ops.chunk_attention import (
+    chunk_positions_read,
 )
 from building_llm_from_scratch_tpu.ops.decode_step import LIVE_BLOCK
 from building_llm_from_scratch_tpu.parallel.collectives import (
@@ -376,6 +380,23 @@ class DecodeEngine:
             self.decode_attention = (
                 "live_blocks" if any(b for b, _ in self._attn_reads)
                 else "whole_buffer")
+        #: the same for the chunk program's attention
+        #: (``chunk_attention_path``): "live_blocks" (the key blocks that
+        #: hold the row's live positions) | "materialised", or "paged"; None
+        #: where prefill is not chunked and no chunk program ever runs.
+        #: ``_chunk_reads``: a layer's (on the kernel's path, buffer length):
+        #: what a tick's ``chunk_kv_touched`` counts
+        chunked = self.kv_policy.prefill_chunk > 0
+        self._chunk_reads = [self._chunk_read(self.cache, l)
+                             for l in range(cfg.n_layers)] if chunked else []
+        if not chunked:
+            self.chunk_attention = None
+        elif self._paged:
+            self.chunk_attention = "paged"
+        else:
+            self.chunk_attention = (
+                "live_blocks" if any(k for k, _ in self._chunk_reads)
+                else "materialised")
         #: the weights ride every compiled program as an ARGUMENT: closed
         #: over, jit bakes them into each program as constants (GPT2-124M
         #: bf16: 0.3 GB per program, 40 s per compile on the chip, and
@@ -1581,6 +1602,11 @@ class DecodeEngine:
             st["pos"] = lo + C
             self.prefill_chunks += 1
             self._tick_rec["chunks"] = self._tick_rec.get("chunks", 0) + 1
+            # inside the span: the phases of a tick add up to its wall
+            self._tick_rec["chunk_kv_touched"] = (
+                self._tick_rec.get("chunk_kv_touched", 0)
+                + sum(chunk_positions_read(lo, hi, C, buffer) if kernel
+                      else buffer for kernel, buffer in self._chunk_reads))
         # EARLY insertion: the moment the chunk covering the storable
         # span lands, the pane [0, span) is final — store it NOW so
         # co-admitted sharers (still mid-prefill behind us) catch up
@@ -1835,6 +1861,18 @@ class DecodeEngine:
             cache, self.spec_k + 1, self.cfg.n_heads, layer=l,
             ring=self.cfg.layer_kind(l) == "sliding") == "live_blocks"
         return LIVE_BLOCK if live else 0, cache["k"][l].shape[2]
+
+    def _chunk_read(self, cache, l: int) -> tuple:
+        """(on the chunk kernel's path, key positions of the buffer) of
+        layer ``l``'s chunk attention (``chunk_attention_path``): the kernel
+        reads the live key blocks (``chunk_positions_read``), any other
+        path the buffer whole, the page table's a gathered view of the
+        row's logical length."""
+        if self._paged:
+            return False, self._cache_len
+        return (chunk_attention_path(
+            cache, self.kv_policy.prefill_chunk, self.cfg.n_heads,
+            layer=l) == "live_blocks", cache["k"][l].shape[2])
 
     def _kv_positions_read(self, decoding) -> tuple:  # holds: _lock
         """Cache positions this tick's attention has to read, and those it
@@ -2480,6 +2518,7 @@ class DecodeEngine:
             kv_bytes_per_slot=bps["total_bytes"],
             kv_append=self.kv_append,
             decode_attention=self.decode_attention,
+            chunk_attention=self.chunk_attention,
             prefix_pane_tokens=(self._prefix_pane_len
                                 if self.prefix_store is not None
                                 else None),
@@ -2900,6 +2939,7 @@ class DecodeEngine:
             out["kv_policy"] = self.kv_policy.describe()
             out["kv_append"] = self.kv_append
             out["decode_attention"] = self.decode_attention
+            out["chunk_attention"] = self.chunk_attention
             out.update(self.layout())
             out["memory"] = self.memory_ledger.describe()
             if self._paged:
@@ -3069,6 +3109,7 @@ class DecodeEngine:
             "warmed_up": self.warmed_up,
             "kv_append": self.kv_append,
             "decode_attention": self.decode_attention,
+            "chunk_attention": self.chunk_attention,
             **self.layout(),
             "draining": self.draining,
             "restarts": self.n_restarts,

@@ -760,6 +760,7 @@ class EngineRouter:
                 "slo_miss_ratio": p["slo_miss_ratio"],
                 "kv_append": p["kv_append"],
                 "decode_attention": p["decode_attention"],
+                "chunk_attention": p["chunk_attention"],
             })
         up = [r for r in replicas if r["status"] == "serving"]
         if self._dead is not None:

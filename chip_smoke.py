@@ -266,6 +266,7 @@ def serve(label: str, reqs_path: str, n: int, extra=(), model=()):
         f"{engine.n_recompiles} bucket-miss compiles after warmup, "
         f"kv_append {engine.kv_append}, "
         f"decode_attention {engine.decode_attention}, "
+        f"chunk_attention {engine.chunk_attention}, "
         f"{time.perf_counter() - t0:.1f}s, peak HBM {peak_hbm_gib()} GiB")
     return engine, results
 
@@ -404,6 +405,10 @@ def phase_kernels() -> None:
     from building_llm_from_scratch_tpu.ops.attention import (
         _xla_attention,
         decode_attention,
+        ring_positions,
+    )
+    from building_llm_from_scratch_tpu.ops.chunk_attention import (
+        chunk_live_attention,
     )
     from building_llm_from_scratch_tpu.ops.decode_step import (
         lane_window_append,
@@ -510,9 +515,33 @@ def phase_kernels() -> None:
               f"{float(np.abs(got - want).max()):.2e} off decode_attention "
               f"on {jnp.dtype(dt).name} panes")
 
+    # the chunk kernel (what a head_dim-128 engine's chunk program attends
+    # with) vs decode_attention on the sliced row, at the rag cell's two
+    # buffers: a ring that has wrapped and a full layer, the prompt ending
+    # inside the chunk, another slot than 0
+    C, Hq, Hkv, hd, slot = 512, 128, 8, 128, 2
+    chunk = jax.jit(chunk_live_attention, static_argnames="window")
+    for Tmax, ring, start in ((4608, True, 9216), (20480, False, 6144)):
+        window = 4096 if ring else None
+        kv_len = start + C - 37
+        qc = jax.random.normal(ks[0], (1, C, Hq, hd), jnp.bfloat16)
+        K, V = (jax.random.normal(kk, (4, Hkv, Tmax, hd), jnp.bfloat16)
+                for kk in ks[1:3])
+        ring_kw = ({"kv_positions": ring_positions(
+            jnp.reshape(start + C - 1, (1,)), Tmax), "window": window}
+            if ring else {})
+        want = jax.jit(lambda q, K, V: decode_attention(
+            q, K[slot:slot + 1], V[slot:slot + 1],
+            q_positions=(start + jnp.arange(C))[None],
+            kv_length=jnp.asarray([kv_len]), **ring_kw))(qc, K, V)
+        got = chunk(qc, K, V, slot, start, kv_len, window=window)
+        gap = float(np.abs(f32(got) - f32(want))[:, :kv_len - start].max())
+        check(gap < 2e-2, f"kernels: chunk attention is {gap:.2e} off "
+              f"decode_attention on a buffer of {Tmax}")
+
     n = run_repo_tpu_tests()
     log(f"kernels: fused attention fwd/grad/dropout, fused dropout-add, "
-        f"lane-window append and live-block attention "
+        f"lane-window append, live-block and chunk attention "
         f"match their XLA references at the real shapes; "
         f"{n} needs_tpu repo test cases pass; "
         f"{time.perf_counter() - t0:.1f}s")

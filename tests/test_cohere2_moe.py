@@ -276,6 +276,71 @@ def test_engine_tokens_match_reference_and_slot_reuse_is_clean(model, policy):
     assert eng.n_recompiles == 0
 
 
+def test_chunk_kernel_path_equals_the_materialised_path(monkeypatch):
+    """The chunk program's attention on the live-block kernel (the rule told
+    it is on a TPU; the kernel interprets on the CPU it really is on) at a
+    ``head_dim`` of 128, window 128 and chunks of 128: a 450-token prompt
+    (its fourth chunk carries pads, and starts past window + chunk: the
+    rings of 256 have wrapped) leaves the logits and every layer's buffers
+    that the materialised path leaves; a second, short request in the same
+    slot reads nothing of the first; the engine names its path and the tick
+    record counts the live key blocks where the other path counts whole
+    buffers."""
+    import functools
+
+    from building_llm_from_scratch_tpu.serving import engine as engine_mod
+
+    C, window, T, n_prompt = 128, 128, 1024, 450
+    cfg = debug_cfg(attn_head_dim=128, sliding_window=window, n_layers=4,
+                    context_length=T)
+    params = tf.init_params(cfg, jax.random.PRNGKey(0))
+    policy = KVCachePolicy(prefill_chunk=C)
+    seq = np.asarray(tokens_of(cfg, n_prompt)[0])
+
+    def prefill():
+        cache = tf.init_slot_cache(cfg, 2, T, policy=policy)
+        chunk = jax.jit(lambda c, t, s: tf.prefill_chunk_into_slot(
+            params, cfg, t, s, jnp.int32(n_prompt), jnp.int32(1), c))
+        for lo in range(0, n_prompt, C):
+            piece = np.zeros((1, C), np.int32)
+            piece[0, :min(C, n_prompt - lo)] = seq[lo:lo + C]
+            logits, cache = chunk(cache, piece, jnp.int32(lo))
+        return logits, cache
+
+    def serve():
+        eng = DecodeEngine(cfg, params, None, n_slots=1, kv_policy=policy,
+                           max_len=T)
+        reqs = [eng.submit(p, SamplingParams(max_new_tokens=3, **GREEDY))
+                for p in (seq, seq[:140])]
+        eng.run_until_idle()
+        chunks = [t["chunk_kv_touched"]
+                  for t in get_metrics().recent("tick") if t.get("chunks")]
+        assert eng.stats()["chunk_attention"] == eng.chunk_attention
+        return (eng.chunk_attention, [r.output_ids for r in reqs],
+                chunks[-6:])
+
+    want_logits, want_cache = prefill()
+    want = serve()
+    on_tpu = functools.partial(tf.chunk_attention_path, backend="tpu")
+    monkeypatch.setattr(tf, "chunk_attention_path", on_tpu)
+    monkeypatch.setattr(engine_mod, "chunk_attention_path", on_tpu)
+    got_logits, got_cache = prefill()
+    got = serve()
+    assert [k.shape[2] for k in got_cache["k"]] == [256, 256, 256, T]
+    assert float(jnp.abs(got_logits - want_logits).max()) < 1e-4
+    for name in ("k", "v"):
+        for a, b in zip(got_cache[name], want_cache[name]):
+            assert float(jnp.abs(a - b).max()) < 1e-4
+    assert (want[0], got[0]) == ("materialised", "live_blocks")
+    assert got[1] == want[1]
+    # the long prompt's four chunks and the short one's two, three rings
+    # and a full layer each: whole buffers; or what is written so far (a
+    # ring: at most 256) less the chunk's own blocks past the prompt
+    assert want[2] == [3 * 256 + T] * 6
+    assert got[2] == [4 * 128, 4 * 256, 3 * 256 + 384, 3 * 256 + 512,
+                      4 * 128, 4 * 256]
+
+
 def test_coresident_requests_in_bf16_agree_with_the_reference(model):
     """The cell's own arithmetic (bfloat16 weights and cache, float32
     router, three of the eight experts held) with three requests sharing

@@ -183,3 +183,8 @@ def test_engine_serves_experts_through_pages():
             * cfg.n_layers for t in ticks[-3:])
     assert out["pages"] == out["slots"]
     assert eng.kv_append == eng.decode_attention == "paged"
+    # the chunk program reads through the table too: a gathered view of the
+    # row's whole logical length, every layer
+    assert eng.chunk_attention == eng.stats()["chunk_attention"] == "paged"
+    chunked = [t for t in get_metrics().recent("tick") if t.get("chunks")]
+    assert chunked[-1]["chunk_kv_touched"] == cfg.n_layers * eng._cache_len

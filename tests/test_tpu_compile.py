@@ -229,6 +229,65 @@ def test_sparse_window_decode_tick_fits_and_copies_no_expert(one_chip,
     assert not re.search(r"= bf16\[8,4096,4096\]", entry)
 
 
+def test_sparse_window_chunk_program_attends_in_one_kernel_a_layer(
+        one_chip, monkeypatch):
+    """The chunk program of ``serve_command_a_plus_rag_mixed`` as the engine
+    jits it (``_chunk_impl``, cache donated) at the cell's own sizes (48
+    slots, a chunk of 512, 128 query heads on 8 key-value heads of 128,
+    rings of 4608 beside one full layer of 20480): each layer's attention
+    is ONE custom call that reaches the slot's row in place (no pane
+    copied, none sliced out), no ``while`` is left (the materialised form
+    ran one key-value head at a time, float32 scores of 8192 query rows
+    against the whole buffer written out: four loops), and the program's
+    temporaries are under the 869,611,008 bytes it needed with them
+    (``scripts/serving_hlo.py`` at the parent, PERF.md section 6, PR 34)."""
+    import re
+
+    from building_llm_from_scratch_tpu.configs import get_config
+    from building_llm_from_scratch_tpu.models import transformer as tf
+    from building_llm_from_scratch_tpu.serving import engine as eng
+    from building_llm_from_scratch_tpu.serving.kvcache import KVCachePolicy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("command_a_plus", "218B", dtype="bf16",
+                     target_context_length=None).replace(
+        n_layers=4, vocab_size=32768, context_length=20480,
+        experts_held=tuple(range(8)))
+    S, C = 48, 512
+    # an engine that holds nothing: only what its program builder reads
+    e = object.__new__(eng.DecodeEngine)
+    e.cfg, e.n_slots, e.max_top_k, e.spec_k, e._paged = cfg, S, 64, 0, False
+    e._cache_shardings = e._sp_sharding = e.mesh_plan = None
+    e.kv_policy = KVCachePolicy(prefill_chunk=C)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    shapes = lambda f, *a: jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(f, *a))
+    params = shapes(lambda: tf.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = shapes(lambda: tf.init_slot_cache(cfg, S, cfg.context_length,
+                                              policy=e.kv_policy))
+    assert [tf.chunk_attention_path(cache, C, cfg.n_heads, layer=l)
+            for l in range(4)] == ["live_blocks"] * 4
+    scalar = lambda dtype: sds((), dtype)
+    compiled = jax.jit(e._chunk_impl, donate_argnums=(0,)).lower(
+        cache, (params, None), sds((1, C), I32), scalar(I32), scalar(I32),
+        scalar(I32), sds((2,), jnp.uint32), scalar(jnp.float32),
+        scalar(I32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 4
+    assert not re.search(r" while\(", hlo)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 869_611_008
+    assert memory.alias_size_in_bytes == sum(
+        2 * np.prod(k.shape) * 2 for k in cache["k"])
+    # a layer's buffers are arguments, updated in place and read by the
+    # kernel where they lie: never copied, never sliced to a row
+    panes = set(re.findall(
+        r"= bf16\[48,8,(?:4608|20480),128\]\S* ([\w\-]+)\(", hlo))
+    assert panes == {"parameter", "dynamic-update-slice"}, panes
+    assert not re.search(r"= bf16\[1,8,(?:4608|20480),128\]", hlo)
+
+
 def test_paged_decode_attention(one_chip):
     S, H, hd, page, n_pages, max_pages = 8, 12, 64, 16, 512, 64
     assert ds.supports_paged_shape(1, page, hd)
@@ -313,6 +372,28 @@ def test_sharded_live_block_attention_on_four_devices(topo):
     assert "bf16[8,3,64,1024]" in hlo and " copy(" not in "".join(
         ln for ln in hlo.split("\n") if "bf16[8,3,1024,64]" in ln
         or "bf16[8,3,64,1024]" in ln)
+
+
+def test_sharded_chunk_live_attention_on_four_devices(topo):
+    """The chunk program's attention under ``--serve_tp 4``: each device
+    attends the chunk's queries of its own two key-value heads (eight query
+    heads) over its own share of the row, and no pane is copied."""
+    from building_llm_from_scratch_tpu.ops import chunk_attention as ca
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
+                ("data", "seq", "model"))
+    heads = _spec(NamedSharding(mesh, P(None, None, "model")))
+    panes = _spec(NamedSharding(mesh, P(None, "model")))
+    q, pane = heads((1, 512, 32, 128)), panes((8, 8, 4608, 128))
+    at = jax.ShapeDtypeStruct((), I32, sharding=NamedSharding(mesh, P()))
+    assert ca.supports_chunk_attention(512, 4608, 128, Hkv=8, Hq=32,
+                                       dtype=BF16)
+    hlo = _compile(trace_under_mesh(
+        lambda *a: ca.chunk_live_attention(*a, window=4096),
+        mesh), q, pane, pane, at, at, at)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "bf16[8,2,4608,128]" in hlo and " copy(" not in "".join(
+        ln for ln in hlo.split("\n") if "bf16[8,2,4608,128]" in ln)
 
 
 @pytest.mark.parametrize("S,Hq,Hkv,dtype", [
