@@ -73,7 +73,7 @@ class ModelConfig:
     hidden_dim: int                      # FFN hidden width
     n_kv_groups: int                     # == n_heads for full MHA
     norm: str = "layernorm"              # 'layernorm' | 'rmsnorm'
-    positional: str = "learned"          # 'learned' | 'rope'
+    positional: str = "learned"          # 'learned' | 'rope' | 'none'
     activation: str = "gelu"             # 'gelu' | 'swiglu'
     qkv_bias: bool = False               # GPT-2 --load_weights sets True
     attn_out_bias: bool = False          # GPT-2 uses biased out-proj
@@ -96,7 +96,8 @@ class ModelConfig:
     tie_embeddings: bool = False         # logits = h @ tok_emb.T, no head leaf
     #: the kinds of one period of layers, repeated down the depth:
     #: 'sliding' (attends to the last ``sliding_window`` positions, itself
-    #: included) | 'full'. Empty = every layer full.
+    #: included) | 'full' | 'linear' (no keys and values: a gated delta
+    #: rule over a recurrent state, below). Empty = every layer full.
     layer_kinds: Tuple[str, ...] = ()
     sliding_window: int = 0
     rope_interleaved: bool = False       # rotate pairs (2i, 2i+1), not halves
@@ -111,21 +112,40 @@ class ModelConfig:
     #: the global ids of the routed experts THIS chip holds (empty = all):
     #: routing is over all, the routed sum over the held ones only
     experts_held: Tuple[int, ...] = ()
+    #: a 'full' or 'sliding' layer's output times sigmoid(n @ wg), the
+    #: query's width, before the output projection
+    attn_out_gate: bool = False
+    #: 'linear' layers (ops/linear_attention.py): ``linear_heads`` heads of
+    #: ``linear_head_dim`` (keys and values alike), a causal depthwise
+    #: convolution of ``linear_conv`` taps on q, k and v, a per-channel
+    #: decay and an output gate each factorised through ``linear_gate_rank``,
+    #: a step size sigmoid(.) in (0, 1), or (0, 2) with ``linear_neg_eigval``
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    linear_conv: int = 4
+    linear_gate_rank: int = 0
+    linear_neg_eigval: bool = False
 
     def __post_init__(self):
         # a JSON file hands lists; the config must stay hashable
         for name in ("layer_kinds", "experts_held"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
         if self.layer_kinds:
-            if set(self.layer_kinds) - {"sliding", "full"}:
+            if set(self.layer_kinds) - {"sliding", "full", "linear"}:
                 raise ValueError(f"layer_kinds {self.layer_kinds}: each is "
-                                 "'sliding' or 'full'")
+                                 "'sliding', 'full' or 'linear'")
             if self.n_layers % len(self.layer_kinds):
                 raise ValueError(
                     f"n_layers {self.n_layers} is not whole periods of "
                     f"{len(self.layer_kinds)} layers")
             if "sliding" in self.layer_kinds and self.sliding_window < 1:
                 raise ValueError("'sliding' layers need sliding_window >= 1")
+            if self.has_linear_layers and not (
+                    self.linear_heads and self.linear_head_dim
+                    and self.linear_gate_rank and self.linear_conv > 1):
+                raise ValueError(
+                    "'linear' layers need linear_heads, linear_head_dim, "
+                    "linear_gate_rank and linear_conv >= 2")
         if self.n_routed_experts:
             held = self.held_experts
             if not (0 < self.n_experts_per_tok <= self.n_routed_experts):
@@ -158,6 +178,19 @@ class ModelConfig:
         return "sliding" in self.layer_kinds
 
     @property
+    def has_linear_layers(self) -> bool:
+        return "linear" in self.layer_kinds
+
+    def layers_of(self, *kinds: str) -> Tuple[int, ...]:
+        """The layers of these kinds, in order."""
+        return tuple(l for l in range(self.n_layers)
+                     if self.layer_kind(l) in kinds)
+
+    @property
+    def linear_width(self) -> int:
+        return self.linear_heads * self.linear_head_dim
+
+    @property
     def jax_dtype(self):
         return DTYPE_MAP[self.dtype]
 
@@ -184,6 +217,16 @@ class ModelConfig:
         if self.qkv_bias:
             qkv += nh * hd + 2 * nkv * hd
         attn_out = (nh * hd) * d + (d if self.attn_out_bias else 0)
+        if self.attn_out_gate:
+            attn_out += d * (nh * hd)
+        # a 'linear' layer's mixer: q, k, v, out; the conv's taps; A_log a
+        # head, dt_bias a channel; the step size; decay and output gate
+        # through their rank; the scale of the norm on a head's output
+        w, r = self.linear_width, self.linear_gate_rank
+        linear = (4 * d * w + self.linear_conv * 3 * w + self.linear_heads
+                  + w + d * self.linear_heads + 2 * (d * r + r * w)
+                  + self.linear_head_dim)
+        n_linear = len(self.layers_of("linear"))
         if self.is_moe:
             held = len(self.held_experts)
             n_routed = (self.n_experts_per_tok * held / self.n_routed_experts
@@ -195,18 +238,20 @@ class ModelConfig:
         else:
             mlp = 2 * d * f + ((f + d) if self.mlp_bias else 0)
         norm_w = d * (2 if self.norm_bias else 1)
-        per_layer = (qkv + attn_out + mlp
-                     + (1 if self.parallel_block else 2) * norm_w)
+        per_layer = mlp + (1 if self.parallel_block else 2) * norm_w
         final_norm = d * (2 if self.norm_bias else 1)
         head = 0 if self.tie_embeddings else d * v
-        total = per_layer * self.n_layers + final_norm + head
+        total = (per_layer * self.n_layers + linear * n_linear
+                 + (qkv + attn_out) * (self.n_layers - n_linear)
+                 + final_norm + head)
         if not exclude_embeddings or self.tie_embeddings:
             total += emb
         return total
 
 
-#: what a model with window layers ("window") or sparse experts ("moe")
-#: does not run through yet: (feature, the property that refuses it, why).
+#: what a model with window layers ("window"), sparse experts ("moe") or
+#: linear-attention layers ("linear") does not run through yet:
+#: (feature, the property that refuses it, why).
 #: ONE list, asked by the flags' checks (``args.perform_checks``) and by the
 #: serving engine at construction, by what the config IS, never by its name
 #: (PERF.md section 7 lists them; ROADMAP R1 / R2 say what each takes)
@@ -239,6 +284,31 @@ UNSUPPORTED = (
     ("lora", "moe",
      "LoRA adapters attach to the dense MLP's projections and the expert "
      "layer has none: run it without adapters"),
+    ("paged", "linear",
+     "a page holds positions and a recurrent state has none: serve it on "
+     "the slot cache"),
+    ("prefix_cache", "linear",
+     "a prefix pane is a slot's first positions; the state after them "
+     "would have to be kept beside it and is not: serve it without "
+     "--serve_prefix_cache"),
+    ("int8_cache", "linear",
+     "the recurrent state is float32 and has no int8 form: serve it with "
+     "the model's own cache type"),
+    ("speculation", "linear",
+     "a rejected draft has already moved the recurrent state and there is "
+     "no copy to go back to: serve it with spec_k 0"),
+    ("tensor_parallel", "linear",
+     "the state's heads have no tensor-parallel split yet: run it on one "
+     "chip, or under dp, fsdp or zero1"),
+    ("pipeline_parallel", "linear",
+     "a pipeline stage runs layers of one kind: run it under dp, fsdp or "
+     "zero1"),
+    ("sequence_parallel", "linear",
+     "a sequence split would hand the state from shard to shard and no "
+     "schedule does"),
+    ("lora", "linear",
+     "LoRA adapters attach to attention's projections and a linear layer "
+     "has others: run it without adapters"),
 )
 
 
@@ -249,13 +319,16 @@ def refuse_unsupported(cfg: "ModelConfig", **features) -> None:
     unknown = set(features) - {name for name, _, _ in UNSUPPORTED}
     if unknown:
         raise TypeError(f"refuse_unsupported: no such feature {unknown}")
-    has = {"window": cfg.has_window_layers, "moe": cfg.is_moe}
+    has = {"window": cfg.has_window_layers, "moe": cfg.is_moe,
+           "linear": cfg.has_linear_layers}
     has["either"] = has["window"] or has["moe"]
     for name, needs, why in UNSUPPORTED:
         if features.get(name) and has[needs]:
             kinds = " and ".join(
                 what for k, what in (("window", "window layers"),
-                                     ("moe", "sparse experts")) if has[k])
+                                     ("moe", "sparse experts"),
+                                     ("linear", "linear-attention layers"))
+                if has[k])
             raise ValueError(f"{cfg.name} ({kinds}): {why}")
 
 
@@ -447,6 +520,45 @@ COMMAND_A_PLUS_CONFIG = ModelConfig(
 )
 
 
+# Solar-Open2-250B (upstage/Solar-Open2-250B, model_type solar_open2): 48
+# serial RMSNorm blocks in periods of four, one gated NoPE GQA layer (64
+# query heads over 8 key-value heads of 128, the output times a sigmoid gate
+# of the query's width) then three gated-delta-rule linear layers (64 heads
+# of 128, conv 4, per-channel decay, step size in (0, 2)); no positions
+# anywhere; every feed-forward 320 sigmoid-routed experts of width 1280
+# (top-8, renormalised) beside one shared expert; embedding and head untied.
+# 250B parameters, 15B active: a chip of an expert-parallel deployment is
+# this config with ``experts_held`` (and, in the benchmark's file, fewer
+# layers and a slice of the vocabulary).
+SOLAR_OPEN2_CONFIG = ModelConfig(
+    name="solar-open2-250b",
+    vocab_size=196_608,
+    context_length=1_048_576,
+    emb_dim=4096,
+    n_heads=64,
+    n_layers=48,
+    hidden_dim=1280,                     # one expert's width
+    n_kv_groups=8,
+    attn_head_dim=128,
+    norm="rmsnorm",
+    positional="none",
+    activation="swiglu",
+    layer_kinds=("full", "linear", "linear", "linear"),
+    attn_out_gate=True,
+    linear_heads=64,
+    linear_head_dim=128,
+    linear_conv=4,
+    linear_gate_rank=128,
+    linear_neg_eigval=True,
+    n_routed_experts=320,
+    n_experts_per_tok=8,
+    n_shared_experts=1,
+    eos_id=2,
+    eos_text="<|endoftext|>",
+    dtype="bf16",
+)
+
+
 # Supported model types and their sizes (reference: utils.py:44-50)
 MODEL_PARAMS_MAPPING = {
     "GPT2": ["124M", "355M", "774M", "1.5B"],
@@ -456,10 +568,12 @@ MODEL_PARAMS_MAPPING = {
     "llama3_2": ["1B"],
     "longctx": ["32k"],
     "command_a_plus": ["218B"],
+    "solar_open2": ["250B"],
 }
 
 _LLAMA_REGISTRY = {
     ("command_a_plus", "218B"): COMMAND_A_PLUS_CONFIG,
+    ("solar_open2", "250B"): SOLAR_OPEN2_CONFIG,
     ("llama2", "7B"): LLAMA2_CONFIG_7B,
     ("llama3", "8B"): LLAMA3_CONFIG_8B,
     ("llama3_1", "8B"): LLAMA31_CONFIG_8B,
@@ -541,8 +655,11 @@ def get_config(model: str, num_params: str, *,
                 context_length=64, n_heads=4, attn_head_dim=16,
                 n_layers=2 * max(1, len(cfg.layer_kinds)), sliding_window=8,
                 n_routed_experts=8, n_experts_per_tok=2,
-                n_shared_experts=2, experts_held=(), vocab_size=512,
-                eos_id=511)
+                n_shared_experts=min(cfg.n_shared_experts, 2),
+                experts_held=(), vocab_size=512, eos_id=511)
+            if cfg.has_linear_layers:
+                tiny.update(linear_heads=4, linear_head_dim=16,
+                            linear_gate_rank=8)
         cfg = cfg.replace(**tiny)
     return cfg
 

@@ -44,14 +44,25 @@ Parameter tree layout (linear weights stored (in, out), applied as x @ w):
 
 A ``parallel_block`` config has no ``norm2``; a sparse one (``cfg.is_moe``)
 has ``blocks["moe"]`` (models/moe.py) in place of ``blocks["mlp"]``. Layers
-of unlike KINDS (``cfg.layer_kinds``: 'sliding' | 'full') share one leaf
+of the KINDS 'sliding' and 'full' (``cfg.layer_kinds``) share one leaf
 shape, so they stack like any others; the kind decides the layer's mask,
-its positions and, in the slot cache, whether its buffer is a ring.
+its positions and, in the slot cache, whether its buffer is a ring. A
+'linear' layer (ops/linear_attention.py) has another mixer with other
+leaves, so the MIXERS are stacked by kind: ``blocks["attn"]`` over the
+attention layers alone (with ``"wg"`` under ``cfg.attn_out_gate``) and
+
+      "linear":  {"wq", "wk", "wv": (Ll, D, W), "wo": (Ll, W, D),
+                  "conv": (Ll, K, 3W), "A_log": (Ll, H), "dt_bias": (Ll, W),
+                  "w_fa", "w_ga": (Ll, D, r), "w_fb", "w_gb": (Ll, r, W),
+                  "w_b": (Ll, D, H), "o_norm": {"scale": (Ll, hd)}}
+
+over the Ll linear ones (W = H * hd); norms and feed-forwards stay (L, ...).
+``_layer_of`` / ``_by_period`` give a layer its own.
 """
 
 from __future__ import annotations
 
-from functools import cached_property
+from functools import cached_property, partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -67,6 +78,13 @@ from building_llm_from_scratch_tpu.ops.attention import (
     ring_positions,
 )
 from building_llm_from_scratch_tpu.ops.activations import gelu, silu
+from building_llm_from_scratch_tpu.ops.linear_attention import (
+    causal_conv,
+    chunked_delta_rule,
+    l2norm,
+    linear_attention_path,
+    recurrent_step,
+)
 from building_llm_from_scratch_tpu.ops.norms import layernorm, rmsnorm
 from building_llm_from_scratch_tpu.ops.rope import (
     apply_rope,
@@ -174,6 +192,31 @@ def _linear_init(key, in_dim: int, out_dim: int, dtype, n_layers=None):
             * std).astype(dtype)
 
 
+def _init_linear_params(cfg: ModelConfig, key: jax.Array, Ll: int) -> Params:
+    """The 'linear' layers' mixers. ``A_log`` and ``dt_bias`` are drawn from
+    the family's ranges (a decay rate A in [1, 16] a head, a time step dt in
+    [0.001, 0.1] a channel, kept as log A and softplus^-1 dt), so slow and
+    fast channels both exist from the start."""
+    D, dt = cfg.emb_dim, cfg.jax_dtype
+    H, W, r = cfg.linear_heads, cfg.linear_width, cfg.linear_gate_rank
+    keys = jax.random.split(key, 12)
+    lin = lambda i, a, b: _linear_init(keys[i], a, b, dt, Ll)
+    step = jnp.exp(jax.random.uniform(
+        keys[10], (Ll, W), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "wq": lin(0, D, W), "wk": lin(1, D, W), "wv": lin(2, D, W),
+        "wo": lin(3, W, D),
+        "conv": lin(4, cfg.linear_conv, 3 * W),
+        "A_log": jnp.log(jax.random.uniform(
+            keys[9], (Ll, H), jnp.float32, 1.0, 16.0)).astype(dt),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        "w_fa": lin(5, D, r), "w_fb": lin(6, r, W),
+        "w_ga": lin(7, D, r), "w_gb": lin(8, r, W),
+        "w_b": lin(11, D, H),
+        "o_norm": {"scale": jnp.ones((Ll, cfg.linear_head_dim), dt)},
+    }
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Build the full parameter pytree for ``cfg``."""
     L, D, V, T = cfg.n_layers, cfg.emb_dim, cfg.vocab_size, cfg.context_length
@@ -184,17 +227,21 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     zeros = lambda *shape: jnp.zeros(shape, dt)
     ones = lambda *shape: jnp.ones(shape, dt)
 
+    Ll = len(cfg.layers_of("linear"))
+    La = L - Ll                    # the mixers are stacked by kind
     attn: Params = {
-        "wq": _linear_init(keys[0], D, Hq * hd, dt, L),
-        "wk": _linear_init(keys[1], D, Hkv * hd, dt, L),
-        "wv": _linear_init(keys[2], D, Hkv * hd, dt, L),
-        "wo": _linear_init(keys[3], Hq * hd, D, dt, L),
+        "wq": _linear_init(keys[0], D, Hq * hd, dt, La),
+        "wk": _linear_init(keys[1], D, Hkv * hd, dt, La),
+        "wv": _linear_init(keys[2], D, Hkv * hd, dt, La),
+        "wo": _linear_init(keys[3], Hq * hd, D, dt, La),
     }
     if cfg.qkv_bias:
-        attn.update(bq=zeros(L, Hq * hd), bk=zeros(L, Hkv * hd),
-                    bv=zeros(L, Hkv * hd))
+        attn.update(bq=zeros(La, Hq * hd), bk=zeros(La, Hkv * hd),
+                    bv=zeros(La, Hkv * hd))
     if cfg.attn_out_bias:
-        attn["bo"] = zeros(L, D)
+        attn["bo"] = zeros(La, D)
+    if cfg.attn_out_gate:
+        attn["wg"] = _linear_init(keys[11], D, Hq * hd, dt, La)
 
     def norm(n_layers=None):
         n: Params = {"scale": ones(n_layers, D) if n_layers else ones(D)}
@@ -203,6 +250,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         return n
 
     blocks: Params = {"norm1": norm(L), "attn": attn}
+    if Ll:
+        blocks["linear"] = _init_linear_params(cfg, keys[12], Ll)
     if not cfg.parallel_block:
         blocks["norm2"] = norm(L)
     if cfg.is_moe:
@@ -425,7 +474,79 @@ def _attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                 window=window,
             )
     out = checkpoint_name(out, "attn_out")
+    if cfg.attn_out_gate:
+        out = _attn_gate(p, out, x)
     return _attn_out_proj(p, out, B, Tq, tp_axis=tp_axis, adp=adp)
+
+
+@jax.named_scope("attention_gate")
+def _attn_gate(p: Params, out: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
+    """``cfg.attn_out_gate``: attention's output (B, T, Hq, hd) times
+    sigmoid(h @ wg), elementwise, before the output projection."""
+    return out * jax.nn.sigmoid(h @ p["wg"]).reshape(out.shape).astype(
+        out.dtype)
+
+
+@jax.named_scope("linear_gates")
+def _linear_gates(cfg: ModelConfig, p: Params, h: jnp.ndarray):
+    """A 'linear' layer's data-dependent rates, float32: the log-decay of
+    each key channel ``g = -exp(A_log) * softplus((h w_fa) w_fb + dt_bias)``
+    (B, T, H, hd), the step size ``beta`` (B, T, H) and the output gate
+    (B, T, H, hd)."""
+    B, T, _ = h.shape
+    f32, heads = jnp.float32, (B, T, cfg.linear_heads, cfg.linear_head_dim)
+    rate = jax.nn.softplus(((h @ p["w_fa"]) @ p["w_fb"]).astype(f32)
+                           + p["dt_bias"].astype(f32)).reshape(heads)
+    g = -jnp.exp(p["A_log"].astype(f32))[:, None] * rate
+    beta = jax.nn.sigmoid((h @ p["w_b"]).astype(f32))
+    if cfg.linear_neg_eigval:
+        beta = 2.0 * beta
+    gate = jax.nn.sigmoid(((h @ p["w_ga"]) @ p["w_gb"]).astype(f32))
+    return g, beta, gate.reshape(heads)
+
+
+def _linear_mixer(cfg: ModelConfig, p: Params, h: jnp.ndarray, through_state,
+                  valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """A 'linear' layer's mixer on its normed input ``h`` (B, T, D): q, k, v
+    through the short convolution, the gated delta rule, a norm and a gate
+    on each head's output, the output projection. ``through_state(run)``
+    hands ``run`` the convolution's tail and the state these tokens start
+    from and keeps what it returns: ``run(tail, state, n_valid=None) ->
+    (o, tail, state)``. Positions that ``valid`` (B, T) bool leaves out
+    (padding, a row that does not decode) move no state."""
+    B, T, _ = h.shape
+    H, hd = cfg.linear_heads, cfg.linear_head_dim
+    with jax.named_scope("linear_proj"):
+        pre = jnp.concatenate([h @ p["wq"], h @ p["wk"], h @ p["wv"]],
+                              axis=-1)
+    g, beta, gate = _linear_gates(cfg, p, h)
+    if valid is not None:
+        g = jnp.where(valid[:, :, None, None], g, 0.0)
+        beta = jnp.where(valid[:, :, None], beta, 0.0)
+
+    def run(tail, state, n_valid=None):
+        x, tail = causal_conv(pre, tail, p["conv"], n_valid)
+        q, k, v = (a.reshape(B, T, H, hd) for a in jnp.split(x, 3, axis=-1))
+        q, k = l2norm(q) * hd ** -0.5, l2norm(k)
+        if linear_attention_path(T) == "step":
+            with jax.named_scope("linear_attention"):
+                o, state = recurrent_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                          beta[:, 0], state)
+            return o[:, None], tail, state
+        o, state = chunked_delta_rule(q, k, v, g, beta, state)
+        return o, tail, state
+
+    o = through_state(run)
+    with jax.named_scope("linear_proj"):
+        y = rmsnorm(o, p["o_norm"]["scale"], eps=cfg.rmsnorm_eps) * gate
+        return y.astype(h.dtype).reshape(B, T, H * hd) @ p["wo"]
+
+
+def _fresh_state(cfg: ModelConfig, B: int, dtype):
+    """The tail and the state before a sequence: zeros."""
+    H, hd = cfg.linear_heads, cfg.linear_head_dim
+    return (jnp.zeros((B, cfg.linear_conv - 1, 3 * H * hd), dtype),
+            jnp.zeros((B, H, hd, hd), jnp.float32))
 
 
 def _layer_rope(cfg: ModelConfig, rope, kind: str):
@@ -502,11 +623,16 @@ def _block(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     else:
         r_attn = r_res1 = r_res2 = None
     n1 = _norm(cfg, p["norm1"], x)
-    h = _attention(cfg, p["attn"], n1, _layer_rope(cfg, rope, kind),
-                   positions, r_attn, deterministic, sp_mesh=sp_mesh,
-                   sp_inside=sp_inside, tp_axis=tp_axis,
-                   adp=adp["attn"] if adp is not None else None,
-                   window=_layer_window(cfg, kind))
+    if kind == "linear":
+        # a whole sequence from the zero state; nothing is kept
+        h = _linear_mixer(cfg, p["linear"], n1, lambda run: run(
+            *_fresh_state(cfg, x.shape[0], x.dtype))[0])
+    else:
+        h = _attention(cfg, p["attn"], n1, _layer_rope(cfg, rope, kind),
+                       positions, r_attn, deterministic, sp_mesh=sp_mesh,
+                       sp_inside=sp_inside, tp_axis=tp_axis,
+                       adp=adp["attn"] if adp is not None else None,
+                       window=_layer_window(cfg, kind))
     if cfg.parallel_block:
         h = h + _ffn(cfg, p, n1, tp_axis=tp_axis, adp=adp)
         return _residual_dropout(x, h, cfg.drop_rate, r_res1, deterministic)
@@ -667,16 +793,18 @@ def forward_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
 
     # the scan runs over PERIODS of layers: one layer where all are of one
     # kind, else ``cfg.layer_kinds`` unlike layers in a row, their stacked
-    # leaves re-led (L, ...) -> (L / P, P, ...)
+    # leaves re-led (L, ...) -> (L / P, P, ...) (``_by_period``)
     kinds = cfg.layer_kinds or ("full",)
     P = len(kinds)
 
     def body(carry, period):
         if P == 1:
             return one_layer(carry, period, kinds[0]), None
+        blocks, *rest = period
         for j, kind in enumerate(kinds):
-            carry = one_layer(
-                carry, jax.tree_util.tree_map(lambda a: a[j], period), kind)
+            carry = one_layer(carry, (
+                _period_layer(cfg, blocks, j),
+                *jax.tree_util.tree_map(lambda a: a[j], rest)), kind)
         return carry, None
 
     if cfg.use_actv_ckpt:
@@ -711,8 +839,8 @@ def forward_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
     else:
         xs = (params["blocks"], layer_rngs)
     if P > 1:
-        xs = jax.tree_util.tree_map(
-            lambda a: a.reshape((L // P, P) + a.shape[1:]), xs)
+        xs = (_by_period(cfg, xs[0]), *jax.tree_util.tree_map(
+            lambda a: a.reshape((L // P, P) + a.shape[1:]), xs[1:]))
     x, _ = jax.lax.scan(body, x, xs,
                         unroll=max(1, _train_scan_unroll(cfg) // P))
     return _norm(cfg, params["final_norm"], x)
@@ -809,8 +937,7 @@ def unstack_blocks(params: Params, cfg: ModelConfig) -> list:
     loop-invariant weight transposes)."""
     blocks = params["blocks"]
     if not cfg.is_moe:
-        return [jax.tree_util.tree_map(lambda a, l=l: a[l], blocks)
-                for l in range(cfg.n_layers)]
+        return [_layer_of(cfg, blocks, l) for l in range(cfg.n_layers)]
     # the routed experts stay stacked, the layer's index beside them: each
     # is sliced inside the conditional that runs it (models/moe.py)
     experts = blocks["moe"]["experts"]
@@ -818,10 +945,47 @@ def unstack_blocks(params: Params, cfg: ModelConfig) -> list:
                              if k != "experts"})
     out = []
     for l in range(cfg.n_layers):
-        layer = jax.tree_util.tree_map(lambda a, l=l: a[l], rest)
+        layer = _layer_of(cfg, rest, l)
         layer["moe"]["experts"] = dict(experts, layer=l)
         out.append(layer)
     return out
+
+
+def _mixer_of(kind: str) -> str:
+    """The group of ``params["blocks"]`` that holds a layer's mixer."""
+    return "linear" if kind == "linear" else "attn"
+
+
+def _layer_of(cfg: ModelConfig, blocks: Params, l: int) -> Params:
+    """Layer ``l``'s view of the stacked ``blocks``: leaf ``[l]``, and of
+    the mixers, stacked by kind, its own at its index among its kind."""
+    if not cfg.has_linear_layers:
+        return jax.tree_util.tree_map(lambda a: a[l], blocks)
+    mixer = _mixer_of(cfg.layer_kind(l))
+    at = sum(_mixer_of(cfg.layer_kind(i)) == mixer for i in range(l))
+    index = lambda i: lambda tree: jax.tree_util.tree_map(
+        lambda a: a[i], tree)
+    return {name: index(at if name == mixer else l)(sub)
+            for name, sub in blocks.items()
+            if name == mixer or name not in ("attn", "linear")}
+
+
+def _by_period(cfg: ModelConfig, blocks: Params) -> Params:
+    """The stacked ``blocks`` for a scan over periods of P layers: (L, ...)
+    -> (L / P, P, ...), a mixer's leaves (stacked over its own kind's
+    layers) -> (L / P, that kind's layers a period, ...)."""
+    kinds = cfg.layer_kinds
+    n = {m: sum(_mixer_of(k) == m for k in kinds) for m in ("attn", "linear")}
+    return {name: jax.tree_util.tree_map(
+        lambda a, p=n.get(name, len(kinds)): a.reshape(
+            (cfg.n_layers // len(kinds), p) + a.shape[1:]), sub)
+        for name, sub in blocks.items()}
+
+
+def _period_layer(cfg: ModelConfig, period: Params, j: int) -> Params:
+    """The ``j``-th layer of one period of ``_by_period``'s view."""
+    one = cfg.replace(n_layers=len(cfg.layer_kinds))
+    return _layer_of(one, period, j)
 
 
 # ---------------------------------------------------------------------------
@@ -965,13 +1129,20 @@ def _slot_pass(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         attn_adp = adp["attn"] if adp is not None else None
         kind = cfg.layer_kind(l)
         h = _norm(cfg, p["norm1"], x)
-        q, k, v = _qkv_proj(cfg, p["attn"], h, _layer_rope(cfg, rope, kind),
-                            positions, adp=attn_adp)
-        out = kv.append_and_attend(l, kind, q, k, v)
-        x = _add_branches(
-            cfg, p, x, h,
-            _attn_out_proj(p["attn"], out, B, Tq, adp=attn_adp),
-            adp=adp, live=live, expert_rows=expert_rows)
+        if kind == "linear":
+            mixed = _linear_mixer(cfg, p["linear"], h,
+                                  partial(kv.through_state, l), valid=live)
+        else:
+            q, k, v = _qkv_proj(cfg, p["attn"], h,
+                                _layer_rope(cfg, rope, kind), positions,
+                                adp=attn_adp)
+            out = kv.append_and_attend(l, kind, q, k, v)
+            if cfg.attn_out_gate:
+                out = _attn_gate(p["attn"], out, h)
+            mixed = _attn_out_proj(p["attn"], out, B, Tq, adp=attn_adp)
+        kv.close_layer(l)
+        x = _add_branches(cfg, p, x, h, mixed, adp=adp, live=live,
+                          expert_rows=expert_rows)
     x = _norm(cfg, params["final_norm"], x)
     if kv.logits_at is not None:
         x = jax.lax.dynamic_slice(x, (0, kv.logits_at, 0),
@@ -1185,6 +1356,43 @@ class _SlotKV:
     def append_and_attend(self, l: int, kind: str, q, k, v) -> jnp.ndarray:
         raise NotImplementedError
 
+    def through_state(self, l: int, run) -> jnp.ndarray:
+        """A 'linear' layer's slot memory is no keys and values but the
+        K-1 tokens its convolution still needs (``cache["conv"][l]``, (rows,
+        K-1, 3W)) and a recurrent state (``cache["state"][l]``, (rows, H, hd,
+        hd) float32). The object hands ``run(tail, state, n_valid) -> (o,
+        tail, state)`` (``_linear_mixer``) what this pass's rows start from
+        (zeros where a sequence starts, whatever the slot held), keeps what
+        comes back for the rows that are real, and returns ``o``."""
+        raise NotImplementedError
+
+    def close_layer(self, l: int) -> None:
+        """After layer ``l``: what it did not write (a 'linear' layer its
+        keys and values, any other a state) is None in ``new``, so every
+        list stays indexed by layer."""
+        for bufs in self.new.values():
+            if len(bufs) == l:
+                bufs.append(None)
+
+    def _keep_state(self, tail, state) -> None:
+        self.new["conv"].append(tail)
+        self.new["state"].append(state)
+
+    def _slot_rows(self, l: int, slot, fresh, n_valid, run) -> jnp.ndarray:
+        """``through_state`` for the one row ``slot``: it starts from zeros
+        where ``fresh``, and the row is written back in place."""
+        row = lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0)
+        tail, state = self.cache["conv"][l], self.cache["state"][l]
+        o, new_tail, new_state = run(
+            *(jnp.where(fresh, jnp.zeros((), a.dtype), row(a))
+              for a in (tail, state)), n_valid)
+        with jax.named_scope("cache_update"):
+            self._keep_state(*(
+                jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype),
+                                                    slot, axis=0)
+                for a, b in ((tail, new_tail), (state, new_state))))
+        return o
+
     def result(self) -> Params:
         return self.new
 
@@ -1206,13 +1414,23 @@ class _SharedLengthKV(_SlotKV):
     prefix to itself. This cache's buffers are all as long as the sequence,
     so a 'sliding' layer needs its window in the mask and no ring."""
 
-    def __init__(self, cfg, cache, Tq: int):
+    def __init__(self, cfg, cache, Tq: int, valid_len=None):
         super().__init__(cfg, cache)
-        self.Tq = Tq
+        self.Tq, self.valid_len = Tq, valid_len
 
     @cached_property
     def positions(self):
         return self.cache["length"] + jnp.arange(self.Tq)
+
+    @property
+    def live(self):
+        """The first ``valid_len`` of the Tq tokens are real (a prompt
+        right-padded to its bucket): only they move a state."""
+        if self.valid_len is None:
+            return None
+        rows = self.cache["state"][self.cfg.layers_of("linear")[0]].shape[0]
+        return jnp.broadcast_to(jnp.arange(self.Tq) < self.valid_len,
+                                (rows, self.Tq))
 
     def append_and_attend(self, l, kind, q, k, v):
         length = self.cache["length"]
@@ -1221,6 +1439,12 @@ class _SharedLengthKV(_SlotKV):
                                 q_positions=self.positions,
                                 kv_length=length + self.Tq,
                                 window=_layer_window(self.cfg, kind))
+
+    def through_state(self, l, run):
+        o, tail, state = run(self.cache["conv"][l], self.cache["state"][l],
+                             self.valid_len)
+        self._keep_state(tail, state)
+        return o
 
     def result(self):
         return dict(self.new, length=self.cache["length"] + self.Tq)
@@ -1265,6 +1489,9 @@ class _PromptKV(_SlotKV):
         self._write_panes(*_zero_pads(self.valid, k, v),
                           (self.slot, 0, 0, 0))
         return out
+
+    def through_state(self, l, run):
+        return self._slot_rows(l, self.slot, True, self.prompt_len, run)
 
 
 class _ChunkKV(_SlotKV):
@@ -1322,6 +1549,12 @@ class _ChunkKV(_SlotKV):
             (1, self.table.shape[1]))
         pos = jnp.minimum(self.positions, self.cache_len - 1)
         return row_tab, row_tab[0, pos // P], pos % P
+
+    def through_state(self, l, run):
+        # a request's first chunk starts from zeros whatever the slot held;
+        # the tail kept ends at the chunk's last real token
+        return self._slot_rows(l, self.slot, self.chunk_start == 0,
+                               self.masks[1][0] - self.chunk_start, run)
 
     def append_and_attend(self, l, kind, q, k, v):
         valid, kv_len, q_pos = self.masks
@@ -1409,6 +1642,22 @@ class _RowsKV(_SlotKV):
             self.cfg, kind, self.cache["k"][l].shape[2], self.lengths)
         K, V = self._append(l, k, v, write_at)
         return self._attend(l, q, K, V, ring_kw)
+
+    def through_state(self, l, run):
+        """Every row one token on; a row that does not decode (free, or
+        between two of its prefill chunks) keeps its tail and its state bit
+        for bit."""
+        if self.Tq > 1:
+            raise ValueError("a verify tick has no way back from a state "
+                             "its rejected drafts have moved")
+        tail, state = self.cache["conv"][l], self.cache["state"][l]
+        o, new_tail, new_state = run(tail, state)
+        if self._live is not None:
+            new_tail = jnp.where(self._live[:, None, None], new_tail, tail)
+            new_state = jnp.where(self._live[:, None, None, None], new_state,
+                                  state)
+        self._keep_state(new_tail, new_state)
+        return o
 
     @jax.named_scope("cache_update")
     def _append(self, l, k, v, write_at):
@@ -1589,14 +1838,17 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                        blocks_list: Optional[list] = None,
                        lora: Optional[Params] = None,
                        lora_scaling=1.0,
-                       lora_blocks_list: Optional[list] = None
-                       ) -> Tuple[jnp.ndarray, Params]:
+                       lora_blocks_list: Optional[list] = None,
+                       valid_len=None) -> Tuple[jnp.ndarray, Params]:
     """Decode forward: process ``tokens`` (B, Tq) given ``cache`` holding
     ``cache['length']`` valid positions; returns (fp32 logits (B, Tq, V),
     updated cache). Static shapes throughout — jit-friendly. Pass
     ``blocks_list`` (from ``unstack_blocks``; ``lora_blocks_list`` from
     ``unstack_lora_blocks``) when calling inside a sampling loop so the
-    per-layer weight slices are hoisted out of it.
+    per-layer weight slices are hoisted out of it. ``valid_len`` (a model
+    with 'linear' layers, a prompt right-padded to its bucket): the first
+    that many tokens are real; the padding writes keys and values that the
+    caller's reset of ``length`` masks, but a state it must not move.
 
     Contract: the caller must ensure ``cache['length'] + Tq <= max_length``
     (the cache allocation). Under jit an overflow cannot raise —
@@ -1612,7 +1864,7 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                    "head": lora["head"]["weight"] if lora is not None
                    else None}
     return _slot_pass(params, cfg, tokens,
-                      _SharedLengthKV(cfg, cache, tokens.shape[1]),
+                      _SharedLengthKV(cfg, cache, tokens.shape[1], valid_len),
                       blocks_list=blocks_list, adapter=adapter)
 
 

@@ -42,24 +42,42 @@ STACKED_KEY = "blocks"
 HEALTH_ARRAYS = ("grad_norm", "param_norm", "update_norm", "update_ratio")
 
 
-def _stacked_len(tree: Dict[str, Any]) -> int:
-    """Leading-axis length shared by the stacked subtree's leaves (the
-    layer count for ``blocks``), or 0 when absent/empty."""
+def _stacked_groups(tree: Dict[str, Any]) -> List[tuple]:
+    """The stacked subtree's leaves by what they are stacked over:
+    ``[(label, rows, leaves)]``. First ``("block", L, ...)``: the leaves
+    stacked over all L layers (every leaf, for most models). A model whose
+    layers are of unlike kinds stacks each kind's mixer over its own layers
+    (models/transformer.py): such a subgroup (fewer rows than L) is a group
+    of its own, ``("attn", La, ...)``, ``("linear", Ll, ...)``, its i-th row
+    the i-th layer OF THAT KIND."""
     sub = tree.get(STACKED_KEY)
     if not isinstance(sub, dict):
-        return 0
+        return []
     leaves = jax.tree_util.tree_leaves(sub)
-    return int(leaves[0].shape[0]) if leaves else 0
+    if not leaves:
+        return []
+    L = max(int(leaf.shape[0]) for leaf in leaves)
+    groups = [("block", L, [x for x in leaves if x.shape[0] == L])]
+    for name in sorted(sub):
+        own = [x for x in jax.tree_util.tree_leaves(sub[name])
+               if x.shape[0] != L]
+        if own:
+            groups.append((str(name), int(own[0].shape[0]), own))
+    return groups
 
 
 def group_names(tree: Dict[str, Any]) -> List[str]:
     """Ordered group labels for ``tree`` (host-side; pairs with the arrays
     ``group_health`` returns). Sorted top-level keys, with the stacked
-    ``blocks`` subtree expanded to ``block_00..block_{L-1}``."""
+    ``blocks`` subtree expanded to ``block_00..block_{L-1}`` (and, for a
+    model that stacks its mixers by kind, that kind's ``attn_00..``,
+    ``linear_00..``: ``_stacked_groups``)."""
     names: List[str] = []
     for key in sorted(tree):
         if key == STACKED_KEY:
-            names.extend(f"block_{i:02d}" for i in range(_stacked_len(tree)))
+            names.extend(f"{label}_{i:02d}"
+                         for label, rows, _ in _stacked_groups(tree)
+                         for i in range(rows))
         else:
             names.append(str(key))
     return names
@@ -74,13 +92,13 @@ def _group_sumsq(tree: Dict[str, Any]) -> jnp.ndarray:
     for key in sorted(tree):
         leaves = jax.tree_util.tree_leaves(tree[key])
         if key == STACKED_KEY:
-            L = _stacked_len(tree)
-            acc = jnp.zeros((L,), jnp.float32)
-            for leaf in leaves:
-                x = leaf.astype(jnp.float32)
-                acc = acc + jnp.sum(jnp.square(x),
-                                    axis=tuple(range(1, x.ndim)))
-            parts.append(acc)
+            for _, rows, stacked in _stacked_groups(tree):
+                acc = jnp.zeros((rows,), jnp.float32)
+                for leaf in stacked:
+                    x = leaf.astype(jnp.float32)
+                    acc = acc + jnp.sum(jnp.square(x),
+                                        axis=tuple(range(1, x.ndim)))
+                parts.append(acc)
         else:
             acc0 = jnp.zeros((), jnp.float32)
             for leaf in leaves:
@@ -100,13 +118,13 @@ def _group_nonfinite(tree: Dict[str, Any]) -> jnp.ndarray:
     for key in sorted(tree):
         leaves = jax.tree_util.tree_leaves(tree[key])
         if key == STACKED_KEY:
-            L = _stacked_len(tree)
-            acc = jnp.zeros((L,), bool)
-            for leaf in leaves:
-                acc = acc | jnp.any(
-                    ~jnp.isfinite(leaf.astype(jnp.float32)),
-                    axis=tuple(range(1, leaf.ndim)))
-            parts.append(acc)
+            for _, rows, stacked in _stacked_groups(tree):
+                acc = jnp.zeros((rows,), bool)
+                for leaf in stacked:
+                    acc = acc | jnp.any(
+                        ~jnp.isfinite(leaf.astype(jnp.float32)),
+                        axis=tuple(range(1, leaf.ndim)))
+                parts.append(acc)
         else:
             acc0 = jnp.zeros((), bool)
             for leaf in leaves:

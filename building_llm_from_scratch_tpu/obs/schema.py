@@ -35,11 +35,15 @@ from typing import Any, Dict, FrozenSet, List
 
 #: Bump when a row type or a load-bearing field changes meaning. The
 #: ``header`` row carries it; consumers key parsing decisions on it.
-SCHEMA_VERSION = 13         # v13: long-context tier — prefill_shard
+SCHEMA_VERSION = 14         # v14: recurrent state beside keys and
+                            # values — serve_warmup gains
+                            # linear_attention, the tick record
+                            # state_rows / state_rows_touched
+                            # (v13: long-context tier — prefill_shard
                             # tick phase (seq-sharded chunk prefill,
                             # --serve_sp), serve_warmup gains
                             # sp / prompt_pane_tokens / max_prompt,
-                            # request_done gains long_prompt
+                            # request_done gains long_prompt)
                             # (v12: paged KV cache — page_admit /
                             # page_share / page_release /
                             # page_pool_exhausted events (serving page
@@ -127,12 +131,16 @@ TICK_IDLE_WAIT = "tick.idle_wait"
 #: of what was read). A sparse model's
 #: decode tick adds ``expert_rows`` (rows each held expert computed, summed
 #: over layers) and ``experts_touched`` (held experts, counted a layer,
-#: that got a row: each read its weights once).
+#: that got a row: each read its weights once). A model with 'linear'
+#: layers adds ``state_rows`` (decoding rows x linear layers: the recurrent
+#: states the tick had to read and write) and ``state_rows_touched`` (those
+#: the fixed-shape step did read and write: every row's).
 TICK_RECORD_FIELDS = ("tick", "t0", "t1", "t_dispatch", "t_fetch", "phases",
                       "rows", "n_slots", "admitted", "queue_depth",
                       "replica", "chunks", "chunk_kv_touched",
                       "kv_positions", "kv_touched",
-                      "expert_rows", "experts_touched")
+                      "expert_rows", "experts_touched",
+                      "state_rows", "state_rows_touched")
 
 #: Trainer StepTimeline segments (``<segment>_s`` fields of training
 #: cadence metrics rows; obs/timeline.py owns the measurement).
@@ -397,7 +405,8 @@ _EVENT_LIST: List[EventSpec] = [
                     "kv_bytes_per_slot", "prefix_pane_tokens", "spec_k",
                     "drafter", "replica", "kv_paged", "page_tokens",
                     "pool_pages", "sp", "prompt_pane_tokens", "max_prompt",
-                    "kv_append", "decode_attention", "chunk_attention"),
+                    "kv_append", "decode_attention", "chunk_attention",
+                    "linear_attention"),
           doc="prefill programs + decode (or spec verify) program "
               "compiled; watchers frozen; records the KVCachePolicy "
               "(quant/chunk/prefix), which append and which attention the "

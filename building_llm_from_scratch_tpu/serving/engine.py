@@ -102,6 +102,9 @@ from building_llm_from_scratch_tpu.ops.chunk_attention import (
     chunk_positions_read,
 )
 from building_llm_from_scratch_tpu.ops.decode_step import LIVE_BLOCK
+from building_llm_from_scratch_tpu.ops.linear_attention import (
+    linear_attention_path,
+)
 from building_llm_from_scratch_tpu.parallel.collectives import (
     trace_under_mesh,
 )
@@ -176,8 +179,8 @@ class DecodeEngine:
         import jax
 
         self.cfg = cfg
-        self._n_window_layers = sum(cfg.layer_kind(l) == "sliding"
-                                    for l in range(cfg.n_layers))
+        self._n_window_layers = len(cfg.layers_of("sliding"))
+        self._n_linear_layers = len(cfg.layers_of("linear"))
         #: parallel/sharding.MeshPlan (or None = the historical
         #: single-device engine, byte-for-byte). tp>1 runs the whole
         #: prefill/decode/verify program family with NamedSharding'd
@@ -372,8 +375,9 @@ class DecodeEngine:
         #: blocks only) | "whole_buffer", or "paged".
         #: ``_attn_reads``: {(block, buffer length): layers}, block 0 where a
         #: layer reads its buffer whole: what a tick's ``kv_touched`` counts
+        attn_layers = cfg.layers_of("full", "sliding")
         self._attn_reads = collections.Counter(
-            self._attention_read(self.cache, l) for l in range(cfg.n_layers))
+            self._attention_read(self.cache, l) for l in attn_layers)
         if self._paged:
             self.decode_attention = "paged"
         else:
@@ -388,7 +392,7 @@ class DecodeEngine:
         #: what a tick's ``chunk_kv_touched`` counts
         chunked = self.kv_policy.prefill_chunk > 0
         self._chunk_reads = [self._chunk_read(self.cache, l)
-                             for l in range(cfg.n_layers)] if chunked else []
+                             for l in attn_layers] if chunked else []
         if not chunked:
             self.chunk_attention = None
         elif self._paged:
@@ -397,6 +401,14 @@ class DecodeEngine:
             self.chunk_attention = (
                 "live_blocks" if any(k for k, _ in self._chunk_reads)
                 else "materialised")
+        #: the form each program's 'linear' layers were built with
+        #: (``linear_attention_path``): the tick's "step", a prefill's
+        #: "chunked"; None for a model with no such layer
+        self.linear_attention = ({
+            "tick": linear_attention_path(self.spec_k + 1),
+            "prefill": linear_attention_path(
+                self.kv_policy.prefill_chunk or self.max_len)}
+            if self._n_linear_layers else None)
         #: the weights ride every compiled program as an ARGUMENT: closed
         #: over, jit bakes them into each program as constants (GPT2-124M
         #: bf16: 0.3 GB per program, 40 s per compile on the chip, and
@@ -679,6 +691,12 @@ class DecodeEngine:
                 ledger.register("kv_scales",
                                 lambda: self._cache_component_bytes()[1],
                                 expected=lambda: bps["scale_bytes"] * n)
+            if "state_bytes" in bps:
+                ledger.register(
+                    "slot_state",
+                    lambda: sum(a.nbytes for key in ("conv", "state")  # graft-ok: GL031 nbytes metadata, runs at ledger cadence under the engine lock
+                                for a in self.cache[key] if a is not None),
+                    expected=lambda: bps["state_bytes"] * n)
             if self.spec_k:
                 bps_full = self.kv_policy.bytes_per_slot(self.cfg,
                                                          self._cache_len)
@@ -721,9 +739,9 @@ class DecodeEngine:
         sum to ``cache_nbytes(self.cache)`` byte-exactly because every
         array's byte count is divisible by its time extent."""
         kv_nb = sum(a.nbytes for key in ("k", "v")
-                    for a in self.cache.get(key, ()))
+                    for a in self.cache.get(key, ()) if a is not None)
         scale_nb = sum(a.nbytes for key in ("k_scale", "v_scale")
-                       for a in self.cache.get(key, ()))
+                       for a in self.cache.get(key, ()) if a is not None)
         slot_kv = kv_nb * self.max_len // self._cache_len
         kv_scales = scale_nb * self.max_len // self._cache_len
         return slot_kv, kv_scales, kv_nb + scale_nb - slot_kv - kv_scales
@@ -930,9 +948,10 @@ class DecodeEngine:
 
     def _step_tail(self) -> tuple:  # holds: _lock
         """The tick program's positional tail: the adapter pool and the
-        slots' rows of it; for a sparse model (which takes no adapter)
-        the rows that decode this tick."""
-        if self.cfg.is_moe:
+        slots' rows of it; for a sparse model or one with 'linear' layers
+        (neither takes an adapter) the rows that decode this tick: the
+        others reach no expert and move no state."""
+        if self.cfg.is_moe or self._n_linear_layers:
             live = np.zeros((self.n_slots,), np.bool_)
             live[[s for s, _ in self.scheduler.active()
                   if s not in self._prefill_state]] = True
@@ -1710,7 +1729,8 @@ class DecodeEngine:
             return
 
         def nan_row(layer):
-            if not jnp.issubdtype(layer.dtype, jnp.floating):
+            if layer is None or not jnp.issubdtype(layer.dtype,
+                                                    jnp.floating):
                 return layer
             host = np.asarray(layer).copy()
             host[slot] = np.nan
@@ -1884,7 +1904,8 @@ class DecodeEngine:
         lengths = self._lengths.tolist()    # plain ints: a few us a tick
         live = [lengths[s] + 1 for s, _ in decoding]
         n_window = self._n_window_layers
-        total = (self.cfg.n_layers - n_window) * sum(live)
+        total = (self.cfg.n_layers - n_window
+                 - self._n_linear_layers) * sum(live)
         if n_window:
             window = self.cfg.sliding_window
             total += n_window * sum(min(n, window) for n in live)
@@ -2061,6 +2082,14 @@ class DecodeEngine:
             # inside the span: the phases of a tick add up to its wall
             (self._tick_rec["kv_positions"],
              self._tick_rec["kv_touched"]) = self._kv_positions_read(decoding)
+            if self._n_linear_layers:
+                # the states this tick has to read and write (a decoding
+                # row's, a 'linear' layer) and those the fixed-shape step
+                # does: every row's
+                self._tick_rec["state_rows"] = (
+                    len(decoding) * self._n_linear_layers)
+                self._tick_rec["state_rows_touched"] = (
+                    self.n_slots * self._n_linear_layers)
             nxt, ok, cache = self._decode(
                 self.cache, self._weights, self._last_tokens, self._lengths,
                 self._base_keys, self._n_gen, self._temps,
@@ -2519,6 +2548,7 @@ class DecodeEngine:
             kv_append=self.kv_append,
             decode_attention=self.decode_attention,
             chunk_attention=self.chunk_attention,
+            linear_attention=self.linear_attention,
             prefix_pane_tokens=(self._prefix_pane_len
                                 if self.prefix_store is not None
                                 else None),
@@ -2896,11 +2926,17 @@ class DecodeEngine:
         """What the model's kinds of layer made of this engine, for
         ``stats()`` and ``/healthz``: the positions a slot holds in a
         window layer's ring and in a full layer (no window layers: the
-        one length), and the routed experts held here."""
+        one length), the recurrent state a slot holds beside them, and the
+        routed experts held here."""
         lengths = self.kv_policy.layer_lengths(self.cfg, self._cache_len)
         out = {"kv_positions": {"full": self._cache_len}}
         if self.cfg.has_window_layers:
-            out["kv_positions"]["ring"] = min(lengths)
+            out["kv_positions"]["ring"] = min(n for n in lengths if n)
+        if self._n_linear_layers:
+            out["state"] = {
+                "layers": self._n_linear_layers,
+                "bytes_per_slot": self.kv_policy.bytes_per_slot(
+                    self.cfg, self._cache_len)["state_bytes"]}
         if self.cfg.is_moe:
             out["experts"] = {"held": list(self.cfg.held_experts),
                               "routed": self.cfg.n_routed_experts,
@@ -2940,6 +2976,7 @@ class DecodeEngine:
             out["kv_append"] = self.kv_append
             out["decode_attention"] = self.decode_attention
             out["chunk_attention"] = self.chunk_attention
+            out["linear_attention"] = self.linear_attention
             out.update(self.layout())
             out["memory"] = self.memory_ledger.describe()
             if self._paged:
@@ -3110,6 +3147,7 @@ class DecodeEngine:
             "kv_append": self.kv_append,
             "decode_attention": self.decode_attention,
             "chunk_attention": self.chunk_attention,
+            "linear_attention": self.linear_attention,
             **self.layout(),
             "draining": self.draining,
             "restarts": self.n_restarts,

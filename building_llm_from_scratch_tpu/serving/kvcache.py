@@ -63,7 +63,10 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from building_llm_from_scratch_tpu.configs import ModelConfig
+from building_llm_from_scratch_tpu.configs import (
+    ModelConfig,
+    refuse_unsupported,
+)
 from building_llm_from_scratch_tpu.obs.metrics import get_metrics
 from building_llm_from_scratch_tpu.utils.logging import setup_logger
 
@@ -152,6 +155,11 @@ class KVCachePolicy:
         """
         import jax.numpy as jnp
 
+        if cfg.has_linear_layers:
+            # a state has no pages and no int8 form: said here too, for a
+            # caller that allocates without an engine
+            refuse_unsupported(cfg, paged=self.paged,
+                               int8_cache=self.quantized)
         if self.paged:
             n_pages = self.total_pool_pages(n_rows, max_length)
             lead = [(n_pages, cfg.n_kv_groups, self.page_tokens)
@@ -160,15 +168,26 @@ class KVCachePolicy:
             lead = [(n_rows, cfg.n_kv_groups, length)
                     for length in self.layer_lengths(cfg, max_length)]
         dt = self.cache_dtype(cfg)
-        cache: Params = {
-            "k": [jnp.zeros(s + (cfg.head_dim,), dt) for s in lead],
-            "v": [jnp.zeros(s + (cfg.head_dim,), dt) for s in lead],
-        }
+        # a 'linear' layer holds no positions: None in the lists of keys and
+        # values, which stay indexed by layer
+        zeros = lambda tail, dtype: [
+            None if not s[2] else jnp.zeros(s + tail, dtype) for s in lead]
+        cache: Params = {"k": zeros((cfg.head_dim,), dt),
+                         "v": zeros((cfg.head_dim,), dt)}
         if self.quantized:
-            cache["k_scale"] = [jnp.zeros(s + (1,), jnp.float32)
-                                for s in lead]
-            cache["v_scale"] = [jnp.zeros(s + (1,), jnp.float32)
-                                for s in lead]
+            cache["k_scale"] = zeros((1,), jnp.float32)
+            cache["v_scale"] = zeros((1,), jnp.float32)
+        if cfg.has_linear_layers:
+            # beside them, a 'linear' layer's memory of a row: the last K-1
+            # tokens of its convolution's input and a float32 state a head
+            linear = [cfg.layer_kind(l) == "linear"
+                      for l in range(cfg.n_layers)]
+            H, hd = cfg.linear_heads, cfg.linear_head_dim
+            cache["conv"] = [
+                jnp.zeros((n_rows, cfg.linear_conv - 1, 3 * H * hd),
+                          cfg.jax_dtype) if on else None for on in linear]
+            cache["state"] = [jnp.zeros((n_rows, H, hd, hd), jnp.float32)
+                              if on else None for on in linear]
         return cache
 
     def ring_length(self, cfg: ModelConfig, max_length: int) -> int:
@@ -189,11 +208,12 @@ class KVCachePolicy:
 
     def layer_lengths(self, cfg: ModelConfig, max_length: int) -> List[int]:
         """Each layer's positions a slot: ``ring_length`` for a 'sliding'
-        layer, ``max_length`` for a 'full' one."""
+        layer, ``max_length`` for a 'full' one, none for a 'linear' one
+        (its memory is a state: ``bytes_per_slot``)."""
         ring = (self.ring_length(cfg, max_length)
                 if cfg.has_window_layers else max_length)
-        return [ring if cfg.layer_kind(l) == "sliding" else max_length
-                for l in range(cfg.n_layers)]
+        by_kind = {"sliding": ring, "full": max_length, "linear": 0}
+        return [by_kind[cfg.layer_kind(l)] for l in range(cfg.n_layers)]
 
     # -- paged layout --------------------------------------------------------
 
@@ -241,9 +261,18 @@ class KVCachePolicy:
         kv = 2 * positions * per_pos * width
         scale = (2 * positions * cfg.n_kv_groups * 4
                  if self.quantized else 0)
-        return {"kv_bytes": kv, "scale_bytes": scale,
-                "total_bytes": kv + scale,
-                "bytes_per_token": (kv + scale) // max_length}
+        out = {"kv_bytes": kv, "scale_bytes": scale,
+               "total_bytes": kv + scale,
+               "bytes_per_token": (kv + scale) // max_length}
+        if cfg.has_linear_layers:
+            # whatever the length: a float32 state a head and the
+            # convolution's tail, a 'linear' layer
+            H, hd = cfg.linear_heads, cfg.linear_head_dim
+            out["state_bytes"] = len(cfg.layers_of("linear")) * (
+                H * hd * hd * 4 + (cfg.linear_conv - 1) * 3 * H * hd
+                * jnp.dtype(cfg.jax_dtype).itemsize)
+            out["total_bytes"] += out["state_bytes"]
+        return out
 
     def describe(self) -> Dict[str, Any]:
         """Event-payload summary (rides ``serve_warmup``)."""
@@ -275,7 +304,7 @@ def cache_nbytes(cache: Params) -> int:
     total = 0
     for leaves in cache.values():
         if isinstance(leaves, (list, tuple)):
-            total += sum(leaf.nbytes for leaf in leaves)
+            total += sum(leaf.nbytes for leaf in leaves if leaf is not None)
         else:
             total += leaves.nbytes
     return total

@@ -267,6 +267,7 @@ def serve(label: str, reqs_path: str, n: int, extra=(), model=()):
         f"kv_append {engine.kv_append}, "
         f"decode_attention {engine.decode_attention}, "
         f"chunk_attention {engine.chunk_attention}, "
+        f"linear_attention {engine.linear_attention}, "
         f"{time.perf_counter() - t0:.1f}s, peak HBM {peak_hbm_gib()} GiB")
     return engine, results
 
@@ -365,6 +366,39 @@ def phase_serve_moe(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
           and len(layout["experts"]["held"]) == 8,
           f"serve_moe: the engine's layout is {layout}")
     check_serve(engine, reqs, results, "serve_moe", n_generate=2)
+
+
+#: gated-delta-rule linear layers beside one gated NoPE attention layer and
+#: sparse experts, at the debug size of ``configs.get_config`` (two periods
+#: of full, linear, linear, linear; 4 heads of 16; 8 experts top-2, 1 shared,
+#: context 64)
+HYBRID_DEBUG = ["--model", "solar_open2", "--num_params", "250B", "--debug"]
+
+
+def phase_serve_hybrid(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
+                               (40, 16, 0.0), (9, 20, 0.7), (23, 30, 0.0))
+                       ) -> None:
+    """A recurrent state beside keys and values on the chip, no timing:
+    chunked prefill in chunks of 16 (a chunk boundary inside four of the
+    prompts, a padded last chunk in all), the decode tick's one-token step
+    of the recurrence, six requests over four slots (so a slot is used
+    again and must start from a zero state), greedy tokens held to the
+    one-shot forward (the chunked form over the whole sequence) and to
+    ``generate()`` like the dense model's."""
+    reqs_path = os.path.join(WORK, "requests_hybrid.jsonl")
+    os.makedirs(WORK, exist_ok=True)
+    reqs = make_requests(reqs_path, shapes)
+    engine, results = serve("serve_hybrid", reqs_path, len(reqs),
+                            extra=["--serve_prefill_chunk", "16"],
+                            model=HYBRID_DEBUG)
+    layout = engine.layout()
+    check(layout["kv_positions"] == {"full": 64}
+          and layout["state"]["layers"] == 6
+          and engine.linear_attention == {"tick": "step",
+                                          "prefill": "chunked"},
+          f"serve_hybrid: the engine's layout is {layout}, its linear "
+          f"layers' forms {engine.linear_attention}")
+    check_serve(engine, reqs, results, "serve_hybrid", n_generate=2)
 
 
 def _load_tests(name: str):
@@ -632,10 +666,10 @@ def main(argv=None) -> int:
                     help="4: run only the multi-chip paths and what they "
                          "are compared with (builder-run)")
     ap.add_argument("--phase", action="append",
-                    choices=["train", "serve", "serve_moe", "kernels",
-                             "train_remat"],
+                    choices=["train", "serve", "serve_moe", "serve_hybrid",
+                             "kernels", "train_remat"],
                     help="run only these one-chip phases (default: train, "
-                         "serve, serve_moe, kernels)")
+                         "serve, serve_moe, serve_hybrid, kernels)")
     args = ap.parse_args(argv)
 
     import jax
@@ -661,10 +695,12 @@ def main(argv=None) -> int:
         phases = {"chips4": phase_chips4}
     else:
         table = {"train": phase_train, "serve": phase_serve,
-                 "serve_moe": phase_serve_moe, "kernels": phase_kernels,
-                 "train_remat": phase_train_remat}
+                 "serve_moe": phase_serve_moe,
+                 "serve_hybrid": phase_serve_hybrid,
+                 "kernels": phase_kernels, "train_remat": phase_train_remat}
         phases = {n: table[n] for n in (
-            args.phase or ["train", "serve", "serve_moe", "kernels"])}
+            args.phase or ["train", "serve", "serve_moe", "serve_hybrid",
+                           "kernels"])}
     shutil.rmtree(WORK, ignore_errors=True)
     failed = []
     t_all = time.perf_counter()

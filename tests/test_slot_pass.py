@@ -42,7 +42,22 @@ BLOCKS = {
     "experts": dict(GPT2, parallel_block=True, tie_embeddings=True,
                     activation="swiglu", mlp_bias=False, n_routed_experts=8,
                     n_experts_per_tok=2, n_shared_experts=2),
+    # a recurrent state beside keys and values: a gated full layer, then a
+    # gated-delta-rule linear one (no positions), a dense MLP. A verify tick
+    # and the paged pool refuse it (``REFUSED``)
+    "linear_state": dict(GPT2, norm="rmsnorm", positional="none",
+                         activation="swiglu", attn_out_bias=False,
+                         mlp_bias=False, norm_bias=False,
+                         layer_kinds=("full", "linear"), attn_out_gate=True,
+                         linear_heads=4, linear_head_dim=8,
+                         linear_gate_rank=4, linear_neg_eigval=True),
 }
+#: what a block form does not run through, and the sentence that says so
+REFUSED = {"linear_state": {
+    "verify": "no way back from a state",
+    "paged_chunks": "a page holds positions",
+    "paged_decode": "a page holds positions",
+    "paged_verify": "a page holds positions"}}
 PROGRAMS = ("prefill_bucket", "prefill_chunks", "decode", "verify",
             "paged_chunks", "paged_decode", "paged_verify")
 
@@ -125,6 +140,13 @@ def _serve(cfg, params, seq, program):
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_every_program_serves_the_forward_logits(model, program):
     cfg, params, seq, want = model
+    refusal = next((why[program] for form, why in REFUSED.items()
+                    if cfg == ModelConfig(**BLOCKS[form])
+                    and program in why), None)
+    if refusal is not None:
+        with pytest.raises(ValueError, match=refusal):
+            _serve(cfg, params, seq, program)
+        return
     with jax.default_matmul_precision("highest"):
         served = _serve(cfg, params, seq, program)
     assert len(served) == {"decode": 1 + N_SEQ - N_PROMPT,
