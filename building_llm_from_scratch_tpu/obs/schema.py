@@ -35,10 +35,11 @@ from typing import Any, Dict, FrozenSet, List
 
 #: Bump when a row type or a load-bearing field changes meaning. The
 #: ``header`` row carries it; consumers key parsing decisions on it.
-SCHEMA_VERSION = 14         # v14: recurrent state beside keys and
+SCHEMA_VERSION = 15         # v15: serve_warmup gains expert_dispatch
+                            # (v14: recurrent state beside keys and
                             # values — serve_warmup gains
                             # linear_attention, the tick record
-                            # state_rows / state_rows_touched
+                            # state_rows / state_rows_touched)
                             # (v13: long-context tier — prefill_shard
                             # tick phase (seq-sharded chunk prefill,
                             # --serve_sp), serve_warmup gains
@@ -406,12 +407,14 @@ _EVENT_LIST: List[EventSpec] = [
                     "drafter", "replica", "kv_paged", "page_tokens",
                     "pool_pages", "sp", "prompt_pane_tokens", "max_prompt",
                     "kv_append", "decode_attention", "chunk_attention",
-                    "linear_attention"),
+                    "linear_attention", "expert_dispatch"),
           doc="prefill programs + decode (or spec verify) program "
               "compiled; watchers frozen; records the KVCachePolicy "
               "(quant/chunk/prefix), which append and which attention the "
               "tick program was built with (kv_append, decode_attention) "
               "and which attention the chunk program (chunk_attention), "
+              "how each program's rows reach the held experts "
+              "(expert_dispatch), "
               "the speculative config "
               "(spec_k/drafter) when on, and the seq-sharded prefill "
               "geometry (sp/prompt_pane_tokens/max_prompt) on "

@@ -49,6 +49,7 @@ fault tolerance):
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 import time
 from typing import List, Optional, Sequence, Union
@@ -64,6 +65,7 @@ from building_llm_from_scratch_tpu.generate import (
     sample_tokens_dynamic,
     token_rng,
 )
+from building_llm_from_scratch_tpu.models.moe import expert_dispatch_path
 from building_llm_from_scratch_tpu.models.transformer import (
     chunk_attention_path,
     decode_attention_path,
@@ -409,6 +411,21 @@ class DecodeEngine:
             "prefill": linear_attention_path(
                 self.kv_policy.prefill_chunk or self.max_len)}
             if self._n_linear_layers else None)
+        #: how each program's rows reach the held experts
+        #: (``expert_dispatch_path``): the tick's "per_expert" (one
+        #: conditional a held expert), a chunk's "grouped" on a TPU (one
+        #: kernel over the experts that got rows), "chunk" None where prefill
+        #: is not chunked (a prompt bucket asks for its own rows); None for a
+        #: dense model
+        self.expert_dispatch = None
+        if cfg.is_moe:
+            dispatch = functools.partial(
+                expert_dispatch_path, cfg,
+                dtype=self.params["blocks"]["moe"]["experts"]["gate"].dtype)
+            self.expert_dispatch = {
+                "tick": dispatch(self.n_slots * (self.spec_k + 1)),
+                "chunk": (dispatch(self.kv_policy.prefill_chunk)
+                          if chunked else None)}
         #: the weights ride every compiled program as an ARGUMENT: closed
         #: over, jit bakes them into each program as constants (GPT2-124M
         #: bf16: 0.3 GB per program, 40 s per compile on the chip, and
@@ -491,8 +508,6 @@ class DecodeEngine:
         # prefix-EXTRACT program deliberately does NOT donate
         # — it only reads the cache (the next donating call reuses the
         # same arrays).
-        import functools
-
         def jit(fn, **kw):
             # the tp/sp engine's kernels shard_map over the plan's mesh
             return jax.jit(trace_under_mesh(
@@ -2549,6 +2564,7 @@ class DecodeEngine:
             decode_attention=self.decode_attention,
             chunk_attention=self.chunk_attention,
             linear_attention=self.linear_attention,
+            expert_dispatch=self.expert_dispatch,
             prefix_pane_tokens=(self._prefix_pane_len
                                 if self.prefix_store is not None
                                 else None),
@@ -2977,6 +2993,7 @@ class DecodeEngine:
             out["decode_attention"] = self.decode_attention
             out["chunk_attention"] = self.chunk_attention
             out["linear_attention"] = self.linear_attention
+            out["expert_dispatch"] = self.expert_dispatch
             out.update(self.layout())
             out["memory"] = self.memory_ledger.describe()
             if self._paged:
@@ -3148,6 +3165,7 @@ class DecodeEngine:
             "decode_attention": self.decode_attention,
             "chunk_attention": self.chunk_attention,
             "linear_attention": self.linear_attention,
+            "expert_dispatch": self.expert_dispatch,
             **self.layout(),
             "draining": self.draining,
             "restarts": self.n_restarts,

@@ -268,6 +268,7 @@ def serve(label: str, reqs_path: str, n: int, extra=(), model=()):
         f"decode_attention {engine.decode_attention}, "
         f"chunk_attention {engine.chunk_attention}, "
         f"linear_attention {engine.linear_attention}, "
+        f"expert_dispatch {engine.expert_dispatch}, "
         f"{time.perf_counter() - t0:.1f}s, peak HBM {peak_hbm_gib()} GiB")
     return engine, results
 
@@ -573,9 +574,42 @@ def phase_kernels() -> None:
         check(gap < 2e-2, f"kernels: chunk attention is {gap:.2e} off "
               f"decode_attention on a buffer of {Tmax}")
 
+    # the grouped expert product (what a sparse engine's chunk program
+    # reaches its held experts with) vs the loop of conditionals, at the
+    # longdoc cell's layer: 512 rows top-8 of 320, 20 held, a padded tail
+    from building_llm_from_scratch_tpu.configs import get_config
+    from building_llm_from_scratch_tpu.models import moe
+
+    cfg = get_config("solar_open2", "250B", dtype="bf16",
+                     target_context_length=None).replace(
+        experts_held=tuple(range(20)))
+    N, width, F = 512, cfg.emb_dim, cfg.hidden_dim
+    check(moe.expert_dispatch_path(cfg, N, jnp.bfloat16) == "grouped"
+          and moe.expert_dispatch_path(cfg, 48, jnp.bfloat16) == "per_expert",
+          "kernels: a 512-row chunk is not grouped or a 48-row tick is")
+    experts = {name: 0.02 * jax.random.normal(kk, (2, 20) + shape,
+                                              jnp.bfloat16)
+               for name, kk, shape in (("gate", ks[0], (width, F)),
+                                       ("up", ks[1], (width, F)),
+                                       ("down", ks[2], (F, width)))}
+    experts["layer"] = 1
+    rows = jax.random.normal(ks[3], (N, width), jnp.bfloat16)
+    ids, weights = moe.route(cfg, 0.2 * jax.random.normal(
+        ks[4], (width, cfg.n_routed_experts)), rows)
+    live = jnp.arange(N) < N - 37
+    (got, n_got), (want, n_want) = (
+        jax.jit(lambda p, form=form: form(cfg, p, rows, ids, weights, live))(
+            experts) for form in (moe._routed, moe._per_expert))
+    gap = float(np.abs(f32(got) - f32(want)).max())
+    check(np.array_equal(n_got, n_want) and int(n_want.sum()) > 100
+          and gap < 2e-2 * max(1.0, float(np.abs(f32(want)).max())),
+          f"kernels: the grouped experts are {gap:.2e} off the per-expert "
+          f"form, rows {n_got.tolist()} against {n_want.tolist()}")
+
     n = run_repo_tpu_tests()
     log(f"kernels: fused attention fwd/grad/dropout, fused dropout-add, "
-        f"lane-window append, live-block and chunk attention "
+        f"lane-window append, live-block and chunk attention and the "
+        f"grouped experts "
         f"match their XLA references at the real shapes; "
         f"{n} needs_tpu repo test cases pass; "
         f"{time.perf_counter() - t0:.1f}s")

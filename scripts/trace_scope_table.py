@@ -10,7 +10,10 @@ is any ``jax.profiler`` capture (``<log_dir>/plugins/profile/*/*.xplane.pb``).
 A benchmark run removes its own (``.benchmark_work/<cell>/trace``) when it
 ends, so copy that one out before ``run_cell`` returns. A fusion carries the
 scope of one of its operations, so neighbouring scopes are not to be read
-finely; a ``while`` loop's own event carries none, its body's do.
+finely. A ``while`` loop's or a ``conditional``'s own event spans the events
+of its body, which carry their scopes: it is listed apart and is in no
+scope's sum (summed in, a program's experts behind conditionals read twice
+what they cost: PERF.md section 6, PR 37).
 """
 import collections
 import gzip
@@ -28,6 +31,8 @@ SCOPES = ("cache_update", "window_attention", "linear_attention",
           "moe_experts", "moe_shared", "mlp", "head_xent", "head",
           "cross_entropy", "sampling")
 PROGRAMS_SHOWN = 3
+#: operations whose event spans their body's events
+CONTAINERS = ("while", "conditional")
 
 
 def union_ps(intervals) -> int:
@@ -81,12 +86,17 @@ def table(path: str) -> None:
         mine = sorted(runs[program])
         by_scope = collections.defaultdict(list)
         kinds = collections.defaultdict(collections.Counter)
+        spans = collections.Counter()
         for a, b, name, tf_op in ops:
             if any(ra <= a and b <= rb for ra, rb in mine):
+                kind = (re.match(r"%?([A-Za-z_\-]*)", name).group(1)
+                        or name[:20])
+                if kind in CONTAINERS:
+                    spans[kind] += b - a
+                    continue
                 scope = scope_of(tf_op)
                 by_scope[scope].append((a, b))
-                kinds[scope][re.match(r"%?([A-Za-z_\-]*)", name).group(1)
-                             or name[:20]] += b - a
+                kinds[scope][kind] += b - a
         n, ms = len(mine), 1e9
         total = union_ps(iv for ivs in by_scope.values() for iv in ivs)
         median = sorted(b - a for a, b in mine)[n // 2]
@@ -98,6 +108,9 @@ def table(path: str) -> None:
                             for k, v in kinds[scope].most_common(4))
             print(f"  {scope:14s} {busy / ms / n:8.3f} ms a run "
                   f"({100 * busy / total:5.1f}%)  [{top}]")
+        for kind, ps in spans.most_common():
+            print(f"  ({kind} events span {ps / ms / n:.3f} ms a run of the "
+                  f"above: their bodies')")
 
 
 if __name__ == "__main__":

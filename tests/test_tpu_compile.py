@@ -236,7 +236,9 @@ def test_sparse_window_chunk_program_attends_in_one_kernel_a_layer(
     slots, a chunk of 512, 128 query heads on 8 key-value heads of 128,
     rings of 4608 beside one full layer of 20480): each layer's attention
     is ONE custom call that reaches the slot's row in place (no pane
-    copied, none sliced out), no ``while`` is left (the materialised form
+    copied, none sliced out) and each layer's held experts ONE more (the
+    grouped product, no conditional left and no copy of a layer's experts
+    made on the way in: PR 37), no ``while`` is left (the materialised form
     ran one key-value head at a time, float32 scores of 8192 query rows
     against the whole buffer written out: four loops), and the program's
     temporaries are under the 869,611,008 bytes it needed with them
@@ -274,8 +276,13 @@ def test_sparse_window_chunk_program_attends_in_one_kernel_a_layer(
         scalar(I32), sds((2,), jnp.uint32), scalar(jnp.float32),
         scalar(I32)).compile()
     hlo = compiled.as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 4
-    assert not re.search(r" while\(", hlo)
+    from building_llm_from_scratch_tpu.models import moe
+
+    assert moe.expert_dispatch_path(cfg, C, BF16) == "grouped"
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 8
+    assert not re.search(r" while\(| conditional\(", hlo)
+    entry = hlo[hlo.index("ENTRY "):]
+    assert not re.search(r"= bf16\[8,4096,4096\]", entry)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 869_611_008
     assert memory.alias_size_in_bytes == sum(
@@ -308,11 +315,12 @@ def test_state_beside_keys_and_values_programs_fit_and_copy_no_expert(
     128) and convolution tails, 20 of 320 experts held, chunks of 512. Each
     fits one chip beside its 11.4 GB of arguments with under 1 GB of
     temporaries, keys, values, states and tails are all aliased through,
-    every routed expert sits behind its own conditional (4 layers x 20 held,
-    a chunk's 512 rows in four blocks each) and no copy of a layer's experts
-    is made on the way in; the tick runs the one-token step (no loop over a
-    state), the chunk the chunked form (one loop over sub-chunks a linear
-    layer) and its full layer ONE attention kernel."""
+    the tick's every routed expert sits behind its own conditional (4 layers
+    x 20 held) where the chunk's 512 rows reach theirs in ONE grouped kernel
+    a layer with no conditional left (PR 37), and no copy of a layer's
+    experts is made on the way in; the tick runs the one-token step (no loop
+    over a state), the chunk the chunked form (one loop over sub-chunks a
+    linear layer) and its full layer ONE attention kernel."""
     import re
 
     from building_llm_from_scratch_tpu.configs import get_config
@@ -360,8 +368,8 @@ def test_state_beside_keys_and_values_programs_fit_and_copy_no_expert(
         assert memory.temp_size_in_bytes < 1.0e9, name
         assert memory.alias_size_in_bytes == held, name
         hlo = compiled.as_text()
-        n_blocks = 1 if name == "tick" else C // 128
-        assert len(re.findall(r" conditional\(", hlo)) == 80 * n_blocks
+        assert len(re.findall(r" conditional\(", hlo)) == (
+            80 if name == "tick" else 0), name
         entry = hlo[hlo.index("ENTRY "):]
         assert not re.search(r"= bf16\[20,(?:4096,1280|1280,4096)\]", entry)
         # the tick's two are the head_dim-128 scatter append's (keys,
@@ -369,7 +377,7 @@ def test_state_beside_keys_and_values_programs_fit_and_copy_no_expert(
         assert len(re.findall(r" while\(", hlo)) == (2 if name == "tick"
                                                      else 3), name
         assert hlo.count('custom_call_target="tpu_custom_call"') == (
-            0 if name == "tick" else 1), name
+            0 if name == "tick" else 1 + 4), name
 
 
 def test_paged_decode_attention(one_chip):
@@ -389,6 +397,31 @@ def test_lora_bgmv(one_chip):
     hlo = _compile(ds.lora_bgmv, s((S, D)), s((N, D, r)), s((N, r, O)),
                    s((S,), I32), s((N,), jnp.float32))
     assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("H,F", [(8, 4096), (20, 1280)])
+def test_grouped_experts_real_widths(one_chip, H, F):
+    """The grouped expert product at both sparse cells' widths (a chunk's
+    512 rows top-8: a buffer of 4096 rows; D 4096; 8 experts of width 4096
+    or 20 of 1280, stacked over four layers): the gate admits the shape, the
+    VMEM the kernel asks for is granted, the grid's length is a value (the
+    visits that hold a row), and the stacked experts reach the kernel as
+    they lie: no slice and no copy of them in the program."""
+    import re
+
+    from building_llm_from_scratch_tpu.ops import grouped_experts as ge
+
+    L, D, M = 4, 4096, 4096
+    assert ge.supports_grouped_experts(M, D, F, BF16)
+    assert ge.buffer_rows(M) == M and ge._width_tile(D, F, 2) in (512, 256)
+    s = _spec(one_chip)
+    hlo = _compile(ge.grouped_gated_product, s((M, D)), s((L, H, D, F)),
+                   s((L, H, D, F)), s((L, H, F, D)), s((), I32),
+                   s((H,), I32))
+    assert hlo.count("tpu_custom_call") == 1
+    stacked = set(re.findall(
+        rf"= bf16\[{L},{H},(?:{D},{F}|{F},{D})\]\S* ([\w\-]+)\(", hlo))
+    assert stacked == {"parameter"}, stacked
 
 
 def test_xent_fwd_largest_admitted_shape(one_chip):
@@ -478,6 +511,22 @@ def test_sharded_chunk_live_attention_on_four_devices(topo):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
     assert "bf16[8,2,4608,128]" in hlo and " copy(" not in "".join(
         ln for ln in hlo.split("\n") if "bf16[8,2,4608,128]" in ln)
+
+
+def test_grouped_experts_under_a_data_mesh_on_four_devices(topo):
+    """A sparse model's forward pass under a four-device data mesh (the
+    expert layer has no tensor-parallel split): GSPMD refuses a bare Mosaic
+    call, so the grouped product shard_maps itself with every operand whole
+    on every device."""
+    from building_llm_from_scratch_tpu.ops import grouped_experts as ge
+
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    s = _spec(NamedSharding(mesh, P()))
+    hlo = _compile(trace_under_mesh(ge.grouped_gated_product, mesh),
+                   s((1024, 1024)), s((2, 8, 1024, 512)),
+                   s((2, 8, 1024, 512)), s((2, 8, 512, 1024)), s((), I32),
+                   s((8,), I32))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("S,Hq,Hkv,dtype", [
