@@ -97,7 +97,8 @@ class ModelConfig:
     #: the kinds of one period of layers, repeated down the depth:
     #: 'sliding' (attends to the last ``sliding_window`` positions, itself
     #: included) | 'full' | 'linear' (no keys and values: a gated delta
-    #: rule over a recurrent state, below). Empty = every layer full.
+    #: rule over a recurrent state, below) | 'ssm' (none either: a
+    #: selective state space, below). Empty = every layer full.
     layer_kinds: Tuple[str, ...] = ()
     sliding_window: int = 0
     rope_interleaved: bool = False       # rotate pairs (2i, 2i+1), not halves
@@ -125,15 +126,25 @@ class ModelConfig:
     linear_conv: int = 4
     linear_gate_rank: int = 0
     linear_neg_eigval: bool = False
+    #: 'ssm' layers (ops/selective_scan.py; Mamba-1 with the Jamba family's
+    #: norms on dt, B and C): ``ssm_inner`` channels, each a diagonal
+    #: recurrence over ``ssm_state`` float32 states, behind a causal
+    #: depthwise convolution of ``ssm_conv`` taps (with a bias; the
+    #: projections have none); step, B and C are read from the channels
+    #: through ``ssm_dt_rank`` + 2 x ``ssm_state`` columns
+    ssm_inner: int = 0
+    ssm_state: int = 0
+    ssm_dt_rank: int = 0
+    ssm_conv: int = 4
 
     def __post_init__(self):
         # a JSON file hands lists; the config must stay hashable
         for name in ("layer_kinds", "experts_held"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
         if self.layer_kinds:
-            if set(self.layer_kinds) - {"sliding", "full", "linear"}:
+            if set(self.layer_kinds) - {"sliding", "full", "linear", "ssm"}:
                 raise ValueError(f"layer_kinds {self.layer_kinds}: each is "
-                                 "'sliding', 'full' or 'linear'")
+                                 "'sliding', 'full', 'linear' or 'ssm'")
             if self.n_layers % len(self.layer_kinds):
                 raise ValueError(
                     f"n_layers {self.n_layers} is not whole periods of "
@@ -146,6 +157,12 @@ class ModelConfig:
                 raise ValueError(
                     "'linear' layers need linear_heads, linear_head_dim, "
                     "linear_gate_rank and linear_conv >= 2")
+            if "ssm" in self.layer_kinds and not (
+                    self.ssm_inner and self.ssm_state and self.ssm_dt_rank
+                    and self.ssm_conv > 1):
+                raise ValueError(
+                    "'ssm' layers need ssm_inner, ssm_state, ssm_dt_rank "
+                    "and ssm_conv >= 2")
         if self.n_routed_experts:
             held = self.held_experts
             if not (0 < self.n_experts_per_tok <= self.n_routed_experts):
@@ -185,6 +202,28 @@ class ModelConfig:
         """The layers of these kinds, in order."""
         return tuple(l for l in range(self.n_layers)
                      if self.layer_kind(l) in kinds)
+
+    @property
+    def has_state_layers(self) -> bool:
+        return bool(self.state_layers)
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers whose memory of a sequence is a recurrent state and
+        a convolution's tail, not keys and values: 'linear' and 'ssm'."""
+        return self.layers_of("linear", "ssm")
+
+    def state_shapes(self, kind: str) -> Tuple[Tuple[int, ...],
+                                               Tuple[int, ...]]:
+        """One row's memory in a layer of this kind: (the convolution's
+        tail (K-1, channels), in the activation type; the state, float32:
+        a matrix a head, or an 'ssm' layer's N states a channel with the
+        channels last, on a TPU's lanes)."""
+        if kind == "ssm":
+            return ((self.ssm_conv - 1, self.ssm_inner),
+                    (self.ssm_state, self.ssm_inner))
+        H, hd = self.linear_heads, self.linear_head_dim
+        return (self.linear_conv - 1, 3 * H * hd), (H, hd, hd)
 
     @property
     def linear_width(self) -> int:
@@ -227,6 +266,14 @@ class ModelConfig:
                   + w + d * self.linear_heads + 2 * (d * r + r * w)
                   + self.linear_head_dim)
         n_linear = len(self.layers_of("linear"))
+        # an 'ssm' layer's mixer: in (u and z) and out; the conv's taps and
+        # bias; dt, B and C read from the channels, a norm's scale on each;
+        # dt back to the channels with its bias; A_log a channel and state;
+        # the skip D
+        i, n, r = self.ssm_inner, self.ssm_state, self.ssm_dt_rank
+        ssm = (3 * d * i + (self.ssm_conv + 1) * i + i * (r + 2 * n)
+               + (r + 2 * n) + r * i + i + i * n + i)
+        n_ssm = len(self.layers_of("ssm"))
         if self.is_moe:
             held = len(self.held_experts)
             n_routed = (self.n_experts_per_tok * held / self.n_routed_experts
@@ -241,8 +288,8 @@ class ModelConfig:
         per_layer = mlp + (1 if self.parallel_block else 2) * norm_w
         final_norm = d * (2 if self.norm_bias else 1)
         head = 0 if self.tie_embeddings else d * v
-        total = (per_layer * self.n_layers + linear * n_linear
-                 + (qkv + attn_out) * (self.n_layers - n_linear)
+        total = (per_layer * self.n_layers + linear * n_linear + ssm * n_ssm
+                 + (qkv + attn_out) * (self.n_layers - n_linear - n_ssm)
                  + final_norm + head)
         if not exclude_embeddings or self.tie_embeddings:
             total += emb
@@ -250,11 +297,11 @@ class ModelConfig:
 
 
 #: what a model with window layers ("window"), sparse experts ("moe") or
-#: linear-attention layers ("linear") does not run through yet:
-#: (feature, the property that refuses it, why).
+#: layers that hold a recurrent state ("state": 'linear' and 'ssm' alike)
+#: does not run through yet: (feature, the property that refuses it, why).
 #: ONE list, asked by the flags' checks (``args.perform_checks``) and by the
 #: serving engine at construction, by what the config IS, never by its name
-#: (PERF.md section 7 lists them; ROADMAP R1 / R2 say what each takes)
+#: (PERF.md section 7 lists them; ROADMAP R1 / R2 / R4 say what each takes)
 UNSUPPORTED = (
     ("paged", "window",
      "the paged pool maps a slot's positions onto pages one to one and has "
@@ -284,51 +331,54 @@ UNSUPPORTED = (
     ("lora", "moe",
      "LoRA adapters attach to the dense MLP's projections and the expert "
      "layer has none: run it without adapters"),
-    ("paged", "linear",
+    ("paged", "state",
      "a page holds positions and a recurrent state has none: serve it on "
      "the slot cache"),
-    ("prefix_cache", "linear",
+    ("prefix_cache", "state",
      "a prefix pane is a slot's first positions; the state after them "
      "would have to be kept beside it and is not: serve it without "
      "--serve_prefix_cache"),
-    ("int8_cache", "linear",
+    ("int8_cache", "state",
      "the recurrent state is float32 and has no int8 form: serve it with "
      "the model's own cache type"),
-    ("speculation", "linear",
+    ("speculation", "state",
      "a rejected draft has already moved the recurrent state and there is "
      "no copy to go back to: serve it with spec_k 0"),
-    ("tensor_parallel", "linear",
-     "the state's heads have no tensor-parallel split yet: run it on one "
-     "chip, or under dp, fsdp or zero1"),
-    ("pipeline_parallel", "linear",
+    ("tensor_parallel", "state",
+     "the state's heads or channels have no tensor-parallel split yet: run "
+     "it on one chip, or under dp, fsdp or zero1"),
+    ("pipeline_parallel", "state",
      "a pipeline stage runs layers of one kind: run it under dp, fsdp or "
      "zero1"),
-    ("sequence_parallel", "linear",
+    ("sequence_parallel", "state",
      "a sequence split would hand the state from shard to shard and no "
      "schedule does"),
-    ("lora", "linear",
-     "LoRA adapters attach to attention's projections and a linear layer "
-     "has others: run it without adapters"),
+    ("lora", "state",
+     "LoRA adapters attach to attention's projections and a layer that "
+     "holds a state has others: run it without adapters"),
 )
 
 
 def refuse_unsupported(cfg: "ModelConfig", **features) -> None:
     """``features``: a name of ``UNSUPPORTED`` -> whether the caller is
     about to use it. Raises one sentence for the first that ``cfg``'s window
-    layers or sparse experts do not support: nothing is silently wrong."""
+    layers, sparse experts or recurrent states do not support: nothing is
+    silently wrong."""
     unknown = set(features) - {name for name, _, _ in UNSUPPORTED}
     if unknown:
         raise TypeError(f"refuse_unsupported: no such feature {unknown}")
     has = {"window": cfg.has_window_layers, "moe": cfg.is_moe,
-           "linear": cfg.has_linear_layers}
+           "state": cfg.has_state_layers}
     has["either"] = has["window"] or has["moe"]
     for name, needs, why in UNSUPPORTED:
         if features.get(name) and has[needs]:
             kinds = " and ".join(
-                what for k, what in (("window", "window layers"),
-                                     ("moe", "sparse experts"),
-                                     ("linear", "linear-attention layers"))
-                if has[k])
+                what for on, what in (
+                    (has["window"], "window layers"),
+                    (has["moe"], "sparse experts"),
+                    (cfg.has_linear_layers, "linear-attention layers"),
+                    ("ssm" in cfg.layer_kinds, "state-space layers"))
+                if on)
             raise ValueError(f"{cfg.name} ({kinds}): {why}")
 
 
@@ -559,6 +609,40 @@ SOLAR_OPEN2_CONFIG = ModelConfig(
 )
 
 
+# AI21-Jamba2-3B (ai21labs/AI21-Jamba2-3B, model_type jamba): 28 serial
+# RMSNorm blocks (eps 1e-6) in periods of fourteen, thirteen Mamba-1
+# selective-state-space layers (5120 channels of 16 states behind a 4-tap
+# convolution; dt, B and C each through a norm of its own) around one NoPE
+# multi-query attention layer (20 query heads, ONE key-value head of 128) at
+# index 7; no positions anywhere; every feed-forward a dense SwiGLU of 8192
+# (``num_experts`` 1); head tied to the embedding. 3.03B parameters: one chip
+# holds it whole.
+JAMBA2_3B_CONFIG = ModelConfig(
+    name="ai21-jamba2-3b",
+    vocab_size=65_536,
+    context_length=262_144,
+    emb_dim=2560,
+    n_heads=20,
+    n_layers=28,
+    hidden_dim=8192,
+    n_kv_groups=1,
+    attn_head_dim=128,
+    norm="rmsnorm",
+    rmsnorm_eps=1e-6,
+    positional="none",
+    activation="swiglu",
+    tie_embeddings=True,
+    layer_kinds=("ssm",) * 7 + ("full",) + ("ssm",) * 6,
+    ssm_inner=5120,
+    ssm_state=16,
+    ssm_dt_rank=160,
+    ssm_conv=4,
+    eos_id=2,
+    eos_text="<|endoftext|>",
+    dtype="bf16",
+)
+
+
 # Supported model types and their sizes (reference: utils.py:44-50)
 MODEL_PARAMS_MAPPING = {
     "GPT2": ["124M", "355M", "774M", "1.5B"],
@@ -569,11 +653,13 @@ MODEL_PARAMS_MAPPING = {
     "longctx": ["32k"],
     "command_a_plus": ["218B"],
     "solar_open2": ["250B"],
+    "jamba2": ["3B"],
 }
 
 _LLAMA_REGISTRY = {
     ("command_a_plus", "218B"): COMMAND_A_PLUS_CONFIG,
     ("solar_open2", "250B"): SOLAR_OPEN2_CONFIG,
+    ("jamba2", "3B"): JAMBA2_3B_CONFIG,
     ("llama2", "7B"): LLAMA2_CONFIG_7B,
     ("llama3", "8B"): LLAMA3_CONFIG_8B,
     ("llama3_1", "8B"): LLAMA31_CONFIG_8B,
@@ -649,17 +735,24 @@ def get_config(model: str, num_params: str, *,
         tiny = dict(context_length=16, emb_dim=32, n_layers=2, n_heads=2,
                     n_kv_groups=min(cfg.n_kv_groups, 2), hidden_dim=64)
         if cfg.layer_kinds or cfg.is_moe:
-            # the same block at a size the CPU holds: two whole periods,
-            # rings that wrap inside the 64 positions, 8 experts top-2
+            # the same block at a size the CPU holds: two whole periods (one
+            # where a period is longer than eight layers), rings that wrap
+            # inside the 64 positions, 8 experts top-2
+            P = max(1, len(cfg.layer_kinds))
             tiny.update(
                 context_length=64, n_heads=4, attn_head_dim=16,
-                n_layers=2 * max(1, len(cfg.layer_kinds)), sliding_window=8,
-                n_routed_experts=8, n_experts_per_tok=2,
-                n_shared_experts=min(cfg.n_shared_experts, 2),
-                experts_held=(), vocab_size=512, eos_id=511)
+                n_layers=P if P > 8 else 2 * P, sliding_window=8,
+                vocab_size=512, eos_id=511)
+            if cfg.is_moe:
+                tiny.update(
+                    n_routed_experts=8, n_experts_per_tok=2,
+                    n_shared_experts=min(cfg.n_shared_experts, 2),
+                    experts_held=())
             if cfg.has_linear_layers:
                 tiny.update(linear_heads=4, linear_head_dim=16,
                             linear_gate_rank=8)
+            if "ssm" in cfg.layer_kinds:
+                tiny.update(ssm_inner=64, ssm_state=8, ssm_dt_rank=4)
         cfg = cfg.replace(**tiny)
     return cfg
 

@@ -227,7 +227,7 @@ def _generate_cached(params, cfg: ModelConfig, prompt: jnp.ndarray,
     logits, cache = forward_with_cache(
         params, cfg, prompt, cache, blocks_list, **lora_kw,
         # the padding moves no recurrent state
-        valid_len=prompt_len if cfg.has_linear_layers else None)
+        valid_len=prompt_len if cfg.has_state_layers else None)
     # real prompt occupies [0, prompt_len); pad slots hold garbage k/v that
     # decode overwrites (and kv_length masks meanwhile)
     cache = dict(cache, length=prompt_len)

@@ -56,7 +56,17 @@ attention layers alone (with ``"wg"`` under ``cfg.attn_out_gate``) and
                   "w_fa", "w_ga": (Ll, D, r), "w_fb", "w_gb": (Ll, r, W),
                   "w_b": (Ll, D, H), "o_norm": {"scale": (Ll, hd)}}
 
-over the Ll linear ones (W = H * hd); norms and feed-forwards stay (L, ...).
+over the Ll linear ones (W = H * hd); an 'ssm' layer
+(ops/selective_scan.py) a third,
+
+      "ssm":     {"w_in": (Ls, D, 2I), "w_out": (Ls, I, D),
+                  "conv": (Ls, K, I), "conv_b": (Ls, I),
+                  "w_x": (Ls, I, R + 2N), "w_dt": (Ls, R, I),
+                  "dt_bias", "D": (Ls, I), "A_log": (Ls, N, I),
+                  "dt_norm": {"scale": (Ls, R)},
+                  "b_norm", "c_norm": {"scale": (Ls, N)}}
+
+over the Ls state-space ones; norms and feed-forwards stay (L, ...).
 ``_layer_of`` / ``_by_period`` give a layer its own.
 """
 
@@ -86,6 +96,12 @@ from building_llm_from_scratch_tpu.ops.linear_attention import (
     recurrent_step,
 )
 from building_llm_from_scratch_tpu.ops.norms import layernorm, rmsnorm
+from building_llm_from_scratch_tpu.ops.selective_scan import (
+    selective_scan,
+    selective_scan_kernel,
+    selective_scan_path,
+    selective_step,
+)
 from building_llm_from_scratch_tpu.ops.rope import (
     apply_rope,
     precompute_rope_params,
@@ -217,6 +233,29 @@ def _init_linear_params(cfg: ModelConfig, key: jax.Array, Ll: int) -> Params:
     }
 
 
+def _init_ssm_params(cfg: ModelConfig, key: jax.Array, Ls: int) -> Params:
+    """The 'ssm' layers' mixers, by the family's init: a channel's N decay
+    rates A = 1..N (kept as log A), a time step dt in [0.001, 0.1] a channel
+    (kept as softplus^-1 dt), the skip D = 1."""
+    D, dt = cfg.emb_dim, cfg.jax_dtype
+    I, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    keys = jax.random.split(key, 6)
+    lin = lambda i, a, b: _linear_init(keys[i], a, b, dt, Ls)
+    ones = lambda n: {"scale": jnp.ones((Ls, n), dt)}
+    step = jnp.exp(jax.random.uniform(
+        keys[5], (Ls, I), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "w_in": lin(0, D, 2 * I), "w_out": lin(1, I, D),
+        "conv": lin(2, cfg.ssm_conv, I), "conv_b": jnp.zeros((Ls, I), dt),
+        "w_x": lin(3, I, R + 2 * N), "w_dt": lin(4, R, I),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1.0, N + 1))[:, None], (Ls, N, I)).astype(dt),
+        "D": jnp.ones((Ls, I), dt),
+        "dt_norm": ones(R), "b_norm": ones(N), "c_norm": ones(N),
+    }
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Build the full parameter pytree for ``cfg``."""
     L, D, V, T = cfg.n_layers, cfg.emb_dim, cfg.vocab_size, cfg.context_length
@@ -227,8 +266,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     zeros = lambda *shape: jnp.zeros(shape, dt)
     ones = lambda *shape: jnp.ones(shape, dt)
 
-    Ll = len(cfg.layers_of("linear"))
-    La = L - Ll                    # the mixers are stacked by kind
+    Ll, Ls = len(cfg.layers_of("linear")), len(cfg.layers_of("ssm"))
+    La = L - Ll - Ls               # the mixers are stacked by kind
     attn: Params = {
         "wq": _linear_init(keys[0], D, Hq * hd, dt, La),
         "wk": _linear_init(keys[1], D, Hkv * hd, dt, La),
@@ -252,6 +291,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     blocks: Params = {"norm1": norm(L), "attn": attn}
     if Ll:
         blocks["linear"] = _init_linear_params(cfg, keys[12], Ll)
+    if Ls:
+        blocks["ssm"] = _init_ssm_params(cfg, keys[13], Ls)
     if not cfg.parallel_block:
         blocks["norm2"] = norm(L)
     if cfg.is_moe:
@@ -542,11 +583,65 @@ def _linear_mixer(cfg: ModelConfig, p: Params, h: jnp.ndarray, through_state,
         return y.astype(h.dtype).reshape(B, T, H * hd) @ p["wo"]
 
 
-def _fresh_state(cfg: ModelConfig, B: int, dtype):
+def _ssm_mixer(cfg: ModelConfig, p: Params, h: jnp.ndarray, through_state,
+               valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """An 'ssm' layer's mixer (Mamba-1, with the Jamba family's norms) on its
+    normed input ``h`` (B, T, D): ``[u, z] = h w_in``; u through the short
+    convolution (with its bias) and SiLU; step, B and C read from u, each
+    through an RMSNorm of its own; ``delta = softplus(dt w_dt + dt_bias)``;
+    the selective scan over the channels' states (ops/selective_scan.py);
+    ``(y * silu(z)) w_out``. ``through_state(run)`` and ``valid`` as
+    ``_linear_mixer``'s: a position left out has ``delta = 0`` and moves no
+    state. The state, the exponential, the softplus, the three norms and the
+    recurrence are float32; the tail is the activations' type."""
+    T = h.shape[1]
+    I, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    f32 = jnp.float32
+    with jax.named_scope("ssm_proj"):
+        u, z = jnp.split(h @ p["w_in"], 2, axis=-1)
+
+    def run(tail, state, n_valid=None):
+        with jax.named_scope("ssm_conv"):
+            x, tail = causal_conv(u, tail, p["conv"], n_valid, p["conv_b"])
+        with jax.named_scope("ssm_proj"):
+            dt, Bm, Cm = (
+                rmsnorm(a.astype(f32), p[name]["scale"].astype(f32),
+                        eps=cfg.rmsnorm_eps)
+                for a, name in zip(
+                    jnp.split(x.astype(h.dtype) @ p["w_x"], (R, R + N),
+                              axis=-1),
+                    ("dt_norm", "b_norm", "c_norm")))
+            delta = jax.nn.softplus((dt.astype(h.dtype) @ p["w_dt"]).astype(
+                f32) + p["dt_bias"].astype(f32))
+            if valid is not None:
+                delta = jnp.where(valid[:, :, None], delta, 0.0)
+        A, D = -jnp.exp(p["A_log"].astype(f32)), p["D"].astype(f32)
+        with jax.named_scope("selective_scan"):
+            path = selective_scan_path(T, I, N)
+            if path == "step":
+                y, state = selective_step(x[:, 0], delta[:, 0], A, Bm[:, 0],
+                                          Cm[:, 0], D, state)
+                return y[:, None], tail, state
+            scan = (selective_scan_kernel if path == "kernel"
+                    else selective_scan)
+            y, state = scan(x, delta, A, Bm, Cm, D, state)
+            return y, tail, state
+
+    y = through_state(run)
+    with jax.named_scope("ssm_proj"):
+        return (y * jax.nn.silu(z.astype(f32))).astype(h.dtype) @ p["w_out"]
+
+
+#: the mixers whose memory of a sequence is a tail and a state, by kind; each
+#: ``mixer(cfg, p, h, through_state, valid)``
+_STATE_MIXERS = {"linear": _linear_mixer, "ssm": _ssm_mixer}
+
+
+def _fresh_state(cfg: ModelConfig, kind: str, B: int, dtype):
     """The tail and the state before a sequence: zeros."""
-    H, hd = cfg.linear_heads, cfg.linear_head_dim
-    return (jnp.zeros((B, cfg.linear_conv - 1, 3 * H * hd), dtype),
-            jnp.zeros((B, H, hd, hd), jnp.float32))
+    tail, state = cfg.state_shapes(kind)
+    return (jnp.zeros((B,) + tail, dtype),
+            jnp.zeros((B,) + state, jnp.float32))
 
 
 def _layer_rope(cfg: ModelConfig, rope, kind: str):
@@ -623,10 +718,10 @@ def _block(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     else:
         r_attn = r_res1 = r_res2 = None
     n1 = _norm(cfg, p["norm1"], x)
-    if kind == "linear":
+    if kind in _STATE_MIXERS:
         # a whole sequence from the zero state; nothing is kept
-        h = _linear_mixer(cfg, p["linear"], n1, lambda run: run(
-            *_fresh_state(cfg, x.shape[0], x.dtype))[0])
+        h = _STATE_MIXERS[kind](cfg, p[kind], n1, lambda run: run(
+            *_fresh_state(cfg, kind, x.shape[0], x.dtype))[0])
     else:
         h = _attention(cfg, p["attn"], n1, _layer_rope(cfg, rope, kind),
                        positions, r_attn, deterministic, sp_mesh=sp_mesh,
@@ -951,15 +1046,19 @@ def unstack_blocks(params: Params, cfg: ModelConfig) -> list:
     return out
 
 
+#: the groups of ``params["blocks"]`` that hold mixers, stacked by kind
+_MIXERS = ("attn", "linear", "ssm")
+
+
 def _mixer_of(kind: str) -> str:
     """The group of ``params["blocks"]`` that holds a layer's mixer."""
-    return "linear" if kind == "linear" else "attn"
+    return kind if kind in _STATE_MIXERS else "attn"
 
 
 def _layer_of(cfg: ModelConfig, blocks: Params, l: int) -> Params:
     """Layer ``l``'s view of the stacked ``blocks``: leaf ``[l]``, and of
     the mixers, stacked by kind, its own at its index among its kind."""
-    if not cfg.has_linear_layers:
+    if not cfg.has_state_layers:
         return jax.tree_util.tree_map(lambda a: a[l], blocks)
     mixer = _mixer_of(cfg.layer_kind(l))
     at = sum(_mixer_of(cfg.layer_kind(i)) == mixer for i in range(l))
@@ -967,7 +1066,7 @@ def _layer_of(cfg: ModelConfig, blocks: Params, l: int) -> Params:
         lambda a: a[i], tree)
     return {name: index(at if name == mixer else l)(sub)
             for name, sub in blocks.items()
-            if name == mixer or name not in ("attn", "linear")}
+            if name == mixer or name not in _MIXERS}
 
 
 def _by_period(cfg: ModelConfig, blocks: Params) -> Params:
@@ -975,7 +1074,7 @@ def _by_period(cfg: ModelConfig, blocks: Params) -> Params:
     -> (L / P, P, ...), a mixer's leaves (stacked over its own kind's
     layers) -> (L / P, that kind's layers a period, ...)."""
     kinds = cfg.layer_kinds
-    n = {m: sum(_mixer_of(k) == m for k in kinds) for m in ("attn", "linear")}
+    n = {m: sum(_mixer_of(k) == m for k in kinds) for m in _MIXERS}
     return {name: jax.tree_util.tree_map(
         lambda a, p=n.get(name, len(kinds)): a.reshape(
             (cfg.n_layers // len(kinds), p) + a.shape[1:]), sub)
@@ -1129,9 +1228,10 @@ def _slot_pass(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         attn_adp = adp["attn"] if adp is not None else None
         kind = cfg.layer_kind(l)
         h = _norm(cfg, p["norm1"], x)
-        if kind == "linear":
-            mixed = _linear_mixer(cfg, p["linear"], h,
-                                  partial(kv.through_state, l), valid=live)
+        if kind in _STATE_MIXERS:
+            mixed = _STATE_MIXERS[kind](cfg, p[kind], h,
+                                        partial(kv.through_state, l),
+                                        valid=live)
         else:
             q, k, v = _qkv_proj(cfg, p["attn"], h,
                                 _layer_rope(cfg, rope, kind), positions,
@@ -1170,7 +1270,8 @@ def kv_append_path(cache: Params, Tq: int,
         supports_lane_append,
     )
 
-    pane = cache["k"][0]                       # (S, Hkv, Tmax, hd)
+    # (S, Hkv, Tmax, hd): the first layer that holds keys and values
+    pane = next(a for a in cache["k"] if a is not None)
     _, Hkv, Tmax, hd = pane.shape
     if ((backend or jax.default_backend()) == "tpu"
             and not _cache_quantized(cache)
@@ -1357,11 +1458,12 @@ class _SlotKV:
         raise NotImplementedError
 
     def through_state(self, l: int, run) -> jnp.ndarray:
-        """A 'linear' layer's slot memory is no keys and values but the
-        K-1 tokens its convolution still needs (``cache["conv"][l]``, (rows,
-        K-1, 3W)) and a recurrent state (``cache["state"][l]``, (rows, H, hd,
-        hd) float32). The object hands ``run(tail, state, n_valid) -> (o,
-        tail, state)`` (``_linear_mixer``) what this pass's rows start from
+        """A 'linear' or 'ssm' layer's slot memory is no keys and values but
+        the K-1 tokens its convolution still needs (``cache["conv"][l]``,
+        (rows, K-1, channels)) and a recurrent state (``cache["state"][l]``,
+        float32: (rows, H, hd, hd), or (rows, N, I) in an 'ssm' layer:
+        ``cfg.state_shapes``). The object hands ``run(tail, state, n_valid) ->
+        (o, tail, state)`` (``_STATE_MIXERS``) what this pass's rows start from
         (zeros where a sequence starts, whatever the slot held), keeps what
         comes back for the rows that are real, and returns ``o``."""
         raise NotImplementedError
@@ -1428,7 +1530,7 @@ class _SharedLengthKV(_SlotKV):
         right-padded to its bucket): only they move a state."""
         if self.valid_len is None:
             return None
-        rows = self.cache["state"][self.cfg.layers_of("linear")[0]].shape[0]
+        rows = self.cache["state"][self.cfg.state_layers[0]].shape[0]
         return jnp.broadcast_to(jnp.arange(self.Tq) < self.valid_len,
                                 (rows, self.Tq))
 
@@ -1653,9 +1755,9 @@ class _RowsKV(_SlotKV):
         tail, state = self.cache["conv"][l], self.cache["state"][l]
         o, new_tail, new_state = run(tail, state)
         if self._live is not None:
-            new_tail = jnp.where(self._live[:, None, None], new_tail, tail)
-            new_state = jnp.where(self._live[:, None, None, None], new_state,
-                                  state)
+            rows = lambda a: self._live[(slice(None),) + (None,) * (a.ndim - 1)]
+            new_tail = jnp.where(rows(tail), new_tail, tail)
+            new_state = jnp.where(rows(state), new_state, state)
         self._keep_state(new_tail, new_state)
         return o
 

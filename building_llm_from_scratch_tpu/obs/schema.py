@@ -35,7 +35,8 @@ from typing import Any, Dict, FrozenSet, List
 
 #: Bump when a row type or a load-bearing field changes meaning. The
 #: ``header`` row carries it; consumers key parsing decisions on it.
-SCHEMA_VERSION = 15         # v15: serve_warmup gains expert_dispatch
+SCHEMA_VERSION = 16         # v16: serve_warmup gains selective_scan
+                            # (v15: serve_warmup gains expert_dispatch)
                             # (v14: recurrent state beside keys and
                             # values — serve_warmup gains
                             # linear_attention, the tick record
@@ -120,7 +121,8 @@ TICK_IDLE_WAIT = "tick.idle_wait"
 #: row is the one anchor to unix time. ``phases`` holds the self seconds
 #: of each phase that ran; ``tick`` is ``n_ticks`` after the tick; ``rows``
 #: of the program's ``n_slots`` rows decoded a token, after ``chunks`` prefill
-#: chunks of slots in mid-prefill (chunked prefill only, absent at 0) whose
+#: chunks of slots in mid-prefill (chunked prefill only, absent at 0) that
+#: held ``chunk_tokens`` real tokens (their padding left out) and whose
 #: attention read ``chunk_kv_touched`` key positions (summed over the layers:
 #: the live key blocks in a layer on the chunk kernel's path, whole buffers in
 #: any other), reading
@@ -132,13 +134,13 @@ TICK_IDLE_WAIT = "tick.idle_wait"
 #: of what was read). A sparse model's
 #: decode tick adds ``expert_rows`` (rows each held expert computed, summed
 #: over layers) and ``experts_touched`` (held experts, counted a layer,
-#: that got a row: each read its weights once). A model with 'linear'
-#: layers adds ``state_rows`` (decoding rows x linear layers: the recurrent
+#: that got a row: each read its weights once). A model with 'linear' or
+#: 'ssm' layers adds ``state_rows`` (decoding rows x those layers: the recurrent
 #: states the tick had to read and write) and ``state_rows_touched`` (those
 #: the fixed-shape step did read and write: every row's).
 TICK_RECORD_FIELDS = ("tick", "t0", "t1", "t_dispatch", "t_fetch", "phases",
                       "rows", "n_slots", "admitted", "queue_depth",
-                      "replica", "chunks", "chunk_kv_touched",
+                      "replica", "chunks", "chunk_tokens", "chunk_kv_touched",
                       "kv_positions", "kv_touched",
                       "expert_rows", "experts_touched",
                       "state_rows", "state_rows_touched")
@@ -407,7 +409,8 @@ _EVENT_LIST: List[EventSpec] = [
                     "drafter", "replica", "kv_paged", "page_tokens",
                     "pool_pages", "sp", "prompt_pane_tokens", "max_prompt",
                     "kv_append", "decode_attention", "chunk_attention",
-                    "linear_attention", "expert_dispatch"),
+                    "linear_attention", "selective_scan",
+                    "expert_dispatch"),
           doc="prefill programs + decode (or spec verify) program "
               "compiled; watchers frozen; records the KVCachePolicy "
               "(quant/chunk/prefix), which append and which attention the "

@@ -60,8 +60,10 @@ def l2norm(x: jnp.ndarray) -> jnp.ndarray:
 
 @jax.named_scope("linear_conv")
 def causal_conv(pre: jnp.ndarray, tail: jnp.ndarray, w: jnp.ndarray,
-                n_valid=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """A depthwise causal convolution of K taps over time, then SiLU.
+                n_valid=None, bias: Optional[jnp.ndarray] = None
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """A depthwise causal convolution of K taps over time (plus ``bias``
+    (Ch,), where given), then SiLU.
     ``pre`` (B, T, Ch) are the tokens' channels, ``tail`` (B, K-1, Ch) the
     K-1 tokens before them (zeros before a sequence), ``w`` (K, Ch), its last
     tap on the current token. -> (float32 (B, T, Ch); the next tail: the
@@ -70,6 +72,8 @@ def causal_conv(pre: jnp.ndarray, tail: jnp.ndarray, w: jnp.ndarray,
     x = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
     w32 = w.astype(jnp.float32)
     out = sum(x[:, j:j + T].astype(jnp.float32) * w32[j] for j in range(K))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     start = T if n_valid is None else n_valid
     new_tail = jax.lax.dynamic_slice_in_dim(x, start, K - 1, axis=1)
     return jax.nn.silu(out), new_tail.astype(tail.dtype)

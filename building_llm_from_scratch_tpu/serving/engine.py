@@ -107,6 +107,9 @@ from building_llm_from_scratch_tpu.ops.decode_step import LIVE_BLOCK
 from building_llm_from_scratch_tpu.ops.linear_attention import (
     linear_attention_path,
 )
+from building_llm_from_scratch_tpu.ops.selective_scan import (
+    selective_scan_path,
+)
 from building_llm_from_scratch_tpu.parallel.collectives import (
     trace_under_mesh,
 )
@@ -183,6 +186,9 @@ class DecodeEngine:
         self.cfg = cfg
         self._n_window_layers = len(cfg.layers_of("sliding"))
         self._n_linear_layers = len(cfg.layers_of("linear"))
+        self._n_ssm_layers = len(cfg.layers_of("ssm"))
+        #: layers whose memory of a row is a recurrent state, either kind
+        self._n_state_layers = self._n_linear_layers + self._n_ssm_layers
         #: parallel/sharding.MeshPlan (or None = the historical
         #: single-device engine, byte-for-byte). tp>1 runs the whole
         #: prefill/decode/verify program family with NamedSharding'd
@@ -411,6 +417,16 @@ class DecodeEngine:
             "prefill": linear_attention_path(
                 self.kv_policy.prefill_chunk or self.max_len)}
             if self._n_linear_layers else None)
+        #: the same for its 'ssm' layers (``selective_scan_path``): the
+        #: tick's "step", a prefill's "kernel" on a TPU at shapes the kernel
+        #: takes, else "scan"
+        self.selective_scan = ({
+            "tick": selective_scan_path(self.spec_k + 1, cfg.ssm_inner,
+                                        cfg.ssm_state),
+            "prefill": selective_scan_path(
+                self.kv_policy.prefill_chunk or self.max_len, cfg.ssm_inner,
+                cfg.ssm_state)}
+            if self._n_ssm_layers else None)
         #: how each program's rows reach the held experts
         #: (``expert_dispatch_path``): the tick's "per_expert" (one
         #: conditional a held expert), a chunk's "grouped" on a TPU (one
@@ -963,10 +979,10 @@ class DecodeEngine:
 
     def _step_tail(self) -> tuple:  # holds: _lock
         """The tick program's positional tail: the adapter pool and the
-        slots' rows of it; for a sparse model or one with 'linear' layers
-        (neither takes an adapter) the rows that decode this tick: the
+        slots' rows of it; for a sparse model or one whose layers hold a
+        state (neither takes an adapter) the rows that decode this tick: the
         others reach no expert and move no state."""
-        if self.cfg.is_moe or self._n_linear_layers:
+        if self.cfg.is_moe or self._n_state_layers:
             live = np.zeros((self.n_slots,), np.bool_)
             live[[s for s, _ in self.scheduler.active()
                   if s not in self._prefill_state]] = True
@@ -1636,6 +1652,8 @@ class DecodeEngine:
             st["pos"] = lo + C
             self.prefill_chunks += 1
             self._tick_rec["chunks"] = self._tick_rec.get("chunks", 0) + 1
+            self._tick_rec["chunk_tokens"] = (
+                self._tick_rec.get("chunk_tokens", 0) + hi - lo)
             # inside the span: the phases of a tick add up to its wall
             self._tick_rec["chunk_kv_touched"] = (
                 self._tick_rec.get("chunk_kv_touched", 0)
@@ -1920,7 +1938,7 @@ class DecodeEngine:
         live = [lengths[s] + 1 for s, _ in decoding]
         n_window = self._n_window_layers
         total = (self.cfg.n_layers - n_window
-                 - self._n_linear_layers) * sum(live)
+                 - self._n_state_layers) * sum(live)
         if n_window:
             window = self.cfg.sliding_window
             total += n_window * sum(min(n, window) for n in live)
@@ -2097,14 +2115,14 @@ class DecodeEngine:
             # inside the span: the phases of a tick add up to its wall
             (self._tick_rec["kv_positions"],
              self._tick_rec["kv_touched"]) = self._kv_positions_read(decoding)
-            if self._n_linear_layers:
+            if self._n_state_layers:
                 # the states this tick has to read and write (a decoding
-                # row's, a 'linear' layer) and those the fixed-shape step
-                # does: every row's
+                # row's, a layer that holds one) and those the fixed-shape
+                # step does: every row's
                 self._tick_rec["state_rows"] = (
-                    len(decoding) * self._n_linear_layers)
+                    len(decoding) * self._n_state_layers)
                 self._tick_rec["state_rows_touched"] = (
-                    self.n_slots * self._n_linear_layers)
+                    self.n_slots * self._n_state_layers)
             nxt, ok, cache = self._decode(
                 self.cache, self._weights, self._last_tokens, self._lengths,
                 self._base_keys, self._n_gen, self._temps,
@@ -2564,6 +2582,7 @@ class DecodeEngine:
             decode_attention=self.decode_attention,
             chunk_attention=self.chunk_attention,
             linear_attention=self.linear_attention,
+            selective_scan=self.selective_scan,
             expert_dispatch=self.expert_dispatch,
             prefix_pane_tokens=(self._prefix_pane_len
                                 if self.prefix_store is not None
@@ -2948,9 +2967,9 @@ class DecodeEngine:
         out = {"kv_positions": {"full": self._cache_len}}
         if self.cfg.has_window_layers:
             out["kv_positions"]["ring"] = min(n for n in lengths if n)
-        if self._n_linear_layers:
+        if self._n_state_layers:
             out["state"] = {
-                "layers": self._n_linear_layers,
+                "layers": self._n_state_layers,
                 "bytes_per_slot": self.kv_policy.bytes_per_slot(
                     self.cfg, self._cache_len)["state_bytes"]}
         if self.cfg.is_moe:
@@ -2993,6 +3012,7 @@ class DecodeEngine:
             out["decode_attention"] = self.decode_attention
             out["chunk_attention"] = self.chunk_attention
             out["linear_attention"] = self.linear_attention
+            out["selective_scan"] = self.selective_scan
             out["expert_dispatch"] = self.expert_dispatch
             out.update(self.layout())
             out["memory"] = self.memory_ledger.describe()
@@ -3165,6 +3185,7 @@ class DecodeEngine:
             "decode_attention": self.decode_attention,
             "chunk_attention": self.chunk_attention,
             "linear_attention": self.linear_attention,
+            "selective_scan": self.selective_scan,
             "expert_dispatch": self.expert_dispatch,
             **self.layout(),
             "draining": self.draining,

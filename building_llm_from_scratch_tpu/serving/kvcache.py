@@ -55,6 +55,7 @@ allocation rule without an import cycle.
 from __future__ import annotations
 
 import hashlib
+import math
 import threading
 import time
 from collections import OrderedDict
@@ -155,7 +156,7 @@ class KVCachePolicy:
         """
         import jax.numpy as jnp
 
-        if cfg.has_linear_layers:
+        if cfg.has_state_layers:
             # a state has no pages and no int8 form: said here too, for a
             # caller that allocates without an engine
             refuse_unsupported(cfg, paged=self.paged,
@@ -168,8 +169,8 @@ class KVCachePolicy:
             lead = [(n_rows, cfg.n_kv_groups, length)
                     for length in self.layer_lengths(cfg, max_length)]
         dt = self.cache_dtype(cfg)
-        # a 'linear' layer holds no positions: None in the lists of keys and
-        # values, which stay indexed by layer
+        # a 'linear' or 'ssm' layer holds no positions: None in the lists of
+        # keys and values, which stay indexed by layer
         zeros = lambda tail, dtype: [
             None if not s[2] else jnp.zeros(s + tail, dtype) for s in lead]
         cache: Params = {"k": zeros((cfg.head_dim,), dt),
@@ -177,17 +178,19 @@ class KVCachePolicy:
         if self.quantized:
             cache["k_scale"] = zeros((1,), jnp.float32)
             cache["v_scale"] = zeros((1,), jnp.float32)
-        if cfg.has_linear_layers:
-            # beside them, a 'linear' layer's memory of a row: the last K-1
-            # tokens of its convolution's input and a float32 state a head
-            linear = [cfg.layer_kind(l) == "linear"
-                      for l in range(cfg.n_layers)]
-            H, hd = cfg.linear_heads, cfg.linear_head_dim
+        if cfg.has_state_layers:
+            # beside them, a 'linear' or 'ssm' layer's memory of a row: the
+            # last K-1 tokens of its convolution's input and a float32 state
+            # (``cfg.state_shapes``: a matrix a head, or N states a channel)
+            held = set(cfg.state_layers)
+            shapes = [cfg.state_shapes(cfg.layer_kind(l)) if l in held
+                      else None for l in range(cfg.n_layers)]
             cache["conv"] = [
-                jnp.zeros((n_rows, cfg.linear_conv - 1, 3 * H * hd),
-                          cfg.jax_dtype) if on else None for on in linear]
-            cache["state"] = [jnp.zeros((n_rows, H, hd, hd), jnp.float32)
-                              if on else None for on in linear]
+                jnp.zeros((n_rows,) + s[0], cfg.jax_dtype) if s else None
+                for s in shapes]
+            cache["state"] = [
+                jnp.zeros((n_rows,) + s[1], jnp.float32) if s else None
+                for s in shapes]
         return cache
 
     def ring_length(self, cfg: ModelConfig, max_length: int) -> int:
@@ -208,11 +211,12 @@ class KVCachePolicy:
 
     def layer_lengths(self, cfg: ModelConfig, max_length: int) -> List[int]:
         """Each layer's positions a slot: ``ring_length`` for a 'sliding'
-        layer, ``max_length`` for a 'full' one, none for a 'linear' one
-        (its memory is a state: ``bytes_per_slot``)."""
+        layer, ``max_length`` for a 'full' one, none for a 'linear' or
+        'ssm' one (its memory is a state: ``bytes_per_slot``)."""
         ring = (self.ring_length(cfg, max_length)
                 if cfg.has_window_layers else max_length)
-        by_kind = {"sliding": ring, "full": max_length, "linear": 0}
+        by_kind = {"sliding": ring, "full": max_length, "linear": 0,
+                   "ssm": 0}
         return [by_kind[cfg.layer_kind(l)] for l in range(cfg.n_layers)]
 
     # -- paged layout --------------------------------------------------------
@@ -264,13 +268,14 @@ class KVCachePolicy:
         out = {"kv_bytes": kv, "scale_bytes": scale,
                "total_bytes": kv + scale,
                "bytes_per_token": (kv + scale) // max_length}
-        if cfg.has_linear_layers:
-            # whatever the length: a float32 state a head and the
-            # convolution's tail, a 'linear' layer
-            H, hd = cfg.linear_heads, cfg.linear_head_dim
-            out["state_bytes"] = len(cfg.layers_of("linear")) * (
-                H * hd * hd * 4 + (cfg.linear_conv - 1) * 3 * H * hd
-                * jnp.dtype(cfg.jax_dtype).itemsize)
+        if cfg.has_state_layers:
+            # whatever the length: a float32 state and the convolution's
+            # tail, a 'linear' or 'ssm' layer
+            el = jnp.dtype(cfg.jax_dtype).itemsize
+            out["state_bytes"] = sum(
+                math.prod(state) * 4 + math.prod(tail) * el
+                for tail, state in (cfg.state_shapes(cfg.layer_kind(l))
+                                    for l in cfg.state_layers))
             out["total_bytes"] += out["state_bytes"]
         return out
 

@@ -402,6 +402,34 @@ def phase_serve_hybrid(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
     check_serve(engine, reqs, results, "serve_hybrid", n_generate=2)
 
 
+#: Mamba-1 selective-state-space layers around one multi-query attention
+#: layer, dense, tied, at the debug size of ``configs.get_config`` (one
+#: period of fourteen layers; 64 channels of 8 states; context 64)
+SSM_DEBUG = ["--model", "jamba2", "--num_params", "3B", "--debug"]
+
+
+def phase_serve_ssm(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
+                            (40, 16, 0.0), (9, 20, 0.7), (23, 30, 0.0))
+                    ) -> None:
+    """``phase_serve_hybrid`` for the other recurrent state: thirteen
+    state-space layers' states and tails beside one layer's keys and
+    values, the scan in its XLA forms (the debug width is under the
+    kernel's; ``phase_kernels`` holds the kernel at the cell's)."""
+    reqs_path = os.path.join(WORK, "requests_ssm.jsonl")
+    os.makedirs(WORK, exist_ok=True)
+    reqs = make_requests(reqs_path, shapes)
+    engine, results = serve("serve_ssm", reqs_path, len(reqs),
+                            extra=["--serve_prefill_chunk", "16"],
+                            model=SSM_DEBUG)
+    layout = engine.layout()
+    check(layout["kv_positions"] == {"full": 64}
+          and layout["state"]["layers"] == 13
+          and engine.selective_scan == {"tick": "step", "prefill": "scan"},
+          f"serve_ssm: the engine's layout is {layout}, its state-space "
+          f"layers' forms {engine.selective_scan}")
+    check_serve(engine, reqs, results, "serve_ssm", n_generate=2)
+
+
 def _load_tests(name: str):
     spec = importlib.util.spec_from_file_location(
         name, os.path.join(HERE, "tests", name + ".py"))
@@ -606,10 +634,37 @@ def phase_kernels() -> None:
           f"kernels: the grouped experts are {gap:.2e} off the per-expert "
           f"form, rows {n_got.tolist()} against {n_want.tolist()}")
 
+    # the selective scan (what a state-space engine's chunk program runs)
+    # vs the step under a lax.scan, at the widechat cell's layer: 512 tokens
+    # of 5120 channels x 16 states from a non-zero state, the family's A,
+    # steps from 0.001 to 1, a padded tail (delta 0)
+    from building_llm_from_scratch_tpu.ops import selective_scan as ss
+
+    T, I, N = 512, 5120, 16
+    check(ss.selective_scan_path(T, I, N) == "kernel"
+          and ss.selective_scan_path(1, I, N) == "step",
+          "kernels: a 512-token chunk is not on the scan kernel's path")
+    delta = jnp.exp(jax.random.uniform(ks[1], (1, T, I), minval=np.log(1e-3),
+                                       maxval=0.0))
+    delta = jnp.where(jnp.arange(T)[None, :, None] < T - 37, delta, 0.0)
+    args = (jax.random.normal(ks[0], (1, T, I)), delta,
+            -jnp.broadcast_to(jnp.arange(1.0, N + 1)[:, None], (N, I)),
+            jax.random.normal(ks[2], (1, T, N)),
+            jax.random.normal(ks[3], (1, T, N)),
+            jax.random.normal(ks[4], (I,)),
+            jax.random.normal(ks[5], (1, N, I)))
+    (y_got, s_got), (y_want, s_want) = (
+        jax.jit(form)(*args)
+        for form in (ss.selective_scan_kernel, ss.selective_scan))
+    gap = max(float(np.abs(f32(y_got) - f32(y_want)).max()),
+              float(np.abs(f32(s_got) - f32(s_want)).max()))
+    check(gap < 1e-4, f"kernels: the selective-scan kernel is {gap:.2e} off "
+          "the step under a lax.scan")
+
     n = run_repo_tpu_tests()
     log(f"kernels: fused attention fwd/grad/dropout, fused dropout-add, "
-        f"lane-window append, live-block and chunk attention and the "
-        f"grouped experts "
+        f"lane-window append, live-block and chunk attention, the "
+        f"grouped experts and the selective scan "
         f"match their XLA references at the real shapes; "
         f"{n} needs_tpu repo test cases pass; "
         f"{time.perf_counter() - t0:.1f}s")
@@ -701,9 +756,10 @@ def main(argv=None) -> int:
                          "are compared with (builder-run)")
     ap.add_argument("--phase", action="append",
                     choices=["train", "serve", "serve_moe", "serve_hybrid",
-                             "kernels", "train_remat"],
+                             "serve_ssm", "kernels", "train_remat"],
                     help="run only these one-chip phases (default: train, "
-                         "serve, serve_moe, serve_hybrid, kernels)")
+                         "serve, serve_moe, serve_hybrid, serve_ssm, "
+                         "kernels)")
     args = ap.parse_args(argv)
 
     import jax
@@ -731,10 +787,11 @@ def main(argv=None) -> int:
         table = {"train": phase_train, "serve": phase_serve,
                  "serve_moe": phase_serve_moe,
                  "serve_hybrid": phase_serve_hybrid,
+                 "serve_ssm": phase_serve_ssm,
                  "kernels": phase_kernels, "train_remat": phase_train_remat}
         phases = {n: table[n] for n in (
             args.phase or ["train", "serve", "serve_moe", "serve_hybrid",
-                           "kernels"])}
+                           "serve_ssm", "kernels"])}
     shutil.rmtree(WORK, ignore_errors=True)
     failed = []
     t_all = time.perf_counter()

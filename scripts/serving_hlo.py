@@ -10,8 +10,9 @@ from the benchmark's own configuration and traffic files, at the cells'
 sizes: ``gpt2-1.5b``, 32 slots: ``_decode_impl`` and ``_prefill_impl`` at
 the smallest and largest bucket the chat cell warms; the
 ``command-a-plus-05-2026`` cut and the ``solar-open2-250b`` cut, 48 slots
-each: ``_decode_impl`` and ``_chunk_impl`` at 512 (seven programs; the first
-five are the contract of PR 30, the last two exist from PR 35 on). ``<tree>`` is a checkout (``git archive <commit> |
+each, and ``ai21-jamba2-3b`` whole, 192 slots: ``_decode_impl`` and
+``_chunk_impl`` at 512 (nine programs; the first five are the contract of
+PR 30, two exist from PR 35 on and the last two from PR 39 on). ``<tree>`` is a checkout (``git archive <commit> |
 tar -x -C <dir>`` for a parent), so two commits are dumped by two calls.
 Run with ``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` beside a test run. About 20 s a
 program.
@@ -134,10 +135,22 @@ def dump(tree: str, outdir: str, only: set) -> None:
                 row(I32), sds((S, 2), U32), row(I32), row(F32), row(I32),
                 None, None, None, sds((S,), jnp.bool_))
 
+    if not os.path.exists(os.path.join(tree, "benchmark", "configs",
+                                       "ai21-jamba2-3b.json")):
+        return                      # a tree from before PR 39
+    e, cache, weights = cell("ai21-jamba2-3b.json", "chat_wide_poisson.json")
+    S, C = e.n_slots, e.kv_policy.prefill_chunk
+    one_program(f"widechat_chunk_{C}", e._chunk_impl, cache, weights,
+                sds((1, C), I32), scalar(I32), scalar(I32), scalar(I32), key,
+                scalar(F32), scalar(I32))
+    one_program("widechat_decode", e._decode_impl, cache, weights, row(I32),
+                row(I32), sds((S, 2), U32), row(I32), row(F32), row(I32),
+                None, None, None, sds((S,), jnp.bool_))
+
 
 SCOPES = ("attention", "window_attention", "linear_attention",
           "linear_conv", "linear_gates", "linear_proj", "attention_gate",
-          "cache_update", "mlp",
+          "selective_scan", "ssm_conv", "ssm_proj", "cache_update", "mlp",
           "moe_router", "moe_experts", "moe_shared", "head", "sampling")
 
 

@@ -26,7 +26,7 @@ os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
 #: the scopes the programs set (models/transformer.py, generate.py,
 #: training/train_step.py), the longer name before its prefix
 SCOPES = ("cache_update", "window_attention", "linear_attention",
-          "linear_conv", "linear_gates", "linear_proj", "attention_gate",
+          "selective_scan", "ssm_conv", "ssm_proj", "linear_conv", "linear_gates", "linear_proj", "attention_gate",
           "attention", "moe_router",
           "moe_experts", "moe_shared", "mlp", "head_xent", "head",
           "cross_entropy", "sampling")

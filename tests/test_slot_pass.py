@@ -51,13 +51,20 @@ BLOCKS = {
                          layer_kinds=("full", "linear"), attn_out_gate=True,
                          linear_heads=4, linear_head_dim=8,
                          linear_gate_rank=4, linear_neg_eigval=True),
+    # the other recurrent state: a selective-state-space layer (no
+    # positions), then a multi-query full one, a tied head. Refused as above
+    "ssm_state": dict(GPT2, norm="rmsnorm", positional="none",
+                      activation="swiglu", attn_out_bias=False,
+                      mlp_bias=False, norm_bias=False, tie_embeddings=True,
+                      n_kv_groups=1, layer_kinds=("ssm", "full"),
+                      ssm_inner=64, ssm_state=8, ssm_dt_rank=4),
 }
 #: what a block form does not run through, and the sentence that says so
-REFUSED = {"linear_state": {
-    "verify": "no way back from a state",
-    "paged_chunks": "a page holds positions",
-    "paged_decode": "a page holds positions",
-    "paged_verify": "a page holds positions"}}
+_NO_STATE = {"verify": "no way back from a state",
+             "paged_chunks": "a page holds positions",
+             "paged_decode": "a page holds positions",
+             "paged_verify": "a page holds positions"}
+REFUSED = {"linear_state": _NO_STATE, "ssm_state": _NO_STATE}
 PROGRAMS = ("prefill_bucket", "prefill_chunks", "decode", "verify",
             "paged_chunks", "paged_decode", "paged_verify")
 

@@ -231,7 +231,7 @@ def _after_mixer(cfg, p, x):
     """x + Mix(RMSNorm1(x)): what the expert layer's norm reads."""
     n1 = tf._norm(cfg, p["norm1"], x)
     return x + tf._linear_mixer(cfg, p["linear"], n1, lambda run: run(
-        *tf._fresh_state(cfg, x.shape[0], x.dtype))[0])
+        *tf._fresh_state(cfg, "linear", x.shape[0], x.dtype))[0])
 
 
 # -- (d) rows whose memory is no prefix ---------------------------------------
@@ -409,7 +409,7 @@ def test_state_bytes_the_ledger_and_the_tick_record(model):
      "the state after them"),
     (dict(kv_policy=KVCachePolicy(kv_quant="int8")), "no int8 form"),
     (dict(spec_k=2), "already moved the recurrent state"),
-    (dict(adapters=object()), "a linear layer has others"),
+    (dict(adapters=object()), "a layer that holds a state has others"),
 ])
 def test_engine_refuses_what_a_state_does_not_support(model, kw, match):
     """By the linear layers alone: the config here has no experts."""
@@ -460,5 +460,5 @@ def test_flags_refuse_what_the_config_does_not_support(tmp_path, flags,
 def test_a_config_with_linear_layers_says_what_they_are():
     with pytest.raises(ValueError, match="linear_heads"):
         debug_cfg(linear_heads=0)
-    with pytest.raises(ValueError, match="'sliding', 'full' or 'linear'"):
+    with pytest.raises(ValueError, match="'sliding', 'full', 'linear' or 'ssm'"):
         debug_cfg(layer_kinds=("full", "conv"))

@@ -424,6 +424,27 @@ def test_grouped_experts_real_widths(one_chip, H, F):
     assert stacked == {"parameter"}, stacked
 
 
+@pytest.mark.parametrize("B,T", [(1, 512), (2, 1024)])
+def test_selective_scan_real_widths(one_chip, B, T):
+    """The selective-scan kernel at the state-space cell's layer (5120
+    channels of 16 float32 states; a prefill chunk of 512 tokens, and the
+    longest span the gate admits, two rows of it): the gate admits the shape,
+    B and C fit scalar memory, the VMEM the kernel asks for is granted, and
+    the program is ONE kernel call. The next span up is refused by the gate,
+    not by the compiler mid-run."""
+    from building_llm_from_scratch_tpu.ops import selective_scan as ss
+
+    I, N = 5120, 16
+    assert ss.supports_selective_scan_kernel(T, I, N)
+    assert not ss.supports_selective_scan_kernel(2048, I, N)
+    s = _spec(one_chip)
+    f32 = jnp.float32
+    hlo = _compile(ss.selective_scan_kernel, s((B, T, I), f32),
+                   s((B, T, I), f32), s((N, I), f32), s((B, T, N), f32),
+                   s((B, T, N), f32), s((I,), f32), s((B, N, I), f32))
+    assert hlo.count("tpu_custom_call") == 1
+
+
 def test_xent_fwd_largest_admitted_shape(one_chip):
     """The gate's VMEM budget, at its edge: the largest hidden width it
     admits for 2048 rows compiles, and the next lane multiple is refused
