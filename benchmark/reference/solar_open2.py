@@ -63,16 +63,15 @@ rounded to float8 e4m3, one scale a tensor. The router, the convolution and
 the recurrence stay float32 there too. ``"bf16"`` (tests) rounds the same
 operands to bfloat16.
 
-NEAR-TIES are left out of the comparison as ``cohere2_moe.py`` leaves them
-out (``NEAR_TIE``, the same share of the row's rms logit), but the tie is
-looked for at every rank, not between the 8th and 9th logits alone
-(``_route``): an expert held here that is chosen and lies within ``NEAR_TIE``
-of the best one not chosen, or is not chosen and lies that near the last one
-chosen. The chip's runs showed why (PERF.md section 6, PR 35): the served
-tokens that the 8th/9th rule left standing 0.5-0.75 under the reference's
+NEAR-TIES are left out of the comparison by the one rule of
+``near_tie.py``, which ``cohere2_moe.py`` imports too: an expert held here
+that is chosen and lies within ``NEAR_TIE`` of the best one not chosen, or is
+not chosen and lies that near the last one chosen, at any rank. The chip's
+runs showed why (PERF.md section 6, PR 35): the served tokens that a rule on
+the 8th and 9th logits alone left standing 0.5-0.75 under the reference's
 best each had a held expert 7th within 0.02 of the 9th, or 10th that near the
-8th, and the program had chosen the other way. That file's second rule,
-``COHERENT_REPEATS``, is NOT here: it exists because that model's first
+8th, and the program had chosen the other way. ``cohere2_moe.py``'s second
+rule, ``COHERENT_REPEATS``, is NOT here: it exists because that model's first
 router reads the token's embedding alone, so a token that ties there ties at
 every one of its positions. Here every router reads ``RMSNorm2(x + Mix)``,
 the mixer's output included, so no tie is a token's own, and no chip run
@@ -98,19 +97,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-F8_MAX = 448.0          # largest finite float8_e4m3fn
+from benchmark.reference import near_tie
+from benchmark.reference.near_tie import LEAST_COMPARED, NEAR_TIE, left_out
 
-#: an expert held here whose logit lies nearer the edge of the chosen k than
-#: this share of the row's root-mean-square logit is a NEAR-TIE (``_route``):
-#: bfloat16 rounding of the layers before moves a logit by up to about a
-#: hundredth of that, the program and this file may then pick different
-#: experts, and that position's logits differ by a whole expert's output,
-#: which says nothing of either's arithmetic (``cohere2_moe.py`` has how the
-#: number was found; on this cell's served tokens the reference with bfloat16
-#: operands chose another way at margins up to 0.034, and a rule of 0.03 left
-#: a 0.198 standing where 0.04 and over left 0.086: PERF.md section 6).
-#: ``served_token_gaps`` leaves such positions out.
-NEAR_TIE = 0.08
+F8_MAX = 448.0          # largest finite float8_e4m3fn
 
 
 def _fp8_round(x):
@@ -195,11 +185,7 @@ def _expert(x, gate, up, down, precision):
 def _route(m, n, router):
     """-> (weight of each routed expert for each row (N, E), zero outside
     the k chosen; margin (N,): how near the edge of the chosen k the nearest
-    expert HELD HERE lies, as a share of the row's rms logit: a chosen one
-    over the best not chosen (the (k+1)-th logit), one not chosen under the
-    last chosen (the k-th). Whatever its rank: the 7th of 8 leaves the
-    chosen when the 8th and the 9th both pass it, the 10th enters when it
-    passes the 8th and the 9th, and the 9th has to pass either way.)"""
+    expert held here lies, at any rank: ``near_tie.margin``)."""
     logits = n @ router
     k, held = m["n_experts_per_tok"], jnp.asarray(_held(m))
     top, ids = jax.lax.top_k(logits, min(k + 1, logits.shape[-1]))
@@ -207,22 +193,7 @@ def _route(m, n, router):
     w = scores / jnp.sum(scores, -1, keepdims=True)
     dense = jnp.zeros_like(logits).at[
         jnp.arange(logits.shape[0])[:, None], ids[:, :k]].set(w)
-    if top.shape[-1] > k:
-        last_in, best_out = top[:, k - 1:k], top[:, k:k + 1]
-        ours = logits[:, held]
-        to_edge = jnp.where(ours >= last_in, ours - best_out, last_in - ours)
-        rms = jnp.sqrt(jnp.mean(logits ** 2, axis=-1))
-        margin = jnp.min(to_edge, axis=-1) / rms
-    else:
-        margin = jnp.full(logits.shape[:1], jnp.inf)
-    return dense, margin
-
-
-def left_out(margins) -> np.ndarray:
-    """margins (L, T), one row a layer (``_route``) -> (T,) bool: the
-    positions the comparison leaves out, those where in any layer an expert
-    held here lies within ``NEAR_TIE`` of the edge of the chosen."""
-    return (np.asarray(margins) < NEAR_TIE).any(axis=0)
+    return dense, near_tie.margin(logits, top, k, held)
 
 
 def _attend(q, q_pos, k, v, k_pos, block_rows):
@@ -377,7 +348,7 @@ def hidden_fn(params, model: Dict[str, Any], tokens, *,
               precision: str = "float32", block_rows: Optional[int] = None,
               piece_rows: Optional[int] = None):
     """(T,) int tokens -> (final-normed hidden rows (T, D) float32, every
-    layer's router margin (L, T): ``_route``, ``left_out``). The
+    layer's router margin (L, T): ``_route``, ``near_tie.left_out``). The
     rows go ``piece_rows`` at a time through the projections, the recurrence
     and the experts and ``block_rows`` at a time through attention's scores;
     the default for each is all of them."""
@@ -405,10 +376,6 @@ def logits_fn(params, model: Dict[str, Any], tokens, *,
         params["head"]["weight"], precision)
     return jnp.stack([rows(t) for t in tokens])
 
-
-#: the least share of the served positions a run has to compare (the chip's
-#: runs compared 0.46-0.51 of them)
-LEAST_COMPARED = 0.3
 
 #: at the cell's size: one key-value head's scores for 128 rows of 8 query
 #: heads against 33,792 keys are 138 MB of float32; the convolution's input
@@ -462,6 +429,7 @@ def served_token_gaps(params, model, sequences: Sequence[Tuple[Any, Any]],
 
     worst, worst_control, n_tokens, n_compared = 0.0, 0.0, 0, 0
     longest_prompt = longest = 0
+    read = []
     with jax.default_matmul_precision("highest"):
         for prompt, served in sequences:
             seq = np.zeros((pad_to,), np.int32)
@@ -475,6 +443,7 @@ def served_token_gaps(params, model, sequences: Sequence[Tuple[Any, Any]],
                 head(params["head"]["weight"], tokens, h, h_low, control)
                 + (margins,))
             keep = ~left_out(margins)[at]
+            read.append((n_p - 1, g[at], margins[:, at], keep))
             if keep.any():
                 worst = max(worst, float(g[at][keep].max()))
                 worst_control = max(worst_control,
@@ -491,7 +460,8 @@ def served_token_gaps(params, model, sequences: Sequence[Tuple[Any, Any]],
                     "the chosen, in any layer",
         "near_tie": NEAR_TIE,
         "longest_prompt": longest_prompt,
-        "longest_sequence": longest}}), flush=True)
+        "longest_sequence": longest,
+        "widest_gap_at": near_tie.widest(read)}}), flush=True)
     if share < LEAST_COMPARED:
         worst = float("inf")
     return {"widest_gap": worst,

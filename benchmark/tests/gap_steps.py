@@ -99,8 +99,12 @@ def main(argv=None) -> int:
             "gaps_and_tick_ms_by_chunks": by_k,
             "cumulative_share_by_chunks": cumulative,
             "recompiles": engine.n_recompiles}), flush=True)
-    print(json.dumps({"stats": {k: engine.stats()[k] for k in (
-        "kv_append", "kv_positions", "experts", "kv_policy")}}), flush=True)
+    # what the engine says of itself, as far as this model's has it (a dense
+    # model's has no "experts")
+    said = engine.stats()
+    print(json.dumps({"stats": {k: said[k] for k in (
+        "kv_append", "kv_positions", "experts", "kv_policy")
+        if k in said}}), flush=True)
     engine.shutdown(drain=False)
     return 0
 
