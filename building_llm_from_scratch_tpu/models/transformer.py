@@ -1263,7 +1263,9 @@ def kv_append_path(cache: Params, Tq: int,
     (ops/decode_step.lane_window_append: one in-place kernel call a layer)
     on a TPU backend for the shapes ``supports_lane_append`` admits,
     ``"scatter"`` (the per-row ``slot_cache_append``) for everything else:
-    verify (Tq = k+1), int8 caches, ``head_dim`` 128, any other backend.
+    verify (Tq = k+1), int8 caches, ``head_dim`` 128 (positions on the
+    sublanes there: ``live_block_attention`` reads that layout, the append
+    has no kernel for it yet), any other backend.
     The engine reports the name (``stats()["kv_append"]``). ``backend`` is
     for tests, which have no TPU to ask about."""
     from building_llm_from_scratch_tpu.ops.decode_step import (
@@ -1287,13 +1289,16 @@ def decode_attention_path(cache: Params, Tq: int, n_heads: int, *,
     """THE rule for how ``_RowsKV`` attends in layer ``layer``, the sibling
     of ``kv_append_path`` and made the same way: ``"live_blocks"``
     (ops/decode_step.live_block_attention: one kernel call a layer that
-    reads, for each row, only the lane blocks its live positions reach) on
-    a TPU backend for the shapes ``supports_live_attention`` admits,
+    reads, for each row, only the key blocks its live positions reach) on
+    a TPU backend for the shapes ``supports_live_attention`` admits: a
+    float cache in either layout the runtime keeps one in, ``head_dim``
+    under 128 (blocks of lanes; no ring) or 128 (blocks of sublanes; a
+    ring, ``ring``: the layer attends by ``kv_positions`` / ``window``, is
+    read by position, and a row that does not decode not at all).
     ``"whole_buffer"`` (``decode_attention``: two reductions over the whole
     buffer, the lengths a mask) for everything else: verify (Tq = k+1),
-    int8 caches and their scale sidecars, ``head_dim`` 128, a ring
-    (``ring``: the layer attends by ``kv_positions`` / ``window``), any
-    other backend. The engine reports the name
+    int8 caches and their scale sidecars, a ring at ``head_dim`` under 128,
+    any other backend. The engine reports the name
     (``stats()["decode_attention"]``). ``backend`` is for tests."""
     from building_llm_from_scratch_tpu.ops.decode_step import (
         supports_live_attention,
@@ -1301,10 +1306,11 @@ def decode_attention_path(cache: Params, Tq: int, n_heads: int, *,
 
     pane = cache["k"][layer]                   # (S, Hkv, Tmax, hd)
     S, Hkv, Tmax, hd = pane.shape
-    if ((backend or jax.default_backend()) == "tpu" and not ring
+    if ((backend or jax.default_backend()) == "tpu"
             and not _cache_quantized(cache)
             and supports_live_attention(Tq, Tmax, hd, S=S, Hkv=Hkv,
-                                        Hq=n_heads, dtype=pane.dtype)):
+                                        Hq=n_heads, dtype=pane.dtype,
+                                        ring=ring)):
         return "live_blocks"
     return "whole_buffer"
 
@@ -1437,8 +1443,9 @@ class _SlotKV:
     object and nowhere else: the write (pane, per-row scatter, lane kernel,
     page table; int8 quantise-on-write with its sidecars), the read, the
     ring arithmetic, the pad-zeroing. A later layout (a latent cache, a
-    ``head_dim``-128 twin of the lane kernels, an append that rides the
-    attention call) is one more of these, or a change inside one.
+    ``head_dim``-128 append in place, an append that rides the attention
+    call) is one more of these, or a change inside one (as the sublane
+    form of the tick's attention was: PR 43).
 
     With it the span's geometry, which the same facts decide: ``positions``
     of the pass's tokens, ``live`` ((B, Tq) bool or None: the positions that
@@ -1798,7 +1805,8 @@ class _RowsKV(_SlotKV):
         """Each row's queries (at ``positions``) against its own appended
         prefix of (K, V). The two forms (``decode_attention_path``) are the
         same arithmetic: the kernel leaves unread what ``decode_attention``
-        reads and masks to 0."""
+        reads and masks to 0, and the rows that do not decode, whose
+        outputs nothing reads."""
         with _attention_scope(bool(ring_kw)):
             if decode_attention_path(self.cache, self.Tq, self.cfg.n_heads,
                                      layer=l, ring=bool(ring_kw)
@@ -1808,7 +1816,8 @@ class _RowsKV(_SlotKV):
                 )
 
                 return live_block_attention(
-                    q, K, V, self.lengths + 1,
+                    q, K, V, self.lengths + 1, live=self._live,
+                    window=ring_kw.get("window"),
                     interpret=jax.default_backend() != "tpu")
             return decode_attention(q, K, V, q_positions=self.positions,
                                     kv_length=self.lengths + self.Tq,
@@ -2078,7 +2087,8 @@ def decode_slots(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     """One decode tick: ``verify_slots`` (its arguments) at Tq = 1
     (``tokens`` (S, 1) are each slot's last accepted token), where the
     append is one in-place ``lane_window_append`` a layer and the attention
-    the live-block kernel wherever the two rules admit them; returns (fp32
-    logits (S, V), updated cache)."""
+    the live-block kernel (in the layout ``head_dim`` gives the cache)
+    wherever the two rules admit them; returns (fp32 logits (S, V), updated
+    cache)."""
     logits, new = verify_slots(params, cfg, tokens, *args, **kw)
     return logits[:, 0], new

@@ -29,8 +29,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from building_llm_from_scratch_tpu.ops.decode_step import (
     _LANES,
+    _MASKED,
     _NEG_BIG,
     _VMEM_BUDGET,
+    _ring_age,
 )
 from building_llm_from_scratch_tpu.parallel.collectives import mesh_kernel
 from building_llm_from_scratch_tpu.parallel.mesh import MODEL_AXIS
@@ -45,11 +47,6 @@ _KEY_BLOCKS = (512, 256, 128)
 #: faster on the chip and cost a warm start 2.4 s (PERF.md section 7, PR 34)
 _QUERY_ROWS = 1024
 _ROW_TILE = 512
-#: what a masked score reads: under the running maximum's start
-#: (``_NEG_BIG``), so its probability is exp(-1e30) = 0 exactly, also for a
-#: query that has seen no live key yet (an old block of a ring lies outside
-#: the window of the chunk's later queries)
-_MASKED = 2 * _NEG_BIG
 
 
 def _key_block(C: int, Tmax: int):
@@ -115,7 +112,7 @@ def _first_position(j, last, *, block: int, ring_len):
     at = j * block
     if ring_len is None:
         return at
-    pos = last - jax.lax.rem(last - at + ring_len, ring_len)
+    pos = last - _ring_age(jax.lax.rem(last, ring_len), at, ring_len)
     return jnp.where(pos < 0, at, pos)
 
 

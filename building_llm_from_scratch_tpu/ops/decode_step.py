@@ -11,8 +11,10 @@ a pane.
 
   - ``lane_window_append``: a tick's keys and values into every row at its
     own length, one aliased call a layer (PR 26).
-  - ``live_block_attention``: each row's one query against the lane blocks
-    its live positions reach, and no others (PR 29).
+  - ``live_block_attention``: each row's one query against the key blocks
+    its live positions reach, and no others: blocks of lanes where
+    ``head_dim`` is under 128 (PR 29), of sublanes at 128, where a ring is
+    read by position and a row that does not decode not at all (PR 43).
   - ``slot_cache_append`` / ``quantize_kv``: the per-row scatter and the
     int8 quantise-on-write that everything outside the two gates keeps
     (``supports_lane_append``, ``supports_live_attention``).
@@ -266,31 +268,47 @@ def _live_block_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref,
     done_ref[0] = jax.lax.fori_loop(0, rows, one_row, done_ref[0])
 
 
-def live_block_attention(q, k_cache, v_cache, kv_length, *, interpret=False):
+def live_block_attention(q, k_cache, v_cache, kv_length, *, live=None,
+                         window=None, interpret=False):
     """``decode_attention`` for ONE query token a row (``q`` (S, 1, Hq,
     hd), per-row ``kv_length`` (S,): the row attends positions
-    ``[0, kv_length)``) that reads only the lane blocks each row's live
+    ``[0, kv_length)``) that reads only the key blocks each row's live
     positions reach, where ``decode_attention`` reduces over the whole
     (S, Hkv, Tmax, hd) buffer with the lengths as a mask: a masked
     position contributed exactly 0 there, so leaving it unread is the
-    same arithmetic. A free slot (length 0 or 1) reads one block.
+    same arithmetic.
 
-    Like ``lane_window_append`` it works on the cache's own device layout
-    (positions on the lanes for ``head_dim < 128``: the ``swapaxes`` is a
-    bitcast, no pane is copied or relaid). The buffers stay in HBM and the
-    kernel copies blocks itself (``kv_length`` scalar-prefetched), so HBM
-    traffic is the block-rounded live lengths and nothing is paid for the
-    blocks past them (``LIVE_BLOCK`` positions each); ``Hq // Hkv`` query
-    heads ride one key-value head as rows of its products.
+    It works on the cache's own device layout, and the block axis follows
+    it. ``head_dim < 128``: positions on the lanes (the ``swapaxes`` is a
+    bitcast, no pane is copied or relaid), blocks of ``LIVE_BLOCK`` lanes;
+    a free slot (length 0 or 1) reads one block. ``head_dim == 128``:
+    positions on the sublanes, the buffer read as it lies in blocks of
+    rows (``_rows_block``); there a 'sliding' layer's ring (``window``: the
+    row attends ``(kv_length - window, kv_length)``, position p at index
+    p mod Tmax, ``ring_positions``' arithmetic) is read by position, the
+    blocks its window reaches and a row shorter than the ring its own
+    prefix, and a row that does not decode (``live`` (S,) bool; None:
+    every row does) starts no copy and reads zeros. Either way the buffers
+    stay in HBM and the kernel copies blocks itself (the rows' scalars
+    prefetched), so HBM traffic is the block-rounded live positions and
+    nothing is paid for the blocks past them; ``Hq // Hkv`` query heads ride
+    one key-value head as rows of its products.
 
     Under a mesh (``--serve_tp``) heads shard over the model axis like
     the slot cache; ``interpret=True`` runs on CPU for parity tests."""
     heads = (None, None, MODEL_AXIS, None)
     panes = (None, MODEL_AXIS, None, None)
-    return mesh_kernel(
-        lambda _, *a: _live_attention_local(*a, interpret=interpret),
-        (q, k_cache, v_cache, jnp.asarray(kv_length, jnp.int32)),
-        (heads, panes, panes, (None,)), heads)
+    kv_length = jnp.asarray(kv_length, jnp.int32)
+    if q.shape[-1] < _LANES:
+        local = functools.partial(_live_attention_local, interpret=interpret)
+    else:
+        if live is not None:
+            kv_length = jnp.where(live, kv_length, 0)
+        local = functools.partial(_live_rows_local, window=window,
+                                  interpret=interpret)
+    return mesh_kernel(lambda _, *a: local(*a),
+                       (q, k_cache, v_cache, kv_length),
+                       (heads, panes, panes, (None,)), heads)
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
@@ -335,6 +353,220 @@ def _live_attention_local(q, k_cache, v_cache, kv_length, *, interpret):
         name="live_block_attention",
         interpret=interpret,
     )(jnp.minimum(kv_length, Tmax), qr, t(k_cache), t(v_cache))
+    return out[:, :, :G].reshape(S, 1, Hq, hd)
+
+
+#: what a masked score reads: under the running maximum's start
+#: (``_NEG_BIG``), so its probability is exp(-1e30) = 0 exactly, also for a
+#: query that has seen no live key yet (an old block of a ring lies outside
+#: the window of a chunk's later queries)
+_MASKED = 2 * _NEG_BIG
+
+
+def _ring_age(newest, index, ring_len: int):
+    """How many positions behind the newest one the position at ``index``
+    of a ring of ``ring_len`` lies, the newest at index ``newest`` (both in
+    [0, ring_len)): 0 at ``newest``, 1 at the index before it, around the
+    ring's end. A buffer as long as the sequence is the ring that never
+    wraps. THE ring arithmetic of both kernels that read one by position
+    (``ops/chunk_attention.py::_first_position``; below); no division, so
+    it serves a vector of indices as well as a scalar."""
+    age = newest - index
+    return jnp.where(age < 0, age + ring_len, age)
+
+
+#: key positions of one block of the sublane form: the largest of these
+#: that divides the buffer. Measured on the chip at the cells' four buffer
+#: lengths and their mixes of lengths, and at 192 short rows (PERF.md
+#: section 6, PR 43; ms a call at 128 / 256 / 512): a ring of 4,608 with 7
+#: rows of 48 decoding 0.249 / 0.217 / 0.225, a full layer of 20,480 the
+#: same rows 0.238 / 0.215 / 0.223, of 33,792 with 8 rows 0.353 / 0.298 /
+#: 0.290, two of 3,072 at one key-value head with 72 rows of 192 0.239 /
+#: 0.198 / 0.160, 192 rows of 40-200 positions 0.231 / 0.191 / 0.206 at
+#: one key-value head and 0.350 / 0.351 / 0.618 at eight. 256 is within 3%
+#: of the best wherever rows are long; 512 copies too many dead positions
+#: for short rows at eight heads, 128 pays a step's fixed cost twice
+_ROW_BLOCKS = (256, 128)
+
+
+def _rows_block(Tmax: int):
+    return next((b for b in _ROW_BLOCKS if Tmax % b == 0), None)
+
+
+def live_block_span(kv_length, *, ring_len: int, block: int, window, xp=jnp):
+    """The blocks of a ``ring_len``-position buffer that hold the live
+    positions of rows at ``kv_length`` (an int array; 0: a row that reads
+    nothing): those under the length, in a ring (``window``) the newest
+    ``window`` of them, which may wrap the buffer's end. -> (first block,
+    blocks from it on around the end, the newest position's index, live
+    positions), each like ``kv_length``. ``xp=np`` is the host's twin (the
+    engine's ``kv_touched``)."""
+    n = kv_length if window is not None else xp.minimum(kv_length, ring_len)
+    live = xp.minimum(n, min(window or ring_len, ring_len))
+    oldest = (n - live) % ring_len
+    blocks = xp.minimum((oldest % block + live + block - 1) // block,
+                        ring_len // block)
+    return oldest // block, blocks, (n - 1) % ring_len, live
+
+
+# rows of the scalar-prefetched (5, S + 1) table of ``_live_rows_kernel``
+_FIRST, _BLOCKS, _NEWEST, _LIVE, _NEXT = range(5)
+
+
+def _live_rows_kernel(at_ref, q_ref, k_hbm, v_hbm, o_ref,
+                      kbuf, vbuf, sem, slot_ref, m_ref, d_ref, acc_ref, *,
+                      scale: float, block: int):
+    """``_live_block_kernel`` for the layout ``head_dim`` 128 has on the
+    device (positions on the sublanes: a key block is (block, 128) rows as
+    they lie). One grid cell = ``rows`` slots; for each in turn its live
+    blocks (``live_block_span``, a row of ``at_ref`` each; a ring's may
+    wrap) are copied from HBM into one of two VMEM buffers while the block
+    before is folded into the running max / denominator / accumulator, all
+    heads at once; the one copy in flight is the row's next block or, at its
+    last, the first block of the next row that has any (``_NEXT``), across
+    grid cells too. A row with no live position starts no copy and reads 0.
+    Rows and blocks are rolled (``fori_loop``): one body, lowered once."""
+    rows = q_ref.shape[0]
+    n_rows = rows * pl.num_programs(0)
+    ring_len = k_hbm.shape[2]
+    ring_blocks = ring_len // block
+    first_row = pl.program_id(0) * rows
+
+    def copies(row, b, slot):
+        at = pl.ds(pl.multiple_of(b * block, block), block)
+        return [pltpu.make_async_copy(hbm.at[row, :, at, :], buf.at[slot],
+                                      sem.at[i, slot])
+                for i, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
+
+    def start(row, b, slot):
+        for copy in copies(row, b, slot):
+            copy.start()
+
+    def around(b):
+        return jnp.where(b >= ring_blocks, b - ring_blocks, b)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _first_copy():
+        slot_ref[0] = 0
+        row = at_ref[_NEXT, 0]
+        pl.when(row < n_rows)(lambda: start(row, at_ref[_FIRST, row], 0))
+
+    def one_row(r, slot):
+        row = first_row + r
+        first, n_blocks = at_ref[_FIRST, row], at_ref[_BLOCKS, row]
+        newest, n_live = at_ref[_NEWEST, row], at_ref[_LIVE, row]
+        next_row = at_ref[_NEXT, row + 1]
+        m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
+        d_ref[...] = jnp.zeros_like(d_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q = q_ref[r]                                      # (Hkv, Rp, hd)
+
+        def one_block(j, slot):
+            b = around(first + j)
+            more = j + 1 < n_blocks
+            pl.when(jnp.logical_or(more, next_row < n_rows))(
+                lambda: start(jnp.where(more, row, next_row),
+                              jnp.where(more, around(b + 1),
+                                        at_ref[_FIRST, next_row]),
+                              1 - slot))
+            for copy in copies(row, b, slot):
+                copy.wait()
+            behind = newest - b * block     # of the block's first index
+
+            def seen(shape, axis):      # the block's indices, by position
+                return _ring_age(behind, jax.lax.broadcasted_iota(
+                    jnp.int32, shape, axis), ring_len) < n_live
+
+            # a masked probability is exactly 0, but 0 * NaN is not: what
+            # lies outside a row's live positions never enters a product
+            v = jnp.where(seen((1, block, 1), 1), vbuf[slot],
+                          jnp.zeros((), vbuf.dtype))
+            sc = jax.lax.dot_general(q, kbuf[slot],
+                                     (((2,), (2,)), ((0,), (0,))),
+                                     preferred_element_type=jnp.float32)
+            sc = jnp.where(seen((1, 1, block), 2), sc * scale, _MASKED)
+            m_new = jnp.maximum(m_ref[...],
+                                jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_ref[...] - m_new)
+            p = jnp.exp(sc - m_new)                   # (Hkv, Rp, block)
+            m_ref[...] = m_new
+            d_ref[...] = d_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            # probabilities in the cache's dtype before the value product,
+            # as ``decode_attention`` casts them
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            return 1 - slot
+
+        slot = jax.lax.fori_loop(0, n_blocks, one_block, slot)
+        d = d_ref[...]
+        o_ref[r] = (acc_ref[...] / jnp.where(d == 0.0, 1.0, d)
+                    ).astype(o_ref.dtype)
+        return slot
+
+    # the buffer that the copy in flight (asked for by the block before,
+    # in this cell or the one before it) lands in
+    slot_ref[0] = jax.lax.fori_loop(0, rows, one_row, slot_ref[0])
+
+
+def _query_rows(G: int, itemsize: int) -> int:
+    """A group's query heads as rows of one product, padded to whole
+    sublane tiles of the cache's dtype."""
+    tile = 32 // itemsize
+    return -(-G // tile) * tile
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def _live_rows_local(q, k_cache, v_cache, kv_length, *, window, interpret):
+    # jitted for the reason ``_lane_append_local`` is: one lowering a
+    # program for all its layers of one buffer shape (and window)
+    S, Tq, Hq, hd = q.shape
+    _, Hkv, Tmax, _ = k_cache.shape
+    if Tq != 1:
+        raise ValueError(f"live_block_attention is single-token only; "
+                         f"Tq={Tq}")
+    block = _rows_block(Tmax)
+    G = Hq // Hkv
+    Rp = _query_rows(G, k_cache.dtype.itemsize)
+    qr = q.reshape(S, Hkv, G, hd).astype(k_cache.dtype)
+    if Rp != G:
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Rp - G), (0, 0)))
+    first, blocks, newest, live = live_block_span(
+        kv_length, ring_len=Tmax, block=block, window=window)
+    # the next row after each that has a block to read (S: none), and
+    # before them all the first such row
+    reads = jnp.where(blocks > 0, jnp.arange(S), S)
+    next_row = jnp.append(jax.lax.cummin(reads, reverse=True), S)
+    at = jnp.concatenate([
+        jnp.pad(jnp.stack([first, blocks, newest, live]), ((0, 0), (0, 1))),
+        next_row[None]]).astype(jnp.int32)
+    rows = _live_attention_rows(S)
+    some_rows = pl.BlockSpec((rows, Hkv, Rp, hd),
+                             lambda i, at_ref: (i, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_live_rows_kernel, scale=1.0 / float(hd) ** 0.5,
+                          block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S // rows,),
+            in_specs=[some_rows, in_hbm, in_hbm],
+            out_specs=some_rows,
+            scratch_shapes=[
+                pltpu.VMEM((2, Hkv, block, hd), k_cache.dtype),
+                pltpu.VMEM((2, Hkv, block, hd), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),                  # buffer in use
+                pltpu.VMEM((Hkv, Rp, 1), jnp.float32),        # running max
+                pltpu.VMEM((Hkv, Rp, 1), jnp.float32),        # denominator
+                pltpu.VMEM((Hkv, Rp, hd), jnp.float32),       # accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, Rp, hd), q.dtype),
+        name="live_rows_attention",
+        interpret=interpret,
+    )(at, qr, k_cache, v_cache)
     return out[:, :, :G].reshape(S, 1, Hq, hd)
 
 
@@ -602,17 +834,55 @@ def _live_attention_vmem_bytes(hd: int, S: int, Hkv: int, Hq: int,
             + 3 * Hkv * Rp * LIVE_BLOCK * 4)
 
 
+def _live_rows_vmem_bytes(Tmax: int, hd: int, S: int, Hkv: int, Hq: int,
+                          itemsize: int) -> int:
+    """The same for the sublane form: its blocks are ``_rows_block`` rows
+    of whole lane tiles."""
+    Rp, block = _query_rows(Hq // Hkv, itemsize), _rows_block(Tmax)
+    return (4 * Hkv * block * hd * itemsize
+            + 4 * _live_attention_rows(S) * Hkv * Rp * hd * itemsize
+            + 3 * Hkv * Rp * _LANES * 4
+            + 3 * Hkv * Rp * block * 4)
+
+
 def supports_live_attention(Tq: int, Tmax: int, hd: int, *, S: int, Hkv: int,
-                            Hq: int, dtype) -> bool:
+                            Hq: int, dtype, ring: bool = False) -> bool:
     """``live_block_attention`` eligibility, ``supports_lane_append``'s
-    twin: one query token a row, a float cache whose ``head_dim`` is under
-    a lane tile (positions on the lanes, so the kernel's view is a
-    bitcast), whole lane blocks, whole groups of query heads, and a cell
-    inside the VMEM budget. Whatever this refuses keeps
+    twin: one query token a row, a float cache, whole groups of query
+    heads, whole key blocks and a cell inside the VMEM budget, in one of
+    the two layouts the runtime keeps a cache in: ``head_dim`` under a lane
+    tile (positions on the lanes, so the kernel's view is a bitcast; no
+    ring there), or one whole lane tile (positions on the sublanes, read
+    as they lie; a ring by position). Whatever this refuses keeps
     ``decode_attention``."""
     dtype = jnp.dtype(dtype)
-    return (Tq == 1 and jnp.issubdtype(dtype, jnp.floating)
-            and hd < _LANES and hd % 16 == 0 and Tmax % LIVE_BLOCK == 0
-            and Hq % Hkv == 0
+    if not (Tq == 1 and jnp.issubdtype(dtype, jnp.floating)
+            and Hq % Hkv == 0):
+        return False
+    if hd == _LANES:
+        return (_rows_block(Tmax) is not None
+                and _live_rows_vmem_bytes(Tmax, hd, S, Hkv, Hq,
+                                          dtype.itemsize) <= _VMEM_BUDGET)
+    return (not ring and hd < _LANES and hd % 16 == 0
+            and Tmax % LIVE_BLOCK == 0
             and _live_attention_vmem_bytes(hd, S, Hkv, Hq, dtype.itemsize)
             <= _VMEM_BUDGET)
+
+
+def live_positions_read(kv_length, Tmax: int, hd: int, *, live=None,
+                        window=None) -> int:
+    """Key positions ``live_block_attention`` reads in one layer, its
+    arguments as numpy arrays: the host's twin of the kernels' block
+    arithmetic (the engine's ``kv_touched``). On the lanes every row reads
+    its block-rounded length and a free one one block; on the sublanes
+    ``live_block_span`` of the rows that decode."""
+    import numpy as np
+
+    if hd < _LANES:
+        return int((-(-np.clip(kv_length, 1, Tmax) // LIVE_BLOCK)).sum()
+                   ) * LIVE_BLOCK
+    if live is not None:
+        kv_length = np.where(live, kv_length, 0)
+    block = _rows_block(Tmax)
+    return int(live_block_span(kv_length, ring_len=Tmax, block=block,
+                               window=window, xp=np)[1].sum()) * block

@@ -103,7 +103,7 @@ from building_llm_from_scratch_tpu.obs.timeline import (
 from building_llm_from_scratch_tpu.ops.chunk_attention import (
     chunk_positions_read,
 )
-from building_llm_from_scratch_tpu.ops.decode_step import LIVE_BLOCK
+from building_llm_from_scratch_tpu.ops.decode_step import live_positions_read
 from building_llm_from_scratch_tpu.ops.linear_attention import (
     linear_attention_path,
 )
@@ -379,10 +379,10 @@ class DecodeEngine:
         self.kv_append = ("paged" if self._paged else
                           kv_append_path(self.cache, self.spec_k + 1))
         #: the same for the tick program's attention
-        #: (``decode_attention_path``): "live_blocks" (a row's live lane
-        #: blocks only) | "whole_buffer", or "paged".
-        #: ``_attn_reads``: {(block, buffer length): layers}, block 0 where a
-        #: layer reads its buffer whole: what a tick's ``kv_touched`` counts
+        #: (``decode_attention_path``): "live_blocks" (the key blocks a row's
+        #: live positions reach) | "whole_buffer", or "paged".
+        #: ``_attn_reads``: {(on the kernel's path, buffer length, head_dim,
+        #: a ring's window): layers}: what a tick's ``kv_touched`` counts
         attn_layers = cfg.layers_of("full", "sliding")
         self._attn_reads = collections.Counter(
             self._attention_read(self.cache, l) for l in attn_layers)
@@ -390,7 +390,7 @@ class DecodeEngine:
             self.decode_attention = "paged"
         else:
             self.decode_attention = (
-                "live_blocks" if any(b for b, _ in self._attn_reads)
+                "live_blocks" if any(k[0] for k in self._attn_reads)
                 else "whole_buffer")
         #: the same for the chunk program's attention
         #: (``chunk_attention_path``): "live_blocks" (the key blocks that
@@ -1905,15 +1905,18 @@ class DecodeEngine:
     # -- tracing / tick accounting ----------------------------------------
 
     def _attention_read(self, cache, l: int) -> tuple:
-        """(lane block, buffer length) of layer ``l``'s decode attention:
-        the block ``live_block_attention`` reads by, 0 where the layer
-        reads its buffer whole (``decode_attention_path``)."""
+        """(on the kernel's path, buffer length, ``head_dim``, window) of
+        layer ``l``'s decode attention (``decode_attention_path``): what
+        ``live_positions_read`` needs to count the kernel's reads; any other
+        path reads the buffer whole. ``window``: a ring's, else None."""
         if self._paged:
-            return 0, self._cache_len
-        live = decode_attention_path(
+            return False, self._cache_len, 0, None
+        ring = self.cfg.layer_kind(l) == "sliding"
+        _, _, Tmax, hd = cache["k"][l].shape
+        return (decode_attention_path(
             cache, self.spec_k + 1, self.cfg.n_heads, layer=l,
-            ring=self.cfg.layer_kind(l) == "sliding") == "live_blocks"
-        return LIVE_BLOCK if live else 0, cache["k"][l].shape[2]
+            ring=ring) == "live_blocks", Tmax, hd,
+            self.cfg.sliding_window if ring else None)
 
     def _chunk_read(self, cache, l: int) -> tuple:
         """(on the chunk kernel's path, key positions of the buffer) of
@@ -1931,9 +1934,12 @@ class DecodeEngine:
         """Cache positions this tick's attention has to read, and those it
         does read. Has to: each decoding row's live positions (the one it
         appends included), summed over the layers, a window layer counting
-        no more than its window. Does: every row of the fixed-shape program,
-        free slots too, a layer on the kernel's path its block-rounded
-        length and any other its whole buffer (``_attn_reads``)."""
+        no more than its window. Does (``_attn_reads``): a layer on the
+        kernel's path what ``live_positions_read`` counts for the rows the
+        program is handed (every row's block-rounded length, a free slot's
+        too; where the tick names the rows that decode, ``_step_tail``,
+        and the kernel reads by them, theirs alone, a ring's the blocks its
+        window reaches), any other every row's whole buffer."""
         lengths = self._lengths.tolist()    # plain ints: a few us a tick
         live = [lengths[s] + 1 for s, _ in decoding]
         n_window = self._n_window_layers
@@ -1942,11 +1948,16 @@ class DecodeEngine:
         if n_window:
             window = self.cfg.sliding_window
             total += n_window * sum(min(n, window) for n in live)
-        touched = 0
-        for (block, buffer), layers in self._attn_reads.items():
-            touched += layers * (
-                sum(-(-min(n + 1, buffer) // block) for n in lengths) * block
-                if block else self.n_slots * buffer)
+        kv_length, rows = self._lengths + 1, None
+        if self.cfg.is_moe or self._n_state_layers:
+            rows = np.zeros((self.n_slots,), np.bool_)
+            rows[[s for s, _ in decoding]] = True
+        touched = sum(
+            layers * (live_positions_read(kv_length, buffer, hd, live=rows,
+                                          window=window)
+                      if kernel else self.n_slots * buffer)
+            for (kernel, buffer, hd, window), layers
+            in self._attn_reads.items())
         return total, touched
 
     def _emit_span(self, req: Request) -> None:

@@ -261,8 +261,13 @@ def serve(label: str, reqs_path: str, n: int, extra=(), model=()):
         "--mode", "serve", "--serve_prompts", reqs_path, "--serve_out", out,
         *extra, *model])
     results = read_results(out, n, label)
-    log(f"{label}: {n} requests, "
-        f"{sum(r['n_tokens'] for r in results)} tokens, "
+    serve_line(label, engine, results, t0)
+    return engine, results
+
+
+def serve_line(label: str, engine, results: list, t0: float) -> None:
+    log(f"{label}: {len(results)} requests, "
+        f"{sum(len(r['token_ids']) for r in results)} tokens, "
         f"{engine.n_recompiles} bucket-miss compiles after warmup, "
         f"kv_append {engine.kv_append}, "
         f"decode_attention {engine.decode_attention}, "
@@ -270,7 +275,6 @@ def serve(label: str, reqs_path: str, n: int, extra=(), model=()):
         f"linear_attention {engine.linear_attention}, "
         f"expert_dispatch {engine.expert_dispatch}, "
         f"{time.perf_counter() - t0:.1f}s, peak HBM {peak_hbm_gib()} GiB")
-    return engine, results
 
 
 def check_serve(engine, reqs: list, results: list, label: str,
@@ -292,7 +296,9 @@ def check_serve(engine, reqs: list, results: list, label: str,
     # prompts past the engine's warmup cap compile their bucket on first
     # arrival and report it as a recompile (a bucket miss, by design);
     # anything beyond those is a real one
-    misses = ({engine._bucket_len(len(r["prompt_ids"])) for r in reqs}
+    # (a chunked prefill has one shape and no bucket to miss)
+    misses = (set() if engine.kv_policy.prefill_chunk else
+              {engine._bucket_len(len(r["prompt_ids"])) for r in reqs}
               - set(engine.prompt_buckets()))
     check(engine.n_recompiles == len(misses),
           f"{label}: {engine.n_recompiles} recompiles after warmup, "
@@ -428,6 +434,53 @@ def phase_serve_ssm(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
           f"serve_ssm: the engine's layout is {layout}, its state-space "
           f"layers' forms {engine.selective_scan}")
     check_serve(engine, reqs, results, "serve_ssm", n_generate=2)
+
+
+def phase_serve_rows(shapes=((500, 12, 0.0), (40, 20, 0.0), (300, 8, 0.8),
+                             (17, 16, 0.0), (450, 10, 0.7))) -> None:
+    """The ``head_dim``-128 tick on the chip, no timing: the parallel block
+    of rings and experts at its debug size but with heads of 128 and a
+    context of 640 (``main`` has no flag for either: the engine is built
+    here), chunks of 128 into rings of 384 that three prompts wrap, five
+    requests over three slots. On a TPU the tick attends with
+    ``live_block_attention``'s sublane form in every layer (a ring by
+    position, the rows that do not decode unread) and says so; greedy
+    tokens are held to the one-shot forward and to ``generate()`` like the
+    dense model's."""
+    import jax
+
+    from building_llm_from_scratch_tpu.configs import get_config
+    from building_llm_from_scratch_tpu.models import init_params
+    from building_llm_from_scratch_tpu.serving import (
+        DecodeEngine,
+        KVCachePolicy,
+        SamplingParams,
+    )
+
+    os.makedirs(WORK, exist_ok=True)
+    reqs = make_requests(os.path.join(WORK, "requests_rows.jsonl"), shapes)
+    cfg = get_config("command_a_plus", "218B", debug=True).replace(
+        attn_head_dim=128, context_length=640, sliding_window=256)
+    t0 = time.perf_counter()
+    engine = DecodeEngine(cfg, init_params(cfg, jax.random.PRNGKey(SEED)),
+                          n_slots=3, max_len=640, max_queue=len(reqs),
+                          kv_policy=KVCachePolicy(prefill_chunk=128))
+    handles = [engine.submit(r["prompt_ids"], SamplingParams(**{
+        k: v for k, v in r.items() if k != "prompt_ids"})) for r in reqs]
+    engine.run_until_idle()
+    results = [{"token_ids": list(h.output_ids),
+                "finish_reason": h.finish_reason} for h in handles]
+    serve_line("serve_rows", engine, results, t0)
+    layout = engine.layout()
+    check(layout["kv_positions"] == {"full": 640, "ring": 384},
+          f"serve_rows: the engine's layout is {layout}")
+    if jax.default_backend() == "tpu":
+        check((engine.decode_attention, engine.chunk_attention,
+               engine.kv_append) == ("live_blocks", "live_blocks", "scatter"),
+              f"serve_rows: decode_attention {engine.decode_attention}, "
+              f"chunk_attention {engine.chunk_attention}, kv_append "
+              f"{engine.kv_append} at head_dim 128 on a TPU")
+    check_serve(engine, reqs, results, "serve_rows", n_generate=2)
 
 
 def _load_tests(name: str):
@@ -577,6 +630,33 @@ def phase_kernels() -> None:
               f"kernels: live-block attention is "
               f"{float(np.abs(got - want).max()):.2e} off decode_attention "
               f"on {jnp.dtype(dt).name} panes")
+
+    # the same kernel in the layout head_dim 128 has (positions on the
+    # sublanes: what the rag, longdoc and widechat ticks attend with) vs
+    # decode_attention, at their buffers: a ring wrapped and not, block
+    # edges, a full buffer, rows that do not decode beside long ones
+    rows = jax.jit(live_block_attention, static_argnames="window")
+    for S, Hq, Hkv, Tmax, window in ((48, 128, 8, 4608, 4096),
+                                     (48, 64, 8, 33792, None),
+                                     (192, 20, 1, 3072, None)):
+        top = 4 * Tmax if window else Tmax
+        n = jnp.asarray(([1, 511, 512, 513, top] + np.random.default_rng(
+            SEED).integers(1, top + 1, S - 5).tolist()), jnp.int32)
+        decodes = jnp.arange(S) % 3 != 1
+        q1 = jax.random.normal(ks[0], (S, 1, Hq, 128), jnp.bfloat16)
+        K, V = (jax.random.normal(kk, (S, Hkv, Tmax, 128), jnp.bfloat16)
+                for kk in ks[1:3])
+        ring_kw = ({"kv_positions": ring_positions(n - 1, Tmax),
+                    "window": window} if window else {})
+        want = jax.jit(lambda q, K, V, n: decode_attention(
+            q, K, V, q_positions=(n - 1)[:, None], kv_length=n,
+            **ring_kw))(q1, K, V, n)
+        got = f32(rows(q1, K, V, n, live=decodes, window=window))
+        gap = float(np.abs(got - f32(want))[np.asarray(decodes)].max())
+        check(gap < 2e-2 and not got[~np.asarray(decodes)].any(),
+              f"kernels: live-block attention on sublanes is {gap:.2e} off "
+              f"decode_attention on a buffer of {Tmax}, or a row that does "
+              f"not decode reads something")
 
     # the chunk kernel (what a head_dim-128 engine's chunk program attends
     # with) vs decode_attention on the sliced row, at the rag cell's two
@@ -756,10 +836,11 @@ def main(argv=None) -> int:
                          "are compared with (builder-run)")
     ap.add_argument("--phase", action="append",
                     choices=["train", "serve", "serve_moe", "serve_hybrid",
-                             "serve_ssm", "kernels", "train_remat"],
+                             "serve_ssm", "serve_rows", "kernels",
+                             "train_remat"],
                     help="run only these one-chip phases (default: train, "
                          "serve, serve_moe, serve_hybrid, serve_ssm, "
-                         "kernels)")
+                         "serve_rows, kernels)")
     args = ap.parse_args(argv)
 
     import jax
@@ -788,10 +869,11 @@ def main(argv=None) -> int:
                  "serve_moe": phase_serve_moe,
                  "serve_hybrid": phase_serve_hybrid,
                  "serve_ssm": phase_serve_ssm,
+                 "serve_rows": phase_serve_rows,
                  "kernels": phase_kernels, "train_remat": phase_train_remat}
         phases = {n: table[n] for n in (
             args.phase or ["train", "serve", "serve_moe", "serve_hybrid",
-                           "serve_ssm", "kernels"])}
+                           "serve_ssm", "serve_rows", "kernels"])}
     shutil.rmtree(WORK, ignore_errors=True)
     failed = []
     t_all = time.perf_counter()

@@ -4,6 +4,7 @@ compare two dumps with everything that only names a source stripped.
 
     python scripts/serving_hlo.py dump <tree> <outdir> [program ...]
     python scripts/serving_hlo.py cmp <outdir_a> <outdir_b>
+    python scripts/serving_hlo.py lower <tree> [program ...]
 
 ``dump`` builds the programs as ``DecodeEngine`` jits them (cache donated)
 from the benchmark's own configuration and traffic files, at the cells'
@@ -28,6 +29,14 @@ by-scope trace table reads (``scripts/trace_scope_table.py``). A refactor of
 ``models/transformer.py``'s slot pass or of the engine's builders holds the
 first; the order in which a program's jaxpr creates its operations shows in
 the second (PERF.md section 6, PR 30).
+
+``lower`` builds the same programs and stops before the compiler: the
+seconds of ``jit(...).lower()`` alone, which is the tracing and the kernels'
+lowering to Mosaic in Python that every process repeats before it can ask
+the compile cache (a warm ``setup_s``: PERF.md section 6, PRs 34 and 43).
+Run it where the number is wanted (the chip's host is slower at it than
+this sandbox), one tree a process, the programs in the order a cell's
+process builds them (the chunk before the tick).
 """
 
 import base64
@@ -40,9 +49,11 @@ import sys
 import time
 
 
-def dump(tree: str, outdir: str, only: set) -> None:
+def dump(tree: str, outdir, only: set) -> None:
+    """``outdir`` None: lower only, and print the seconds."""
     sys.path.insert(0, tree)
-    os.makedirs(outdir, exist_ok=True)
+    if outdir is not None:
+        os.makedirs(outdir, exist_ok=True)
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -92,7 +103,11 @@ def dump(tree: str, outdir: str, only: set) -> None:
         if only and name not in only:
             return
         t0 = time.time()
-        compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+        lowered = jax.jit(fn, donate_argnums=(0,)).lower(*args)
+        if outdir is None:
+            print(name, f"lowered in {time.time() - t0:.3f}s", flush=True)
+            return
+        compiled = lowered.compile()
         with open(os.path.join(outdir, name + ".hlo"), "w") as f:
             f.write(compiled.as_text())
         mem = compiled.memory_analysis()
@@ -228,6 +243,8 @@ def compare(a: str, b: str) -> int:
 if __name__ == "__main__":
     if len(sys.argv) >= 4 and sys.argv[1] == "dump":
         dump(os.path.abspath(sys.argv[2]), sys.argv[3], set(sys.argv[4:]))
+    elif len(sys.argv) >= 3 and sys.argv[1] == "lower":
+        dump(os.path.abspath(sys.argv[2]), None, set(sys.argv[3:]))
     elif len(sys.argv) == 4 and sys.argv[1] == "cmp":
         sys.exit(compare(sys.argv[2], sys.argv[3]))
     else:

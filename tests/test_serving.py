@@ -250,10 +250,162 @@ def test_live_block_attention_never_reads_past_a_rows_length(lane_blocks,
     _close(got, _attend_whole(q, K, V, lens), dtype)
 
 
+# -- the same kernel in the layout ``head_dim`` 128 has (positions on the
+# sublanes: blocks of rows, rings by position, rows that do not decode
+# unread) ------------------------------------------------------------------
+
+def _rows_case(lengths, dtype, Hkv, G, T=_ATTN_T, seed=0):
+    return _attn_case(lengths, dtype, G, Hkv=Hkv, T=T, D=128, seed=seed)
+
+
+def _attend_ring(q, K, V, lens, window):
+    from building_llm_from_scratch_tpu.ops.attention import (
+        decode_attention,
+        ring_positions,
+    )
+
+    return decode_attention(
+        q, K, V, q_positions=(lens - 1)[:, None], kv_length=lens,
+        kv_positions=ring_positions(lens - 1, K.shape[2]), window=window)
+
+
+def _live_rows(lane_blocks, q, K, V, lens, **kw):
+    assert lane_blocks._rows_block(K.shape[2]) == _ATTN_B
+    return jax.jit(lambda *a: lane_blocks.live_block_attention(
+        *a, interpret=True, **kw))(q, K, V, lens)
+
+
+@pytest.mark.parametrize("Hkv,G", [(8, 16), (8, 8), (1, 20), (2, 4)],
+                         ids=["rag_8x16", "longdoc_8x8", "widechat_1x20",
+                              "llama_gqa_2x4"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("lengths", [
+    [0, 1, _ATTN_B - 1, _ATTN_B, _ATTN_B + 1, _ATTN_T],
+    # 24 rows are three grid cells of 8: a cell's first block is asked for
+    # by the cell before it, over rows that read nothing
+    [int(n) * (i % 3 > 0) for i, n in enumerate(
+        np.random.default_rng(0).integers(1, _ATTN_T + 1, 24))],
+], ids=["edges", "mixed24_three_cells"])
+def test_live_rows_attention_matches_decode_attention(lane_blocks, lengths,
+                                                      dtype, Hkv, G):
+    """The sublane form at the three cells' head groupings: every row's
+    output equals ``decode_attention``'s on the same panes at each edge of
+    a block; a row of length 0 reads nothing and writes zeros."""
+    q, K, V, lens = _rows_case(lengths, dtype, Hkv, G)
+    got = _live_rows(lane_blocks, q, K, V, lens)
+    live = np.asarray(lens) > 0
+    _close(got[live], _attend_whole(q, K, V, lens)[live], dtype)
+    assert not np.asarray(got[~live].astype(jnp.float32)).any()
+
+
+@pytest.mark.parametrize("window", [256, 200],
+                         ids=["window_whole_blocks", "window_edge_in_block"])
+@pytest.mark.parametrize("lengths", [
+    [1, 100, 200, 256, 257, _ATTN_T],
+    [_ATTN_T + 1, _ATTN_T + 100, 2 * _ATTN_T - 1, 2 * _ATTN_T],
+    [2 * _ATTN_T + 130, 5000, 16384, 3, 0, 300],
+], ids=["unwrapped", "wrapped_once", "wrapped_often_beside_short"])
+def test_live_rows_attention_reads_a_ring_by_position(lane_blocks, lengths,
+                                                      window):
+    """A 'sliding' layer's buffer is a ring of 384 positions written at
+    ``length mod 384``: the kernel attends the last ``window`` positions
+    wherever they lie, as ``decode_attention`` does with ``ring_positions``;
+    a row shorter than the ring reads its own prefix only."""
+    q, K, V, lens = _rows_case(lengths, jnp.float32, 2, 4, seed=2)
+    got = _live_rows(lane_blocks, q, K, V, lens, window=window)
+    live = np.asarray(lens) > 0
+    _close(got[live], _attend_ring(q, K, V, lens, window)[live], jnp.float32)
+
+
+@pytest.mark.parametrize("window", [None, 200], ids=["full", "ring"])
+@pytest.mark.parametrize("garbage", [np.nan, 3e38], ids=["nan", "huge"])
+def test_live_rows_attention_reads_nothing_it_should_not(lane_blocks,
+                                                         garbage, window):
+    """NaN, or the largest finite number, in every position outside a
+    decoding row's live ones (past its length, or behind its window) and in
+    EVERY position of the rows that do not decode, one of them a slot
+    between two prefill chunks at 16k: nothing of it reaches an output, and
+    the rows that do not decode read zeros."""
+    from building_llm_from_scratch_tpu.ops.attention import ring_positions
+
+    lengths = [1, _ATTN_B + 1, 16000, 300, 700 if window else _ATTN_T, 40]
+    live = jnp.asarray([True, True, False, True, True, False])
+    q, K, V, lens = _rows_case(lengths, jnp.float32, 2, 4, seed=3)
+    pos = (ring_positions(lens - 1, _ATTN_T) if window
+           else jnp.broadcast_to(jnp.arange(_ATTN_T), (len(lengths),
+                                                       _ATTN_T)))
+    seen = (pos >= 0) & (pos < lens[:, None]) & live[:, None]
+    if window:
+        seen &= (lens - 1)[:, None] - pos < window
+    dirty = [jnp.where(seen[:, None, :, None], x, jnp.float32(garbage))
+             for x in (K, V)]
+    got = _live_rows(lane_blocks, q, *dirty, lens, live=live, window=window)
+    want = (_attend_ring(q, K, V, lens, window) if window
+            else _attend_whole(q, K, V, lens))
+    _close(got[np.asarray(live)], want[np.asarray(live)], jnp.float32)
+    assert not np.asarray(got[~np.asarray(live)]).any()
+
+
+@pytest.mark.parametrize("lengths,live,window,blocks", [
+    ([130, 384, 0, 5], None, None, 2 + 3 + 0 + 1),
+    ([130, 16000, 0, 5], [True, False, True, True], None, 2 + 0 + 0 + 1),
+    # a ring of 384 and a window of 200: positions 450..649 lie at indices
+    # 66..265, three blocks; a row of 200 reads its own prefix, two;
+    # positions 800..999 lie at indices 32..231, two
+    ([650, 200, 1000], None, 200, 3 + 2 + 2),
+    # the window's newest positions wrap the end: 330..383 and 0..145
+    ([384 + 146], None, 200, 3),
+], ids=["full", "rows_that_do_not_decode", "ring", "ring_wrapping"])
+def test_live_positions_read_counts_the_kernels_blocks(lane_blocks, lengths,
+                                                       live, window, blocks):
+    """The host's twin of the block arithmetic (the engine's
+    ``kv_touched``), and the table the kernel is handed, agree."""
+    n = np.asarray(lengths)
+    live = None if live is None else np.asarray(live)
+    assert lane_blocks.live_positions_read(
+        n, _ATTN_T, 128, live=live, window=window) == blocks * _ATTN_B
+    on_device = lane_blocks.live_block_span(
+        jnp.asarray(np.where(live, n, 0) if live is not None else n),
+        ring_len=_ATTN_T, block=_ATTN_B, window=window)
+    assert int(on_device[1].sum()) == blocks
+    # the lane layout reads every row, a free one one block, and no ring
+    assert lane_blocks.live_positions_read(
+        np.asarray([130, 384, 0, 5]), _ATTN_T, 64,
+        live=np.asarray([True, False, True, True])) == 7 * _ATTN_B
+
+
+@pytest.mark.parametrize("why,want,Tq,ring,backend,kw", [
+    ("head_dim_128", "live_blocks", 1, False, "tpu", {}),
+    ("head_dim_128_ring", "live_blocks", 1, True, "tpu", {}),
+    ("llama_gqa_bf16", "live_blocks", 1, False, "tpu",
+     dict(H=8, T=4096, dtype=jnp.bfloat16)),
+    ("int8_cache", "whole_buffer", 1, False, "tpu",
+     dict(dtype=jnp.int8, quant=True)),
+    ("verify_tq", "whole_buffer", 3, False, "tpu", {}),
+    ("not_whole_blocks", "whole_buffer", 1, False, "tpu", dict(T=192)),
+    ("over_vmem_budget", "whole_buffer", 1, False, "tpu",
+     dict(H=128, T=512)),
+    ("not_a_tpu", "whole_buffer", 1, True, None, {}),
+])
+def test_decode_attention_rule_at_head_dim_128(why, want, Tq, ring, backend,
+                                               kw):
+    """One rule for both layouts: at ``head_dim`` 128 it admits a float
+    cache of whole row blocks, ring or not, and refuses what it refuses
+    under 128 (int8, verify, a cell over the VMEM budget, any other
+    backend)."""
+    from building_llm_from_scratch_tpu.models import transformer as tf
+
+    cache = _append_cache(**{"D": 128, "T": 384, **kw})
+    H = cache["k"][0].shape[1]
+    assert tf.decode_attention_path(cache, Tq, 4 * H, ring=ring,
+                                    backend=backend) == want
+
+
 @pytest.mark.parametrize("why,Tq,kw", [
     ("verify_tq", 3, {}),
     ("int8_cache", 1, dict(dtype=jnp.int8, quant=True)),
-    ("head_dim_128", 1, dict(D=128)),
+    ("head_dim_256", 1, dict(D=256)),
     ("ring_arguments", 1, {}),
     ("tmax_not_lane_multiple", 1, dict(T=192 + 8)),
     ("over_vmem_budget", 1, dict(H=512, D=64)),
@@ -486,6 +638,77 @@ def test_engine_tokens_identical_under_live_block_attention(monkeypatch,
     assert whole[2] == live[2] == L * (13 + 132)
     assert whole[3] == L * S * T                # three whole buffers
     assert live[3] == L * (1 + 2 + 1) * _ATTN_B     # the free slot: one
+
+
+@pytest.mark.parametrize("family,size,kw", [
+    ("command_a_plus", "218B", dict(n_layers=4, sliding_window=256)),
+    ("solar_open2", "250B", dict(n_layers=4)),
+    ("jamba2", "3B", {}),
+], ids=["cohere2_rings", "solar_open2_state", "jamba_mqa"])
+def test_engine_tokens_identical_under_live_rows_attention(monkeypatch,
+                                                           lane_blocks,
+                                                           family, size, kw):
+    """The three ``head_dim``-128 families at their debug sizes, one engine
+    run with the tick's attention on the kernel's sublane form (the rule
+    told it is on a TPU), one on ``decode_attention``: the same greedy and
+    sampled tokens with a slot free, a short request and one whose prompt
+    has wrapped the rings, each engine names its path, and a tick in which
+    the long request decodes alone touches its block-rounded live
+    positions and nothing of the other slots."""
+    import functools
+
+    from building_llm_from_scratch_tpu.configs import get_config
+    from building_llm_from_scratch_tpu.models import transformer as tf
+    from building_llm_from_scratch_tpu.obs.metrics import get_metrics
+    from building_llm_from_scratch_tpu.serving import KVCachePolicy
+    from building_llm_from_scratch_tpu.serving import engine as engine_mod
+
+    T, C, S, n_long = 640, 128, 3, 500
+    cfg = get_config(family, size, debug=True, dtype="fp32").replace(
+        attn_head_dim=128, context_length=T, **kw)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(2, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in (n_long, 5)]
+    cases = [SamplingParams(max_new_tokens=9, temperature=0.0, seed=3,
+                            ignore_eos=True),
+             SamplingParams(max_new_tokens=4, temperature=0.9, top_k=5,
+                            seed=3, ignore_eos=True)]
+
+    def run():
+        eng = DecodeEngine(cfg, params, n_slots=S, max_len=T,
+                           kv_policy=KVCachePolicy(prefill_chunk=C))
+        handles = [eng.submit(p, sp) for p, sp in zip(prompts, cases)]
+        eng.run_until_idle()
+        assert all(h.done and h.finish_reason == "length" for h in handles)
+        assert eng.stats()["decode_attention"] == eng.decode_attention \
+            == eng.healthz_payload()["decode_attention"]
+        assert eng.kv_append == "scatter"
+        tick = [t for t in get_metrics().recent("tick")
+                if t.get("rows") == 1 and not t.get("chunks")][-1]
+        return (eng.decode_attention, [h.output_ids for h in handles],
+                tick["kv_positions"], tick["kv_touched"],
+                [k.shape[2] for k in eng.cache["k"] if k is not None])
+
+    whole = run()
+    on_tpu = functools.partial(tf.decode_attention_path, backend="tpu")
+    monkeypatch.setattr(tf, "decode_attention_path", on_tpu)
+    monkeypatch.setattr(engine_mod, "decode_attention_path", on_tpu)
+    live = run()
+    assert whole[0] == "whole_buffer" and live[0] == "live_blocks"
+    assert live[1] == whole[1]
+    # the last tick: the long request alone, 500 + 7 positions held and one
+    # appended. Every buffer is whole blocks of 128 and of no more: a full
+    # one of 640 reads four; a ring of 256 + 128 holds the newest 256
+    # (indices 252..383 and 0..123: three blocks)
+    buffers, n = live[4], n_long + 8
+    rings = [b for b in buffers if b == 384]
+    assert set(buffers) <= {384, T}
+    assert whole[2] == live[2] == (len(buffers) - len(rings)) * n \
+        + len(rings) * 256
+    assert whole[3] == S * sum(buffers)
+    assert live[3] == (len(buffers) - len(rings)) * 4 * _ATTN_B \
+        + len(rings) * 3 * _ATTN_B
 
 
 def test_slot_reuse_and_seed_reproducibility(model):

@@ -227,6 +227,11 @@ def test_sparse_window_decode_tick_fits_and_copies_no_expert(one_chip,
     assert len(re.findall(r" conditional\(", hlo)) == 32
     entry = hlo[hlo.index("ENTRY "):]
     assert not re.search(r"= bf16\[8,4096,4096\]", entry)
+    # each layer's attention is the live-block kernel, inside scoped VMEM
+    assert [tf.decode_attention_path(cache, 1, cfg.n_heads, layer=l,
+                                     ring=l < 3) for l in range(4)] \
+        == ["live_blocks"] * 4
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 4
 
 
 def test_sparse_window_chunk_program_attends_in_one_kernel_a_layer(
@@ -376,8 +381,103 @@ def test_state_beside_keys_and_values_programs_fit_and_copy_no_expert(
         # values: ROADMAP S4), none a loop over a state
         assert len(re.findall(r" while\(", hlo)) == (2 if name == "tick"
                                                      else 3), name
+        # the tick's one is its full layer's live-block attention
         assert hlo.count('custom_call_target="tpu_custom_call"') == (
-            0 if name == "tick" else 1 + 4), name
+            1 if name == "tick" else 1 + 4), name
+
+
+@pytest.mark.parametrize("family,size,S,cut,buffers,bodies,compiles", [
+    ("command_a_plus", "218B", 48,
+     dict(n_layers=4, vocab_size=32768, context_length=20480,
+          experts_held=tuple(range(8))),
+     [4608, 4608, 4608, 20480], 2, False),
+    ("solar_open2", "250B", 48,
+     dict(n_layers=4, vocab_size=24576, context_length=33792,
+          experts_held=tuple(range(20))),
+     [33792], 1, False),
+    ("jamba2", "3B", 192, dict(context_length=3072), [3072, 3072], 1, True),
+], ids=["rag", "longdoc", "widechat"])
+def test_head_dim_128_decode_tick_lowers_one_kernel_a_buffer_shape(
+        one_chip, monkeypatch, family, size, S, cut, buffers, bodies,
+        compiles):
+    """The set-up guard, as counts (PERF.md section 6, PR 43: every process
+    lowers each distinct kernel to Mosaic in Python before it can ask the
+    compile cache, and the rag cell's warm ``setup_s`` has 0.9 s to spend).
+    The decode tick of each ``head_dim``-128 cell as the engine jits it, at
+    the cell's widths: every attention layer is on the live-block kernel
+    and the lowered text holds ONE body of it a buffer shape, called by
+    every layer of that shape: the rag tick's three rings share one and its
+    full layer has the other, widechat's two layers share one. A layer of a
+    shape already lowered that adds another (a kernel no longer behind one
+    ``jax.jit``, or a static argument that differs by layer) fails here.
+    The widechat tick, which no other test compiles, is also compiled: 192
+    rows at one key-value head fit scoped VMEM inside the whole program."""
+    import re
+
+    from building_llm_from_scratch_tpu.configs import get_config
+    from building_llm_from_scratch_tpu.models import transformer as tf
+    from building_llm_from_scratch_tpu.serving import engine as eng
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config(family, size, dtype="bf16",
+                     target_context_length=None).replace(**cut)
+    e = _holds_nothing(eng, cfg, S, 512)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    shapes = lambda f, *a: jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(f, *a))
+    params = shapes(lambda: tf.init_params(cfg, jax.random.PRNGKey(0)))
+    blocks = (None if cfg.is_moe
+              else shapes(lambda p: tf.unstack_blocks(p, cfg), params))
+    cache = shapes(lambda: tf.init_slot_cache(cfg, S, cfg.context_length,
+                                              policy=e.kv_policy))
+    layers = [l for l, k in enumerate(cache["k"]) if k is not None]
+    assert [cache["k"][l].shape[2] for l in layers] == buffers
+    assert [tf.decode_attention_path(
+        cache, 1, cfg.n_heads, layer=l,
+        ring=cfg.layer_kind(l) == "sliding") for l in layers] \
+        == ["live_blocks"] * len(layers)
+    assert tf.kv_append_path(cache, 1) == "scatter"
+    row = lambda dt: sds((S,), dt)
+    lowered = jax.jit(e._decode_impl, donate_argnums=(0,)).lower(
+        cache, (params, blocks), row(I32), row(I32), sds((S, 2), jnp.uint32),
+        row(I32), row(jnp.float32), row(I32), None, None, None,
+        row(jnp.bool_))
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @_live_rows_local", text)) \
+        == len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", text)) \
+        == bodies
+    assert len(re.findall(r"call @_live_rows_local", text)) == len(layers)
+    if compiles:
+        hlo = lowered.compile().as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') \
+            == len(layers)
+
+
+@pytest.mark.parametrize("S,Hq,Hkv,Tmax,window", [
+    (48, 128, 8, 4608, 4096),    # the rag cell's rings
+    (48, 128, 8, 20480, None),   # and its full layer
+    (48, 64, 8, 33792, None),    # longdoc's one full layer
+    (192, 20, 1, 3072, None),    # widechat: 20 query heads on one
+    (32, 32, 8, 8192, None),     # Llama-3-8B: four query heads a group
+    (16, 64, 8, 4096, None),     # Llama-2-70B's grouping
+])
+def test_live_rows_attention_real_widths(one_chip, S, Hq, Hkv, Tmax, window):
+    """The sublane form at every buffer shape the cells hold, and at the
+    reference's Llama sizes, which the same rule admits."""
+    assert ds.supports_live_attention(1, Tmax, 128, S=S, Hkv=Hkv, Hq=Hq,
+                                      dtype=BF16, ring=window is not None)
+    s = _spec(one_chip)
+    pane = s((S, Hkv, Tmax, 128), BF16)
+    hlo = _compile(
+        lambda q, K, V, n, live: ds.live_block_attention(
+            q, K, V, n, live=live, window=window),
+        s((S, 1, Hq, 128), BF16), pane, pane, s((S,), I32),
+        s((S,), jnp.bool_))
+    assert hlo.count("tpu_custom_call") == 1
+    # the buffers are read as they lie: no pane is copied or relaid
+    assert not [ln for ln in hlo.split("\n")
+                if f"bf16[{S},{Hkv},{Tmax},128]" in ln and " copy(" in ln]
 
 
 def test_paged_decode_attention(one_chip):
@@ -510,6 +610,26 @@ def test_sharded_live_block_attention_on_four_devices(topo):
     assert "bf16[8,3,64,1024]" in hlo and " copy(" not in "".join(
         ln for ln in hlo.split("\n") if "bf16[8,3,1024,64]" in ln
         or "bf16[8,3,64,1024]" in ln)
+
+
+def test_sharded_live_rows_attention_on_four_devices(topo):
+    """The same under ``--serve_tp 4`` at ``head_dim`` 128 (the sublane
+    form, a ring): each device reads the live blocks of its own two
+    key-value heads as they lie, and no pane is copied."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
+                ("data", "seq", "model"))
+    heads = _spec(NamedSharding(mesh, P(None, None, "model")))
+    panes = _spec(NamedSharding(mesh, P(None, "model")))
+    q, pane = heads((8, 1, 32, 128)), panes((8, 8, 4608, 128))
+    whole = lambda shape, dt: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, P()))
+    hlo = _compile(trace_under_mesh(
+        lambda q, K, V, n, live: ds.live_block_attention(
+            q, K, V, n, live=live, window=4096), mesh),
+        q, pane, pane, whole((8,), I32), whole((8,), jnp.bool_))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "bf16[8,2,4608,128]" in hlo and " copy(" not in "".join(
+        ln for ln in hlo.split("\n") if "bf16[8,2,4608,128]" in ln)
 
 
 def test_sharded_chunk_live_attention_on_four_devices(topo):
