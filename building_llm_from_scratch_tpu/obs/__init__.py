@@ -8,6 +8,9 @@ Entry points:
     (obs/metrics.py);
   - ``StepTimeline`` / ``annotate`` / ``window_stats`` — per-step
     wall-clock breakdown + jax.profiler trace annotation (obs/timeline.py);
+  - ``SetupTimeline`` / ``books_init`` / ``emit_setup_record`` — the books
+    of an engine's or a trainer's set-up: every span kept, drained once
+    into one ``setup`` record (obs/timeline.py);
   - ``flops_per_token`` / ``compute_mfu`` / ``mfu_from_flops`` /
     ``format_mfu`` / ``device_specs`` — analytic FLOPs and MFU against
     chip peak, one device-spec table (obs/mfu.py);
@@ -16,7 +19,9 @@ Entry points:
     (obs/health.py);
   - ``CompileWatcher`` / ``aot_compile`` / ``configure_compile_cache`` —
     AOT compile capture, HLO cost/memory analysis, recompile detection,
-    persistent-cache wiring (obs/compile.py);
+    persistent-cache wiring (obs/compile.py); ``program_table``: one
+    ``program`` record a program the process builds, watched or not
+    (trace, lower, cache load or compile, the cache's verdict);
   - ``StallDetector`` — opt-in hung-step flight recorder (obs/stall.py);
   - ``Histogram`` / ``RollingRatio`` / ``render_prometheus`` — serving
     aggregation: fixed-bucket latency histograms, rolling SLO burn-rate
@@ -34,6 +39,7 @@ from building_llm_from_scratch_tpu.obs.compile import (
     CompileWatcher,
     aot_compile,
     configure_compile_cache,
+    program_table,
 )
 from building_llm_from_scratch_tpu.obs.health import (
     describe_health,
@@ -78,8 +84,12 @@ from building_llm_from_scratch_tpu.obs.perf import (
 from building_llm_from_scratch_tpu.obs.stall import StallDetector
 from building_llm_from_scratch_tpu.obs.timeline import (
     NON_STEP_SEGMENTS,
+    SetupTimeline,
     StepTimeline,
     annotate,
+    books_init,
+    emit_setup_record,
+    setup_line,
     window_stats,
 )
 
@@ -105,6 +115,7 @@ __all__ = [
     "CompileWatcher",
     "aot_compile",
     "configure_compile_cache",
+    "program_table",
     "describe_health",
     "first_nonfinite_group",
     "group_health",
@@ -119,7 +130,11 @@ __all__ = [
     "fingerprint_digest",
     "StallDetector",
     "NON_STEP_SEGMENTS",
+    "SetupTimeline",
     "StepTimeline",
     "annotate",
+    "books_init",
+    "emit_setup_record",
+    "setup_line",
     "window_stats",
 ]

@@ -12,6 +12,13 @@ breakdown: arguments / outputs / temps / generated code vs device
 capacity — the OOM postmortem numbers). Each capture lands as one
 ``compile`` event in the metrics JSONL plus gauges.
 
+Every program the process builds, watched or not, also leaves one
+memory-only ``program`` record in the metrics hub (``keep_program_books``:
+one process-wide ``jax.monitoring`` listener, which fires only when JAX
+builds something): tracing, lowering and cache load or compile apart, and
+the persistent cache's verdict by JAX's own events. They are the books the
+set-up metrics are read from (obs/schema.py ``PROGRAM_RECORD_FIELDS``).
+
 A signature change after the first call is a RECOMPILE — the classic silent
 TPU performance bug (a ragged last batch, a dtype drift after resume): the
 watcher emits a ``recompile`` event naming the exact leaf-path shape/dtype
@@ -32,6 +39,7 @@ function (whose implicit compile still happens, just unmeasured).
 from __future__ import annotations
 
 import os
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -241,10 +249,137 @@ def executable_device_count(compiled) -> int:
         return 1
 
 
+# ---------------------------------------------------------------------------
+# The books of what the process builds: one `program` record a program
+# ---------------------------------------------------------------------------
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_TO_MLIR = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_VERDICTS = {"/jax/compilation_cache/cache_hits": "hit",
+                   "/jax/compilation_cache/cache_misses": "miss"}
+
+
+class _Building(threading.local):
+    """What JAX has said, on this thread, of the program being built: its
+    events come on the thread that builds, in the order tracing, lowering,
+    the cache's verdict, the retrieval's seconds, the backend's seconds."""
+
+    #: inside ``aot_compile``: its caller books the program, the listener
+    #: takes the tracing's seconds and the cache's verdict down
+    watched = False
+    cache = "off"
+    retrieval_s: Optional[float] = None
+    lower_s = 0.0
+    #: the longest tracing JAX reported: every nested ``jit`` reports its own
+    #: INSIDE the outer one's, so the outermost is the longest, not the sum
+    trace_s = 0.0
+
+    def take(self) -> Dict[str, Any]:
+        """The cache's part of the record, and the slate wiped for the
+        next program."""
+        out: Dict[str, Any] = {"cache": self.cache}
+        if self.retrieval_s is not None:
+            out["retrieval_s"] = round(self.retrieval_s, 4)
+        self.cache, self.retrieval_s, self.lower_s = "off", None, 0.0
+        self.trace_s = 0.0
+        return out
+
+
+_building = _Building()
+_listening = False
+_listen_lock = threading.Lock()
+
+
+def _on_event(name: str, **_) -> None:
+    verdict = _CACHE_VERDICTS.get(name)
+    if verdict is not None:
+        _building.cache = verdict
+
+
+def _on_duration(name: str, secs: float, **kw) -> None:
+    if name == _TRACE:
+        if _building.watched and secs > _building.trace_s:
+            _building.trace_s = secs
+    elif name == _CACHE_RETRIEVAL:
+        _building.retrieval_s = secs
+    elif _building.watched:
+        return
+    elif name == _TO_MLIR:
+        _building.lower_s = secs
+    elif name == _BACKEND_COMPILE:
+        # a program no watcher wraps (an eager convert_element_type, a
+        # request's _threefry_seed): booked under JAX's name for it
+        lower_s = _building.lower_s
+        _keep_program(str(kw.get("fun_name", "?")), None, round(lower_s, 4),
+                      round(secs, 4), watched=False, **_building.take())
+
+
+def keep_program_books() -> None:
+    """Register THE one process-wide ``jax.monitoring`` listener pair (once;
+    JAX keeps listeners for the life of the process). It fires only when
+    JAX builds something: nothing per call, per tick or per step. Every
+    entry point gets it through ``configure_compile_cache``, every engine
+    and trainer through ``obs/timeline.books_init``, every watched build
+    through ``aot_compile``."""
+    global _listening
+    with _listen_lock:
+        if _listening:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
+
+def _keep_program(label: str, trace_s: Optional[float], lower_s: float,
+                  load_or_compile_s: float, *, watched: bool, cache: str,
+                  retrieval_s: Optional[float] = None) -> None:
+    """One ``program`` record (obs/schema.py ``PROGRAM_RECORD_FIELDS``),
+    stamped now, on the thread that built the program."""
+    rec = {"label": label, "t_end": time.perf_counter(),
+           "time": time.time(), "trace_s": trace_s, "lower_s": lower_s,
+           "load_or_compile_s": load_or_compile_s, "cache": cache,
+           "watched": watched, "thread": threading.current_thread().name}
+    if retrieval_s is not None:
+        rec["retrieval_s"] = retrieval_s
+    get_metrics().keep_record("program", rec)
+
+
+def book_program(label: str, stats: Dict[str, Any]) -> None:
+    """The record of a program ``aot_compile`` built, from its ``stats``."""
+    trace_s = stats.get("trace_seconds", 0.0)
+    _keep_program(label, trace_s,
+                  round(stats.get("lower_seconds", 0.0) - trace_s, 4),
+                  stats.get("backend_compile_seconds", 0.0), watched=True,
+                  cache=stats.get("cache", "off"),
+                  retrieval_s=stats.get("cache_retrieval_seconds"))
+
+
+def program_table(records=None) -> List[Dict[str, Any]]:
+    """The operator's reading of the ``program`` records: label, the three
+    seconds and the cache's verdict, in the order the programs were built."""
+    if records is None:
+        records = get_metrics().recent("program")
+    return [{k: r.get(k) for k in ("label", "trace_s", "lower_s",
+                                   "load_or_compile_s", "cache")}
+            for r in records]
+
+
 def aot_compile(fn: Callable, *args) -> Tuple[Any, Dict[str, Any]]:
     """Explicitly lower+compile a jitted callable for ``args``; returns
     (compiled_executable, stats). Stats carry ``compile_seconds`` split
-    into lower/backend-compile, cost analysis and the memory breakdown.
+    into ``lower_seconds`` (tracing AND lowering, as it always read; of it
+    ``trace_seconds``, the Python function run into a jaxpr, is JAX's own
+    report of the outermost trace) and ``backend_compile_seconds`` (a load
+    from the persistent cache or the compiler: ``cache`` says which, by
+    JAX's own events), cost analysis and the memory breakdown.
+
+    The stages are NOT taken apart by separate ``fn.trace()`` and
+    ``.lower()`` calls: measured on the chip (PERF.md section 6, PR 44) that
+    form costs a warm start 0.15 s a 48-layer program over this one call.
 
     Cost numbers are GLOBAL: ``cost_analysis()`` reports the per-device
     SPMD module (measured: a 2-device-sharded matmul reports half the
@@ -255,16 +390,28 @@ def aot_compile(fn: Callable, *args) -> Tuple[Any, Dict[str, Any]]:
     per-device (it is compared against one device's HBM capacity).
 
     Raises whatever the trace/compile raises — callers own fallback."""
-    t0 = time.perf_counter()
-    lowered = fn.lower(*args)
-    t1 = time.perf_counter()
-    compiled = lowered.compile()
-    t2 = time.perf_counter()
+    keep_program_books()
+    _building.take()
+    _building.watched = True
+    try:
+        t0 = time.perf_counter()
+        lowered = fn.lower(*args)
+        t1 = time.perf_counter()
+        compiled = lowered.compile()
+        t2 = time.perf_counter()
+    finally:
+        _building.watched = False
+        trace_s = _building.trace_s
+        cache = _building.take()
     stats: Dict[str, Any] = {
         "compile_seconds": round(t2 - t0, 4),
+        "trace_seconds": round(min(trace_s, t1 - t0), 4),
         "lower_seconds": round(t1 - t0, 4),
         "backend_compile_seconds": round(t2 - t1, 4),
+        "cache": cache["cache"],
     }
+    if "retrieval_s" in cache:
+        stats["cache_retrieval_seconds"] = cache["retrieval_s"]
     cost = extract_cost_analysis(compiled)
     n_dev = executable_device_count(compiled)
     stats["executable_device_count"] = n_dev
@@ -321,6 +468,10 @@ class CompileWatcher:
         #: carries an "inputs" leaf), for the HLO-measured MFU.
         self.hlo_flops_per_token: Optional[float] = None
         self.memory: Dict[str, int] = {}
+        #: (start, end) of each capture on ``time.perf_counter``: where a
+        #: caller's set-up timeline books the build it cannot see from
+        #: outside (the trainer's first step)
+        self.capture_stamps: List[Tuple[float, float]] = []
 
     # -- internals -------------------------------------------------------
 
@@ -347,7 +498,9 @@ class CompileWatcher:
 
     def _capture(self, sig: Tuple, *args) -> Callable:
         entries_before = self._cache_entries()
+        t0 = time.perf_counter()
         compiled, stats = aot_compile(self._fn, *args)
+        self.capture_stamps.append((t0, time.perf_counter()))
         entries_after = self._cache_entries()
         self.n_compiles += 1
         self.compile_seconds_total += stats["compile_seconds"]
@@ -379,6 +532,7 @@ class CompileWatcher:
                                   and entries_before > 0)
         _notify_collectors("on_compile", self.label, sig, stats,
                            n_tokens=n_tokens)
+        book_program(self.label, stats)
         sink = get_metrics()
         sink.event("compile", **event)
         sink.gauge("compile_seconds_total",
@@ -473,6 +627,7 @@ def configure_compile_cache(cache_dir: Optional[str] = None) -> str:
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
+    keep_program_books()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")

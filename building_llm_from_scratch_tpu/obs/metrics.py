@@ -331,8 +331,11 @@ def render_prometheus(counters: Dict[str, float],
 
 #: Newest rows kept in memory per kind, file or no file (``recent``), for
 #: the kinds that have a reader in the process: ``RECENT_RECORDS`` of a
-#: memory-only kind (``keep_record``: the engine's tick records, a dozen
-#: numbers each; eight minutes of 60 ms ticks) and ``RECENT_FILE_ROWS`` of
+#: memory-only kind (``keep_record``: the engine's ``tick`` records, a dozen
+#: numbers each, eight minutes of 60 ms ticks; one ``program`` record a
+#: program the process builds and one ``setup`` record an engine or
+#: trainer: obs/schema.py lists the fields of all three) and
+#: ``RECENT_FILE_ROWS`` of
 #: the JSONL row types ``RECENT_FILE_KINDS`` (larger: a cadence row carries
 #: every counter and gauge). ``event`` and ``health`` rows go to the file
 #: only.
@@ -429,7 +432,8 @@ class MetricLogger:
 
     def recent(self, kind: str) -> List[Dict[str, Any]]:
         """The newest rows of ``kind``, oldest first: a JSONL row type of
-        ``RECENT_FILE_KINDS`` or a ``keep_record`` kind (``tick``)."""
+        ``RECENT_FILE_KINDS`` or a ``keep_record`` kind (``tick``,
+        ``program``, ``setup``)."""
         with self._lock:
             return list(self._recent.get(kind, ()))
 

@@ -35,7 +35,11 @@ from typing import Any, Dict, FrozenSet, List
 
 #: Bump when a row type or a load-bearing field changes meaning. The
 #: ``header`` row carries it; consumers key parsing decisions on it.
-SCHEMA_VERSION = 16         # v16: serve_warmup gains selective_scan
+SCHEMA_VERSION = 17         # v17: the books of set-up: the memory-only
+                            # `program` and `setup` records, a `setup`
+                            # span root, compile gains trace_seconds
+                            # and cache, serve_warmup gains programs
+                            # (v16: serve_warmup gains selective_scan)
                             # (v15: serve_warmup gains expert_dispatch)
                             # (v14: recurrent state beside keys and
                             # values — serve_warmup gains
@@ -145,6 +149,55 @@ TICK_RECORD_FIELDS = ("tick", "t0", "t1", "t_dispatch", "t_fetch", "phases",
                       "expert_rows", "experts_touched",
                       "state_rows", "state_rows_touched")
 
+#: One memory-only record per program the process builds
+#: (``get_metrics().recent("program")``; obs/compile.py): ``label`` is the
+#: ``CompileWatcher``'s, or JAX's ``fun_name`` for a program no watcher wraps
+#: (``watched`` false: an eager ``convert_element_type``, a request's
+#: ``_threefry_seed``). ``t_end`` is a ``time.perf_counter`` reading (the
+#: clock of the tick records and of a span row's ``t_submit``), ``time`` unix
+#: seconds (the clock of every row of the hub). ``trace_s`` (the Python
+#: function run into a jaxpr: JAX's own report of the outermost trace, every
+#: nested ``jit``'s lying inside it, so the longest and never the sum; None
+#: for a program no watcher wraps), ``lower_s`` (the jaxpr to StableHLO,
+#: each pallas kernel's body to Mosaic in it) and ``load_or_compile_s``
+#: (``.compile()``: a load from the persistent cache or the compiler) are
+#: wall seconds; ``cache`` is ``hit`` / ``miss`` by JAX's own
+#: ``/jax/compilation_cache`` events, ``off`` where the build asked no
+#: cache; a hit adds ``retrieval_s``, the part of the load that read the
+#: entry. ``thread`` names the thread that built it (an engine's loop, a
+#: trainer's, a caller's of its own).
+PROGRAM_RECORD_FIELDS = ("label", "t_end", "time", "trace_s", "lower_s",
+                         "load_or_compile_s", "cache", "watched",
+                         "retrieval_s", "thread")
+
+#: One memory-only record per engine or trainer
+#: (``get_metrics().recent("setup")``, and the ``setup`` span row's twin):
+#: the set-up timeline (obs/timeline.py ``SetupTimeline``) drained once,
+#: when the engine has started or the trainer has made its first blocking
+#: fetch. ``t0`` / ``t_end`` are ``time.perf_counter`` readings, ``time``
+#: is ``t0`` in unix seconds, ``wall_s`` their distance and ``self_s`` the
+#: part of it no phase covers (what the caller did between the phases).
+#: ``spans`` lists every span in the order it opened: ``name``, ``t0``,
+#: ``dur_s``, ``self_s`` (its duration less the spans inside it) and
+#: ``depth`` (0: a phase of ``SETUP_PHASES``); self seconds, the root's
+#: with them, sum to ``wall_s``. ``source`` is ``serve`` or ``train``.
+SETUP_RECORD_FIELDS = ("source", "t0", "t_end", "time", "wall_s", "self_s",
+                       "spans", "replica")
+
+#: The phases of set-up (depth 0 of a ``setup`` record). Serving: ``init``
+#: is ``DecodeEngine.__init__`` (inside it ``weights_layout``: the
+#: per-layer copy of a dense model's stacked weights, and ``cache_alloc``),
+#: ``warmup`` is ``warmup()`` (inside it one ``build:<label>`` a program
+#: call, from the call to its return, and ``first_runs``: from the last
+#: call's return to the fetch that ends warm-up), ``start`` is
+#: ``start()``. Training: ``init`` is the trainer's construction of its
+#: state, optimiser and steps, ``build:train_step`` the first step's trace,
+#: lowering and compile, ``first_runs`` from there to the end of the
+#: first blocking fetch.
+SETUP_PHASES = {"serve": ("init", "warmup", "start"),
+                "train": ("init", "build:train_step", "first_runs")}
+SETUP_BUILD_PREFIX = "build:"
+
 #: Trainer StepTimeline segments (``<segment>_s`` fields of training
 #: cadence metrics rows; obs/timeline.py owns the measurement).
 TRAIN_SEGMENTS = ("data_wait", "dispatch", "host_fetch", "eval", "sample",
@@ -183,7 +236,9 @@ SERVING_LIFECYCLE_EVENTS = ("engine_restart", "drain", "serve_error",
 #: request (same ``request_id``, stamped with pid/incarnation).
 #: ``rpc`` is one server-side RPC handle (method + request_id), so the
 #: merged timeline can show client wait vs server handle per hop.
-SPAN_NAMES = ("request", "worker_request", "rpc")
+#: ``setup`` is the set-up timeline of one engine or trainer, its spans
+#: flattened into the children (``SETUP_RECORD_FIELDS``).
+SPAN_NAMES = ("request", "worker_request", "rpc", "setup")
 
 #: Child span names under a ``request`` root, in lifecycle order.
 #: ``router`` (fleet dispatch hop, serving/router.py) only appears on
@@ -244,14 +299,17 @@ _EVENT_LIST: List[EventSpec] = [
           doc="TokenCache hit/encode (source: memory|disk|encoded)"),
     # -- compile telemetry ------------------------------------------------
     _spec("compile", required=("label",),
-          optional=("compile_seconds", "lower_seconds",
-                    "backend_compile_seconds", "executable_device_count",
+          optional=("compile_seconds", "trace_seconds", "lower_seconds",
+                    "backend_compile_seconds", "cache",
+                    "cache_retrieval_seconds", "executable_device_count",
                     "flops", "flops_per_device", "transcendentals",
                     "bytes_accessed", "memory", "n_compiles",
                     "tokens_per_step", "hbm_capacity_bytes",
                     "hbm_budget_frac", "cache_dir", "cache_entries",
                     "cache_hit"),
-          doc="one AOT compile capture (obs/compile.py)"),
+          doc="one AOT compile capture (obs/compile.py); lower_seconds "
+              "holds trace_seconds; cache is hit|miss|off by JAX's own "
+              "events, cache_hit the guess from the directory's count"),
     _spec("recompile", required=("label",),
           optional=("n_recompiles", "n_changed_leaves", "diff"),
           doc="argument-signature change after the legitimate set closed"),
@@ -410,7 +468,7 @@ _EVENT_LIST: List[EventSpec] = [
                     "pool_pages", "sp", "prompt_pane_tokens", "max_prompt",
                     "kv_append", "decode_attention", "chunk_attention",
                     "linear_attention", "selective_scan",
-                    "expert_dispatch"),
+                    "expert_dispatch", "programs"),
           doc="prefill programs + decode (or spec verify) program "
               "compiled; watchers frozen; records the KVCachePolicy "
               "(quant/chunk/prefix), which append and which attention the "
@@ -418,6 +476,8 @@ _EVENT_LIST: List[EventSpec] = [
               "and which attention the chunk program (chunk_attention), "
               "how each program's rows reach the held experts "
               "(expert_dispatch), "
+              "what each program the process built so far cost (programs: "
+              "label, trace_s, lower_s, load_or_compile_s, cache), "
               "the speculative config "
               "(spec_k/drafter) when on, and the seq-sharded prefill "
               "geometry (sp/prompt_pane_tokens/max_prompt) on "

@@ -76,7 +76,10 @@ from building_llm_from_scratch_tpu.models.transformer import (
     unstack_blocks,
     verify_slots,
 )
-from building_llm_from_scratch_tpu.obs.compile import CompileWatcher
+from building_llm_from_scratch_tpu.obs.compile import (
+    CompileWatcher,
+    program_table,
+)
 from building_llm_from_scratch_tpu.obs.memory import (
     MemoryLedger,
     pytree_nbytes,
@@ -88,6 +91,7 @@ from building_llm_from_scratch_tpu.obs.metrics import (
     render_prometheus,
 )
 from building_llm_from_scratch_tpu.obs.schema import (
+    SETUP_BUILD_PREFIX,
     TICK_BETWEEN,
     TICK_IDLE_WAIT,
     TICK_PHASES,
@@ -99,6 +103,8 @@ from building_llm_from_scratch_tpu.obs.timeline import (
     StepTimeline,
     annotate,
     annotate_step,
+    books_init,
+    emit_setup_record,
 )
 from building_llm_from_scratch_tpu.ops.chunk_attention import (
     chunk_positions_read,
@@ -166,6 +172,7 @@ class DecodeEngine:
     is thread-safe either way.
     """
 
+    @books_init     # self._setup_tl: the books of set-up, `init` open
     def __init__(self, cfg: ModelConfig, params, tokenizer=None, *,
                  n_slots: int = 4, max_len: Optional[int] = None,
                  max_queue: int = 64, max_top_k: int = 64,
@@ -361,9 +368,10 @@ class DecodeEngine:
 
         self.queue = RequestQueue(max_queue)
         self.scheduler = Scheduler(self.n_slots)
-        self.cache = self._place_cache(init_slot_cache(
-            cfg, self.n_slots, self._cache_len,
-            policy=self.kv_policy))                     # guarded-by: _lock
+        with self._setup_tl.span("cache_alloc"):
+            self.cache = self._place_cache(init_slot_cache(
+                cfg, self.n_slots, self._cache_len,
+                policy=self.kv_policy))                 # guarded-by: _lock
         # pin the cache pytree's shardings for the life of the engine:
         # every compiled program constrains its cache OUTPUT to these, so
         # the donated rebind can never drift to a GSPMD-chosen layout
@@ -457,8 +465,9 @@ class DecodeEngine:
         #: it), and at the sparse cell's size 6.25 GB of it + 6.25 GB of
         #: copy + 6.74 GB of cache is 19.2 GB of 16 (PERF.md sections 6
         #: and 7, PR 28; ROADMAP S3)
-        self._blocks = (None if cfg.is_moe
-                        else unstack_blocks(self.params, cfg))
+        with self._setup_tl.span("weights_layout"):
+            self._blocks = (None if cfg.is_moe
+                            else unstack_blocks(self.params, cfg))
         self._weights = (self.params, self._blocks)
         if self.adapters is not None and mesh_plan is not None:
             # the stacked pool rides every compiled call as data — it has
@@ -597,6 +606,8 @@ class DecodeEngine:
         self._generation = 0        # guarded-by: _restart_lock [writes]
         self.n_restarts = 0         # guarded-by: _restart_lock [writes]
         self.warmed_up = False
+        #: the set-up record went to the metrics hub (once, in `start`)
+        self._setup_emitted = False
         # live service-time estimate for SLO-aware admission: EWMAs of
         # per-token decode time and tokens-per-request over finished
         # requests (alpha 0.2 — a few requests of history dominate)
@@ -2513,7 +2524,8 @@ class DecodeEngine:
         import jax
 
         t0 = time.monotonic()
-        with self._lock:
+        build = lambda label: self._setup_tl.span(SETUP_BUILD_PREFIX + label)
+        with self._setup_tl.span("warmup"), self._lock:
             zero_key = np.zeros_like(self._base_keys[0])
             # warm WITH the adapter-pool argument tail when a registry is
             # attached (id −1 = base): the adapter graph is part of THE
@@ -2527,39 +2539,46 @@ class DecodeEngine:
                 # paged: the warmup table is ALL ZEROS — every scatter/
                 # gather rides the pinned trash page, so warming compiles
                 # the real programs without allocating a single page
-                tok, _ok, cache = self._prefill_chunk(
-                    self.cache, self._weights, dummy, np.int32(0),
-                    np.int32(1), np.int32(0),
-                    zero_key, np.float32(0.0), np.int32(0),
-                    *self._paged_tail(self._pool_args_for(np.int32(-1)), 3))
+                with build("serve_prefill_chunk"):
+                    tok, _ok, cache = self._prefill_chunk(
+                        self.cache, self._weights, dummy, np.int32(0),
+                        np.int32(1), np.int32(0),
+                        zero_key, np.float32(0.0), np.int32(0),
+                        *self._paged_tail(
+                            self._pool_args_for(np.int32(-1)), 3))
                 self.cache = cache
                 if self.prefix_store is not None and not self._paged:
                     # paged hit/store are host table writes — the copy/
                     # extract programs exist but are never dispatched
-                    panes = self._prefix_extract(self.cache, np.int32(0),
-                                                 np.int32(1))
-                    self.cache = self._prefix_copy(self.cache, panes,
-                                                   np.int32(0))
+                    with build("serve_prefix_extract"):
+                        panes = self._prefix_extract(
+                            self.cache, np.int32(0), np.int32(1))
+                    with build("serve_prefix_copy"):
+                        self.cache = self._prefix_copy(self.cache, panes,
+                                                       np.int32(0))
             else:
                 buckets = self.prompt_buckets()
                 for Tpb in buckets:
                     dummy = np.zeros((1, Tpb), np.int32)
-                    tok, _ok, cache = self._prefill(
-                        self.cache, self._weights, dummy, np.int32(1),
-                        np.int32(0), zero_key, np.float32(0.0),
-                        np.int32(0), *self._pool_args_for(np.int32(-1)))
+                    with build("serve_prefill"):
+                        tok, _ok, cache = self._prefill(
+                            self.cache, self._weights, dummy, np.int32(1),
+                            np.int32(0), zero_key, np.float32(0.0),
+                            np.int32(0), *self._pool_args_for(np.int32(-1)))
                     self.cache = cache
             # the Tq=k+1 verify program IS the tick program when
             # speculation is on — warm (and freeze) it instead of a
             # plain decode step that would never run
             warm_tokens = (np.zeros((self.n_slots, self.spec_k + 1), np.int32)
                            if self.spec_k else self._last_tokens)
-            nxt, *_, cache = (self._verify or self._decode)(
-                self.cache, self._weights, warm_tokens, self._lengths,
-                self._base_keys, self._n_gen, self._temps, self._topks,
-                *self._step_tail())
+            with build("serve_verify" if self.spec_k else "serve_decode"):
+                nxt, *_, cache = (self._verify or self._decode)(
+                    self.cache, self._weights, warm_tokens, self._lengths,
+                    self._base_keys, self._n_gen, self._temps, self._topks,
+                    *self._step_tail())
             self.cache = cache
-            jax.device_get(nxt)               # block until compiled + ran
+            with self._setup_tl.span("first_runs"):
+                jax.device_get(nxt)           # block until compiled + ran
             if isinstance(self._prefill, CompileWatcher):
                 for w in self._watchers():
                     w.freeze()
@@ -2595,6 +2614,7 @@ class DecodeEngine:
             linear_attention=self.linear_attention,
             selective_scan=self.selective_scan,
             expert_dispatch=self.expert_dispatch,
+            programs=program_table(),
             prefix_pane_tokens=(self._prefix_pane_len
                                 if self.prefix_store is not None
                                 else None),
@@ -2626,10 +2646,29 @@ class DecodeEngine:
     def start(self) -> None:
         if self._thread is not None:
             return
-        self._stop.clear()
-        if self.supervisor is not None:
-            self.supervisor.start()
-        self._spawn_loop()
+        with self._setup_tl.span("start"):
+            self._stop.clear()
+            if self.supervisor is not None:
+                self.supervisor.start()
+            self._spawn_loop()
+        if not self._setup_emitted:
+            # set-up is over: its books go to the hub, once an engine
+            self._setup_emitted = True
+            emit_setup_record(self._setup_record())
+
+    def _setup_record(self) -> dict:
+        record = self._setup_tl.record("serve")
+        if self.replica is not None:
+            record["replica"] = self.replica
+        return record
+
+    def setup_books(self) -> dict:
+        """Why this engine took as long to come up as it did: the set-up
+        timeline so far (obs/schema.py ``SETUP_RECORD_FIELDS``: `init`,
+        `warmup`, `start` and the spans inside them) and what each program
+        the process has built cost (``PROGRAM_RECORD_FIELDS``: trace, lower,
+        cache load or compile; a cache that missed says so)."""
+        return {"record": self._setup_record(), "programs": program_table()}
 
     def _spawn_loop(self) -> None:
         """Start one decode-loop thread bound to the CURRENT generation.
@@ -3026,6 +3065,7 @@ class DecodeEngine:
             out["selective_scan"] = self.selective_scan
             out["expert_dispatch"] = self.expert_dispatch
             out.update(self.layout())
+            out["setup"] = self.setup_books()
             out["memory"] = self.memory_ledger.describe()
             if self._paged:
                 out["page_pool"] = self.page_pool.stats()
@@ -3199,6 +3239,7 @@ class DecodeEngine:
             "selective_scan": self.selective_scan,
             "expert_dispatch": self.expert_dispatch,
             **self.layout(),
+            "setup": self.setup_books(),
             "draining": self.draining,
             "restarts": self.n_restarts,
             # structured snapshot (one probe answers "how is it

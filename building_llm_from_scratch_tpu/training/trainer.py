@@ -57,8 +57,10 @@ from building_llm_from_scratch_tpu.models.lora import merge_lora
 from building_llm_from_scratch_tpu.obs import (
     CompileWatcher,
     StepTimeline,
+    books_init,
     compute_mfu,
     describe_health,
+    emit_setup_record,
     format_mfu,
     get_metrics,
     mfu_from_flops,
@@ -201,6 +203,9 @@ class Trainer:
         self.step_seconds_total = 0.0
         self.prefetch_stall_total = 0
         self.timeline = StepTimeline()
+        #: the books of set-up (obs/timeline.SetupTimeline): `_setup` opens
+        #: them, the first blocking fetch closes them; None outside
+        self._setup_tl = None
         # (epoch, file_index, batch_index) of the NEXT batch to train —
         # written into checkpoint metadata so resume fast-forwards the
         # deterministic shuffled loader to the exact mid-epoch position
@@ -273,6 +278,7 @@ class Trainer:
     # Setup
     # ------------------------------------------------------------------
 
+    @books_init     # self._setup_tl: the books of set-up, `init` open
     def _setup(self, total_steps: int):
         """Build optimizer/schedule/jitted steps once total steps are known
         (the reference computes its cosine horizon the same way,
@@ -968,6 +974,21 @@ class Trainer:
                         self.watchdog.observe(base + i + 1, loss)
                 finally:
                     self._ctx_health = None
+        if self._setup_tl is not None:
+            self._close_setup_books()
+
+    def _close_setup_books(self):
+        """The first blocking fetch has returned: set-up is over. Its
+        timeline (obs/schema.py ``SETUP_PHASES``: `init`, the first step's
+        build from the watcher's stamps, the first runs from there to now)
+        goes to the metrics hub as one record."""
+        tl, self._setup_tl = self._setup_tl, None
+        watcher = self._compile_watcher
+        if watcher is not None and watcher.capture_stamps:
+            t0, t1 = watcher.capture_stamps[0]
+            tl.book("build:train_step", t0, t1)
+            tl.book("first_runs", t1, time.perf_counter())
+        emit_setup_record(tl.record("train"))
 
     def _emit_health_row(self):
         """One ``health`` JSONL row per logging cadence: group names +

@@ -202,6 +202,21 @@ def check_trainer(trainer, label: str, expect_kernels: bool,
         f"{w.compile_seconds_total:.1f}s, {w.n_recompiles} recompiles, "
         f"{n_kernels} "
         f"tpu_custom_call, peak HBM {peak_hbm_gib()} GiB")
+    setup_log(label)
+
+
+def setup_log(label: str, record=None) -> None:
+    """Where set-up went, by the program's own books (obs/timeline.py):
+    ``record``, or the newest one the metrics hub holds (a trainer's)."""
+    from building_llm_from_scratch_tpu.obs import (
+        get_metrics,
+        program_table,
+        setup_line,
+    )
+
+    if record is None:
+        record = (get_metrics().recent("setup") or [None])[-1]
+    log(f"{label}: {setup_line(record, program_table())}")
 
 
 def phase_train(expect_kernels: bool = True, model=(),
@@ -275,6 +290,7 @@ def serve_line(label: str, engine, results: list, t0: float) -> None:
         f"linear_attention {engine.linear_attention}, "
         f"expert_dispatch {engine.expert_dispatch}, "
         f"{time.perf_counter() - t0:.1f}s, peak HBM {peak_hbm_gib()} GiB")
+    setup_log(label, engine.setup_books()["record"])
 
 
 def check_serve(engine, reqs: list, results: list, label: str,

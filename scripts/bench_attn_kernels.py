@@ -5,8 +5,8 @@ amortizes away.
 CAVEAT (r5): the per-rep numbers include the carry reduction over the
 (B, H, T, D) output (~6M-element fp32 sum per rep), which dominates the
 kernels themselves at these shapes — treat the output as RELATIVE between
-configurations sharing a loop shape, and use the xplane profile
-(scripts/profile_xplane.py) for absolute per-kernel times. The r5 sweep's
+configurations sharing a loop shape, and use a device trace's table by
+scope (scripts/trace_scope_table.py) for absolute per-kernel times. The r5 sweep's
 relative result: 512/512 blocks remain best for fwd+bwd with dropout;
 bq=1024/bk=512 ties within noise.
 

@@ -499,6 +499,10 @@ def test_cli_router_path(tmp_path):
     assert len(done) == 6
     assert all(r.get("replica") in (0, 1) for r in done)
     spans = [r for r in rows if r.get("type") == "span"]
+    # each replica's set-up books are a span row of their own
+    setups = [s for s in spans if s["name"] == "setup"]
+    assert sorted(s["replica"] for s in setups) == [0, 1]
+    spans = [s for s in spans if s["name"] == "request"]
     assert len(spans) == 6
     for s in spans:
         assert [c["name"] for c in s["children"]][0] == "router"

@@ -602,10 +602,15 @@ def test_hub_keeps_rows_with_and_without_a_file(model, tmp_path):
         eng.shutdown(drain=False)
         return {h.id for h in handles}
 
+    def requests(hub):
+        # the engine's one `setup` row is a span row too (obs/timeline.py)
+        return [r for r in hub.recent("span") if r["name"] == "request"]
+
     hub = configure_metrics(None)
     ids = run()
-    spans = hub.recent("span")
+    spans = requests(hub)
     assert {s["request_id"] for s in spans} == ids
+    assert len(hub.recent("span")) == len(spans) + 1
     for s in spans:
         assert [c["name"] for c in s["children"]] == [
             "queued", "prefill", "decode"]
@@ -622,7 +627,7 @@ def test_hub_keeps_rows_with_and_without_a_file(model, tmp_path):
     for kind in ("span", "metrics"):
         assert [r for r in rows if r["type"] == kind] == hub.recent(kind)
     assert any(r["type"] == "event" for r in rows)
-    assert {r["request_id"] for r in hub.recent("span")} == ids
+    assert {r["request_id"] for r in requests(hub)} == ids
     assert not any(r["type"] == "tick" for r in rows)   # memory only
     configure_metrics(None)
 
