@@ -94,13 +94,17 @@ from building_llm_from_scratch_tpu.ops.linear_attention import (
     l2norm,
     linear_attention_path,
     recurrent_step,
+    recurrent_step_rows,
 )
 from building_llm_from_scratch_tpu.ops.norms import layernorm, rmsnorm
 from building_llm_from_scratch_tpu.ops.selective_scan import (
+    live_rows_table,
     selective_scan,
     selective_scan_kernel,
     selective_scan_path,
     selective_step,
+    selective_step_rows,
+    supports_step_rows,
 )
 from building_llm_from_scratch_tpu.ops.rope import (
     apply_rope,
@@ -552,9 +556,12 @@ def _linear_mixer(cfg: ModelConfig, p: Params, h: jnp.ndarray, through_state,
     through the short convolution, the gated delta rule, a norm and a gate
     on each head's output, the output projection. ``through_state(run)``
     hands ``run`` the convolution's tail and the state these tokens start
-    from and keeps what it returns: ``run(tail, state, n_valid=None) ->
-    (o, tail, state)``. Positions that ``valid`` (B, T) bool leaves out
-    (padding, a row that does not decode) move no state."""
+    from and keeps what it returns: ``run(tail, state, n_valid=None,
+    rows=None) -> (o, tail, state)``. Positions that ``valid`` (B, T) bool
+    leaves out (padding, a row that does not decode) move no state; with
+    ``rows`` (``_RowsKV.live_rows``: a tick that walks its decoding rows in
+    place, ``state_step_path``) the step reads and writes the named rows'
+    states alone."""
     B, T, _ = h.shape
     H, hd = cfg.linear_heads, cfg.linear_head_dim
     with jax.named_scope("linear_proj"):
@@ -565,14 +572,17 @@ def _linear_mixer(cfg: ModelConfig, p: Params, h: jnp.ndarray, through_state,
         g = jnp.where(valid[:, :, None, None], g, 0.0)
         beta = jnp.where(valid[:, :, None], beta, 0.0)
 
-    def run(tail, state, n_valid=None):
+    def run(tail, state, n_valid=None, rows=None):
         x, tail = causal_conv(pre, tail, p["conv"], n_valid)
         q, k, v = (a.reshape(B, T, H, hd) for a in jnp.split(x, 3, axis=-1))
         q, k = l2norm(q) * hd ** -0.5, l2norm(k)
         if linear_attention_path(T) == "step":
             with jax.named_scope("linear_attention"):
-                o, state = recurrent_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                          beta[:, 0], state)
+                token = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                if rows is not None:
+                    o, state = recurrent_step_rows(*token, state, rows)
+                else:
+                    o, state = recurrent_step(*token, state)
             return o[:, None], tail, state
         o, state = chunked_delta_rule(q, k, v, g, beta, state)
         return o, tail, state
@@ -600,7 +610,7 @@ def _ssm_mixer(cfg: ModelConfig, p: Params, h: jnp.ndarray, through_state,
     with jax.named_scope("ssm_proj"):
         u, z = jnp.split(h @ p["w_in"], 2, axis=-1)
 
-    def run(tail, state, n_valid=None):
+    def run(tail, state, n_valid=None, rows=None):
         with jax.named_scope("ssm_conv"):
             x, tail = causal_conv(u, tail, p["conv"], n_valid, p["conv_b"])
         with jax.named_scope("ssm_proj"):
@@ -619,8 +629,13 @@ def _ssm_mixer(cfg: ModelConfig, p: Params, h: jnp.ndarray, through_state,
         with jax.named_scope("selective_scan"):
             path = selective_scan_path(T, I, N)
             if path == "step":
-                y, state = selective_step(x[:, 0], delta[:, 0], A, Bm[:, 0],
-                                          Cm[:, 0], D, state)
+                token = (x[:, 0], delta[:, 0], A, Bm[:, 0], Cm[:, 0], D)
+                if rows is not None:
+                    y, state = selective_step_rows(
+                        *token, state, rows,
+                        interpret=jax.default_backend() != "tpu")
+                else:
+                    y, state = selective_step(*token, state)
                 return y[:, None], tail, state
             scan = (selective_scan_kernel if path == "kernel"
                     else selective_scan)
@@ -1315,6 +1330,33 @@ def decode_attention_path(cache: Params, Tq: int, n_heads: int, *,
     return "whole_buffer"
 
 
+def state_step_path(cache: Params, kind: str, Tq: int, *, layer: int,
+                    rows_named: bool, backend: Optional[str] = None) -> str:
+    """THE rule for how ``_RowsKV`` steps the state of layer ``layer`` (of
+    ``kind`` 'ssm' or 'linear'), the fourth of the family and made the same
+    way: ``"live_rows"`` (the step walks the rows that decode, in place in
+    the donated buffer, and reads and writes no other row's state: an 'ssm'
+    layer ops/selective_scan.selective_step_rows, one kernel call a layer
+    over a table made once a tick; a 'linear' layer
+    ops/linear_attention.recurrent_step_rows, one trip a row) on a TPU
+    backend for a one-token tick that names its rows (``rows_named``:
+    ``live`` was handed in) at shapes the walk takes (an 'ssm' state of whole
+    groups of 1024 channels and whole tiles of at most 32 states:
+    ``supports_step_rows``; any 'linear' state). ``"whole_buffer"`` (the step
+    over every row and a select that keeps the old state of those that do not
+    decode) for everything else: any other backend, a width that is not whole
+    groups, a tick that names no rows, a verify tick (which ``_RowsKV``
+    refuses anyway). The engine reports the name (``stats()["state_step"]``).
+    ``backend`` is for tests."""
+    if ((backend or jax.default_backend()) != "tpu" or Tq != 1
+            or not rows_named):
+        return "whole_buffer"
+    if kind == "ssm":
+        _, N, I = cache["state"][layer].shape
+        return "live_rows" if supports_step_rows(I, N) else "whole_buffer"
+    return "live_rows" if kind == "linear" else "whole_buffer"
+
+
 def chunk_attention_path(cache: Params, C: int, n_heads: int, *,
                          layer: int = 0,
                          backend: Optional[str] = None) -> str:
@@ -1752,19 +1794,31 @@ class _RowsKV(_SlotKV):
         K, V = self._append(l, k, v, write_at)
         return self._attend(l, q, K, V, ring_kw)
 
+    @cached_property
+    def live_rows(self):
+        """The table of the rows that decode (``live_rows_table``) for the
+        steps that walk them: made once a tick, not once a layer."""
+        return live_rows_table(self._live)
+
     def through_state(self, l, run):
         """Every row one token on; a row that does not decode (free, or
         between two of its prefill chunks) keeps its tail and its state bit
-        for bit."""
+        for bit: ``state_step_path`` says whether because the step walks the
+        decoding rows alone, or by a select over the whole buffer."""
         if self.Tq > 1:
             raise ValueError("a verify tick has no way back from a state "
                              "its rejected drafts have moved")
         tail, state = self.cache["conv"][l], self.cache["state"][l]
-        o, new_tail, new_state = run(tail, state)
+        walk = state_step_path(
+            self.cache, self.cfg.layer_kind(l), self.Tq, layer=l,
+            rows_named=self._live is not None) == "live_rows"
+        o, new_tail, new_state = run(
+            tail, state, None, self.live_rows if walk else None)
         if self._live is not None:
             rows = lambda a: self._live[(slice(None),) + (None,) * (a.ndim - 1)]
             new_tail = jnp.where(rows(tail), new_tail, tail)
-            new_state = jnp.where(rows(state), new_state, state)
+            if not walk:
+                new_state = jnp.where(rows(state), new_state, state)
         self._keep_state(new_tail, new_state)
         return o
 

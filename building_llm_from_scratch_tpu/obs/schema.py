@@ -35,10 +35,14 @@ from typing import Any, Dict, FrozenSet, List
 
 #: Bump when a row type or a load-bearing field changes meaning. The
 #: ``header`` row carries it; consumers key parsing decisions on it.
-SCHEMA_VERSION = 17         # v17: the books of set-up: the memory-only
+SCHEMA_VERSION = 18         # v18: serve_warmup gains state_step; the
+                            # tick record's state_rows_touched counts
+                            # what the step did touch (the decoding
+                            # rows in a layer that walks them)
+                            # (v17: the books of set-up: the memory-only
                             # `program` and `setup` records, a `setup`
                             # span root, compile gains trace_seconds
-                            # and cache, serve_warmup gains programs
+                            # and cache, serve_warmup gains programs)
                             # (v16: serve_warmup gains selective_scan)
                             # (v15: serve_warmup gains expert_dispatch)
                             # (v14: recurrent state beside keys and
@@ -141,7 +145,8 @@ TICK_IDLE_WAIT = "tick.idle_wait"
 #: that got a row: each read its weights once). A model with 'linear' or
 #: 'ssm' layers adds ``state_rows`` (decoding rows x those layers: the recurrent
 #: states the tick had to read and write) and ``state_rows_touched`` (those
-#: the fixed-shape step did read and write: every row's).
+#: its steps did read and write: the decoding rows' in a layer whose step
+#: walks them, ``state_step_path``, every row's in any other).
 TICK_RECORD_FIELDS = ("tick", "t0", "t1", "t_dispatch", "t_fetch", "phases",
                       "rows", "n_slots", "admitted", "queue_depth",
                       "replica", "chunks", "chunk_tokens", "chunk_kv_touched",
@@ -467,7 +472,7 @@ _EVENT_LIST: List[EventSpec] = [
                     "drafter", "replica", "kv_paged", "page_tokens",
                     "pool_pages", "sp", "prompt_pane_tokens", "max_prompt",
                     "kv_append", "decode_attention", "chunk_attention",
-                    "linear_attention", "selective_scan",
+                    "linear_attention", "selective_scan", "state_step",
                     "expert_dispatch", "programs"),
           doc="prefill programs + decode (or spec verify) program "
               "compiled; watchers frozen; records the KVCachePolicy "

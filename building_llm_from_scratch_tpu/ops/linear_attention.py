@@ -92,6 +92,32 @@ def recurrent_step(q, k, v, g, beta, state):
     return o, decayed + k[..., None] * u[..., None, :]
 
 
+@jax.jit     # a program's layers of one shape lower ONE body
+def recurrent_step_rows(q, k, v, g, beta, state, table):
+    """``recurrent_step`` for the rows ``table`` names alone ((S + 1,) int32:
+    the rows that decode in front, their count last; ``live_rows_table``),
+    in place: one trip a named row slices that row's state out, steps it and
+    writes it back where it lay. q, k, g (S, H, dk), v (S, H, dv), beta (S,
+    H), state (S, H, dk, dv) -> (o (S, H, dv), the state). A row that is not
+    named is neither read nor written: it keeps its state bit for bit and
+    its ``o`` reads 0. A trip moves one state in and out (some 8 MB at 64
+    heads of 128 x 128), so a few long trips: the form for few, large
+    states."""
+    S = state.shape[0]
+    row = lambda a, r: jax.lax.dynamic_slice_in_dim(a, r, 1, axis=0)
+    put = lambda a, b, r: jax.lax.dynamic_update_slice_in_dim(a, b, r, axis=0)
+
+    def one(j, carry):
+        o, state = carry
+        r = table[j]
+        o_r, s_r = recurrent_step(*(row(a, r) for a in (q, k, v, g, beta)),
+                                  row(state, r))
+        return put(o, o_r, r), put(state, s_r, r)
+
+    return jax.lax.fori_loop(0, table[S], one,
+                             (jnp.zeros(v.shape, jnp.float32), state))
+
+
 def recurrent_scan(q, k, v, g, beta, state):
     """``recurrent_step`` over T tokens: q, k, g (B, T, H, dk), v (B, T, H,
     dv), beta (B, T, H) -> (o (B, T, H, dv), the state after the last)."""

@@ -172,3 +172,183 @@ def selective_scan_kernel(u, delta, A, Bm, Cm, D, state, *,
     return mesh_kernel(
         lambda _, *a: _kernel_local(*a, interpret=interpret),
         args, tuple(whole(a) for a in args), (whole(u), whole(state)))
+
+
+# -- the tick's step over the rows that decode --------------------------------
+
+#: a row's state, u and delta each have this many VMEM buffers: while one row
+#: is stepped the two after it are on their way in and the one before it is
+#: on its way out (a row is 327,680 B each way, 0.8 us of bandwidth, and is
+#: stepped in some 440 cycles: a walk that waited for each row would be
+#: bound by the copies' latency). The kernel alone on a v5e (PERF.md section
+#: 6, PR 45; ms a call with 0 / 1 / 72 / 192 of 192 rows named, one program
+#: stepping 26 buffers of (192, 16, 5120) in turn, some 0.075 of each call
+#: being that program's own relayouts of u and delta): 0.078 / 0.081 / 0.096
+#: / 0.221, where the step over every row and its select take 0.216 at any
+#: count; in the widechat tick 0.031 a call at 62 decoding rows
+_STEP_BUFFERS = 4
+
+
+def supports_step_rows(inner: int, n_state: int) -> bool:
+    """``selective_step_rows`` eligibility: whole groups of 1024 channels and
+    whole sublane tiles of at most 32 states (the state is walked as it lies
+    in the cache: eight states by 128 channels a register)."""
+    return (inner % GROUP == 0 and n_state % _SUBLANES == 0
+            and 1 <= n_state <= 32)
+
+
+def live_rows_table(live: jnp.ndarray) -> jnp.ndarray:
+    """``live`` (S,) bool -> (S + 1,) int32: the rows that decode, in order,
+    in front (what follows them is S), and their count last. Made once a
+    tick; every layer's walk reads it from scalar memory."""
+    S = live.shape[0]
+    rows = jnp.sort(jnp.where(live, jnp.arange(S, dtype=jnp.int32), S))
+    return jnp.append(rows, jnp.sum(live, dtype=jnp.int32))
+
+
+def state_rows_walked(n_decoding: int, n_slots: int, walked_layers: int,
+                      other_layers: int) -> int:
+    """The host's twin of the walk (the engine's ``state_rows_touched``):
+    the states a tick's steps read and write. A layer on the walk's path
+    (``selective_step_rows``, ``recurrent_step_rows``: the table names the
+    decoding rows and nothing else is copied) touches its decoding rows',
+    any other layer every slot's."""
+    return n_decoding * walked_layers + n_slots * other_layers
+
+
+def _step_rows_kernel(rows_ref, b_ref, c_ref, u_hbm, dt_hbm, a_ref, d_ref,
+                      s_hbm, y_ref, s_out_hbm, sbuf, ubuf, dtbuf, sem,
+                      *, N: int, G: int):
+    """``selective_step`` for the rows ``rows_ref`` names ((S + 1,), the
+    count last), in place in ``s_hbm`` / ``s_out_hbm`` (one buffer, (S, N,
+    I)). b_ref, c_ref (S * N,) scalar memory; u_hbm, dt_hbm (S, 1, I) left in
+    HBM (a row of them is copied as it lies; as (S, I) it would be one
+    sublane of each tile, which no copy may slice); a_ref (N, I), d_ref (1,
+    I) and the output y_ref (S, I) whole in VMEM. A row's state comes in as
+    it lies (registers of eight states by 128 channels), is stepped where it
+    landed and goes back from there, u and delta beside it; its y is stored
+    into its row of y_ref, which starts as zeros and leaves as one block in
+    the layout its consumer reads. No state but a named row's is read or
+    written."""
+    S = s_hbm.shape[0]
+    n = rows_ref[S]
+    y_ref[...] = jnp.zeros_like(y_ref)
+
+    def copies_in(j, k):
+        r = rows_ref[j]
+        return [pltpu.make_async_copy(s_hbm.at[r], sbuf.at[k], sem.at[0, k]),
+                pltpu.make_async_copy(u_hbm.at[r], ubuf.at[k], sem.at[1, k]),
+                pltpu.make_async_copy(dt_hbm.at[r], dtbuf.at[k],
+                                      sem.at[2, k])]
+
+    def copies_out(j, k):
+        return [pltpu.make_async_copy(sbuf.at[k], s_out_hbm.at[rows_ref[j]],
+                                      sem.at[3, k])]
+
+    def start(copies):
+        for copy in copies:
+            copy.start()
+
+    def wait(copies):
+        for copy in copies:
+            copy.wait()
+
+    def step(j, k):
+        r = rows_ref[j]
+        states = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
+
+        def column(n, cols):    # the row's N scalars down the sublanes
+            return tuple(jnp.where(states == n, ref[r * N + n], col)
+                         for ref, col in zip((b_ref, c_ref), cols))
+
+        zeros = jnp.zeros((N, 1), jnp.float32)
+        Bc, Cc = jax.lax.fori_loop(0, N, column, (zeros, zeros))
+
+        def group(g, carry):
+            # a group's 1024 channels at once, as arrays: every read of the
+            # buffers before any write (a write would hold back the reads
+            # behind it), and a few operations to lower
+            at = pl.ds(pl.multiple_of(g * GROUP, GROUP), GROUP)
+            u, dt = ubuf[k, :, at], dtbuf[k, :, at]
+            s = (jnp.exp(dt * a_ref[:, at]) * sbuf[k, :, at]
+                 + Bc * (dt * u))
+            sbuf[k, :, at] = s
+            y_ref[pl.ds(r, 1), at] = (jnp.sum(s * Cc, axis=0, keepdims=True)
+                                      + d_ref[:, at] * u)
+            return carry
+
+        jax.lax.fori_loop(0, G, group, 0)
+
+    for j in range(_STEP_BUFFERS - 2):
+        pl.when(j < n)(lambda j=j: start(copies_in(j, j)))
+
+    def one_row(j, carry):
+        k = j % _STEP_BUFFERS
+        ahead = j + _STEP_BUFFERS - 2
+        k_ahead = ahead % _STEP_BUFFERS
+        # the buffer the row two ahead lands in is the one the row two
+        # behind left from
+        pl.when(j >= 2)(lambda: wait(copies_out(j - 2, k_ahead)))
+        pl.when(ahead < n)(lambda: start(copies_in(ahead, k_ahead)))
+        wait(copies_in(j, k))
+        step(j, k)
+        start(copies_out(j, k))
+        return carry
+
+    jax.lax.fori_loop(0, n, one_row, 0)
+    for back in (2, 1):
+        pl.when(n >= back)(lambda back=back: wait(
+            copies_out(n - back, (n - back) % _STEP_BUFFERS)))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _step_rows_local(table, u, delta, A, Bm, Cm, D, state,
+                     interpret: bool = False):
+    # jitted so that a program's layers of one shape lower ONE body
+    S, N, I = state.shape
+    # a row of u or delta as its own (1, I) array: see the kernel
+    rows = lambda a: a.reshape(S, 1, I)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    f32 = jnp.float32
+    small = pltpu.VMEM((_STEP_BUFFERS, 1, I), f32)
+    return pl.pallas_call(
+        functools.partial(_step_rows_kernel, N=N, G=I // GROUP),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[in_hbm, in_hbm, in_vmem, in_vmem, in_hbm],
+            out_specs=[in_vmem, in_hbm],
+            scratch_shapes=[
+                pltpu.VMEM((_STEP_BUFFERS, N, I), f32), small, small,
+                pltpu.SemaphoreType.DMA((4, _STEP_BUFFERS)),
+            ],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((S, I), f32),
+                   jax.ShapeDtypeStruct((S, N, I), f32)),
+        # operand 7 (the three tables count): the state, in place
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="selective_step_rows",
+        interpret=interpret,
+    )(table, Bm.reshape(S * N), Cm.reshape(S * N), rows(u), rows(delta), A,
+      D.reshape(1, I), state)
+
+
+def selective_step_rows(u, delta, A, Bm, Cm, D, state, table, *,
+                        interpret: bool = False
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``selective_step`` for the rows ``table`` names alone
+    (``live_rows_table``), in place: u, delta (S, I), Bm, Cm (S, N), state
+    (S, N, I) -> (y (S, I), the state). The state of a row that is not named
+    is neither read nor written: it keeps it bit for bit, and its ``y``
+    reads 0. Shapes as ``supports_step_rows`` says."""
+    from building_llm_from_scratch_tpu.parallel.collectives import mesh_kernel
+
+    f32 = lambda a: a.astype(jnp.float32)
+    args = (table,) + tuple(map(f32, (u, delta, A, Bm, Cm, D, state)))
+    whole = lambda a: (None,) * a.ndim
+    return mesh_kernel(
+        lambda _, *a: _step_rows_local(*a, interpret=interpret),
+        args, tuple(whole(a) for a in args), (whole(u), whole(state)))

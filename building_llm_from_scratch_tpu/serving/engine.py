@@ -73,6 +73,7 @@ from building_llm_from_scratch_tpu.models.transformer import (
     kv_append_path,
     prefill_chunk_into_slot,
     prefill_into_slot,
+    state_step_path,
     unstack_blocks,
     verify_slots,
 )
@@ -115,6 +116,7 @@ from building_llm_from_scratch_tpu.ops.linear_attention import (
 )
 from building_llm_from_scratch_tpu.ops.selective_scan import (
     selective_scan_path,
+    state_rows_walked,
 )
 from building_llm_from_scratch_tpu.parallel.collectives import (
     trace_under_mesh,
@@ -435,6 +437,20 @@ class DecodeEngine:
                 self.kv_policy.prefill_chunk or self.max_len, cfg.ssm_inner,
                 cfg.ssm_state)}
             if self._n_ssm_layers else None)
+        #: how the tick program steps those layers' states
+        #: (``state_step_path``): "live_rows" (the step walks the rows that
+        #: decode, in place, and touches no other row's state) |
+        #: "whole_buffer" (every row stepped, a select keeps the old state of
+        #: those that do not decode); None for a model with no such layer.
+        #: ``_state_walks``: the layers on the walk's path, which a tick's
+        #: ``state_rows_touched`` counts by their decoding rows
+        self._state_walks = sum(
+            state_step_path(self.cache, cfg.layer_kind(l), self.spec_k + 1,
+                            layer=l, rows_named=True) == "live_rows"
+            for l in cfg.state_layers)
+        self.state_step = (
+            ("live_rows" if self._state_walks else "whole_buffer")
+            if self._n_state_layers else None)
         #: how each program's rows reach the held experts
         #: (``expert_dispatch_path``): the tick's "per_expert" (one
         #: conditional a held expert), a chunk's "grouped" on a TPU (one
@@ -2139,12 +2155,14 @@ class DecodeEngine:
              self._tick_rec["kv_touched"]) = self._kv_positions_read(decoding)
             if self._n_state_layers:
                 # the states this tick has to read and write (a decoding
-                # row's, a layer that holds one) and those the fixed-shape
-                # step does: every row's
+                # row's, a layer that holds one) and those its step does:
+                # theirs alone in a layer on the walk's path, every row's
+                # in any other
                 self._tick_rec["state_rows"] = (
                     len(decoding) * self._n_state_layers)
-                self._tick_rec["state_rows_touched"] = (
-                    self.n_slots * self._n_state_layers)
+                self._tick_rec["state_rows_touched"] = state_rows_walked(
+                    len(decoding), self.n_slots, self._state_walks,
+                    self._n_state_layers - self._state_walks)
             nxt, ok, cache = self._decode(
                 self.cache, self._weights, self._last_tokens, self._lengths,
                 self._base_keys, self._n_gen, self._temps,
@@ -2613,6 +2631,7 @@ class DecodeEngine:
             chunk_attention=self.chunk_attention,
             linear_attention=self.linear_attention,
             selective_scan=self.selective_scan,
+            state_step=self.state_step,
             expert_dispatch=self.expert_dispatch,
             programs=program_table(),
             prefix_pane_tokens=(self._prefix_pane_len
@@ -3063,6 +3082,7 @@ class DecodeEngine:
             out["chunk_attention"] = self.chunk_attention
             out["linear_attention"] = self.linear_attention
             out["selective_scan"] = self.selective_scan
+            out["state_step"] = self.state_step
             out["expert_dispatch"] = self.expert_dispatch
             out.update(self.layout())
             out["setup"] = self.setup_books()
@@ -3237,6 +3257,7 @@ class DecodeEngine:
             "chunk_attention": self.chunk_attention,
             "linear_attention": self.linear_attention,
             "selective_scan": self.selective_scan,
+            "state_step": self.state_step,
             "expert_dispatch": self.expert_dispatch,
             **self.layout(),
             "setup": self.setup_books(),

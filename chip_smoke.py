@@ -288,6 +288,7 @@ def serve_line(label: str, engine, results: list, t0: float) -> None:
         f"decode_attention {engine.decode_attention}, "
         f"chunk_attention {engine.chunk_attention}, "
         f"linear_attention {engine.linear_attention}, "
+        f"state_step {engine.state_step}, "
         f"expert_dispatch {engine.expert_dispatch}, "
         f"{time.perf_counter() - t0:.1f}s, peak HBM {peak_hbm_gib()} GiB")
     setup_log(label, engine.setup_books()["record"])
@@ -414,13 +415,19 @@ def phase_serve_hybrid(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
     engine, results = serve("serve_hybrid", reqs_path, len(reqs),
                             extra=["--serve_prefill_chunk", "16"],
                             model=HYBRID_DEBUG)
+    import jax
+
     layout = engine.layout()
+    # any 'linear' state is walked by the decoding rows on a TPU
+    walk = "live_rows" if jax.default_backend() == "tpu" else "whole_buffer"
     check(layout["kv_positions"] == {"full": 64}
           and layout["state"]["layers"] == 6
           and engine.linear_attention == {"tick": "step",
-                                          "prefill": "chunked"},
+                                          "prefill": "chunked"}
+          and engine.state_step == walk,
           f"serve_hybrid: the engine's layout is {layout}, its linear "
-          f"layers' forms {engine.linear_attention}")
+          f"layers' forms {engine.linear_attention}, the tick's state step "
+          f"{engine.state_step}")
     check_serve(engine, reqs, results, "serve_hybrid", n_generate=2)
 
 
@@ -435,8 +442,9 @@ def phase_serve_ssm(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
                     ) -> None:
     """``phase_serve_hybrid`` for the other recurrent state: thirteen
     state-space layers' states and tails beside one layer's keys and
-    values, the scan in its XLA forms (the debug width is under the
-    kernel's; ``phase_kernels`` holds the kernel at the cell's)."""
+    values, the scan and the tick's state step in their XLA forms (the debug
+    width is under the kernels'; ``phase_kernels`` holds both kernels at the
+    cell's)."""
     reqs_path = os.path.join(WORK, "requests_ssm.jsonl")
     os.makedirs(WORK, exist_ok=True)
     reqs = make_requests(reqs_path, shapes)
@@ -446,9 +454,11 @@ def phase_serve_ssm(shapes=((5, 12, 0.0), (30, 20, 0.0), (17, 8, 0.8),
     layout = engine.layout()
     check(layout["kv_positions"] == {"full": 64}
           and layout["state"]["layers"] == 13
-          and engine.selective_scan == {"tick": "step", "prefill": "scan"},
+          and engine.selective_scan == {"tick": "step", "prefill": "scan"}
+          and engine.state_step == "whole_buffer",
           f"serve_ssm: the engine's layout is {layout}, its state-space "
-          f"layers' forms {engine.selective_scan}")
+          f"layers' forms {engine.selective_scan}, the tick's state step "
+          f"{engine.state_step}")
     check_serve(engine, reqs, results, "serve_ssm", n_generate=2)
 
 
@@ -757,10 +767,36 @@ def phase_kernels() -> None:
     check(gap < 1e-4, f"kernels: the selective-scan kernel is {gap:.2e} off "
           "the step under a lax.scan")
 
+    # the tick's walk over the decoding rows (what a state-space engine's
+    # tick program runs) vs the step over every row and a select, at the
+    # widechat cell's layer: 192 rows of 5120 channels x 16 states, 72 of
+    # them decoding; the others keep their states bit for bit
+    S = 192
+    live = jnp.zeros((S,), bool).at[
+        jax.random.permutation(ks[0], S)[:72]].set(True)
+    token = (jax.random.normal(ks[1], (S, I)),
+             jnp.exp(jax.random.uniform(ks[2], (S, I), minval=np.log(1e-3),
+                                        maxval=0.0)),
+             args[2], jax.random.normal(ks[3], (S, N)),
+             jax.random.normal(ks[4], (S, N)), args[5])
+    state = jax.random.normal(ks[5], (S, N, I))
+    y_want, s_want = jax.jit(ss.selective_step)(*token, state)
+    y_got, s_got = jax.jit(lambda *a: ss.selective_step_rows(
+        *a, ss.live_rows_table(live)))(*token, state)
+    on = np.asarray(live)
+    gap = max(float(np.abs(f32(y_got) - f32(y_want))[on].max()),
+              float(np.abs(f32(s_got) - f32(s_want))[on].max()))
+    check(ss.supports_step_rows(I, N) and gap < 1e-4
+          and np.array_equal(f32(s_got)[~on], f32(state)[~on])
+          and not f32(y_got)[~on].any(),
+          f"kernels: the walk over the decoding rows is {gap:.2e} off the "
+          "step, or a row that does not decode moved")
+
     n = run_repo_tpu_tests()
     log(f"kernels: fused attention fwd/grad/dropout, fused dropout-add, "
         f"lane-window append, live-block and chunk attention, the "
-        f"grouped experts and the selective scan "
+        f"grouped experts, the selective scan and the tick's walk over the "
+        f"decoding rows "
         f"match their XLA references at the real shapes; "
         f"{n} needs_tpu repo test cases pass; "
         f"{time.perf_counter() - t0:.1f}s")

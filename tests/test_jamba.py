@@ -292,6 +292,165 @@ def test_a_row_that_does_not_decode_keeps_state_and_tail_bit_for_bit(model):
             assert not np.array_equal(a[0], b[0]), (name, l)
 
 
+# -- (d2) the tick's step walks the decoding rows in place -------------------
+
+def step_inputs(S, I, N, seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    u = jax.random.normal(ks[0], (S, I))
+    delta = jax.nn.softplus(jax.random.normal(ks[1], (S, I)))
+    A = -jnp.broadcast_to(jnp.arange(1.0, N + 1)[:, None], (N, I))
+    Bm = jax.random.normal(ks[2], (S, N))
+    Cm = jax.random.normal(ks[3], (S, N))
+    D = jax.random.normal(ks[4], (I,))
+    return (u, delta, A, Bm, Cm, D), jax.random.normal(ks[5], (S, N, I))
+
+
+@pytest.mark.parametrize("S,I,N,live", [
+    (192, 5120, 16, range(0, 192, 3)),              # the cell's widths
+    (8, 5120, 16, [6]),
+    (8, 1024, 8, []),
+    (8, 1024, 8, [0]),
+    (8, 1024, 8, [7]),
+    (8, 1024, 8, [0, 1, 2]),                         # live rows first
+    (8, 1024, 8, [5, 6, 7]),                         # last
+    (8, 2048, 16, [1, 4, 6]),                        # scattered
+    (8, 1024, 8, range(8)),                          # all
+    (192, 1024, 8, range(192)),
+], ids=lambda v: str(len(v)) if not isinstance(v, int) else str(v))
+def test_the_walk_is_the_step_on_the_live_rows_and_reads_no_other(S, I, N,
+                                                                   live):
+    """``selective_step_rows`` (interpret mode) against ``selective_step``
+    and a select. Every state a tick may not read holds NaN and 3e38: those
+    rows come back bit for bit, and nothing non-finite reaches ``y``."""
+    assert ss.supports_step_rows(I, N)
+    token, state = step_inputs(S, I, N, seed=S + I + len(live))
+    mask = np.zeros((S,), bool)
+    mask[list(live)] = True
+    poison = jnp.where(jnp.arange(I) % 2 == 0, jnp.nan, 3e38)
+    state = jnp.where(mask[:, None, None], state, poison)
+    want_y, want_s = ss.selective_step(*token, state)
+    mask = jnp.asarray(mask)
+    table = ss.live_rows_table(mask)
+    assert table[:-1].tolist() == sorted(live) + [S] * (S - len(live))
+    assert int(table[-1]) == len(live)
+    y, new = jax.jit(lambda *a: ss.selective_step_rows(
+        *a, table, interpret=True))(*token, state)
+    dead = ~np.asarray(mask)
+    assert np.array_equal(np.asarray(new)[dead], np.asarray(state)[dead],
+                          equal_nan=True)
+    assert bool(jnp.isfinite(y).all()) and not np.asarray(y)[dead].any()
+    on = np.asarray(mask)
+    assert np.isfinite(np.asarray(new)[on]).all()
+    np.testing.assert_allclose(np.asarray(y)[on], np.asarray(want_y)[on],
+                               rtol=1e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(new)[on], np.asarray(want_s)[on],
+                               rtol=1e-6, atol=2e-6)
+
+
+def test_state_step_path_names_the_form_by_what_the_tick_is(model):
+    cfg = debug_cfg(ssm_inner=1024)
+    cache = tf.init_slot_cache(cfg, 4, cfg.context_length, policy=CHUNKED)
+    l = cfg.state_layers[0]
+    path = lambda **kw: tf.state_step_path(cache, "ssm", kw.pop("Tq", 1), **{
+        "layer": l, "rows_named": True, "backend": "tpu", **kw})
+    assert path() == "live_rows"
+    assert path(backend="cpu") == "whole_buffer"
+    assert path(backend=None) == "whole_buffer"          # this is a CPU
+    assert path(rows_named=False) == "whole_buffer"
+    assert path(Tq=3) == "whole_buffer"
+    # a width that is not whole groups of 1024, states that are not whole
+    # tiles or too many
+    narrow = tf.init_slot_cache(model[0], 4, 64, policy=CHUNKED)
+    assert tf.state_step_path(narrow, "ssm", 1, layer=l, rows_named=True,
+                              backend="tpu") == "whole_buffer"
+    assert ss.supports_step_rows(5120, 16)
+    assert not ss.supports_step_rows(5120, 12)
+    assert not ss.supports_step_rows(5120, 64)
+    assert not ss.supports_step_rows(5000, 16)
+    # the host's twin: decoding rows in a layer that walks, slots elsewhere
+    assert ss.state_rows_walked(72, 192, 26, 0) == 72 * 26
+    assert ss.state_rows_walked(72, 192, 0, 26) == 192 * 26
+    assert ss.state_rows_walked(5, 48, 2, 1) == 5 * 2 + 48
+
+
+def walking(monkeypatch):
+    """The rule as a TPU answers it, in the program and in the engine."""
+    from building_llm_from_scratch_tpu.serving import engine as engine_mod
+
+    rule = tf.state_step_path
+    forced = lambda *a, **kw: rule(*a, **dict(kw, backend="tpu"))
+    monkeypatch.setattr(tf, "state_step_path", forced)
+    monkeypatch.setattr(engine_mod, "state_step_path", forced)
+
+
+def test_a_walked_tick_keeps_the_other_rows_bit_for_bit(monkeypatch):
+    """The tick program on the walk's path: a row that does not decode keeps
+    its state and tail bit for bit though it holds NaN, and the decoding
+    row's logits are those of the whole-buffer form."""
+    cfg = debug_cfg(n_layers=3, layer_kinds=("ssm", "full", "ssm"),
+                    ssm_inner=1024)
+    params = tf.init_params(cfg, jax.random.PRNGKey(3))
+    seq = np.asarray(tokens_of(cfg, 40)[0])
+    cache = tf.init_slot_cache(cfg, 3, cfg.context_length, policy=CHUNKED)
+    _, cache = prefill(cfg, params, cache, seq, 20, 0, True)
+    cache["state"] = [None if a is None else a.at[1:].set(jnp.nan)
+                      for a in cache["state"]]
+    before = jax.tree_util.tree_map(np.asarray, cache)
+    want, _ = decode(cfg, params, cache, seq[20], 20, 0)
+    walking(monkeypatch)
+    got, after = decode(cfg, params, cache, seq[20], 20, 0)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < TOL
+    for name in ("state", "conv"):
+        for l in cfg.state_layers:
+            a, b = before[name][l], np.asarray(after[name][l])
+            assert np.array_equal(a[1:], b[1:], equal_nan=True), (name, l)
+            assert not np.array_equal(a[0], b[0]), (name, l)
+
+
+def test_engine_tokens_identical_with_the_walk_on_and_off(monkeypatch):
+    """Greedy requests through the engine, chunked prefill between decode
+    ticks and slots used again, with the tick's step on either path: the same
+    tokens; on the walk's path every tick's ``state_rows_touched`` is its
+    ``state_rows``, a tick whose one decoding row stands beside a free slot
+    and one between two of its chunks among them."""
+    cfg = debug_cfg(n_layers=3, layer_kinds=("ssm", "full", "ssm"),
+                    ssm_inner=1024)
+    params = tf.init_params(cfg, jax.random.PRNGKey(3))
+    prompts = [np.asarray(tokens_of(cfg, n, seed=n)[0])
+               for n in (5, 40, 23, 9)]
+
+    def serve():
+        eng = DecodeEngine(cfg, params, None, n_slots=3,
+                           max_len=cfg.context_length, kv_policy=CHUNKED,
+                           max_queue=8)
+        first = eng.submit(prompts[0], SamplingParams(max_new_tokens=14,
+                                                      **GREEDY))
+        while not first.output_ids:
+            eng.step()
+        rest = [eng.submit(p, SamplingParams(max_new_tokens=6, **GREEDY))
+                for p in prompts[1:]]
+        eng.run_until_idle()
+        return eng, [list(r.output_ids) for r in [first] + rest]
+
+    eng, want = serve()
+    assert eng.state_step == "whole_buffer"
+    assert eng.stats()["state_step"] == eng.healthz_payload()["state_step"] \
+        == "whole_buffer"
+    mark = get_metrics().recent("tick")[-1]["t1"]
+    walking(monkeypatch)
+    eng, got = serve()
+    assert eng.state_step == eng.stats()["state_step"] == "live_rows"
+    assert got == want
+    ticks = [t for t in get_metrics().recent("tick")
+             if t.get("state_rows") and t["t0"] >= mark]
+    assert ticks and all(
+        t["state_rows_touched"] == t["state_rows"] == 2 * t["rows"]
+        for t in ticks)
+    assert any(t["rows"] == 1 and t.get("chunk_tokens") == CHUNK
+               for t in ticks)
+
+
 def test_coresident_requests_match_the_reference(model):
     """Three requests of unlike lengths through the engine, chunked prefill
     between decode ticks, slots used again: each one's greedy tokens are the

@@ -246,6 +246,10 @@ def test_the_trainer_books_init_the_steps_build_and_its_first_runs(
     datafile.write_text("a stitch in time saves nine, they say. " * 16)
     cfg = train_cfg()
     tok = ByteTokenizer()
+    # what an earlier test of this process built would be served from JAX's
+    # own caches and leave no record here: the constructor's programs are
+    # booked when they are built
+    jax.clear_caches()
     trainer = Trainer(cfg, init_params(cfg, jax.random.PRNGKey(0)), tok,
                       PretrainLoader(tok, batch_size=2, max_length=16),
                       output_dir=str(tmp_path / "out"), eval_freq=4,
@@ -287,12 +291,14 @@ def test_a_setup_timeline_books_a_span_from_its_stamps():
 
 #: sha256 of the source of what the books may not touch, at the parent
 #: commit (fcf8daa): the tick, the watcher's call (its hit path with it),
-#: the trainer's step loop
+#: the trainer's step loop. ``_tick`` as PR 45 left it: its
+#: ``state_rows_touched`` is what the state step did touch
+#: (``state_rows_walked``), no other line moved
 HOT_PATHS = {
     "DecodeEngine.step":
         "a81839718718eba117a43a4d7417f9f077bdd5de6db5318f1ef303635f5174eb",
     "DecodeEngine._tick":
-        "0251c79338402f7be474227e12241fc841edd137a82afbc33c8144f7607c5e2f",
+        "40cbacba0a5d26ff76caceb2df075f4836440e50be86454bb1e96a921bcfe5ba",
     "DecodeEngine._chunk_tick":
         "dd541c60773592879a1b37813e2e0c64132cf2fb1888647b8736b6dcf957fa05",
     "CompileWatcher.__call__":

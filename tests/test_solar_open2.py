@@ -271,6 +271,140 @@ def test_decode_tick_leaves_a_mid_prefill_slot_bit_identical(model):
     assert float(jnp.abs(jnp.stack(got) - want).max()) < 2e-5
 
 
+# -- (d2) the tick's step walks the decoding rows in place -------------------
+
+@pytest.mark.parametrize("S,H,hd,live", [
+    (48, 64, 128, [3, 17, 18, 40]),                 # the cell's widths
+    (6, 4, 16, []),
+    (6, 4, 16, [2]),
+    (6, 4, 16, [0, 1]),                             # live rows first
+    (6, 4, 16, [4, 5]),                             # last
+    (6, 4, 16, [0, 3, 5]),                          # scattered
+    (6, 4, 16, range(6)),                           # all
+], ids=lambda v: str(len(v)) if not isinstance(v, int) else str(v))
+def test_the_walk_is_the_step_on_the_live_rows_and_reads_no_other(S, H, hd,
+                                                                   live):
+    """``recurrent_step_rows`` against ``recurrent_step``. Every state a
+    tick may not read holds NaN and 3e38: those rows come back bit for bit,
+    and nothing non-finite reaches ``o``."""
+    from building_llm_from_scratch_tpu.ops.selective_scan import (
+        live_rows_table,
+    )
+
+    ks = jax.random.split(jax.random.PRNGKey(S + len(live)), 6)
+    q = la.l2norm(jax.random.normal(ks[0], (S, H, hd))) * hd ** -0.5
+    k = la.l2norm(jax.random.normal(ks[1], (S, H, hd)))
+    v = jax.random.normal(ks[2], (S, H, hd))
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (S, H, hd)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (S, H)))
+    mask = np.zeros((S,), bool)
+    mask[list(live)] = True
+    poison = jnp.where(jnp.arange(hd) % 2 == 0, jnp.nan, 3e38)
+    state = jnp.where(mask[:, None, None, None],
+                      jax.random.normal(ks[5], (S, H, hd, hd)), poison)
+    want_o, want_s = la.recurrent_step(q, k, v, g, beta, state)
+    o, new = jax.jit(la.recurrent_step_rows)(
+        q, k, v, g, beta, state, live_rows_table(jnp.asarray(mask)))
+    dead = ~mask
+    assert np.array_equal(np.asarray(new)[dead], np.asarray(state)[dead],
+                          equal_nan=True)
+    assert bool(jnp.isfinite(o).all()) and not np.asarray(o)[dead].any()
+    np.testing.assert_allclose(np.asarray(o)[mask], np.asarray(want_o)[mask],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(new)[mask],
+                               np.asarray(want_s)[mask], rtol=1e-6, atol=1e-6)
+
+
+def walking(monkeypatch):
+    """The rule as a TPU answers it, in the program and in the engine."""
+    from building_llm_from_scratch_tpu.serving import engine as engine_mod
+
+    rule = tf.state_step_path
+    forced = lambda *a, **kw: rule(*a, **dict(kw, backend="tpu"))
+    monkeypatch.setattr(tf, "state_step_path", forced)
+    monkeypatch.setattr(engine_mod, "state_step_path", forced)
+
+
+def test_state_step_path_takes_any_linear_state(model):
+    cfg = model[0]
+    cache = tf.init_slot_cache(cfg, 3, cfg.context_length, policy=CHUNKED)
+    l = cfg.state_layers[0]
+    path = lambda **kw: tf.state_step_path(cache, "linear", kw.pop("Tq", 1),
+                                           **{"layer": l, "rows_named": True,
+                                              "backend": "tpu", **kw})
+    assert path() == "live_rows"
+    assert path(backend="cpu") == path(backend=None) == "whole_buffer"
+    assert path(rows_named=False) == path(Tq=2) == "whole_buffer"
+
+
+def test_a_walked_tick_leaves_a_mid_prefill_slot_bit_identical(model,
+                                                               monkeypatch):
+    """``test_decode_tick_leaves_a_mid_prefill_slot_bit_identical`` on the
+    walk's path, the free slot's states NaN: the decoding row's logits are
+    the whole-buffer form's, and no other row's state or tail moved."""
+    cfg, params, _ = model
+    seq = np.asarray(tokens_of(cfg, 45, seed=8)[0])
+    other = np.asarray(tokens_of(cfg, 20, seed=9)[0])
+    cache = tf.init_slot_cache(cfg, 3, cfg.context_length, policy=CHUNKED)
+    _, cache = prefill(cfg, params, cache, other, 19, 0, True)
+    _, cache = prefill(cfg, params, cache, seq, 16, 1, True)     # chunk one
+    cache["state"] = [None if a is None else a.at[2].set(jnp.nan)
+                      for a in cache["state"]]
+    kept = lambda c: jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda a: np.asarray(a[1:]), {n: c[n] for n in ("state", "conv")}))
+    before = kept(cache)
+    want, _ = decode(cfg, params, cache, other[19], 19, 0, others=[(1, 16)])
+    walking(monkeypatch)
+    got, after = decode(cfg, params, cache, other[19], 19, 0,
+                        others=[(1, 16)])
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    for a, b in zip(before, kept(after)):
+        assert a.tobytes() == b.tobytes()
+    assert all(not np.array_equal(np.asarray(cache["state"][l][0]),
+                                  np.asarray(after["state"][l][0]))
+               for l in cfg.state_layers)
+
+
+def test_engine_tokens_identical_with_the_walk_on_and_off(model, monkeypatch):
+    """Greedy requests through the engine, chunked prefill between decode
+    ticks and slots used again, with the tick's step on either path: the same
+    tokens; on the walk's path every tick's ``state_rows_touched`` is its
+    ``state_rows``, a tick whose one decoding row stands beside a free slot
+    and one between two of its chunks among them."""
+    cfg, params, _ = model
+    prompts = [np.asarray(tokens_of(cfg, n, seed=s)[0])
+               for n, s in ((5, 12), (38, 11), (13, 13), (33, 14))]
+
+    def serve():
+        eng = DecodeEngine(cfg, params, None, n_slots=3, kv_policy=CHUNKED,
+                           max_queue=8)
+        first = eng.submit(prompts[0], SamplingParams(max_new_tokens=14,
+                                                      **GREEDY))
+        while not first.output_ids:
+            eng.step()
+        rest = [eng.submit(p, SamplingParams(max_new_tokens=6, **GREEDY))
+                for p in prompts[1:]]
+        eng.run_until_idle()
+        return eng, [list(r.output_ids) for r in [first] + rest]
+
+    eng, want = serve()
+    assert eng.state_step == eng.stats()["state_step"] == "whole_buffer"
+    mark = get_metrics().recent("tick")[-1]["t1"]
+    walking(monkeypatch)
+    eng, got = serve()
+    assert eng.state_step == eng.healthz_payload()["state_step"] \
+        == "live_rows"
+    assert got == want
+    ticks = [t for t in get_metrics().recent("tick")
+             if t.get("state_rows") and t["t0"] >= mark]
+    assert ticks and all(
+        t["state_rows_touched"] == t["state_rows"] == 6 * t["rows"]
+        for t in ticks)
+    assert any(t["rows"] == 1 and t.get("chunk_tokens") == CHUNK
+               for t in ticks)
+
+
 @pytest.mark.parametrize("policy", [CHUNKED, KVCachePolicy()],
                          ids=["chunked", "monolithic"])
 def test_engine_tokens_match_reference_and_slot_reuse_is_clean(model, policy):

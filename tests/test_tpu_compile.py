@@ -323,9 +323,11 @@ def test_state_beside_keys_and_values_programs_fit_and_copy_no_expert(
     the tick's every routed expert sits behind its own conditional (4 layers
     x 20 held) where the chunk's 512 rows reach theirs in ONE grouped kernel
     a layer with no conditional left (PR 37), and no copy of a layer's
-    experts is made on the way in; the tick runs the one-token step (no loop
-    over a state), the chunk the chunked form (one loop over sub-chunks a
-    linear layer) and its full layer ONE attention kernel."""
+    experts is made on the way in; the tick steps each linear layer's state
+    one decoding row a trip (``state_step_path``: one loop a linear layer,
+    the state still aliased through: PR 45), the chunk runs the chunked form
+    (one loop over sub-chunks a linear layer) and its full layer ONE
+    attention kernel."""
     import re
 
     from building_llm_from_scratch_tpu.configs import get_config
@@ -377,9 +379,9 @@ def test_state_beside_keys_and_values_programs_fit_and_copy_no_expert(
             80 if name == "tick" else 0), name
         entry = hlo[hlo.index("ENTRY "):]
         assert not re.search(r"= bf16\[20,(?:4096,1280|1280,4096)\]", entry)
-        # the tick's two are the head_dim-128 scatter append's (keys,
-        # values: ROADMAP S4), none a loop over a state
-        assert len(re.findall(r" while\(", hlo)) == (2 if name == "tick"
+        # the tick's: the head_dim-128 scatter append's two (keys, values:
+        # ROADMAP S4) and one walk over the decoding rows a linear layer
+        assert len(re.findall(r" while\(", hlo)) == (2 + 3 if name == "tick"
                                                      else 3), name
         # the tick's one is its full layer's live-block attention
         assert hlo.count('custom_call_target="tpu_custom_call"') == (
@@ -410,8 +412,13 @@ def test_head_dim_128_decode_tick_lowers_one_kernel_a_buffer_shape(
     full layer has the other, widechat's two layers share one. A layer of a
     shape already lowered that adds another (a kernel no longer behind one
     ``jax.jit``, or a static argument that differs by layer) fails here.
-    The widechat tick, which no other test compiles, is also compiled: 192
-    rows at one key-value head fit scoped VMEM inside the whole program."""
+    The same count for the state step's walk (PR 45): widechat's 26 'ssm'
+    layers call ONE ``selective_step_rows`` body, longdoc's three 'linear'
+    layers ONE ``recurrent_step_rows``. The widechat tick, which no other
+    test compiles, is also compiled: 192 rows at one key-value head and the
+    walk's four rows in flight fit scoped VMEM inside the whole program,
+    every state is aliased through, and no copy of a (192, 16, 5120) float32
+    buffer is made."""
     import re
 
     from building_llm_from_scratch_tpu.configs import get_config
@@ -444,14 +451,40 @@ def test_head_dim_128_decode_tick_lowers_one_kernel_a_buffer_shape(
         row(I32), row(jnp.float32), row(I32), None, None, None,
         row(jnp.bool_))
     text = lowered.as_text()
+    # the state step's walk (PR 45): ONE body for every 'ssm' layer (a
+    # kernel), one for every 'linear' layer (a loop, no kernel)
+    kinds = [cfg.layer_kind(l) for l in cfg.state_layers]
+    assert [tf.state_step_path(cache, kind, 1, layer=l, rows_named=True)
+            for l, kind in zip(cfg.state_layers, kinds)] \
+        == ["live_rows"] * len(kinds)
+    for kind, body in (("ssm", "_step_rows_local"),
+                       ("linear", "recurrent_step_rows")):
+        n = kinds.count(kind)
+        assert len(re.findall(rf"func\.func private @{body}\b", text)) \
+            == min(n, 1), kind
+        assert len(re.findall(rf"call @{body}\b", text)) == n, kind
+    ssm = kinds.count("ssm")
     assert len(re.findall(r"func\.func private @_live_rows_local", text)) \
-        == len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", text)) \
         == bodies
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", text)) \
+        == bodies + min(ssm, 1)
     assert len(re.findall(r"call @_live_rows_local", text)) == len(layers)
     if compiles:
-        hlo = lowered.compile().as_text()
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
         assert hlo.count('custom_call_target="tpu_custom_call"') \
-            == len(layers)
+            == len(layers) + ssm
+        # the aliasing took: every state goes through the walk in place,
+        # and nothing in the program holds a second (192, 16, 5120) buffer
+        held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(cache))
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == held
+        assert memory.temp_size_in_bytes < 0.3e9
+        state = rf"f32\[{S},{cfg.ssm_state},{cfg.ssm_inner}\]"
+        assert len(re.findall(state, hlo)) > ssm
+        made = set(re.findall(rf"= {state}\S* ([\w\-]+)\(", hlo))
+        assert made <= {"parameter", "get-tuple-element"}, made
 
 
 @pytest.mark.parametrize("S,Hq,Hkv,Tmax,window", [
@@ -543,6 +576,57 @@ def test_selective_scan_real_widths(one_chip, B, T):
                    s((B, T, I), f32), s((N, I), f32), s((B, T, N), f32),
                    s((B, T, N), f32), s((I,), f32), s((B, N, I), f32))
     assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("S", [192, 8])
+def test_selective_step_rows_real_widths(one_chip, S):
+    """The tick's walk over the decoding rows at the state-space cell's layer
+    (5120 channels of 16 float32 states): the rule admits the shape, a row's
+    (1, 5120) operands are copied as they lie (no slice off the tiling), the
+    call is ONE kernel, and the state is written in place: the program holds
+    no second (S, 16, 5120) buffer."""
+    import re
+
+    from building_llm_from_scratch_tpu.ops import selective_scan as ss
+
+    I, N = 5120, 16
+    assert ss.supports_step_rows(I, N)
+    s = _spec(one_chip)
+    f32 = jnp.float32
+
+    def step(u, delta, A, Bm, Cm, D, state, live):
+        return ss.selective_step_rows(u, delta, A, Bm, Cm, D, state,
+                                      ss.live_rows_table(live))
+
+    compiled = jax.jit(step, donate_argnums=(6,)).lower(
+        s((S, I), f32), s((S, I), f32), s((N, I), f32), s((S, N), f32),
+        s((S, N), f32), s((I,), f32), s((S, N, I), f32),
+        s((S,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().alias_size_in_bytes == S * N * I * 4
+    made = set(re.findall(rf"= f32\[{S},{N},{I}\]\S* ([\w\-]+)\(", hlo))
+    assert made <= {"parameter", "get-tuple-element"}, made
+
+
+def test_step_rows_under_serve_tp_on_four_devices(topo):
+    """The walk under ``--serve_tp 4``: GSPMD refuses a bare Mosaic call, so
+    the step shard_maps itself with every operand whole on every shard, as
+    ``selective_scan_kernel`` does."""
+    from building_llm_from_scratch_tpu.ops import selective_scan as ss
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
+                ("data", "seq", "model"))
+    whole = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, P()))
+    S, I, N = 8, 5120, 16
+    hlo = _compile(trace_under_mesh(
+        lambda u, delta, A, Bm, Cm, D, state, live: ss.selective_step_rows(
+            u, delta, A, Bm, Cm, D, state, ss.live_rows_table(live)),
+        mesh), whole((S, I)), whole((S, I)), whole((N, I)), whole((S, N)),
+        whole((S, N)), whole((I,)), whole((S, N, I)),
+        whole((S,), jnp.bool_))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_xent_fwd_largest_admitted_shape(one_chip):
